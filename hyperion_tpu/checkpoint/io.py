@@ -345,7 +345,15 @@ def export_gathered(path: str | Path, params: Any) -> Path | None:
 
 
 def load_gathered(path: str | Path) -> dict:
-    """Read an exported `.npz` back into a nested param dict."""
+    """Read an exported `.npz` back into a nested param dict. The npy
+    format has no name for bfloat16 and hands such leaves back as
+    2-byte void; `export_gathered` writes no other 2-byte void type, so
+    they are viewed as the bfloat16 they were."""
+    import ml_dtypes
+
     with np.load(path) as z:
         flat = {k: z[k] for k in z.files}
+    for k, v in flat.items():
+        if v.dtype == np.dtype("V2"):
+            flat[k] = v.view(ml_dtypes.bfloat16)
     return traverse_util.unflatten_dict(flat, sep="/")

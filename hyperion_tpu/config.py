@@ -52,15 +52,6 @@ class OptimizationConfig:
     compile_tier: str = "jit"        # jit | jit+pallas (compile_bench variants)
     attention_impl: str | None = None  # override just attention: xla | pallas
     donate_state: bool = True        # buffer donation into the train step
-    # persistent XLA compilation cache directory (cli/main.py resolves
-    # it to a per-backend subdir and points jax at it in-process —
-    # never by mutating the environment). Empty = the
-    # HYPERION_COMPILE_CACHE env var, else no persistent cache. With a
-    # cache, `--supervise` restarts and mid-epoch resumes skip the
-    # multi-minute train-step recompile. Caution: on this deployment's
-    # CPU backend reloading a cached executable can abort the process
-    # (the bench.py import-leak postmortem) — use on real chips.
-    compile_cache: str = ""
 
 
 @dataclasses.dataclass

@@ -191,7 +191,7 @@ def load_wikitext2(
 
     def _read(fn):
         """Dataset reads ride the IO retry/backoff: a transient storage
-        fault (NFS failover, flaky tunnel — or a chaos `io_fail` plan)
+        fault (NFS failover — or a chaos `io_fail` plan)
         backs off and retries instead of crashing the epoch; truly
         corrupt bytes (ValueError from verify/parse) surface at once."""
 

@@ -223,6 +223,15 @@ def _grouped_cache_attention(q, ck, cv, mask, rep):
     return out.reshape(B, T, H, D).astype(q.dtype)
 
 
+def _chain_view(pool, block_tables):
+    """Each row's block chain gathered out of the pool
+    `[NB, Hkv, bs, D]` into the contiguous `[B, MB*bs, Hkv, D]` view
+    `_grouped_cache_attention` reads."""
+    g = pool[block_tables]                      # [B, MB, Hkv, bs, D]
+    B, MB, Hkv, bs, D = g.shape
+    return g.swapaxes(2, 3).reshape(B, MB * bs, Hkv, D)
+
+
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
 
@@ -239,9 +248,12 @@ class LlamaAttention(nn.Module):
         independent requests from one batched cache).
 
         Paged path: `block_tables` [B, MB] int32 switches `cache` to a
-        pooled layout {'k','v': [num_blocks, block_size, Hkv, D]}
+        pooled layout {'k','v': [num_blocks, Hkv, block_size, D]}
         (`init_paged_cache`): logical position p of row b lives at
         physical block `block_tables[b, p // bs]`, offset `p % bs`.
+        `Hkv` sits ahead of `block_size` so one (block, head) tile is
+        the array's last two dims — the only pool block the TPU kernel
+        compiler accepts.
         Writes scatter through the table; reads either gather each
         row's blocks back into a contiguous [B, MB*bs] view for the
         same masked grouped attention (`paged_attn_impl="gather"`) or
@@ -271,7 +283,7 @@ class LlamaAttention(nn.Module):
 
         if cache is not None and block_tables is not None:
             B, T = x.shape[0], x.shape[1]
-            bs = cache["k"].shape[1]
+            bs = cache["k"].shape[2]
             MB = block_tables.shape[1]
             L = MB * bs
             idx = jnp.asarray(cache_index, jnp.int32)
@@ -287,8 +299,23 @@ class LlamaAttention(nn.Module):
                 jnp.int32(0),
             )
             off = cols % bs
-            ck = cache["k"].at[phys, off].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[phys, off].set(v.astype(cache["v"].dtype))
+            # scatter D-wide rows of the pool seen as [NB*Hkv*bs, D] (a
+            # free reshape): row (phys*Hkv + h)*bs + off. Scattering
+            # [Hkv, D] windows at (phys, :, off) instead made XLA
+            # re-lay out the whole pool around the scatter for windows
+            # of 2..64 tokens — two pool-sized temporaries per call.
+            Hkv = c.n_kv_heads
+            rows = ((phys[:, :, None] * Hkv
+                     + jnp.arange(Hkv, dtype=jnp.int32)) * bs
+                    + off[:, :, None])                      # [B, T, Hkv]
+
+            def write(pool, new):
+                flat = pool.reshape(-1, pool.shape[-1])
+                return flat.at[rows].set(new.astype(pool.dtype)).reshape(
+                    pool.shape)
+
+            ck = write(cache["k"], k)
+            cv = write(cache["v"], v)
             if c.paged_attn_impl == "pallas":
                 # read the pools in place: the kernel walks the block
                 # table itself, so no contiguous copy is materialized
@@ -298,16 +325,16 @@ class LlamaAttention(nn.Module):
 
                 out = paged_attention(q, ck, cv, block_tables, base)
             elif c.paged_attn_impl == "gather":
-                # gather each row's chain into the contiguous view the
-                # grouped attention expects; rows beyond a row's
-                # frontier are masked off exactly as in the slab layout
-                vk = ck[block_tables].reshape(B, L, ck.shape[2], ck.shape[3])
-                vv = cv[block_tables].reshape(B, L, cv.shape[2], cv.shape[3])
+                # gather each row's chain, [B, MB, Hkv, bs, D]; rows
+                # beyond a row's frontier are masked off exactly as in
+                # the slab layout
                 kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
                 q_pos = base[:, None, None] + \
                     jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)[None]
                 mask = kv_pos[None] <= q_pos  # [B, T, L]
-                out = _grouped_cache_attention(q, vk, vv, mask, rep)
+                out = _grouped_cache_attention(
+                    q, _chain_view(ck, block_tables),
+                    _chain_view(cv, block_tables), mask, rep)
             else:
                 raise ValueError(
                     f"unknown paged_attn_impl {c.paged_attn_impl!r} "
@@ -426,7 +453,7 @@ def init_paged_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
     addressed through a table must still stay under cfg.max_len — the
     rope table is the binding constraint, exactly as for `init_cache`."""
     dtype = dtype or cfg.compute_dtype
-    shape = (num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     return [
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for _ in range(cfg.n_layers)

@@ -45,8 +45,9 @@ gradients are zero under any masked loss, and every backward term is
 multiplied by dO or delta (both zero there), so gradients stay clean —
 same caveat as every standard flash implementation.
 
-On non-TPU backends the kernels run in interpret mode so the full test
-suite exercises them on the simulated CPU mesh.
+On the CPU backend the kernels run in interpret mode so the full test
+suite exercises them on the simulated CPU mesh; on a TPU they are
+compiled; any other backend is refused (`ops/pallas/backend.py`).
 
 TPU lowering note: Mosaic requires the last two dims of every physical
 block to be (8, 128)-divisible or equal to the array dims
@@ -80,6 +81,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hyperion_tpu.ops.attention import NEG_INF
+from hyperion_tpu.ops.pallas.backend import (
+    LANES,      # lane-broadcast width for per-row stats (lse/delta)
+    SUBLANES,   # sublane-broadcast height for the padding mask
+    interpret_on_backend,
+)
 
 # Defaults from the round-4 on-chip sweep (scripts/flash_block_probe.py,
 # v5e, seq 4k/16k, D=64): 1024x1024 tiles reach 34 (fwd) / 41-44 (train)
@@ -89,8 +95,6 @@ from hyperion_tpu.ops.attention import NEG_INF
 # logits tile alone is block_q*block_kv*4 B).
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_KV = 1024
-LANES = 128     # lane-broadcast width for per-row stats (lse/delta)
-SUBLANES = 8    # sublane-broadcast height for the padding mask
 # Performance-relevant revision of this kernel pair, stamped into every
 # attention_bench CSV row so offline readers (compare_to_reference.py's
 # auto-picks column) can tell a capture of THIS kernel from a stale one.
@@ -167,16 +171,13 @@ def _mask_arg(padding_mask):
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return interpret_on_backend()
 
 
 def _compiler_params():
     if _interpret():
         return None
-    from hyperion_tpu.utils.compat import pallas_tpu_compiler_params
-
-    # via compat: jax 0.5 renamed TPUCompilerParams -> CompilerParams
-    return pallas_tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
 

@@ -20,9 +20,19 @@ Kernel shape:
     the output itself.
 
 Rows/vocab are padded to tile multiples with NEG_INF columns (which
-change neither lse nor gradients) and zero rows (sliced off). On
-non-TPU backends the kernels run in interpret mode, so the CPU test
-suite exercises them.
+change neither lse nor gradients) and zero rows (sliced off). On the
+CPU backend the kernels run in interpret mode, so the test suite
+exercises them.
+
+The per-row side operands (targets, loss, lse, g) and the three
+running-statistic scratches are carried 2-D, replicated across the 128
+lanes (`[N, LANES]`, the trick flash_attention.py uses for its mask).
+As 1-D `(bn,)` blocks they lowered through `jax.export` and were then
+refused by the chip's compiler: XLA tiles a 1-D s32/f32 array by 1024
+and Mosaic by the block's 256, and no single `block_n` satisfies both
+inside the VMEM limit. The replicas cost N*128*4 bytes of HBM per
+operand, against N*V for the logits. `tests/test_tpu_compile.py`
+compiles forward+backward at vocab 50257 and 32000 for a described v5e.
 """
 
 from __future__ import annotations
@@ -35,22 +45,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hyperion_tpu.ops.attention import NEG_INF
+from hyperion_tpu.ops.pallas.backend import LANES, interpret_on_backend
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_V = 2048
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return interpret_on_backend()
 
 
 def _compiler_params():
     if _interpret():
         return None
-    from hyperion_tpu.utils.compat import pallas_tpu_compiler_params
-
-    # via compat: jax 0.5 renamed TPUCompilerParams -> CompilerParams
-    return pallas_tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"),
     )
 
@@ -69,17 +77,18 @@ def _fwd_kernel(logits_ref, tgt_ref, loss_ref, lse_ref, m_s, l_s, t_s,
         t_s[...] = jnp.zeros_like(t_s)
 
     tile = logits_ref[...].astype(jnp.float32)       # [bn, bv]
-    m_prev, l_prev = m_s[...], l_s[...]
-    m_new = jnp.maximum(m_prev, tile.max(axis=-1))
+    m_prev, l_prev = m_s[...], l_s[...]              # [bn, LANES]
+    m_new = jnp.maximum(m_prev, tile.max(axis=-1, keepdims=True))
     l_s[...] = l_prev * jnp.exp(m_prev - m_new) + jnp.sum(
-        jnp.exp(tile - m_new[:, None]), axis=-1
+        jnp.exp(tile - m_new[:, :1]), axis=-1, keepdims=True
     )
     m_s[...] = m_new
 
     # target logit: each row's target falls in exactly one vocab tile
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
-    hit = col == tgt_ref[...][:, None]
-    t_s[...] = t_s[...] + jnp.sum(jnp.where(hit, tile, 0.0), axis=-1)
+    hit = col == tgt_ref[...][:, :1]
+    t_s[...] = t_s[...] + jnp.sum(
+        jnp.where(hit, tile, 0.0), axis=-1, keepdims=True)
 
     @pl.when(j == n_v - 1)
     def _finalize():
@@ -95,15 +104,21 @@ def _bwd_kernel(logits_ref, tgt_ref, lse_ref, g_ref, dlogits_ref,
                 *, block_v: int):
     j = pl.program_id(1)
     tile = logits_ref[...].astype(jnp.float32)
-    p = jnp.exp(tile - lse_ref[...][:, None])
+    p = jnp.exp(tile - lse_ref[...][:, :1])
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
-    onehot = (col == tgt_ref[...][:, None]).astype(jnp.float32)
+    onehot = (col == tgt_ref[...][:, :1]).astype(jnp.float32)
     dlogits_ref[...] = (
-        (p - onehot) * g_ref[...][:, None]
+        (p - onehot) * g_ref[...][:, :1]
     ).astype(dlogits_ref.dtype)
 
 
 # ---------------------------------------------------------------- public
+
+
+def _lanes(x):
+    """[N] per-row operand -> [N, LANES], each row's value replicated
+    across the lanes; the kernels read lane 0."""
+    return jnp.broadcast_to(x[:, None], (x.shape[0], LANES))
 
 
 def _pad(logits, targets, block_n, block_v):
@@ -136,33 +151,24 @@ def _run_forward(logits, targets, block_n, block_v):
     bn = min(block_n, Np)
     bv = min(block_v, Vp)
     n_v = Vp // bv
+    row = pl.BlockSpec((bn, LANES), lambda i, j: (i, 0))
     loss, lse_p = pl.pallas_call(
         functools.partial(_fwd_kernel, block_v=bv, n_v=n_v),
         grid=(Np // bn, n_v),
-        in_specs=[
-            pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-        ],
+        in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j)), row],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
+            jax.ShapeDtypeStruct((Np, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((Np, LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((bn, LANES), jnp.float32)] * 3,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(lp, tp.astype(jnp.int32))
+    )(lp, _lanes(tp.astype(jnp.int32)))
     # residuals keep the PADDED arrays so backward re-pads nothing —
     # padding the [N, V] logits twice would add a full extra HBM copy
     # of the step's largest tensor
-    return loss[:N], (lp, tp, lse_p, (N, logits.shape[1]))
+    return loss[:N, 0], (lp, tp, lse_p[:, 0], (N, logits.shape[1]))
 
 
 def _fwd(logits, targets, block_n, block_v):
@@ -183,20 +189,16 @@ def _bwd(block_n, block_v, residuals, g):
     bn = min(block_n, Np)
     bv = min(block_v, Vp)
     g_p = jnp.pad(g.astype(jnp.float32), (0, Np - N))
+    row = pl.BlockSpec((bn, LANES), lambda i, j: (i, 0))
     dlogits = pl.pallas_call(
         functools.partial(_bwd_kernel, block_v=bv),
         grid=(Np // bn, Vp // bv),
-        in_specs=[
-            pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-        ],
+        in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j)), row, row, row],
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Np, Vp), lp.dtype),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(lp, tp.astype(jnp.int32), lse_p, g_p)
+    )(lp, _lanes(tp.astype(jnp.int32)), _lanes(lse_p), _lanes(g_p))
     return dlogits[:N, :V], None
 
 

@@ -22,11 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from hyperion_tpu.ops.pallas.backend import interpret_on_backend
+
 DEFAULT_BLOCK_ROWS = 256
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return interpret_on_backend()
 
 
 def _kernel(x_ref, res_ref, w_ref, b_ref, o_ref, *, eps: float):

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from hyperion_tpu.utils import compat
-from hyperion_tpu.utils.compat import axis_size, shard_map
 
 from hyperion_tpu.ops.attention import NEG_INF
 from hyperion_tpu.runtime.mesh import AxisName
@@ -44,7 +43,7 @@ def _local_ring_attention(
     """Runs inside shard_map. q/k/v: [B, T_local, H, D] (this device's
     shard); pad: [B, T_local] (1 = real) or None, rotating around the
     ring alongside the K/V block it masks. Returns [B, T_local, H, D]."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, Tl, H, D = q.shape
 
@@ -122,7 +121,7 @@ def ring_attention(
     # optional padding rides as a fourth arg with a None spec when absent
     # (same pattern as ops.ulysses)
     pad_spec = P(AxisName.BATCH, axis_name) if padding_mask is not None else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec, spec, pad_spec),
         out_specs=spec,

@@ -29,7 +29,6 @@ import functools
 import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from hyperion_tpu.utils.compat import shard_map
 
 from hyperion_tpu.ops.attention import dot_product_attention
 from hyperion_tpu.runtime.mesh import AxisName
@@ -91,7 +90,7 @@ def ulysses_attention(
         args = args + (None,)
         in_specs.append(None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _local_ulysses, axis_name=axis_name, causal=causal, impl=impl
         ),

@@ -38,7 +38,6 @@ import numpy as np
 from jax import lax
 
 from hyperion_tpu.utils import compat
-from hyperion_tpu.utils.compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hyperion_tpu.runtime.mesh import AxisName
@@ -64,7 +63,7 @@ def _local_gpipe(
     holds all of them. Returns [1, M, mb, ...]: this stage's output
     buffer; only the last stage's slice is meaningful."""
     params = jax.tree.map(lambda a: a[0], stage_params)
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     last = n - 1
     perm = [(j, (j + 1) % n) for j in range(n)]
@@ -197,7 +196,7 @@ def gpipe_apply(
     param_specs = (
         P(axis_name) if param_in_specs is None else param_in_specs
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _local_gpipe, stage_fn=stage_fn, axis_name=axis_name, n_micro=M
         ),

@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hyperion_tpu.runtime import dist
-from hyperion_tpu.utils.compat import shard_map
 
 _AXIS = "ring"
 
@@ -81,7 +80,7 @@ def comm_check(devices=None, verbose: bool = True) -> bool:
         t0 = time.perf_counter()
         try:
             out = jax.jit(
-                shard_map(fn, mesh=mesh, in_specs=P(_AXIS), out_specs=P(_AXIS))
+                jax.shard_map(fn, mesh=mesh, in_specs=P(_AXIS), out_specs=P(_AXIS))
             )(x)
             out = np.asarray(jax.block_until_ready(out))
             good = np.allclose(out.reshape(expected.shape), expected)

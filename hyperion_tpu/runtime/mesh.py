@@ -49,16 +49,9 @@ class AxisName:
     BATCH = (DATA, FSDP)
 
 
-# jax < 0.5 has no jax.sharding.AxisType: every mesh IS GSPMD/Auto mode,
-# which is exactly what we pin on newer jax — so on old jax the pin is
-# simply omitted rather than failing mesh construction outright.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def _auto_axis_types() -> tuple | None:
-    if _AXIS_TYPE is None:
-        return None
-    return (_AXIS_TYPE.Auto,) * len(AxisName.ALL)
+# every axis in GSPMD/Auto mode (the explicit-sharding mode rejects ops
+# whose output sharding is ambiguous)
+_AUTO = (jax.sharding.AxisType.Auto,) * len(AxisName.ALL)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,18 +105,13 @@ def make_mesh(
     # make_mesh to Explicit, the sharding-in-types mode, which instead
     # rejects ops whose output sharding is ambiguous — e.g. embedding
     # gathers of a batch-sharded index into an fsdp-sharded table).
-    auto = _auto_axis_types()
     # jax.make_mesh picks a device order that keeps adjacent mesh
     # coordinates ICI-adjacent where it can; fall back to reshape for
     # explicit device lists.
     if devices == jax.devices():
-        if auto is None:
-            return jax.make_mesh(spec.shape, AxisName.ALL)
-        return jax.make_mesh(spec.shape, AxisName.ALL, axis_types=auto)
+        return jax.make_mesh(spec.shape, AxisName.ALL, axis_types=_AUTO)
     arr = np.asarray(devices).reshape(spec.shape)
-    if auto is None:
-        return Mesh(arr, AxisName.ALL)
-    return Mesh(arr, AxisName.ALL, axis_types=auto)
+    return Mesh(arr, AxisName.ALL, axis_types=_AUTO)
 
 
 def make_abstract_mesh(spec: MeshSpec) -> jax.sharding.AbstractMesh:
@@ -136,10 +124,8 @@ def make_abstract_mesh(spec: MeshSpec) -> jax.sharding.AbstractMesh:
         raise ValueError(
             f"abstract mesh needs explicit axis sizes (no -1): {spec}"
         )
-    auto = _auto_axis_types()
-    if auto is None:  # jax < 0.5: AbstractMesh takes (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple(zip(AxisName.ALL, spec.shape)))
-    return jax.sharding.AbstractMesh(spec.shape, AxisName.ALL, axis_types=auto)
+    return jax.sharding.AbstractMesh(spec.shape, AxisName.ALL,
+                                     axis_types=_AUTO)
 
 
 # --- active mesh -------------------------------------------------------

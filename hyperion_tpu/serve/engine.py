@@ -445,7 +445,10 @@ class Engine:
         from hyperion_tpu.obs import trace as trace_mod
 
         self.model = model
-        self.variables = variables
+        # once, here: a host-side tree (the server hands over the
+        # export as numpy) would otherwise be uploaded whole by every
+        # jit call; arrays already on a device stay where they are
+        self.variables = jax.device_put(variables)
         mcfg = model.cfg
         L = cfg.max_len or mcfg.max_len
         if L > mcfg.max_len:
@@ -869,7 +872,7 @@ class Engine:
         payload = np.stack([
             np.stack([np.asarray(layer["k"][block]),
                       np.asarray(layer["v"][block])])
-            for layer in self._cache])  # [L, 2, bs, H, D]
+            for layer in self._cache])  # [L, 2, H, bs, D]
         if self.host.put(chain_tokens, payload):
             self.metrics.on_host_spill(payload.nbytes)
             self.metrics.observe_host_cache(
@@ -884,7 +887,7 @@ class Engine:
         restored stream matches the never-evicted run. Returns bytes
         moved."""
         ids = jnp.asarray(np.asarray(blocks, np.int32))
-        stacked = np.stack(payloads)  # [n, L, 2, bs, H, D]
+        stacked = np.stack(payloads)  # [n, L, 2, H, bs, D]
         moved = int(stacked.nbytes)
         dev = jax.device_put(stacked)
         self._cache = [

@@ -720,7 +720,9 @@ def main(argv=None) -> int:
     from hyperion_tpu.obs import trace as obs_trace
     from hyperion_tpu.serve.engine import Engine, EngineConfig
     from hyperion_tpu.serve.journal import RequestJournal
+    from hyperion_tpu.utils.compile_cache import place_compile_cache
 
+    place_compile_cache()
     tok = None
     if not args.no_tokenizer:
         from hyperion_tpu.data.bpe import ByteBPE
@@ -875,9 +877,10 @@ def main(argv=None) -> int:
                 pass
         drain_evt.set()
 
+    prev_handlers = {}
     for sig in (signal.SIGTERM, signal.SIGINT):
         try:
-            signal.signal(sig, _on_signal)
+            prev_handlers[sig] = signal.signal(sig, _on_signal)
         except ValueError:
             pass  # not the main thread (embedded use): no signal drain
 
@@ -899,6 +902,11 @@ def main(argv=None) -> int:
     except KeyboardInterrupt:
         pass
     finally:
+        # the handler closes over the engine: left installed it keeps
+        # the weights and the KV pool alive after main() has returned
+        # (a caller that serves twice in one process then holds both)
+        for sig, handler in prev_handlers.items():
+            signal.signal(sig, handler)
         if exporter is not None:
             exporter.close()
         if journal is not None:

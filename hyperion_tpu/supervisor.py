@@ -26,7 +26,7 @@ The module is deliberately jax-free (it must stay responsive while a
 child holds a dead backend) and import-light: `train/supervisor.py`
 and `serve/server.py` both build on it without pulling each other in.
 
-Exit-code contract (shared; `scripts/tpu_watch.sh` branches on it):
+Exit-code contract (shared; watching scripts branch on it):
     0   the (possibly restarted) run finished
     2   usage error passed through — argparse rejections don't heal
     3   gave up: restart budget exhausted; a human should look

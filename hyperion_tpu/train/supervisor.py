@@ -23,7 +23,7 @@ Each child runs with `HYPERION_ATTEMPT=<k>`; the trainers stamp that
 into their `train_start` trace event and every heartbeat, so `obs
 doctor` reports the restart lineage of the whole run directory.
 
-Exit codes (the contract `scripts/tpu_watch.sh` defers to):
+Exit codes (the contract watching scripts defer to):
     0  the (possibly restarted) run finished
     3  gave up: max restarts exhausted — re-firing from outside would
        just burn the same wall; a human should look

@@ -1,72 +1,23 @@
-"""Version-bridging imports for jax APIs that moved between releases.
-
-The tree targets jax >= 0.6 (`jax.shard_map`, `jax.sharding.AxisType`),
-but deployment images pin older runtimes; 0.4.x keeps the same
-functionality under `jax.experimental.shard_map` with `check_rep` in
-place of `check_vma`. Callers import from here so every module states
-its requirement once and the fallback logic lives in one place.
+"""Two helpers over `jax.shard_map`'s varying-axes (vma) typing, shared
+by the ring-attention and pipeline loops. The tree runs on one JAX (the
+line pinned in pyproject.toml); everything else that once lived here was
+a branch for a release that is not installed, and callers now use
+`jax.shard_map`, `jax.lax.axis_size` and `pltpu.CompilerParams` directly.
 """
 
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.6
-    _CHECK_KW = None
-except ImportError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """`jax.shard_map` with the replication-check kwarg renamed for old
-    jax (`check_vma` -> `check_rep`); keyword-only like the new API."""
-    if _CHECK_KW and "check_vma" in kw:
-        kw[_CHECK_KW] = kw.pop("check_vma")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+import jax
 
 
 def vma_of(x) -> tuple:
-    """Varying-axes (vma) of an array inside shard_map. jax >= 0.7
-    tracks vma in avals (`jax.typeof(x).vma`); older jax has no vma
-    typing, so everything is trivially compatible — empty tuple."""
-    import jax
-
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return ()
-    return tuple(getattr(typeof(x), "vma", ()))
+    """Mesh axes `x` varies over inside shard_map."""
+    return tuple(jax.typeof(x).vma)
 
 
 def pvary(x, axes: tuple):
-    """Cast `x` to vary over `axes` (`lax.pcast(..., to="varying")`) on
-    jax versions that type-check loop carries by vma; identity where the
-    concept doesn't exist (old jax) or no axes are requested."""
-    import jax
-
-    if not axes or not hasattr(jax.lax, "pcast"):
+    """Cast `x` to vary over `axes`, so a loop carry type-checks against
+    a body whose output varies; identity when no axes are requested."""
+    if not axes:
         return x
     return jax.lax.pcast(x, axis_name=axes, to="varying")
-
-
-def axis_size(axis_name) -> int:
-    """`jax.lax.axis_size` (jax >= 0.6). Old jax constant-folds
-    `psum(1, axis)` over a bound named axis to the same static int, so
-    callers can keep using the result in Python control flow."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """`pltpu.CompilerParams(...)` across the jax 0.5 rename: newer
-    releases call it CompilerParams, 0.4.x (this container's 0.4.37)
-    only has the original TPUCompilerParams. Same fields either way
-    (dimension_semantics et al.), so the kernels pass kwargs through."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

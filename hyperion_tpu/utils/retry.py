@@ -1,8 +1,8 @@
 """Retry with exponential backoff — the IO-resilience primitive.
 
 Preemptible-capacity runs live on shared storage whose failures are
-overwhelmingly *transient* (an NFS server failing over, a GCS 503, a
-flapping tunnel mid-read); the reference answered those with a crashed
+overwhelmingly *transient* (an NFS server failing over, a GCS 503);
+the reference answered those with a crashed
 epoch. Here every checkpoint save/restore and dataset read routes
 through `retry_call`: exponential backoff + deterministic jitter +
 a wall-clock deadline, retrying only errors classified transient —

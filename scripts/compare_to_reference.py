@@ -3,8 +3,8 @@
 
 Reads `results/benchmarks/**` (whatever stages have landed — missing
 files are skipped, not errors) and prints the per-row comparison against
-the MI250X reference numbers hard-coded from BASELINE.md, so RESULTS.md
-can be updated from one deterministic source instead of hand-copied
+the MI250X reference numbers hard-coded from BASELINE.md, so any
+write-up can be updated from one deterministic source instead of hand-copied
 numbers. Run: `python scripts/compare_to_reference.py [--root results/benchmarks]`.
 
 Reference values: `Phase 1/results/benchmarks/Baseline/model_benchmarks.csv:2-4`,

@@ -14,9 +14,9 @@
 set -euo pipefail
 
 # ── single-host tuning ────────────────────────────────────────────────
-# Compile-cache: first jit of a big model is ~minutes; the cache makes
-# relaunches (and the scaling sweep's subprocesses) start fast.
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/jax_compile}"
+# Compile-cache: placed by the program (utils/compile_cache.py) —
+# JAX_COMPILATION_CACHE_DIR if you set it, else <repo>/.jax_cache; the
+# scaling sweep's subprocesses inherit the variable.
 # Don't let a long FSDP gather trip the coordinator heartbeat — the
 # reference raised its watchdog to 7200 s for the same reason.
 export JAX_DISTRIBUTED_HEARTBEAT_TIMEOUT_SECONDS="${JAX_DISTRIBUTED_HEARTBEAT_TIMEOUT_SECONDS:-300}"

@@ -48,7 +48,7 @@ class TestCliDataFlags:
         assert cfg.train.data_dir == ""
 
     def test_real_data_invocation(self):
-        # the capture_round5.sh invocation: outputs under base_dir,
+        # a real-data invocation: outputs under base_dir,
         # corpora from data_dir, training on the real test arrow
         from hyperion_tpu.cli.main import build_parser, make_config
 

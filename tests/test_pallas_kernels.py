@@ -432,13 +432,15 @@ class TestPagedAttention:
 
     def _ref(self, q, kp, vp, bt, base):
         # the llama.py gather read, shape-for-shape
-        from hyperion_tpu.models.llama import _grouped_cache_attention
+        from hyperion_tpu.models.llama import (
+            _chain_view,
+            _grouped_cache_attention,
+        )
 
         B, T, H, D = q.shape
-        Hkv, bs, MB = kp.shape[2], kp.shape[1], bt.shape[1]
+        Hkv, bs, MB = kp.shape[1], kp.shape[2], bt.shape[1]
         L = MB * bs
-        vk = kp[bt].reshape(B, L, Hkv, D)
-        vv = vp[bt].reshape(B, L, Hkv, D)
+        vk, vv = _chain_view(kp, bt), _chain_view(vp, bt)
         kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
         q_pos = base[:, None, None] + \
             jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)[None]
@@ -453,8 +455,8 @@ class TestPagedAttention:
         NB = B * MB + 1
         ks = jax.random.split(jax.random.key(seed), 3)
         q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
-        kp = jax.random.normal(ks[1], (NB, bs, Hkv, D), jnp.float32)
-        vp = jax.random.normal(ks[2], (NB, bs, Hkv, D), jnp.float32)
+        kp = jax.random.normal(ks[1], (NB, Hkv, bs, D), jnp.float32)
+        vp = jax.random.normal(ks[2], (NB, Hkv, bs, D), jnp.float32)
         rng = np.random.default_rng(seed)
         bt = np.zeros((B, MB), np.int32)
         base = rng.integers(0, MB * bs - T + 1, B).astype(np.int32)

@@ -156,7 +156,7 @@ class TestValidation:
 
 
 class TestBreakevenAcceptance:
-    """spec_breakeven_acceptance — the pure cost model the RESULTS.md
+    """spec_breakeven_acceptance — the pure cost model the speculative
     pairing analysis uses (decode_bench.spec_breakeven_acceptance)."""
 
     def test_free_draft_needs_nothing(self):

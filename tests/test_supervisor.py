@@ -232,63 +232,73 @@ class TestSuperviseFlagStripping:
             "--model", "llama", "--epochs", "2"]
         assert _strip_supervise_flags(["--max-restarts=3", "--supervise"]) == []
 
-    def test_compile_cache_flag_rides_through_to_children(self):
-        """Supervised children re-exec the same argv minus supervision
-        flags — --compile-cache must survive so each restart points
-        itself (in-process, per backend) at the shared cache and skips
-        the recompile."""
-        from hyperion_tpu.cli.main import _strip_supervise_flags
-
-        argv = ["--model", "llama", "--supervise",
-                "--compile-cache", "/tmp/cc", "--max-restarts", "2"]
-        assert _strip_supervise_flags(argv) == [
-            "--model", "llama", "--compile-cache", "/tmp/cc"]
-
-
 class TestCompileCache:
-    def test_per_backend_subdir_and_in_process_config(self, tmp_path,
-                                                      monkeypatch):
+    """One rule (utils/compile_cache.py): an outside
+    JAX_COMPILATION_CACHE_DIR is left alone — JAX reads it itself, and
+    supervised children inherit it — else the cache is <repo>/.jax_cache."""
+
+    @pytest.fixture()
+    def cache_dir_restored(self):
         import jax
 
-        from hyperion_tpu.cli.main import setup_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
 
-        monkeypatch.delenv("HYPERION_COMPILE_CACHE", raising=False)
+    def test_outside_variable_wins_and_nothing_is_set_in_code(
+            self, tmp_path, monkeypatch, cache_dir_restored):
+        import jax
+
+        from hyperion_tpu.utils.compile_cache import place_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "/sentinel")
         before = dict(os.environ)
-        assert setup_compile_cache("") is None  # off by default
-        d = setup_compile_cache(str(tmp_path / "cache"))
-        try:
-            assert d == str(tmp_path / "cache" / "cpu")
-            assert (tmp_path / "cache" / "cpu").is_dir()
-            assert jax.config.jax_compilation_cache_dir == d
-            # the import-leak lesson: configuration is in-process only,
-            # never a mutated environment later children would inherit
-            assert dict(os.environ) == before
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+        assert place_compile_cache() == str(tmp_path)
+        # no jax.config.update happened, and the environment is as it was
+        assert jax.config.jax_compilation_cache_dir == "/sentinel"
+        assert dict(os.environ) == before
 
-    def test_env_var_fallback(self, tmp_path, monkeypatch):
+    def test_without_the_variable_the_cache_is_under_the_repo(
+            self, monkeypatch, cache_dir_restored):
         import jax
 
-        from hyperion_tpu.cli.main import setup_compile_cache
+        from hyperion_tpu.utils.compile_cache import (
+            DEFAULT_DIR,
+            place_compile_cache,
+        )
 
-        monkeypatch.setenv("HYPERION_COMPILE_CACHE",
-                           str(tmp_path / "envcache"))
-        try:
-            d = setup_compile_cache("")
-            assert d and (tmp_path / "envcache" / "cpu").is_dir()
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = dict(os.environ)
+        assert place_compile_cache() == str(DEFAULT_DIR)
+        assert DEFAULT_DIR == Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        assert dict(os.environ) == before   # in-process only
+
+    @pytest.mark.parametrize("name", [
+        "--compile-cache", "HYPERION_COMPILE_CACHE"])
+    def test_the_old_names_are_gone(self, name):
+        from hyperion_tpu.cli.main import build_parser
+        from hyperion_tpu.config import OptimizationConfig
+
+        assert not hasattr(OptimizationConfig(), "compile_cache")
+        if name.startswith("--"):
+            with pytest.raises(SystemExit):
+                build_parser().parse_args([name, "/tmp/cc"])
+        else:
+            src = (Path(__file__).resolve().parents[1]
+                   / "hyperion_tpu" / "cli" / "main.py").read_text()
+            assert name not in src
 
     def test_cli_threads_overlap_knobs(self):
         from hyperion_tpu.cli.main import build_parser, make_config
 
         args = build_parser().parse_args([
             "--model", "llama", "--prefetch-depth", "4",
-            "--no-async-checkpoint", "--compile-cache", "/tmp/cc"])
+            "--no-async-checkpoint"])
         cfg = make_config(args, "llama")
         assert cfg.train.prefetch_depth == 4
         assert cfg.train.async_checkpoint is False
-        assert cfg.optimization.compile_cache == "/tmp/cc"
         # defaults: prefetch on at depth 2, async saves on
         dflt = make_config(build_parser().parse_args([]), "language_ddp")
         assert dflt.train.prefetch_depth == 2
@@ -296,7 +306,7 @@ class TestCompileCache:
 
 
 def test_exit_code_contract():
-    """scripts/tpu_watch.sh branches on these — they are API."""
+    """Watching scripts branch on these — they are API."""
     assert supervisor.EXIT_OK == 0
     assert supervisor.EXIT_USAGE == 2
     assert EXIT_GAVE_UP == 3

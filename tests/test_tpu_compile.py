@@ -1,0 +1,166 @@
+"""Compile the Pallas kernels for a described TPU v5e, device-free.
+
+Interpret-mode tests (`test_pallas_kernels.py`) prove numerics and never
+meet the chip's compiler. Lowering through `jax.export` meets Mosaic's
+block-shape check and stops there: fused CE passed it and was then
+refused by the real compile (XLA and Mosaic disagreed on the layout of
+its 1-D per-row operands), and the paged-attention pool block was
+refused at every Llama width. So these tests run the whole compile,
+`jit(...).lower(...).compile()`, against a topology that is described
+and not attached, at the widths the server and the trainer use, and
+assert that the compiled program holds a `tpu_custom_call`.
+
+This is the only file that describes a topology, and it does so inside
+a module-scoped fixture: only one process may load the TPU's library,
+so the call must not run while any module is imported, and must run in
+the test's own process. A compile that passes here is not a chip run;
+`chip_smoke.py` is.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import hyperion_tpu.ops.pallas.flash_attention  # noqa: F401
+import hyperion_tpu.ops.pallas.fused_ce as ce_mod
+import hyperion_tpu.ops.pallas.fused_norm as norm_mod
+import hyperion_tpu.ops.pallas.paged_attention as paged_mod
+
+# the package re-exports the flash_attention function under the module's
+# own name, so `import ... as` would bind the function
+flash_mod = sys.modules["hyperion_tpu.ops.pallas.flash_attention"]
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the library away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache and cannot be read back without one: the next
+    # run would warn and compile again, so keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The session's backend is the CPU, where `_interpret()` picks the
+    interpreter; steer the four kernels to the compiled path here, in
+    the test, rather than through an option of the program."""
+    for mod in (flash_mod, ce_mod, norm_mod, paged_mod):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *avals):
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# Llama-2-7B is MHA (32 KV heads); the 8-KV-head GQA geometry is the
+# 70B/Llama-3 ratio at the same head_dim. Pool: 8 slots x 2048 tokens in
+# 16-token blocks (+ the null block), as chip_smoke.py serves.
+PAGED_HEADS = {"mha": (32, 32), "gqa": (32, 8)}
+PAGED_GEOMETRY = {"decode": (8, 1), "verify": (8, 5), "chunk": (1, 256)}
+
+
+class TestPagedAttentionCompiles:
+    @pytest.mark.parametrize("geometry", sorted(PAGED_GEOMETRY))
+    @pytest.mark.parametrize("heads", sorted(PAGED_HEADS))
+    def test_llama_widths(self, mosaic, one_chip, heads, geometry):
+        H, Hkv = PAGED_HEADS[heads]
+        B, T = PAGED_GEOMETRY[geometry]
+        D, bs, MB = 128, 16, 128
+        pool = S((8 * MB + 1, Hkv, bs, D), BF16)
+        _compile(
+            paged_mod.paged_attention, one_chip,
+            S((B, T, H, D), BF16), pool, pool,
+            S((B, MB), jnp.int32), S((B,), jnp.int32),
+        )
+
+
+class TestFlashAttentionCompiles:
+    @pytest.mark.parametrize("shape", [
+        (8, 1024, 12, 64),     # the reference LM's heads at seq 1024
+        (2, 4096, 32, 128),    # Llama-2-7B heads at full context
+    ], ids=["d64_t1024", "d128_t4096"])
+    def test_fwd_bwd(self, mosaic, one_chip, shape):
+        def loss(q, k, v):
+            out = flash_mod.flash_attention(q, k, v, causal=True)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        a = S(shape, BF16)
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, a, a, a)
+
+    def test_fwd_unaligned_seq(self, mosaic, one_chip):
+        a = S((2, 200, 32, 128), BF16)
+        _compile(
+            lambda q, k, v: flash_mod.flash_attention(q, k, v, causal=True),
+            one_chip, a, a, a)
+
+
+class TestFusedCECompiles:
+    @pytest.mark.parametrize("vocab,dtype", [
+        (50257, jnp.float32), (50257, BF16), (32000, BF16),
+    ], ids=["gpt2_f32", "gpt2_bf16", "llama_bf16"])
+    def test_fwd_bwd(self, mosaic, one_chip, vocab, dtype):
+        N = 32 * 127  # the trainer's [batch, seq-1] rows, not a tile multiple
+
+        def loss(logits, targets):
+            return ce_mod.fused_softmax_xent(logits, targets).mean()
+
+        _compile(jax.grad(loss), one_chip,
+                 S((N, vocab), dtype), S((N,), jnp.int32))
+
+
+class TestFusedNormCompiles:
+    def test_rmsnorm_llama_width(self, mosaic, one_chip):
+        def loss(x, w):
+            y = norm_mod.fused_rmsnorm(x, w)
+            return (y.astype(jnp.float32) ** 2).sum()
+
+        _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                 S((2, 1024, 4096), BF16), S((4096,), jnp.float32))
+
+    def test_rmsnorm_decode_shape(self, mosaic, one_chip):
+        _compile(norm_mod.fused_rmsnorm, one_chip,
+                 S((8, 1, 4096), BF16), S((4096,), jnp.float32))
+
+    def test_layernorm_lm_width(self, mosaic, one_chip):
+        def loss(x, r, w, b):
+            y = norm_mod.fused_layernorm(x, w, b, residual=r)
+            return (y.astype(jnp.float32) ** 2).sum()
+
+        x = S((32, 128, 768), BF16)
+        v = S((768,), jnp.float32)
+        _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip, x, x, v, v)

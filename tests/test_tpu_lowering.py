@@ -1,18 +1,20 @@
-"""Device-free TPU (Mosaic) lowering guards for the Pallas kernels.
+"""Device-free TPU (Mosaic) LOWERING guards for the Pallas kernels.
 
-The interpret-mode tests (`test_pallas_kernels.py`) prove numerics but
-never exercise Mosaic's block-layout rules, which is how round 4's
-real-chip capture found every jit_pallas compile-tier row failing with
-"The Pallas TPU lowering currently requires that the last two
-dimensions of your block shape are divisible by 8 and 128 ..."
-(`jax/_src/pallas/mosaic/lowering.py` `_check_block_mappings`) while
-the whole CPU suite was green. `jax.export` with `platforms=["tpu"]`
-runs that exact lowering on the host with no TPU attached, so these
-tests fail the moment a kernel's BlockSpec goes Mosaic-illegal.
+`jax.export` with `platforms=["tpu"]` runs Mosaic's block-shape check
+(`_check_block_mappings`: "the last two dimensions of your block shape
+are divisible by 8 and 128 ...") on the host with no TPU attached, so
+these tests fail the moment a kernel's BlockSpec goes Mosaic-illegal.
+
+That is all they prove. Lowering is not the chip's compiler: fused CE
+passed this guard and was refused by the real compile (a layout
+disagreement over its 1-D operands), which only
+`tests/test_tpu_compile.py` — a whole compile against a described v5e —
+could see. These stay as the cheaper, narrower check (no TPU library is
+loaded, so they may run in any worker).
 
 Each test monkeypatches the kernel module's `_interpret` gate to False:
 without that, a CPU test session would export the interpreter path and
-prove nothing (the same blind spot these tests exist to close).
+prove nothing.
 """
 
 from __future__ import annotations
