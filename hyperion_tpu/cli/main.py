@@ -114,7 +114,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "policy that prevents a diverged final export")
     p.add_argument("--profile-dir", default="",
                    help="capture a jax.profiler trace of the first epoch "
-                        "into this directory (TensorBoard/XProf format)")
+                        "into this directory (read it with `obs profile "
+                        "--summarize <dir>`)")
     p.add_argument("--prefetch-depth", type=int, default=2,
                    help="assemble batches this many steps ahead on a "
                         "background thread so host input work overlaps "

@@ -205,22 +205,33 @@ def _grouped_cache_attention(q, ck, cv, mask, rep):
     group) and the group rides the einsum. q [B, T, H, D]; ck/cv
     [B, S, Hkv, D]; mask [T, S] shared across the batch, or [B, T, S]
     per-row (the serve engine's slots each mask to their own filled
-    prefix). True = attend."""
+    prefix). True = attend.
+
+    In a device trace (obs/xprof.py) the product is `attention`; the
+    casts of the cache to float32 are `kv_read`, with the gather and the
+    layout copy the paged path makes before calling: together, what it
+    costs to put the cache before the product."""
     from hyperion_tpu.ops.attention import NEG_INF
 
     B, T, H, D = q.shape
     Hkv = ck.shape[2]
-    qf = q.astype(jnp.float32).reshape(B, T, Hkv, rep, D)
-    scale = 1.0 / np.sqrt(D)
-    logits = jnp.einsum(
-        "btgrd,bsgd->bgrts", qf * scale, ck.astype(jnp.float32)
-    )
-    mask = mask[None, None, None] if mask.ndim == 2 \
-        else mask[:, None, None]  # → broadcastable over [B, g, r, T, S]
-    logits = jnp.where(mask, logits, NEG_INF)
-    weights = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bgrts,bsgd->btgrd", weights, cv.astype(jnp.float32))
-    return out.reshape(B, T, H, D).astype(q.dtype)
+    with jax.named_scope("attention"):
+        qf = q.astype(jnp.float32).reshape(B, T, Hkv, rep, D)
+        scale = 1.0 / np.sqrt(D)
+        qf = qf * scale
+    with jax.named_scope("kv_read"):
+        ckf = ck.astype(jnp.float32)
+    with jax.named_scope("attention"):
+        logits = jnp.einsum("btgrd,bsgd->bgrts", qf, ckf)
+        mask = mask[None, None, None] if mask.ndim == 2 \
+            else mask[:, None, None]  # → broadcastable over [B, g, r, T, S]
+        logits = jnp.where(mask, logits, NEG_INF)
+        weights = jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope("kv_read"):
+        cvf = cv.astype(jnp.float32)
+    with jax.named_scope("attention"):
+        out = jnp.einsum("bgrts,bsgd->btgrd", weights, cvf)
+        return out.reshape(B, T, H, D).astype(q.dtype)
 
 
 def _chain_view(pool, block_tables):
@@ -273,13 +284,24 @@ class LlamaAttention(nn.Module):
         rollback."""
         c = self.cfg
         dense = _dense_ctor(c)
-        q = dense(features=(c.n_heads, c.head_dim), name="q_proj")(x)
-        k = dense(features=(c.n_kv_heads, c.head_dim), name="k_proj")(x)
-        v = dense(features=(c.n_kv_heads, c.head_dim), name="v_proj")(x)
+        # The stretches of this call are `jax.named_scope`s, not
+        # sub-modules: a scope names device time in a trace
+        # (`attn/kv_read`, obs/xprof.py) and moves no parameter path,
+        # where a module would rename `attn/q_proj` in every checkpoint.
+        with jax.named_scope("qkv_proj"):
+            q = dense(features=(c.n_heads, c.head_dim), name="q_proj")(x)
+            k = dense(features=(c.n_kv_heads, c.head_dim), name="k_proj")(x)
+            v = dense(features=(c.n_kv_heads, c.head_dim), name="v_proj")(x)
         offset = 0 if cache is None else cache_index
-        q = apply_rope(q, rope_table, offset)
-        k = apply_rope(k, rope_table, offset)
+        with jax.named_scope("rope"):
+            q = apply_rope(q, rope_table, offset)
+            k = apply_rope(k, rope_table, offset)
         rep = c.n_heads // c.n_kv_heads
+
+        def o_proj(out):
+            with jax.named_scope("o_proj"):
+                return dense(
+                    features=c.d_model, axis=(-2, -1), name="o_proj")(out)
 
         if cache is not None and block_tables is not None:
             B, T = x.shape[0], x.shape[1]
@@ -288,61 +310,68 @@ class LlamaAttention(nn.Module):
             L = MB * bs
             idx = jnp.asarray(cache_index, jnp.int32)
             base = idx if idx.ndim == 1 else jnp.full((B,), idx, jnp.int32)
-            cols = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-            # physical address of each new position; anything the table
-            # does not cover lands in the null block, where garbage
-            # (bucket padding, inactive lanes) is harmless by contract
-            phys = jnp.where(
-                cols < L,
-                jnp.take_along_axis(
-                    block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
-                jnp.int32(0),
-            )
-            off = cols % bs
-            # scatter D-wide rows of the pool seen as [NB*Hkv*bs, D] (a
-            # free reshape): row (phys*Hkv + h)*bs + off. Scattering
-            # [Hkv, D] windows at (phys, :, off) instead made XLA
-            # re-lay out the whole pool around the scatter for windows
-            # of 2..64 tokens — two pool-sized temporaries per call.
-            Hkv = c.n_kv_heads
-            rows = ((phys[:, :, None] * Hkv
-                     + jnp.arange(Hkv, dtype=jnp.int32)) * bs
-                    + off[:, :, None])                      # [B, T, Hkv]
+            # `kv_write`: where each new position goes, then the scatter
+            with jax.named_scope("kv_write"):
+                cols = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+                # physical address of each new position; anything the table
+                # does not cover lands in the null block, where garbage
+                # (bucket padding, inactive lanes) is harmless by contract
+                phys = jnp.where(
+                    cols < L,
+                    jnp.take_along_axis(
+                        block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
+                    jnp.int32(0),
+                )
+                off = cols % bs
+                # scatter D-wide rows of the pool seen as [NB*Hkv*bs, D] (a
+                # free reshape): row (phys*Hkv + h)*bs + off. Scattering
+                # [Hkv, D] windows at (phys, :, off) instead made XLA
+                # re-lay out the whole pool around the scatter for windows
+                # of 2..64 tokens — two pool-sized temporaries per call.
+                Hkv = c.n_kv_heads
+                rows = ((phys[:, :, None] * Hkv
+                         + jnp.arange(Hkv, dtype=jnp.int32)) * bs
+                        + off[:, :, None])                      # [B, T, Hkv]
 
-            def write(pool, new):
-                flat = pool.reshape(-1, pool.shape[-1])
-                return flat.at[rows].set(new.astype(pool.dtype)).reshape(
-                    pool.shape)
+                def write(pool, new):
+                    flat = pool.reshape(-1, pool.shape[-1])
+                    return flat.at[rows].set(new.astype(pool.dtype)).reshape(
+                        pool.shape)
 
-            ck = write(cache["k"], k)
-            cv = write(cache["v"], v)
+                ck = write(cache["k"], k)
+                cv = write(cache["v"], v)
             if c.paged_attn_impl == "pallas":
                 # read the pools in place: the kernel walks the block
-                # table itself, so no contiguous copy is materialized
+                # table itself, so no contiguous copy is materialized.
+                # Its read and its product are one kernel: all of it is
+                # `kv_read`, the name the gather path's copies have
                 from hyperion_tpu.ops.pallas.paged_attention import (
                     paged_attention,
                 )
 
-                out = paged_attention(q, ck, cv, block_tables, base)
+                with jax.named_scope("kv_read"):
+                    out = paged_attention(q, ck, cv, block_tables, base)
             elif c.paged_attn_impl == "gather":
                 # gather each row's chain, [B, MB, Hkv, bs, D]; rows
                 # beyond a row's frontier are masked off exactly as in
                 # the slab layout
-                kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
-                q_pos = base[:, None, None] + \
-                    jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)[None]
-                mask = kv_pos[None] <= q_pos  # [B, T, L]
-                out = _grouped_cache_attention(
-                    q, _chain_view(ck, block_tables),
-                    _chain_view(cv, block_tables), mask, rep)
+                with jax.named_scope("attention"):
+                    kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
+                    q_pos = base[:, None, None] + \
+                        jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)[None]
+                    mask = kv_pos[None] <= q_pos  # [B, T, L]
+                with jax.named_scope("kv_read"):
+                    # the gather and the layout copy; the cast to float32
+                    # that completes the read is scoped where it happens
+                    kview = _chain_view(ck, block_tables)
+                    vview = _chain_view(cv, block_tables)
+                out = _grouped_cache_attention(q, kview, vview, mask, rep)
             else:
                 raise ValueError(
                     f"unknown paged_attn_impl {c.paged_attn_impl!r} "
                     "(want 'gather' or 'pallas')"
                 )
-            return dense(
-                features=c.d_model, axis=(-2, -1), name="o_proj"
-            )(out), {"k": ck, "v": cv}
+            return o_proj(out), {"k": ck, "v": cv}
 
         if cache is not None:
             T = x.shape[1]
@@ -353,24 +382,26 @@ class LlamaAttention(nn.Module):
                 B = x.shape[0]
                 rows = jnp.arange(B)[:, None]
                 cols = cache_index[:, None] + jnp.arange(T)[None, :]
-                ck = cache["k"].at[rows, cols].set(
-                    k.astype(cache["k"].dtype))
-                cv = cache["v"].at[rows, cols].set(
-                    v.astype(cache["v"].dtype))
+                with jax.named_scope("kv_write"):
+                    ck = cache["k"].at[rows, cols].set(
+                        k.astype(cache["k"].dtype))
+                    cv = cache["v"].at[rows, cols].set(
+                        v.astype(cache["v"].dtype))
                 S = ck.shape[1]
                 kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, S), 1)
                 q_pos = cache_index[:, None, None] + \
                     jax.lax.broadcasted_iota(jnp.int32, (T, S), 0)[None]
                 mask = kv_pos[None] <= q_pos  # [B, T, S]
             else:
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype),
-                    (0, cache_index, 0, 0)
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype),
-                    (0, cache_index, 0, 0)
-                )
+                with jax.named_scope("kv_write"):
+                    ck = jax.lax.dynamic_update_slice(
+                        cache["k"], k.astype(cache["k"].dtype),
+                        (0, cache_index, 0, 0)
+                    )
+                    cv = jax.lax.dynamic_update_slice(
+                        cache["v"], v.astype(cache["v"].dtype),
+                        (0, cache_index, 0, 0)
+                    )
                 # causal over global positions: query cache_index+i may
                 # see cache rows 0..cache_index+i (the rest of the
                 # buffer is zeros and masked off)
@@ -382,17 +413,17 @@ class LlamaAttention(nn.Module):
                 mask = kv_pos <= q_pos  # [T, S]
             new_cache = {"k": ck, "v": cv}
             out = _grouped_cache_attention(q, ck, cv, mask, rep)
-            return dense(
-                features=c.d_model, axis=(-2, -1), name="o_proj"
-            )(out), new_cache
+            return o_proj(out), new_cache
 
-        if rep != 1:  # GQA: repeat kv heads
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        out = dot_product_attention(
-            q, k, v, causal=True, padding_mask=padding_mask, impl=c.attention_impl
-        )
-        return dense(features=c.d_model, axis=(-2, -1), name="o_proj")(out)
+        with jax.named_scope("attention"):
+            if rep != 1:  # GQA: repeat kv heads
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
+            out = dot_product_attention(
+                q, k, v, causal=True, padding_mask=padding_mask,
+                impl=c.attention_impl
+            )
+        return o_proj(out)
 
 
 class LlamaMLP(nn.Module):
@@ -504,8 +535,11 @@ class Llama(nn.Module):
                                      block_tables)
                 new_cache.append(layer_cache)
         x = RMSNorm(c.norm_eps, c.compute_dtype, c.norm_impl, name="final_norm")(x)
-        logits = _dense_ctor(c)(features=c.vocab_size, name="lm_head")(x)
-        logits = logits.astype(jnp.float32)
+        # flax names the module `lm_head` already; the scope takes the
+        # cast to float32 in with it
+        with jax.named_scope("lm_head"):
+            logits = _dense_ctor(c)(features=c.vocab_size, name="lm_head")(x)
+            logits = logits.astype(jnp.float32)
         return logits if cache is None else (logits, new_cache)
 
     def init_params(self, rng: jax.Array, batch: int = 1, seq: int | None = None):

@@ -106,17 +106,24 @@ class MHA(nn.Module):
             _dense_ctor(c, nn.initializers.xavier_uniform()),
             features=(c.n_heads, c.head_dim),
         )
-        q = dense(name="q_proj")(x)
-        k = dense(name="k_proj")(x)
-        v = dense(name="v_proj")(x)
-        out = dot_product_attention(
-            q, k, v, causal=c.causal, padding_mask=padding_mask, impl=c.attention_impl
-        )
-        return _dense_ctor(c, nn.initializers.xavier_uniform())(
-            features=c.d_model,
-            axis=(-2, -1),
-            name="o_proj",
-        )(out)
+        # stretches of the call as `jax.named_scope`s, the names
+        # models/llama.py uses: a scope names device time in a trace and
+        # moves no parameter path (a sub-module would)
+        with jax.named_scope("qkv_proj"):
+            q = dense(name="q_proj")(x)
+            k = dense(name="k_proj")(x)
+            v = dense(name="v_proj")(x)
+        with jax.named_scope("attention"):
+            out = dot_product_attention(
+                q, k, v, causal=c.causal, padding_mask=padding_mask,
+                impl=c.attention_impl
+            )
+        with jax.named_scope("o_proj"):
+            return _dense_ctor(c, nn.initializers.xavier_uniform())(
+                features=c.d_model,
+                axis=(-2, -1),
+                name="o_proj",
+            )(out)
 
 
 class FusedLayerNorm(nn.Module):
@@ -188,30 +195,32 @@ def lm_backbone(c: TransformerLMConfig, input_ids, padding_mask,
             f"sequence length {T} exceeds max_len {c.max_len} — the "
             f"positional table has no rows past max_len"
         )
-    x = nn.Embed(
-        c.vocab_size,
-        c.d_model,
-        dtype=c.compute_dtype,
-        embedding_init=nn.initializers.normal(0.02),
-        name="tok_emb",
-    )(input_ids)
-    pos = nn.Embed(
-        c.max_len,
-        c.d_model,
-        dtype=c.compute_dtype,
-        embedding_init=nn.initializers.normal(0.02),
-        name="pos_emb",
-    )(jnp.arange(T, dtype=jnp.int32))
-    x = x + pos[None]
-    x = nn.Dropout(c.dropout, deterministic=deterministic)(x)
+    with jax.named_scope("embed"):
+        x = nn.Embed(
+            c.vocab_size,
+            c.d_model,
+            dtype=c.compute_dtype,
+            embedding_init=nn.initializers.normal(0.02),
+            name="tok_emb",
+        )(input_ids)
+        pos = nn.Embed(
+            c.max_len,
+            c.d_model,
+            dtype=c.compute_dtype,
+            embedding_init=nn.initializers.normal(0.02),
+            name="pos_emb",
+        )(jnp.arange(T, dtype=jnp.int32))
+        x = x + pos[None]
+        x = nn.Dropout(c.dropout, deterministic=deterministic)(x)
     for i in range(c.n_layers):
         x = make_block(i)(x, padding_mask, deterministic)
     x = _norm(c, "ln_f")(x)
-    logits = _dense_ctor(c, nn.initializers.normal(0.02))(
-        features=c.vocab_size,
-        name="lm_head",
-    )(x)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = _dense_ctor(c, nn.initializers.normal(0.02))(
+            features=c.vocab_size,
+            name="lm_head",
+        )(x)
+        return logits.astype(jnp.float32)
 
 
 class TransformerLM(nn.Module):

@@ -59,6 +59,16 @@ Live half (PR 10 — the pull-based plane for running fleets):
                   polling the exposition sockets (heartbeat fallback
                   for dead processes); `--once --json` for scripts.
 
+Device half (PR 24 — one plane on the profiler's clock):
+  * `tickprof`  — the engine's host-tick profiler: `prof.seg()` times a
+                  step's segments into the tick record AND holds them
+                  open as spans in a `jax.profiler` trace; the flight
+                  recorder spills the last ticks for a post-mortem.
+  * `xprof`     — `obs profile --summarize`: a profiler trace to
+                  numbers — device seconds by program and scope, idle
+                  seconds by the program's own spans, costs and
+                  roofline shares where the trace carries them.
+
 Reaction half (PR 3 — `train/supervisor.py` + `checkpoint/integrity.py`):
 the doctor's verdicts drive a restart supervisor (crashed/hung ->
 restart from the newest verified checkpoint; diverged -> quarantine

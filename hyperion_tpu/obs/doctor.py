@@ -99,6 +99,20 @@ _SEGMENT_HINTS = {
 }
 
 
+def _largest_child(tickprof: dict, seg: str) -> str:
+    """` (mostly `admit/blocks`, 57% of it)` for a segment whose
+    children (obs/tickprof.py: `parent/child` stretches inside it) the
+    profile carries, else ``: the segment says which part of a step is
+    slow, the child which call inside it."""
+    inside = {k: v for k, v in (tickprof.get("children") or {}).items()
+              if k.startswith(seg + "/")}
+    whole = ((tickprof.get("segments") or {}).get(seg) or {}).get("s")
+    if not inside or not whole:
+        return ""
+    child = max(inside, key=inside.get)
+    return f" (mostly `{child}`, {100 * inside[child] / whole:.0f}% of it)"
+
+
 def locate(target: str | Path) -> tuple[Path, Path]:
     """(telemetry_path, heartbeat_path) for a run dir or a direct
     telemetry.jsonl path (heartbeat is its sibling)."""
@@ -944,7 +958,8 @@ def diagnose(
         frac = tickprof.get("dominant_frac") or 0.0
         if dom and dom != "device" and frac >= HOST_SEGMENT_FRAC:
             host_segment_incidents.append(
-                f"host segment '{dom}' owns {100 * frac:.0f}% of tick "
+                f"host segment '{dom}'{_largest_child(tickprof, dom)} "
+                f"owns {100 * frac:.0f}% of tick "
                 f"time over the last {tickprof.get('ticks')} tick(s) — "
                 f"{_SEGMENT_HINTS.get(dom, 'host-side work')}")
     if host_segment_incidents and verdict in ("healthy", "running",
@@ -1199,12 +1214,18 @@ def render_markdown(d: dict) -> str:
         flag = (" — **host-bound**"
                 if d.get("host_segment_incidents") else "")
         frac = tp.get("dominant_frac")
+        share = (f" {100 * frac:.0f}%"
+                 if isinstance(frac, (int, float)) else "")
+        # what the steps counted (tick record `c`): live context now,
+        # padded prefill work over the same window
+        c = tp.get("counters") or {}
+        counted = (f"; {_fmt(c.get('kv_tokens'))} KV tokens live, "
+                   f"{_fmt(c.get('prefill_tokens'))} prefilled"
+                   if c else "")
         lines.append(
-            f"| host tick profile | dominant `{tp['dominant']}` "
-            f"{100 * frac:.0f}% over {_fmt(tp.get('ticks'))} tick(s)"
-            f"{flag} |" if isinstance(frac, (int, float)) else
-            f"| host tick profile | dominant `{tp['dominant']}` over "
-            f"{_fmt(tp.get('ticks'))} tick(s){flag} |")
+            f"| host tick profile | dominant `{tp['dominant']}`"
+            f"{_largest_child(tp, tp['dominant'])}{share} over "
+            f"{_fmt(tp.get('ticks'))} tick(s){counted}{flag} |")
     rt = d.get("rss_trend")
     if rt:
         flag = " — **climbing**" if d.get("rss_warning") else ""

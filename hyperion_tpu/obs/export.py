@@ -298,39 +298,107 @@ def _roundtrip(socket_path: str | Path, request: bytes,
 
 
 def profile_main(argv: list[str] | None = None) -> int:
-    """`obs profile <dir> --seconds N [--out DIR]` — ask the live
-    process whose obs.sock lives at/next to <dir> to capture an
-    on-demand `jax.profiler` trace (TensorBoard/Perfetto-openable).
-    Exit 0 when the trace started (or was already running), 1 when the
-    backend cannot profile or nothing answered."""
+    """`obs profile <dir> --seconds N [--out DIR] [--summarize]` — ask
+    the live process whose obs.sock lives at/next to <dir> to capture an
+    on-demand `jax.profiler` trace. With `--summarize` the command waits
+    for the trace and ends in its numbers (obs/xprof.py: device seconds
+    by program and scope, idle seconds by the program's spans; JSON, or
+    tables with `--markdown`), reduced HERE, in the asking process: the
+    serving process pays for the trace, never for its reading. Where
+    <dir> is itself a trace (a directory the profiler wrote, or an
+    `.xplane.pb`), `--summarize` reads it and asks no one.
+    Exit 0 when the trace started (or was already running) and, if
+    asked for, was summarized; 1 when the backend cannot profile,
+    nothing answered, or no trace appeared."""
     import argparse
 
     ap = argparse.ArgumentParser(
         prog="obs profile",
         description="request an on-demand jax.profiler trace from a "
-                    "live process via its exposition socket")
+                    "live process via its exposition socket, and/or "
+                    "turn a trace into numbers")
     ap.add_argument("dir", help="run dir / heartbeat path whose "
-                                "obs.sock to talk to")
+                                "obs.sock to talk to; with --summarize "
+                                "also: a trace directory or .xplane.pb")
     ap.add_argument("--seconds", type=float, default=5.0,
                     help="trace duration (default 5)")
     ap.add_argument("--out", default=None,
                     help="trace output dir (default <dir>/profile)")
     ap.add_argument("--json", action="store_true",
                     help="print the raw reply as JSON")
+    ap.add_argument("--summarize", action="store_true",
+                    help="end in the trace's numbers (JSON)")
+    ap.add_argument("--markdown", action="store_true",
+                    help="with --summarize: tables instead of JSON")
     args = ap.parse_args(argv)
+    if args.summarize and _trace_under(args.dir) is not None:
+        return _print_summary(args.dir, args.markdown)
     sock = exposition_path(args.dir)
     out = args.out or str(Path(args.dir) / "profile")
+    asked = time.time()
     reply = request_control(
         sock, {"cmd": "profile", "seconds": args.seconds, "out": out},
         timeout_s=max(5.0, args.seconds + 5.0))
     if reply is None:
         print(f"no live process answered at {sock}", file=sys.stderr)
         return 1
+    status = reply.get("status", "error")
     if args.json:
         print(json.dumps(reply, indent=2, default=repr))
     else:
-        status = reply.get("status", "error")
+        # with --summarize the standard output is the summary's alone
         print(f"profile: {status}"
               + (f" -> {reply.get('dir')}" if reply.get("dir") else "")
-              + (f" ({reply.get('error')})" if reply.get("error") else ""))
-    return 0 if reply.get("status") in ("started", "busy") else 1
+              + (f" ({reply.get('error')})" if reply.get("error") else ""),
+              file=sys.stderr if args.summarize else sys.stdout)
+    if args.summarize and status == "started":
+        # the process stops the trace after `seconds` and writes it out,
+        # which takes seconds more; both processes see the same files
+        # (the socket is a unix one)
+        path = _await_trace(out, asked, float(reply.get("seconds")
+                                              or args.seconds) + 120.0)
+        if path is None:
+            print(f"no trace appeared under {out}", file=sys.stderr)
+            return 1
+        return _print_summary(path, args.markdown)
+    return 0 if status in ("started", "busy") else 1
+
+
+def _trace_under(where) -> Path | None:
+    from hyperion_tpu.obs import xprof
+
+    try:
+        return xprof.xplane_path(where)
+    except (FileNotFoundError, NotADirectoryError):
+        return None
+
+
+def _await_trace(out: str, since: float, timeout_s: float) -> Path | None:
+    """The `.xplane.pb` written under `out` after `since`, once its size
+    has stopped growing."""
+    deadline = time.time() + timeout_s
+    last: tuple[Path, int] | None = None
+    while time.time() < deadline:
+        path = _trace_under(out)
+        if path is not None and path.stat().st_mtime >= since - 1.0:
+            size = path.stat().st_size
+            if last == (path, size) and size > 0:
+                return path
+            last = (path, size)
+        time.sleep(0.5)
+    return None
+
+
+def _print_summary(trace, markdown: bool) -> int:
+    from hyperion_tpu.obs import xprof
+
+    try:
+        summary = xprof.summarize(trace)
+    except ValueError as e:     # a trace in which no device ran anything
+        print(f"obs profile: {trace}: {e}", file=sys.stderr)
+        return 1
+    if markdown:
+        print(xprof.to_markdown(summary), end="")
+    else:
+        print(json.dumps(summary, indent=2, default=repr))
+    return 0

@@ -5,16 +5,19 @@ The engine's recompile-free / zero-device-sync invariants make the
 DEVICE side of a tick boring by construction; what actually moves
 tokens/s run to run is the HOST side — queue pops, draft building,
 block-table uploads, the accept loop, journal fsyncs, client sink
-writes, SLO evaluation. `engine.step()` already stamps most of these
-with ad-hoc `time.monotonic()` pairs; this module formalizes them:
+writes, SLO evaluation. This module is the one way to time them:
 
   * `TickProfiler` — a bounded ring of per-tick segment records. The
-    engine builds one small dict of host-second floats per step and
-    `record()`s it; `snapshot(window_s)` rolls the last-N-seconds into
-    per-segment totals/fractions plus the DOMINANT segment, riding the
-    exposition payload so `obs top` can show each row's hot segment
-    and `obs doctor` can name it when tokens/s degrades ("journal owns
-    61% of tick time — slow disk").
+    engine runs a step inside `with prof.tick(n):` and each stretch of
+    it inside `with prof.seg("admit"):`; a segment adds its host-clock
+    seconds to the step's record AND is a span on the profiler's clock
+    (`serve.step/admit`, a `jax.profiler.TraceAnnotation`) while a
+    device trace is taken, so the same names appear in both.
+    `snapshot(window_s)` rolls the last-N-seconds into per-segment
+    totals/fractions plus the DOMINANT segment, riding the exposition
+    payload so `obs top` can show each row's hot segment and `obs
+    doctor` can name it when tokens/s degrades ("journal owns 61% of
+    tick time — slow disk").
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -23,7 +26,8 @@ with ad-hoc `time.monotonic()` pairs; this module formalizes them:
     2 still leaves evidence. `obs doctor` cites the record's final
     ticks in its crashed/hung verdicts.
 
-Both are host-only (no jax import) and null-safe: a recorder built
+Both are host-only (no jax import: the engine hands the profiler its
+annotator, `utils/profiling.annotate`) and null-safe: a recorder built
 with `path=None` accepts every call and writes nothing, the same
 contract as the null tracer/heartbeat.
 """
@@ -45,6 +49,87 @@ FLIGHT_NAME = "flight.json"
 # unattributed host time is visible instead of silently vanishing.
 SEGMENTS = ("queue_pop", "admit", "chunk", "draft", "bt_upload",
             "device", "accept", "journal", "sink", "slo")
+# A key `parent/child` is a CHILD: a stretch inside the segment `parent`
+# (`device/dispatch`, `admit/fetch`), recorded beside it and left out of
+# every sum over segments — its seconds are already in its parent's.
+CHILD_SEP = "/"
+# the span of a whole step in a profiler trace; a segment's span is
+# `serve.step/<key>`
+STEP_SPAN = "serve.step"
+
+
+class _Seg:
+    """One timed stretch of a step, a context manager (`TickProfiler.seg`).
+    After exit `gross` is its wall seconds and `s` those seconds net of
+    the segments that ran inside it."""
+
+    __slots__ = ("s", "gross", "_prof", "_key", "_span", "_t0", "_inner",
+                 "_record")
+
+    def __init__(self, prof, key, span, record):
+        self._prof, self._key, self._span = prof, key, span
+        self._record = record
+        self._inner = 0.0
+        self.s = self.gross = 0.0
+
+    def __enter__(self):
+        if self._span is not None:
+            self._span.__enter__()
+        if self._record:
+            self._prof._open.append(self)
+        self._t0 = self._prof._clock()
+        return self
+
+    def __exit__(self, *exc):
+        prof = self._prof
+        self.gross = prof._clock() - self._t0
+        self.s = max(0.0, self.gross - self._inner)
+        if self._record:
+            prof._open.pop()
+            cur = prof._cur
+            cur[self._key] = cur.get(self._key, 0.0) + self.s
+            if CHILD_SEP not in self._key:
+                # a segment of its own (journal inside accept, bt_upload
+                # inside device): its seconds leave everything around it
+                for outer in prof._open:
+                    outer._inner += self.s
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        return False
+
+
+class _Tick:
+    """One step being profiled (`TickProfiler.tick`): on a clean exit its
+    record is filed under the number it was opened with, the same number
+    its span carries; a step that raises leaves no record."""
+
+    __slots__ = ("_prof", "_span", "_t0", "_tick", "_counters")
+
+    def __init__(self, prof, tick, span):
+        self._prof, self._tick, self._span = prof, tick, span
+        self._counters = None
+
+    def count(self, **counters) -> None:
+        """What the step counted (the record's `c`)."""
+        self._counters = counters
+
+    def __enter__(self):
+        if self._span is not None:
+            self._span.__enter__()
+        prof = self._prof
+        prof._cur, prof._open = {}, []
+        self._t0 = prof._clock()
+        return self
+
+    def __exit__(self, exc_type, *exc):
+        prof = self._prof
+        total = prof._clock() - self._t0
+        cur, prof._cur, prof._open = prof._cur, None, []
+        if exc_type is None:
+            prof.record(self._tick, cur, total, self._counters)
+        if self._span is not None:
+            self._span.__exit__(exc_type, *exc)
+        return False
 
 
 class TickProfiler:
@@ -53,23 +138,60 @@ class TickProfiler:
     The writer (the engine thread) appends one dict per step; readers
     (the exposition thread, the flight recorder) take list() copies of
     the deque — append/copy on a deque are safe under the GIL, so no
-    lock sits on the hot path."""
+    lock sits on the hot path. `tick()` and recording `seg()`s belong to
+    that one writer thread.
 
-    def __init__(self, capacity: int = 256, wall=time.time):
+    `annotate(name, **args)` returns the context manager that puts a
+    span on the profiler's clock (`utils/profiling.annotate`); None
+    keeps the module free of jax and the segments free of spans."""
+
+    def __init__(self, capacity: int = 256, wall=time.time,
+                 clock=time.monotonic, annotate=None):
         self._ring: deque[dict] = deque(maxlen=max(8, int(capacity)))
         self._wall = wall
+        self._clock = clock
+        self._annotate = annotate
+        self._cur: dict | None = None   # the open tick's seconds by key
+        self._open: list[_Seg] = []     # its open segments, outermost first
         self.ticks_recorded = 0
 
-    def record(self, tick: int, segments: dict, total_s: float) -> None:
-        """One step's breakdown: `segments` maps SEGMENTS names to host
-        seconds (absent = 0), `total_s` is the whole step's wall."""
+    def tick(self, n: int) -> _Tick:
+        """`with prof.tick(n) as tk:` around one step: the span
+        `serve.step` with `tick=n`, and on exit the record of tick `n`:
+        the step's wall as `total_s`, the segments timed inside, and
+        what `tk.count(...)` was given."""
+        ann = self._annotate
+        return _Tick(self, n, ann(STEP_SPAN, tick=n) if ann else None)
+
+    def seg(self, key: str, record: bool = True, **args) -> _Seg:
+        """`with prof.seg("admit"):` times one stretch of the open step
+        under `key` and holds the span `serve.step/<key>` (with `args`
+        as its arguments) open meanwhile. A segment that runs inside
+        another is netted out of it (journal and sink writes inside
+        `accept`, the table upload inside `device`); a child
+        (`device/fetch`) is not. Outside a step, or with `record`
+        false (a caller that may be on another thread than the
+        step's), it is a span and a stopwatch and touches no record."""
+        ann = self._annotate
+        return _Seg(self, key,
+                    ann(f"{STEP_SPAN}/{key}", **args) if ann else None,
+                    record and self._cur is not None)
+
+    def record(self, tick: int, segments: dict, total_s: float,
+               counters: dict | None = None) -> None:
+        """One step's breakdown: `segments` maps SEGMENTS names (and
+        `parent/child` keys) to host seconds (absent = 0), `total_s` is
+        the whole step's wall, `counters` what the step counted."""
         self.ticks_recorded += 1
-        self._ring.append({
+        rec = {
             "tick": int(tick),
             "t_wall": self._wall(),
             "total_s": float(total_s),
             "s": {k: round(float(v), 6) for k, v in segments.items() if v},
-        })
+        }
+        if counters:
+            rec["c"] = dict(counters)
+        self._ring.append(rec)
 
     def tail(self, n: int = 32) -> list[dict]:
         """The most recent <= n records (flight-record payload)."""
@@ -87,9 +209,11 @@ class TickProfiler:
         recs = [r for r in self._ring if r["t_wall"] >= cut]
         total = sum(r["total_s"] for r in recs)
         sums: dict[str, float] = {}
+        children: dict[str, float] = {}
         for r in recs:
             for k, v in r["s"].items():
-                sums[k] = sums.get(k, 0.0) + v
+                into = children if CHILD_SEP in k else sums
+                into[k] = into.get(k, 0.0) + v
         named = sum(sums.values())
         if total > named:
             sums["other"] = total - named
@@ -99,7 +223,7 @@ class TickProfiler:
             for k, v in sorted(sums.items(), key=lambda kv: -kv[1])
         }
         dominant = next(iter(segs), None)
-        return {
+        out = {
             "ticks": len(recs),
             "window_s": window_s,
             "total_s": round(total, 6),
@@ -107,6 +231,17 @@ class TickProfiler:
             "dominant": dominant,
             "dominant_frac": segs[dominant]["frac"] if dominant else None,
         }
+        if children:
+            # seconds inside their parents' (never part of a sum above)
+            out["children"] = {k: round(v, 6) for k, v in sorted(
+                children.items(), key=lambda kv: -kv[1])}
+        if recs and "c" in recs[-1]:
+            # a level reads as of the newest step, a flow over the window
+            out["counters"] = {
+                "kv_tokens": recs[-1]["c"].get("kv_tokens"),
+                "prefill_tokens": sum(r.get("c", {}).get("prefill_tokens", 0)
+                                      for r in recs)}
+        return out
 
 
 class FlightRecorder:

@@ -53,6 +53,10 @@ ROW_KEYS = ("name", "dir", "source", "state", "pid", "phase", "step",
             # introspection plane: the windowed dominant host segment
             # (obs/tickprof.py vocabulary) and host RSS in MB
             "dominant_segment", "rss_mb",
+            # tokens whose keys and values the live slots hold (the tick
+            # record's counter): how full the batch's context is, where
+            # `blocks_in_use` also counts cached prefixes nobody reads
+            "kv_tokens",
             # workload isolation (PR 14): per-SLO-class queue depth and
             # what the self-operating layer is doing right now (engine:
             # class brownout / chunking; router: steering / scaling)
@@ -119,6 +123,7 @@ def _row_from_exposition(row: dict, exp: dict) -> dict:
     row["host_cache_mb"] = gauges.get("serve_host_cache_mb")
     tp = exp.get("tickprof") or {}
     row["dominant_segment"] = tp.get("dominant")
+    row["kv_tokens"] = (tp.get("counters") or {}).get("kv_tokens")
     row["rss_mb"] = (exp.get("memory") or {}).get("rss_mb")
     qbc = exp.get("queue_by_class") or {}
     row["queue_interactive"] = qbc.get("interactive")
@@ -219,6 +224,7 @@ def render(rows: list[dict], base: str, *, window_s: float,
             ("tick", 6), ("occ", 5), ("queue", 5), ("q i/b", 6),
             ("tok/s", 8),
             (f"ttft p99({window_s:.0f}s)", 14), ("blocks", 6),
+            ("kv tok", 7),
             ("tier", 9), ("seg", 9), ("rss", 7),
             ("brown", 5), ("act", 12), ("alerts", 18), ("age", 5)]
     head = " ".join(f"{n:<{w}}" for n, w in cols)
@@ -248,7 +254,7 @@ def render(rows: list[dict], base: str, *, window_s: float,
                  _fmt(r["phase"]), _fmt(r["step"]), occ,
                  _fmt(r["queue"]), qib,
                  _fmt(r["tokens_per_s"]), p99,
-                 _fmt(r["blocks_in_use"]), tier,
+                 _fmt(r["blocks_in_use"]), _fmt(r["kv_tokens"]), tier,
                  _fmt(r["dominant_segment"]), rss,
                  _fmt(bool(r["brownout"])), _fmt(r["act"]),
                  ",".join(r["alerts"] or []) or "-", _fmt(r["age_s"], 0)]

@@ -3,7 +3,10 @@ kernel runs on the process's default backend."""
 
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.experimental import pallas as pl
 
 # One fp32 VMEM tile is (SUBLANES, LANES). Per-row operands (softmax
 # statistics, CE targets, padding masks) are carried replicated across
@@ -26,3 +29,19 @@ def interpret_on_backend() -> bool:
         f"Pallas kernels run compiled on 'tpu' and interpreted on 'cpu'; "
         f"the default backend is {backend!r}"
     )
+
+
+def cost(flops: int, transcendentals: int, *moved,
+         extra_bytes: int = 0) -> pl.CostEstimate:
+    """A kernel's cost for the compiler and for a trace's reader
+    (obs/xprof.py sets a kernel's time against it): the FLOPs and
+    transcendentals its algorithm needs, and as bytes every array in
+    `moved` (operands and results, arrays or `ShapeDtypeStruct`s) once
+    plus `extra_bytes`: what the kernel must read and write, not what
+    a tiling happens to read again."""
+    moved_bytes = sum(
+        math.prod(a.shape) * jax.numpy.dtype(a.dtype).itemsize
+        for a in moved)
+    return pl.CostEstimate(
+        flops=int(flops), transcendentals=int(transcendentals),
+        bytes_accessed=int(moved_bytes + extra_bytes))

@@ -84,6 +84,7 @@ from hyperion_tpu.ops.attention import NEG_INF
 from hyperion_tpu.ops.pallas.backend import (
     LANES,      # lane-broadcast width for per-row stats (lse/delta)
     SUBLANES,   # sublane-broadcast height for the padding mask
+    cost,
     interpret_on_backend,
 )
 
@@ -275,6 +276,17 @@ def _fwd_kernel(
             )
 
 
+def _scores(B, H, Tq, Tkv, causal) -> int:
+    """Query-key pairs the attention needs: under the causal mask
+    (key position <= query position) the lower triangle with its
+    diagonal, whatever tiles a kernel visits."""
+    per_head = Tq * Tkv
+    if causal:
+        n = min(Tq, Tkv)
+        per_head = n * (n + 1) // 2 + (Tq - n) * Tkv
+    return B * H * per_head
+
+
 def _flash_forward(
     q, k, v, padding_mask, causal, block_q, block_kv, need_lse=True
 ):
@@ -287,6 +299,7 @@ def _flash_forward(
     kT = k.transpose(0, 2, 1, 3)
     vT = v.transpose(0, 2, 1, 3)
     n_q, n_kv = Tq // block_q, Tkv // block_kv
+    n_scores = _scores(B, H, Tq, Tkv, causal)
 
     grid = (B, H, n_q, n_kv)
     # batch/head dims are mapped (None) so the physical blocks are the
@@ -341,6 +354,8 @@ def _flash_forward(
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        # QK^T and PV over the scores the mask keeps, one exp a score
+        cost_estimate=cost(4 * n_scores * D, n_scores, *args, *out_shape),
     )(*args)
     o, lse = res if need_lse else (res[0], None)
     return o.transpose(0, 2, 1, 3), lse
@@ -512,6 +527,8 @@ def _flash_backward(
         )
         dq_args.append(mask_arg)
 
+    n_scores = _scores(B, H, Tq, Tkv, causal)
+    dq_shape = jax.ShapeDtypeStruct(qT.shape, q.dtype)
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, causal=causal, sm_scale=sm_scale,
@@ -520,10 +537,12 @@ def _flash_backward(
         grid=(B, H, n_q, n_kv),
         in_specs=dq_in_specs,
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(qT.shape, q.dtype),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        # the scores again, dP = dO V^T, dQ = dS K
+        cost_estimate=cost(6 * n_scores * D, n_scores, *dq_args, dq_shape),
     )(*dq_args)
 
     # transposed sweep: kv tiles outer, q tiles inner
@@ -547,6 +566,10 @@ def _flash_backward(
         )
         dkv_args.append(mask_arg)
 
+    dkv_shape = [
+        jax.ShapeDtypeStruct(kT.shape, k.dtype),
+        jax.ShapeDtypeStruct(vT.shape, v.dtype),
+    ]
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, causal=causal, sm_scale=sm_scale,
@@ -555,16 +578,16 @@ def _flash_backward(
         grid=(B, H, n_kv, n_q),
         in_specs=dkv_in_specs,
         out_specs=[kvspec_t, kvspec_t],
-        out_shape=[
-            jax.ShapeDtypeStruct(kT.shape, k.dtype),
-            jax.ShapeDtypeStruct(vT.shape, v.dtype),
-        ],
+        out_shape=dkv_shape,
         scratch_shapes=[
             pltpu.VMEM((block_kv, D), jnp.float32),
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        # the scores again, dV = P^T dO, dP = dO V^T, dK = dS^T Q
+        cost_estimate=cost(8 * n_scores * D, n_scores,
+                           *dkv_args, *dkv_shape),
     )(*dkv_args)
 
     return (

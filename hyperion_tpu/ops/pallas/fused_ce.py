@@ -45,7 +45,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hyperion_tpu.ops.attention import NEG_INF
-from hyperion_tpu.ops.pallas.backend import LANES, interpret_on_backend
+from hyperion_tpu.ops.pallas.backend import (
+    LANES,
+    cost,
+    interpret_on_backend,
+)
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_V = 2048
@@ -152,19 +156,24 @@ def _run_forward(logits, targets, block_n, block_v):
     bv = min(block_v, Vp)
     n_v = Vp // bv
     row = pl.BlockSpec((bn, LANES), lambda i, j: (i, 0))
+    args = (lp, _lanes(tp.astype(jnp.int32)))
+    out_shape = [
+        jax.ShapeDtypeStruct((Np, LANES), jnp.float32),
+        jax.ShapeDtypeStruct((Np, LANES), jnp.float32),
+    ]
     loss, lse_p = pl.pallas_call(
         functools.partial(_fwd_kernel, block_v=bv, n_v=n_v),
         grid=(Np // bn, n_v),
         in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j)), row],
         out_specs=[row, row],
-        out_shape=[
-            jax.ShapeDtypeStruct((Np, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((Np, LANES), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bn, LANES), jnp.float32)] * 3,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(lp, _lanes(tp.astype(jnp.int32)))
+        # per logit: the running max, the shift, the sum, the target's
+        # pick; one exp a logit and one log a row
+        cost_estimate=cost(4 * Np * Vp, Np * Vp + Np, *args, *out_shape),
+    )(*args)
     # residuals keep the PADDED arrays so backward re-pads nothing —
     # padding the [N, V] logits twice would add a full extra HBM copy
     # of the step's largest tensor
@@ -190,15 +199,19 @@ def _bwd(block_n, block_v, residuals, g):
     bv = min(block_v, Vp)
     g_p = jnp.pad(g.astype(jnp.float32), (0, Np - N))
     row = pl.BlockSpec((bn, LANES), lambda i, j: (i, 0))
+    args = (lp, _lanes(tp.astype(jnp.int32)), _lanes(lse_p), _lanes(g_p))
+    out_shape = jax.ShapeDtypeStruct((Np, Vp), lp.dtype)
     dlogits = pl.pallas_call(
         functools.partial(_bwd_kernel, block_v=bv),
         grid=(Np // bn, Vp // bv),
         in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j)), row, row, row],
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Np, Vp), lp.dtype),
+        out_shape=out_shape,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(lp, _lanes(tp.astype(jnp.int32)), _lanes(lse_p), _lanes(g_p))
+        # per logit: the shift, the target's one, the scale by g; one exp
+        cost_estimate=cost(3 * Np * Vp, Np * Vp, *args, out_shape),
+    )(*args)
     return dlogits[:N, :V], None
 
 

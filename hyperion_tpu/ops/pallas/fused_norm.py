@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from hyperion_tpu.ops.pallas.backend import interpret_on_backend
+from hyperion_tpu.ops.pallas.backend import cost, interpret_on_backend
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -46,10 +46,12 @@ def _kernel_no_res(x_ref, w_ref, b_ref, o_ref, *, eps: float):
     _kernel(x_ref, None, w_ref, b_ref, o_ref, eps=eps)
 
 
-def _row_blocked_call(kernel, x, extra_row_args, vec_args, block_rows):
+def _row_blocked_call(kernel, x, extra_row_args, vec_args, block_rows,
+                      flops_per_element):
     """Shared scaffolding for row-wise norm kernels: flatten to
     (rows, d), tile rows into blocks, broadcast the [d]-shaped vectors
-    to every block, run one fused pass."""
+    to every block, run one fused pass. `flops_per_element` is the
+    kernel's arithmetic on one element of x, for its cost estimate."""
     orig_shape = x.shape
     d = orig_shape[-1]
     x2 = x.reshape(-1, d)
@@ -64,26 +66,32 @@ def _row_blocked_call(kernel, x, extra_row_args, vec_args, block_rows):
     in_specs = (
         [row_spec] * (1 + len(extra_row_args)) + [vec_spec] * len(vec_args)
     )
+    out_shape = jax.ShapeDtypeStruct(x2.shape, x.dtype)
     out = pl.pallas_call(
         kernel,
         grid=(rows // block,),
         in_specs=in_specs,
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        out_shape=out_shape,
         interpret=_interpret(),
+        # one rsqrt a row; every operand and the result move once
+        cost_estimate=cost(flops_per_element * rows * d, rows,
+                           *args, out_shape),
     )(*args)
     return out.reshape(orig_shape)
 
 
 def _forward(x, residual, weight, bias, eps, block_rows):
+    # mean (1), centred square and its mean (3), normalise (2), scale
+    # and shift (2); the residual's add is one more
     if residual is not None:
         return _row_blocked_call(
             functools.partial(_kernel, eps=eps),
-            x, [residual], [weight, bias], block_rows,
+            x, [residual], [weight, bias], block_rows, 9,
         )
     return _row_blocked_call(
         functools.partial(_kernel_no_res, eps=eps),
-        x, [], [weight, bias], block_rows,
+        x, [], [weight, bias], block_rows, 8,
     )
 
 
@@ -142,8 +150,10 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def _rms_forward(x, weight, eps, block_rows):
+    # square and its mean (2), normalise (1), scale (1)
     return _row_blocked_call(
-        functools.partial(_rms_kernel, eps=eps), x, [], [weight], block_rows
+        functools.partial(_rms_kernel, eps=eps), x, [], [weight], block_rows,
+        4,
     )
 
 

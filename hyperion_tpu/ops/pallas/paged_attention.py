@@ -92,6 +92,7 @@ from hyperion_tpu.ops.attention import NEG_INF
 from hyperion_tpu.ops.pallas.backend import (
     LANES,      # softmax statistics are carried lane-replicated
     SUBLANES,   # query rows are padded to a whole fp32 sublane tile
+    cost,
     interpret_on_backend,
 )
 
@@ -239,12 +240,21 @@ def paged_attention(q, k_pool, v_pool, block_tables, base):
             pltpu.VMEM((rows_p, D), jnp.float32),
         ],
     )
+    out_shape = jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype)
+    # every table entry's block of K and of V is read once (unmapped
+    # entries read the null block), never the pools whole; two products
+    # of rows x bs x D per block, one exponential per score
+    chain = B * MB * Hkv * bs
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bs=bs, mb=MB, rep=rep, t=T),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype),
+        out_shape=out_shape,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        cost_estimate=cost(
+            4 * chain * rows * D, chain * rows,
+            qg, out_shape, block_tables, base,
+            extra_bytes=2 * chain * D * k_pool.dtype.itemsize),
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(base, jnp.int32),
       qg, k_pool, v_pool)
     return (

@@ -101,6 +101,7 @@ from hyperion_tpu.obs.tickprof import (
 )
 from hyperion_tpu.serve.journal import MAX_REPLAYS_DEFAULT
 from hyperion_tpu.serve.metrics import ServeMetrics
+from hyperion_tpu.utils.profiling import annotate
 from hyperion_tpu.serve.queue import (
     CLASS_BATCH,
     CLASS_INTERACTIVE,
@@ -126,6 +127,9 @@ _CHUNK_ADMIT = object()
 # every Engine in a process shares one jit cache per surface, so two
 # engines over the same model and shapes (the test suite's shape, and
 # any multi-engine deployment's) compile each executable exactly once.
+# What runs here outside the model has no module boundary to name it in
+# a device trace, so it sits under `jax.named_scope`s: `sampling`,
+# `slot_state`, `kv_copy` (obs/xprof.py groups device time by them).
 
 def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
     # every live slot advances one token: write last_token's K/V at
@@ -139,25 +143,27 @@ def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
         variables, st["last_token"][:, None],
         cache=cache, cache_index=st["lengths"], block_tables=bt,
     )
-    keys = jax.vmap(jax.random.fold_in)(st["keys"], st["lengths"])
-    nxt = sample_token_slots(
-        logits[:, 0], keys,
-        st["temperature"], st["top_k"], st["top_p"],
-    )
-    nxt = jnp.where(act, nxt, jnp.int32(pad_id))
-    adv = act.astype(jnp.int32)
-    gen = st["generated"] + adv
-    lengths = st["lengths"] + adv
-    hit_eos = (nxt == eos_id) if eos_id is not None \
-        else jnp.zeros_like(act)
-    finished = act & (hit_eos | (gen >= st["budget"]))
-    st = {
-        **st,
-        "last_token": jnp.where(act, nxt, st["last_token"]),
-        "generated": gen,
-        "lengths": lengths,
-        "active": act & ~finished,
-    }
+    with jax.named_scope("sampling"):
+        keys = jax.vmap(jax.random.fold_in)(st["keys"], st["lengths"])
+        nxt = sample_token_slots(
+            logits[:, 0], keys,
+            st["temperature"], st["top_k"], st["top_p"],
+        )
+    with jax.named_scope("slot_state"):
+        nxt = jnp.where(act, nxt, jnp.int32(pad_id))
+        adv = act.astype(jnp.int32)
+        gen = st["generated"] + adv
+        lengths = st["lengths"] + adv
+        hit_eos = (nxt == eos_id) if eos_id is not None \
+            else jnp.zeros_like(act)
+        finished = act & (hit_eos | (gen >= st["budget"]))
+        st = {
+            **st,
+            "last_token": jnp.where(act, nxt, st["last_token"]),
+            "generated": gen,
+            "lengths": lengths,
+            "active": act & ~finished,
+        }
     return cache, st, nxt, finished
 
 
@@ -188,45 +194,50 @@ def _spec_tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live,
     # temp>0 rows draw with the slot key folded at that position —
     # the exact fold the sequential tick performs — so a seeded
     # sampling stream is unchanged whether its drafts hit or miss
-    pos = st["lengths"][:, None] + jnp.arange(k + 1, dtype=jnp.int32)[None, :]
-    keys = jax.vmap(jax.vmap(jax.random.fold_in, in_axes=(None, 0)))(
-        st["keys"], pos)
-    t_arr = jax.vmap(
-        lambda lg, ky: sample_token_slots(
-            lg, ky, st["temperature"], st["top_k"], st["top_p"]),
-        in_axes=1, out_axes=1,
-    )(logits, keys)  # [S, k+1]
-    m, v = accept_draft(drafts, t_arr)
-    # emit v[:, j] iff j is within the accepted prefix (+correction),
-    # within the remaining budget, and no earlier eos in the window —
-    # active rows always emit >= 1 (j=0 is the correction of an empty
-    # prefix and budget >= 1 while active), matching the sequential
-    # tick's liveness
-    iota = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
-    is_eos = (v == eos_id) if eos_id is not None \
-        else jnp.zeros(v.shape, bool)
-    eos_before = jnp.cumsum(is_eos.astype(jnp.int32), axis=1) \
-        - is_eos.astype(jnp.int32)
-    remaining = st["budget"] - st["generated"]
-    emit = (iota <= m[:, None]) & (iota < remaining[:, None]) \
-        & (eos_before == 0) & act[:, None]
-    cnt = emit.sum(axis=1).astype(jnp.int32)
-    out = jnp.where(emit, v, jnp.int32(pad_id))
-    last_i = jnp.maximum(cnt - 1, 0)[:, None]
-    last = jnp.take_along_axis(v, last_i, axis=1)[:, 0]
-    ended_eos = jnp.take_along_axis(is_eos, last_i, axis=1)[:, 0] & (cnt > 0)
-    gen = st["generated"] + cnt
-    finished = act & (ended_eos | (gen >= st["budget"]))
-    st = {
-        **st,
-        "last_token": jnp.where(act & (cnt > 0), last, st["last_token"]),
-        "generated": gen,
-        "lengths": st["lengths"] + cnt,
-        "active": act & ~finished,
-    }
-    # accepted DRAFTS only (the correction token is a normal decode
-    # token, not a draft win) — what the acceptance-rate gauge reads
-    acc = jnp.minimum(m, cnt)
+    with jax.named_scope("sampling"):
+        pos = st["lengths"][:, None] \
+            + jnp.arange(k + 1, dtype=jnp.int32)[None, :]
+        keys = jax.vmap(jax.vmap(jax.random.fold_in, in_axes=(None, 0)))(
+            st["keys"], pos)
+        t_arr = jax.vmap(
+            lambda lg, ky: sample_token_slots(
+                lg, ky, st["temperature"], st["top_k"], st["top_p"]),
+            in_axes=1, out_axes=1,
+        )(logits, keys)  # [S, k+1]
+        m, v = accept_draft(drafts, t_arr)
+    with jax.named_scope("slot_state"):
+        # emit v[:, j] iff j is within the accepted prefix (+correction),
+        # within the remaining budget, and no earlier eos in the window —
+        # active rows always emit >= 1 (j=0 is the correction of an empty
+        # prefix and budget >= 1 while active), matching the sequential
+        # tick's liveness
+        iota = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
+        is_eos = (v == eos_id) if eos_id is not None \
+            else jnp.zeros(v.shape, bool)
+        eos_before = jnp.cumsum(is_eos.astype(jnp.int32), axis=1) \
+            - is_eos.astype(jnp.int32)
+        remaining = st["budget"] - st["generated"]
+        emit = (iota <= m[:, None]) & (iota < remaining[:, None]) \
+            & (eos_before == 0) & act[:, None]
+        cnt = emit.sum(axis=1).astype(jnp.int32)
+        out = jnp.where(emit, v, jnp.int32(pad_id))
+        last_i = jnp.maximum(cnt - 1, 0)[:, None]
+        last = jnp.take_along_axis(v, last_i, axis=1)[:, 0]
+        ended_eos = jnp.take_along_axis(is_eos, last_i, axis=1)[:, 0] \
+            & (cnt > 0)
+        gen = st["generated"] + cnt
+        finished = act & (ended_eos | (gen >= st["budget"]))
+        st = {
+            **st,
+            "last_token": jnp.where(act & (cnt > 0), last,
+                                    st["last_token"]),
+            "generated": gen,
+            "lengths": st["lengths"] + cnt,
+            "active": act & ~finished,
+        }
+        # accepted DRAFTS only (the correction token is a normal decode
+        # token, not a draft win) — what the acceptance-rate gauge reads
+        acc = jnp.minimum(m, cnt)
     return cache, st, out, cnt, acc, finished
 
 
@@ -244,28 +255,30 @@ def _prefill_impl(model, eos_id, variables, cache, st, prompt, bt_row,
         variables, prompt, cache=cache, cache_index=start,
         block_tables=bt_row[None],
     )
-    last = jax.lax.dynamic_slice_in_dim(
-        logits[0], true_len - 1, 1, axis=0)  # [1, V]
-    # fold position = (total prompt length - 1): identical whether the
-    # prefix came from cache or compute, so a hit never shifts the
-    # sampling stream
-    fkey = jax.random.fold_in(key, start + true_len - 1)
-    first = sample_token_slots(
-        last, fkey[None], temperature[None], top_k[None], top_p[None],
-    )[0]
-    hit_eos = (first == eos_id) if eos_id is not None else False
-    finished = jnp.logical_or(hit_eos, budget <= 1)
-    st = {
-        "lengths": st["lengths"].at[slot].set(start + true_len),
-        "active": st["active"].at[slot].set(~finished),
-        "last_token": st["last_token"].at[slot].set(first),
-        "generated": st["generated"].at[slot].set(1),
-        "budget": st["budget"].at[slot].set(budget),
-        "temperature": st["temperature"].at[slot].set(temperature),
-        "top_k": st["top_k"].at[slot].set(top_k),
-        "top_p": st["top_p"].at[slot].set(top_p),
-        "keys": st["keys"].at[slot].set(key),
-    }
+    with jax.named_scope("sampling"):
+        last = jax.lax.dynamic_slice_in_dim(
+            logits[0], true_len - 1, 1, axis=0)  # [1, V]
+        # fold position = (total prompt length - 1): identical whether
+        # the prefix came from cache or compute, so a hit never shifts
+        # the sampling stream
+        fkey = jax.random.fold_in(key, start + true_len - 1)
+        first = sample_token_slots(
+            last, fkey[None], temperature[None], top_k[None], top_p[None],
+        )[0]
+    with jax.named_scope("slot_state"):
+        hit_eos = (first == eos_id) if eos_id is not None else False
+        finished = jnp.logical_or(hit_eos, budget <= 1)
+        st = {
+            "lengths": st["lengths"].at[slot].set(start + true_len),
+            "active": st["active"].at[slot].set(~finished),
+            "last_token": st["last_token"].at[slot].set(first),
+            "generated": st["generated"].at[slot].set(1),
+            "budget": st["budget"].at[slot].set(budget),
+            "temperature": st["temperature"].at[slot].set(temperature),
+            "top_k": st["top_k"].at[slot].set(top_k),
+            "top_p": st["top_p"].at[slot].set(top_p),
+            "keys": st["keys"].at[slot].set(key),
+        }
     return cache, st, first, finished
 
 
@@ -292,10 +305,12 @@ def _copy_impl(cache, src, dst):
     # duplicate the writer may overwrite from its divergence offset
     # onward. src/dst are [C] vectors so one executable serves every
     # fork.
-    return [
-        {kv: layer[kv].at[dst].set(layer[kv][src]) for kv in ("k", "v")}
-        for layer in cache
-    ]
+    with jax.named_scope("kv_copy"):
+        return [
+            {kv: layer[kv].at[dst].set(layer[kv][src])
+             for kv in ("k", "v")}
+            for layer in cache
+        ]
 
 
 _SHARED_JITS: dict[bool, tuple] = {}
@@ -574,11 +589,12 @@ class Engine:
         # introspection plane: compile ledger + host-tick profiler +
         # flight recorder (all host-only — none touch the device)
         self.ledger = CompileLedger()
-        self.tickprof = TickProfiler()
+        # every segment of a step is timed through this profiler and is
+        # a span on the device profiler's clock meanwhile
+        self.tickprof = TickProfiler(clock=_CLOCK, annotate=annotate)
         self.flight = (FlightRecorder(flight_path, run=self.tracer.run)
                        if flight_path else null_flight_recorder())
-        self._journal_s = 0.0     # cumulative journal seconds (see _sink_s)
-        self._bt_upload_s = 0.0   # cumulative block-table upload seconds
+        self._prefill_tokens = 0  # padded tokens prefilled this step
         self._last_prefill_bucket: int | None = None
         # `.nbytes` is shape metadata — summing it syncs nothing
         self._param_bytes = int(sum(
@@ -736,25 +752,35 @@ class Engine:
     def _prefill_call(self, req: Request, slot: int, *, start: int = 0,
                       prompt: np.ndarray | None = None,
                       budget: int | None = None,
-                      bucket_len: int | None = None):
+                      bucket_len: int | None = None,
+                      seg: str = "admit"):
+        """One prefill: the prompt's upload, the dispatch and the fetch
+        of its first token, each a child of the step segment `seg` that
+        names the bucket and the prefix hit it ran with."""
         ids = req.prompt_ids if prompt is None else prompt
         suffix = ids[start:]
         P = int(suffix.shape[0])
         Pb = bucket_len or self.bucket(P)
         self._last_prefill_bucket = Pb   # churn context for the ledger
-        buf = np.full((1, Pb), self.cfg.pad_id, np.int32)
-        buf[0, :P] = suffix
-        self._cache, self._state, first, finished = self._prefill_jit(
-            self.model, self.cfg.eos_id,
-            self.variables, self._cache, self._state,
-            jnp.asarray(buf), jnp.asarray(self._bt[slot]),
-            jnp.int32(slot), jnp.int32(start), jnp.int32(P),
-            jnp.float32(req.temperature), jnp.int32(req.top_k),
-            jnp.float32(req.top_p),
-            jnp.int32(req.max_new_tokens if budget is None else budget),
-            jax.random.key(req.seed),
-        )
-        return int(first), bool(finished)
+        self._prefill_tokens += Pb
+        prof, at = self.tickprof, {"bucket": Pb, "start": start}
+        with prof.seg(f"{seg}/upload", **at):
+            buf = np.full((1, Pb), self.cfg.pad_id, np.int32)
+            buf[0, :P] = suffix
+            args = (
+                jnp.asarray(buf), jnp.asarray(self._bt[slot]),
+                jnp.int32(slot), jnp.int32(start), jnp.int32(P),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jnp.float32(req.top_p),
+                jnp.int32(req.max_new_tokens if budget is None else budget),
+                jax.random.key(req.seed),
+            )
+        with prof.seg(f"{seg}/dispatch", **at):
+            self._cache, self._state, first, finished = self._prefill_jit(
+                self.model, self.cfg.eos_id,
+                self.variables, self._cache, self._state, *args)
+        with prof.seg(f"{seg}/fetch", **at):
+            return int(first), bool(finished)
 
     def _live_mask(self) -> np.ndarray:
         """Slots the decode tick may advance: occupied AND not mid-
@@ -767,20 +793,27 @@ class Engine:
              for s, r in enumerate(self._slots)),
             bool, len(self._slots))
 
-    def _tick_device(self):
+    def _tables_on_device(self) -> tuple:
         if self._bt_dev is None:
             # upload only when the table or slot liveness changed —
             # steady-state decode re-uses the device copies, so a tick
-            # costs zero host->device traffic
-            t0u = _CLOCK()
-            self._bt_dev = (jnp.asarray(self._bt),
-                            jnp.asarray(self._live_mask()))
-            self._bt_upload_s += _CLOCK() - t0u
-        self._cache, self._state, toks, fins = self._tick_jit(
-            self.model, self.cfg.eos_id, self.cfg.pad_id,
-            self.variables, self._cache, self._state, *self._bt_dev)
+            # costs zero host->device traffic. A segment of its own:
+            # `device` is the call's wall net of it
+            with self.tickprof.seg("bt_upload"):
+                self._bt_dev = (jnp.asarray(self._bt),
+                                jnp.asarray(self._live_mask()))
+        return self._bt_dev
+
+    def _tick_device(self):
+        prof = self.tickprof
+        tables = self._tables_on_device()
+        with prof.seg("device/dispatch"):
+            self._cache, self._state, toks, fins = self._tick_jit(
+                self.model, self.cfg.eos_id, self.cfg.pad_id,
+                self.variables, self._cache, self._state, *tables)
         # the host fetch is the fence: tick spans time real work
-        return np.asarray(toks), np.asarray(fins)
+        with prof.seg("device/fetch"):
+            return np.asarray(toks), np.asarray(fins)
 
     def _collect_drafts(self) -> np.ndarray:
         """[S, spec_k] proposals for this tick, one drafter call per
@@ -796,17 +829,16 @@ class Engine:
         return drafts
 
     def _spec_tick_device(self, drafts: np.ndarray):
-        if self._bt_dev is None:
-            t0u = _CLOCK()
-            self._bt_dev = (jnp.asarray(self._bt),
-                            jnp.asarray(self._live_mask()))
-            self._bt_upload_s += _CLOCK() - t0u
-        self._cache, self._state, out, cnt, acc, fins = self._spec_jit(
-            self.model, self.cfg.eos_id, self.cfg.pad_id,
-            self.variables, self._cache, self._state, *self._bt_dev,
-            jnp.asarray(drafts))
-        return (np.asarray(out), np.asarray(cnt), np.asarray(acc),
-                np.asarray(fins))
+        prof = self.tickprof
+        tables = self._tables_on_device()
+        with prof.seg("device/dispatch"):
+            self._cache, self._state, out, cnt, acc, fins = self._spec_jit(
+                self.model, self.cfg.eos_id, self.cfg.pad_id,
+                self.variables, self._cache, self._state, *tables,
+                jnp.asarray(drafts))
+        with prof.seg("device/fetch"):
+            return (np.asarray(out), np.asarray(cnt), np.asarray(acc),
+                    np.asarray(fins))
 
     # --------------------------------------------------- block plumbing
 
@@ -908,11 +940,11 @@ class Engine:
         self._bt[slot, :] = 0
         self._bt_dev = None
 
-    def _admit(self, req: Request, slot: int) -> TokenEvent | None:
-        """Prefill `req` into `slot` through the paged pool: radix
-        lookup -> share/COW -> allocate exclusives -> prefill the
-        suffix -> register prompt blocks. Returns the first-token
-        event, or None when allocation lost a race (caller requeues)."""
+    def _claim_blocks(self, req: Request):
+        """The block half of an admission: radix lookup, host-tier
+        probe, pins, allocation, copy-on-write fork and restore. Returns
+        (prompt, budget, start, seq), `start` the prefix hit in tokens,
+        or None when allocation lost a race."""
         reserve = self._pending_reserve.pop(req.id, 0)
         prompt, budget = self._effective(req)
         P = int(prompt.shape[0])
@@ -1004,6 +1036,19 @@ class Engine:
                 else device_start,
                 host_tokens=len(host_payloads) * bs)
             self._hot_roots.note(prefix_root_digest(prompt))
+        return prompt, budget, start, seq
+
+    def _admit(self, req: Request, slot: int) -> TokenEvent | None:
+        """Prefill `req` into `slot` through the paged pool: radix
+        lookup -> share/COW -> allocate exclusives -> prefill the
+        suffix -> register prompt blocks. Returns the first-token
+        event, or None when allocation lost a race (caller requeues)."""
+        with self.tickprof.seg("admit/blocks"):
+            claimed = self._claim_blocks(req)
+        if claimed is None:
+            return None
+        prompt, budget, start, seq = claimed
+        P = int(prompt.shape[0])
         resumed = req.first_token_at is not None
         C = self.cfg.prefill_chunk
         if C > 0 and P - start > C:
@@ -1094,16 +1139,21 @@ class Engine:
         P = int(prompt.shape[0])
         pos = ck["pos"]
         if P - pos > C:
+            prof, at = self.tickprof, {"bucket": C, "start": pos}
+            self._prefill_tokens += C
             t0 = _CLOCK()
-            self._cache = self._chunk_jit(
-                self.model, self.variables, self._cache,
-                jnp.asarray(np.asarray(prompt[pos:pos + C],
-                                       np.int32)[None, :]),
-                jnp.asarray(ck["row"]), jnp.int32(pos))
+            with prof.seg("chunk/upload", **at):
+                args = (jnp.asarray(np.asarray(prompt[pos:pos + C],
+                                               np.int32)[None, :]),
+                        jnp.asarray(ck["row"]), jnp.int32(pos))
+            with prof.seg("chunk/dispatch", **at):
+                self._cache = self._chunk_jit(
+                    self.model, self.variables, self._cache, *args)
             # fence: the segment's wall time must land in THIS step's
             # chunk segment, not smear into the next device call
-            jax.block_until_ready(self._cache)
-            dt = _CLOCK() - t0
+            with prof.seg("chunk/fetch", **at):
+                jax.block_until_ready(self._cache)
+            dt = _CLOCK() - t0   # the request's own account, not the step's
             if ck["resumed"]:
                 req.replay_s += dt
             else:
@@ -1120,7 +1170,8 @@ class Engine:
         resumed = ck["resumed"]
         with self.tracer.span("serve_prefill", step=self._tick_no) as sp:
             first, finished = self._prefill_call(
-                req, slot, start=pos, prompt=prompt, budget=budget)
+                req, slot, start=pos, prompt=prompt, budget=budget,
+                seg="chunk")
             sp.set(request=req.id, slot=slot, prompt_len=P,
                    cached_tokens=pos, bucket=self.bucket(P - pos),
                    resumed=resumed, chunked=True)
@@ -1284,52 +1335,50 @@ class Engine:
         # token a client ever received is already durable, so a replay
         # can never re-compute — hence never re-deliver — it. The
         # client stream stays duplicate-free across kills.
+        # token AND timeout emissions happen only on the engine thread
+        # inside step(): those alone enter the step's record (its
+        # `journal` and `sink` segments, netted out of whichever segment
+        # they happen in). Reject writes run on front-end reader threads
+        # in parallel with ticks: a span, never the record
+        on_step = ev.kind in ("token", "timed_out")
         if self.journal is not None and req._journaled:
-            jt0 = _CLOCK()
-            if ev.kind == "token" and ev.token is not None:
-                self.journal.token(req.id, ev.token)
-            if ev.finished:
-                self.journal.finish(
-                    req.id,
-                    "done" if ev.kind in ("token", "done")
-                    else (ev.reason or ev.kind))
-            if ev.kind in ("token", "timed_out"):
-                # engine-thread emissions only (the _sink_s guard below,
-                # same reasoning): reject writes on front-end reader
-                # threads must not pollute the step profiler's journal
-                # segment
-                self._journal_s += _CLOCK() - jt0
+            with self.tickprof.seg("journal", record=on_step):
+                if ev.kind == "token" and ev.token is not None:
+                    self.journal.token(req.id, ev.token)
+                if ev.finished:
+                    self.journal.finish(
+                        req.id,
+                        "done" if ev.kind in ("token", "done")
+                        else (ev.reason or ev.kind))
             self._journal_guard()
         if self.chaos is not None:
             # the request rides along so tenant-targeted client chaos
             # (slowloris@tenant=...) can pick its victim
             self.chaos.on_client(self._tick_no, req)
         if req.sink is not None:
-            t0 = _CLOCK()
-            try:
-                req.sink(ev)
-            except Exception:  # noqa: BLE001
-                # a client that died mid-stream must cost ITS request,
-                # never the engine: drop the sink, let the slot finish
-                # out its budget (eos/budget latch frees it) — and say
-                # so on the stream, a vanished consumer is evidence
-                req.sink = None
-                self.metrics.on_dropped_sink()
-                self.tracer.event("client_disconnected", request=req.id,
-                                  tick=self._tick_no, **_tr(req))
+            with self.tickprof.seg("sink", record=on_step) as sg:
+                try:
+                    req.sink(ev)
+                except Exception:  # noqa: BLE001
+                    # a client that died mid-stream must cost ITS
+                    # request, never the engine: drop the sink, let the
+                    # slot finish out its budget (eos/budget latch frees
+                    # it) — and say so on the stream, a vanished consumer
+                    # is evidence
+                    req.sink = None
+                    self.metrics.on_dropped_sink()
+                    self.tracer.event("client_disconnected",
+                                      request=req.id, tick=self._tick_no,
+                                      **_tr(req))
             # charge transport time to the REQUEST (a slow client must
             # show up in its own tail attribution, not vanish into the
             # decode gap it inflates)
-            dt = _CLOCK() - t0
+            dt = sg.gross
             req.client_write_s += dt
-            if ev.kind in ("token", "timed_out"):
-                # token AND timeout emissions happen only on the engine
-                # thread inside step(), so this read-modify-write is
+            if on_step:
                 # serial with the decode-gap netting that reads it, and
                 # both block live slots' gaps (a dead client stalling a
-                # timeout write must not read as decode). Reject writes
-                # run on front-end reader threads in parallel with
-                # ticks and must NOT pollute the counter
+                # timeout write must not read as decode)
                 self._sink_s += dt
             self.metrics.on_client_write(dt)
         if self.on_event is not None:
@@ -1696,17 +1745,31 @@ class Engine:
         (block-gated, prefill, budget-limited), ensure every live slot
         owns its next write block (preempting on exhaustion), advance
         all active slots — one token each, or 1..spec_k+1 under the
-        speculative tick — and route emissions."""
+        speculative tick — and route emissions. The whole round is one
+        step of the host-tick profiler (obs/tickprof.py): the span
+        `serve.step`, each stretch a `prof.seg(...)`. Journal and sink
+        writes are segments of their own inside `_emit` wherever they
+        happen, so the segments around them come out net of them."""
+        # the record and the span carry the number the step STARTED under,
+        # the one its `serve_tick` JSONL span and its events carry
+        with self.tickprof.tick(self._tick_no) as tk:
+            emissions = self._step()
+            tk.count(
+                # positions whose keys and values the live slots hold in
+                # the pool: host bookkeeping, no device read
+                kv_tokens=sum(q.n_filled for q in self._seqs
+                              if q is not None),
+                prefill_tokens=self._prefill_tokens)
+        if self.flight.due(self._tick_no):
+            self.flight.spill("periodic", self._flight_payload(),
+                              tick=self._tick_no)
+        return emissions
+
+    def _step(self) -> list[TokenEvent]:
         emissions: list[TokenEvent] = []
         now = _CLOCK()
-        # host-tick profiler (obs/tickprof.py): stamp each segment of
-        # this step into `seg` — pure perf-counter arithmetic, no device
-        # interaction. Journal/sink time is accumulated inside _emit
-        # wherever it happens, so enclosing segments NET those deltas
-        # out rather than double-charging them.
-        seg: dict[str, float] = {}
-        p_start = now
-        j_start, s_start = self._journal_s, self._sink_s
+        prof = self.tickprof
+        self._prefill_tokens = 0
 
         if self._governor is not None:
             tr = self._governor.update(len(self.queue))
@@ -1757,105 +1820,98 @@ class Engine:
                 self._emit(ev)
                 emissions.append(ev)
 
-        t_seg = _CLOCK()
-        free = [s for s, r in enumerate(self._slots) if r is None]
-        if free:
-            admit, expired = self.queue.pop_ready(
-                len(free), now, can_admit=self._can_admit)
-            # pop_ready only expires requests it reaches; a block-gated
-            # head stops the walk, so sweep the remainder too — a
-            # deadline behind a stalled head must still fire on time
-            expired += self.queue.drop_expired(now)
-        else:
-            admit, expired = [], self.queue.drop_expired(now)
-        if CLASS_INTERACTIVE in self.queue.gate_blocked:
-            # an interactive head is denied by the block gate while
-            # batch work holds slots: preempt the YOUNGEST batch slot
-            # to the queue (recompute resume — nothing is lost) so the
-            # freed blocks admit the interactive head next round. One
-            # victim per step: pool accounting settles between rounds,
-            # and a single long prompt must not massacre the whole
-            # batch tier in one tick.
-            batch_live = [
-                s for s, r in enumerate(self._slots)
-                if r is not None and r.sla_class == CLASS_BATCH]
-            if batch_live:
-                victim = max(batch_live,
-                             key=lambda t: self._seqs[t].order)
-                self._preempt(victim, reason="interactive_gate")
-        seg["queue_pop"] = _CLOCK() - t_seg
-        t_seg = _CLOCK()
-        j_mark, s_mark = self._journal_s, self._sink_s
-        for req in expired:
-            self.metrics.on_timeout()
-            req.finish_reason = "timed_out"
-            # enqueued_at, not submitted_at: a preempted-then-requeued
-            # request that expires spent part of its life in a slot,
-            # and that time is replay cost, not queue residency
-            queued = round(max(0.0, now - req.enqueued_at), 6)
-            self.tracer.event("request_timeout", request=req.id,
-                              waited_s=round(now - req.submitted_at, 3),
-                              queued_s=queued, **_tr(req))
-            ev = TokenEvent(req, None, True, kind="timed_out",
-                            reason="deadline exceeded in queue")
-            self._emit(ev)
-            emissions.append(ev)
-        while admit:
-            req = admit.pop(0)
-            slot = free.pop(0)
-            if self.chaos is not None:
-                # poison_request@id=... fires here, at the moment the
-                # request is about to occupy a slot — the journal has
-                # its admit record, so the crash-replay counter (the
-                # poison-pill rule) sees every death it causes
-                self.chaos.on_request(req.id)
-            resumed = self._account_pop(req)
-            ev = self._admit(req, slot)
-            if ev is _CHUNK_ADMIT:
-                # the slot is claimed and prefilling in chunks across
-                # later steps; no token yet, nothing to emit
-                continue
-            if ev is None:
-                # allocation raced an eviction between gate and admit:
-                # requeue head-first in arrival order and retry next
-                # round — degraded, never dropped. EVERY popped request
-                # streams the scheduled/requeued pair so no queue stint
-                # vanishes from the trace: the scheduled event banks
-                # the wait that just ended, the requeue mark starts the
-                # renewed one (and keeps resume flags for the re-pop)
-                req._preempted = resumed
-                for r in reversed([req] + admit):
-                    if r.admitted_at is not None and r is not req:
-                        r._preempted = self._account_pop(r)
-                    self.tracer.event(
-                        "request_requeued", request=r.id,
-                        tick=self._tick_no, reason="alloc_race")
-                    self.mgr.release(self._pending_reserve.pop(r.id, 0))
-                    self.queue.push_front(r)
-                break
-            self._emit(ev)
-            emissions.append(ev)
-            if ev.finished:
-                self._on_finished(req)
+        with prof.seg("queue_pop"):
+            free = [s for s, r in enumerate(self._slots) if r is None]
+            if free:
+                admit, expired = self.queue.pop_ready(
+                    len(free), now, can_admit=self._can_admit)
+                # pop_ready only expires requests it reaches; a block-gated
+                # head stops the walk, so sweep the remainder too — a
+                # deadline behind a stalled head must still fire on time
+                expired += self.queue.drop_expired(now)
+            else:
+                admit, expired = [], self.queue.drop_expired(now)
+            if CLASS_INTERACTIVE in self.queue.gate_blocked:
+                # an interactive head is denied by the block gate while
+                # batch work holds slots: preempt the YOUNGEST batch slot
+                # to the queue (recompute resume — nothing is lost) so the
+                # freed blocks admit the interactive head next round. One
+                # victim per step: pool accounting settles between rounds,
+                # and a single long prompt must not massacre the whole
+                # batch tier in one tick.
+                batch_live = [
+                    s for s, r in enumerate(self._slots)
+                    if r is not None and r.sla_class == CLASS_BATCH]
+                if batch_live:
+                    victim = max(batch_live,
+                                 key=lambda t: self._seqs[t].order)
+                    self._preempt(victim, reason="interactive_gate")
         # admit covers expiry + admission + their prefill calls, net of
         # journal/sink writes those paths perform
-        seg["admit"] = max(0.0, (_CLOCK() - t_seg)
-                           - (self._journal_s - j_mark)
-                           - (self._sink_s - s_mark))
+        with prof.seg("admit"):
+            for req in expired:
+                self.metrics.on_timeout()
+                req.finish_reason = "timed_out"
+                # enqueued_at, not submitted_at: a preempted-then-requeued
+                # request that expires spent part of its life in a slot,
+                # and that time is replay cost, not queue residency
+                queued = round(max(0.0, now - req.enqueued_at), 6)
+                self.tracer.event("request_timeout", request=req.id,
+                                  waited_s=round(now - req.submitted_at, 3),
+                                  queued_s=queued, **_tr(req))
+                ev = TokenEvent(req, None, True, kind="timed_out",
+                                reason="deadline exceeded in queue")
+                self._emit(ev)
+                emissions.append(ev)
+            while admit:
+                req = admit.pop(0)
+                slot = free.pop(0)
+                with prof.seg("admit/gate"):
+                    if self.chaos is not None:
+                        # poison_request@id=... fires here, at the moment
+                        # the request is about to occupy a slot — the
+                        # journal has its admit record, so the crash-
+                        # replay counter (the poison-pill rule) sees
+                        # every death it causes
+                        self.chaos.on_request(req.id)
+                    resumed = self._account_pop(req)
+                ev = self._admit(req, slot)
+                if ev is _CHUNK_ADMIT:
+                    # the slot is claimed and prefilling in chunks across
+                    # later steps; no token yet, nothing to emit
+                    continue
+                if ev is None:
+                    # allocation raced an eviction between gate and admit:
+                    # requeue head-first in arrival order and retry next
+                    # round — degraded, never dropped. EVERY popped request
+                    # streams the scheduled/requeued pair so no queue stint
+                    # vanishes from the trace: the scheduled event banks
+                    # the wait that just ended, the requeue mark starts the
+                    # renewed one (and keeps resume flags for the re-pop)
+                    req._preempted = resumed
+                    for r in reversed([req] + admit):
+                        if r.admitted_at is not None and r is not req:
+                            r._preempted = self._account_pop(r)
+                        self.tracer.event(
+                            "request_requeued", request=r.id,
+                            tick=self._tick_no, reason="alloc_race")
+                        self.mgr.release(self._pending_reserve.pop(r.id, 0))
+                        self.queue.push_front(r)
+                    break
+                self._emit(ev)
+                emissions.append(ev)
+                if ev.finished:
+                    self._on_finished(req)
 
         # one chunked-prefill segment per step, interleaved with the
         # decode tick below — the whole point: co-running slots tick
         # every step while a long prompt fills in bounded bites
-        t_seg = _CLOCK()
-        j_mark, s_mark = self._journal_s, self._sink_s
-        for ev in self._advance_chunks():
-            self._emit(ev)
-            emissions.append(ev)
-            if ev.finished:
-                self._on_finished(ev.request)
-        seg["chunk"] = max(0.0, (_CLOCK() - t_seg)
-                           - (self._journal_s - j_mark)
-                           - (self._sink_s - s_mark))
+        with prof.seg("chunk"):
+            for ev in self._advance_chunks():
+                self._emit(ev)
+                emissions.append(ev)
+                if ev.finished:
+                    self._on_finished(ev.request)
 
         if self.n_active:
             self._ensure_blocks()
@@ -1865,75 +1921,70 @@ class Engine:
                 self.chaos.on_tick(self._tick_no)
             spec = self._spec
             cnts = accs = None
-            t_seg = _CLOCK()
-            drafts = self._collect_drafts() if spec else None
-            seg["draft"] = _CLOCK() - t_seg
-            u_mark = self._bt_upload_s
-            with self.tracer.span("serve_tick", step=self._tick_no) as sp:
-                t0 = _CLOCK()
-                if spec:
-                    toks, cnts, accs, fins = self._spec_tick_device(drafts)
-                else:
-                    toks, fins = self._tick_device()
-                dur = _CLOCK() - t0
-                sp.set(active=self.n_active)
+            with prof.seg("draft"):
+                drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table
-            # upload (when the table went stale) and dispatch+wait
-            seg["bt_upload"] = self._bt_upload_s - u_mark
-            seg["device"] = max(0.0, dur - seg["bt_upload"])
+            # upload (`bt_upload`, when the table went stale) and
+            # `device`: its children dispatch and fetch
+            with self.tracer.span("serve_tick", step=self._tick_no) as sp:
+                with prof.seg("device") as dev:
+                    if spec:
+                        toks, cnts, accs, fins = \
+                            self._spec_tick_device(drafts)
+                    else:
+                        toks, fins = self._tick_device()
+                sp.set(active=self.n_active)
+            dur = dev.gross
             emitted = 0
             slot_ticks = 0
-            tnow = _CLOCK()
-            j_mark, s_mark = self._journal_s, self._sink_s
-            for s, req in enumerate(self._slots):
-                if req is None or s in self._chunking:
-                    # a chunking slot is masked out of the tick — its
-                    # lane computed pad into the null block, nothing
-                    # to route
-                    continue
-                slot_ticks += 1
-                n = int(cnts[s]) if spec else 1
-                if spec:
-                    self.metrics.on_spec(self.cfg.spec_k, int(accs[s]))
-                if n == 0:
-                    continue
-                self._seqs[s].n_filled += n
-                gap_from = getattr(req, "_last_emit_at", None)
-                if gap_from is not None:
-                    # the gap is wall time shared by every slot: net it
-                    # of ALL sink writes since this request's previous
-                    # emission (its own are charged to client_write;
-                    # neighbours' must not masquerade as decode). One
-                    # verify pass produced n tokens, so TPOT charges
-                    # the pass pro-rata across them — the per-token
-                    # cadence a streaming client actually experiences
-                    for _ in range(n):
-                        self.metrics.on_token_gap((tnow - gap_from) / n,
-                                                  req.sla_class)
-                    sink = self._sink_s - getattr(
-                        req, "_sink_mark", self._sink_s)
-                    req.decode_s += max(0.0, tnow - gap_from - sink)
-                req._last_emit_at = tnow
-                req._sink_mark = self._sink_s
-                fin_slot = bool(fins[s])
-                # every accepted token flows through the SAME per-token
-                # path the sequential tick uses: one journal `tok`
-                # record, one stream index, one sink write apiece —
-                # failover dedup and replay never see speculation
-                for j in range(n):
-                    tok = int(toks[s, j]) if spec else int(toks[s])
-                    ev = TokenEvent(req, tok, fin_slot and j == n - 1)
-                    self._emit(ev)
-                    emissions.append(ev)
-                    emitted += 1
-                if fin_slot:
-                    self._on_finished(req)
-                    self._free_slot(s)
-            # accept host path: token routing + gap netting, minus the
-            # journal/sink writes _emit charged to their own segments
-            seg["accept"] = max(0.0, (_CLOCK() - tnow)
-                                - (self._journal_s - j_mark)
-                                - (self._sink_s - s_mark))
+            # accept host path: token routing + gap netting, net of the
+            # journal/sink writes _emit times as segments of their own
+            with prof.seg("accept"):
+                tnow = _CLOCK()
+                for s, req in enumerate(self._slots):
+                    if req is None or s in self._chunking:
+                        # a chunking slot is masked out of the tick — its
+                        # lane computed pad into the null block, nothing
+                        # to route
+                        continue
+                    slot_ticks += 1
+                    n = int(cnts[s]) if spec else 1
+                    if spec:
+                        self.metrics.on_spec(self.cfg.spec_k, int(accs[s]))
+                    if n == 0:
+                        continue
+                    self._seqs[s].n_filled += n
+                    gap_from = getattr(req, "_last_emit_at", None)
+                    if gap_from is not None:
+                        # the gap is wall time shared by every slot: net it
+                        # of ALL sink writes since this request's previous
+                        # emission (its own are charged to client_write;
+                        # neighbours' must not masquerade as decode). One
+                        # verify pass produced n tokens, so TPOT charges
+                        # the pass pro-rata across them — the per-token
+                        # cadence a streaming client actually experiences
+                        for _ in range(n):
+                            self.metrics.on_token_gap((tnow - gap_from) / n,
+                                                      req.sla_class)
+                        sink = self._sink_s - getattr(
+                            req, "_sink_mark", self._sink_s)
+                        req.decode_s += max(0.0, tnow - gap_from - sink)
+                    req._last_emit_at = tnow
+                    req._sink_mark = self._sink_s
+                    fin_slot = bool(fins[s])
+                    # every accepted token flows through the SAME per-token
+                    # path the sequential tick uses: one journal `tok`
+                    # record, one stream index, one sink write apiece —
+                    # failover dedup and replay never see speculation
+                    for j in range(n):
+                        tok = int(toks[s, j]) if spec else int(toks[s])
+                        ev = TokenEvent(req, tok, fin_slot and j == n - 1)
+                        self._emit(ev)
+                        emissions.append(ev)
+                        emitted += 1
+                    if fin_slot:
+                        self._on_finished(req)
+                        self._free_slot(s)
             self.metrics.on_tick(dur, emitted, slot_ticks)
             self._tick_no += 1
             if self.cfg.snapshot_every \
@@ -1964,27 +2015,19 @@ class Engine:
                 self.flight.note("recompile_after_warmup",
                                  executable=g["executable"], **ctx)
 
-        seg["journal"] = self._journal_s - j_start
-        seg["sink"] = self._sink_s - s_start
-        t_seg = _CLOCK()
-        self.metrics.observe_state(
-            len(self.queue), self.n_active, self.cfg.slots)
-        self.metrics.observe_cache(
-            self.mgr.in_use, self.mgr.num_free, self.n_active,
-            self._block_bytes)
-        self._slo_tick()
-        roots = self._hot_roots.top()
-        self.hb.beat(step=self._tick_no, phase="serve",
-                     active=self.n_active, queue=len(self.queue),
-                     **({"alerts": self.slo.active_names()}
-                        if self.slo is not None else {}),
-                     **({"prefix_roots": roots} if roots else {}))
-        seg["slo"] = _CLOCK() - t_seg
-        self.tickprof.record(self._tick_no, seg,
-                             _CLOCK() - p_start)
-        if self.flight.due(self._tick_no):
-            self.flight.spill("periodic", self._flight_payload(),
-                              tick=self._tick_no)
+        with prof.seg("slo"):
+            self.metrics.observe_state(
+                len(self.queue), self.n_active, self.cfg.slots)
+            self.metrics.observe_cache(
+                self.mgr.in_use, self.mgr.num_free, self.n_active,
+                self._block_bytes)
+            self._slo_tick()
+            roots = self._hot_roots.top()
+            self.hb.beat(step=self._tick_no, phase="serve",
+                         active=self.n_active, queue=len(self.queue),
+                         **({"alerts": self.slo.active_names()}
+                            if self.slo is not None else {}),
+                         **({"prefix_roots": roots} if roots else {}))
         return emissions
 
     def run(

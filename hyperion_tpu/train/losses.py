@@ -32,7 +32,13 @@ def next_token_loss(
     are scored against token t+1. A target is counted only when it is a
     real (non-pad) token. impl="pallas" streams the vocab axis through
     the fused logsumexp+gather kernel (one HBM pass over the logits).
+    The whole of it runs under the scope `loss` (obs/xprof.py).
     """
+    with jax.named_scope("loss"):
+        return _next_token_loss(logits, input_ids, attention_mask, impl)
+
+
+def _next_token_loss(logits, input_ids, attention_mask, impl):
     targets = input_ids[:, 1:]
     pred = logits[:, :-1].astype(jnp.float32)
     if impl == "pallas":

@@ -100,7 +100,16 @@ def make_optimizer(
         )
     steps = []
     if grad_clip_norm and grad_clip_norm > 0:
-        steps.append(optax.clip_by_global_norm(grad_clip_norm))
+        clip = optax.clip_by_global_norm(grad_clip_norm)
+
+        def clip_update(updates, state, params=None):
+            # the same transformation under a name a device trace shows
+            # (`optimizer/grad_clip`, obs/xprof.py); its state is the
+            # clip's own, so no checkpoint moves
+            with jax.named_scope("grad_clip"):
+                return clip.update(updates, state, params)
+
+        steps.append(optax.GradientTransformation(clip.init, clip_update))
     steps.append(optax.adamw(lr, b1=b1, b2=b2, weight_decay=weight_decay))
     return optax.chain(*steps)
 

@@ -79,9 +79,10 @@ def make_train_step(
                 if rngs else None
             )
             (_, (metrics, new_bs)), grads = grad_fn(params, bs, mb, mb_rngs)
-            grads_acc = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
-            )
+            with jax.named_scope("grad_accum"):
+                grads_acc = jax.tree.map(
+                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
+                )
             return (grads_acc, new_bs), metrics
 
         zero = jax.tree.map(
@@ -91,7 +92,8 @@ def make_train_step(
             body, (zero, batch_stats),
             (jnp.arange(grad_accum), micro),
         )
-        grads = jax.tree.map(lambda g: g / grad_accum, grads)
+        with jax.named_scope("grad_accum"):
+            grads = jax.tree.map(lambda g: g / grad_accum, grads)
         metrics = jax.tree.map(
             lambda m: m.sum(0) if m.ndim else m, metrics
         )
@@ -108,9 +110,19 @@ def make_train_step(
         grads, metrics, new_bs = grads_and_metrics(
             state.params, state.batch_stats, batch, rngs
         )
-        grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, state.params)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # what follows the gradient has no module to name it in a device
+        # trace (obs/xprof.py): `grad_clip` is the cast and the global
+        # norm (the metric; the clipping of `make_optimizer` needs the
+        # same one, runs inside the optax chain and shows as
+        # `optimizer/grad_clip`), `optimizer` the update and its
+        # application
+        with jax.named_scope("grad_clip"):
+            grads = jax.tree.map(
+                lambda g, p: g.astype(p.dtype), grads, state.params)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -118,7 +130,8 @@ def make_train_step(
             batch_stats=new_bs,
         )
         metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("grad_clip"):
+            metrics["grad_norm"] = optax.global_norm(grads)
         return new_state, metrics
 
     return jax.jit(

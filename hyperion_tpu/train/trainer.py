@@ -398,7 +398,14 @@ def _epoch_loop(
                 t0 = time.perf_counter()
                 device_metrics = []
                 last_batch = None
-                for i, batch in enumerate(feed, start):
+                # in a profiler trace (utils/profiling.py) the loop is
+                # spans on the device's clock: the wait for the feed
+                # (`train.next_batch`), then per step `train` holding
+                # `train.dispatch` and, where a step is fenced,
+                # `train.fetch`. The JSONL spans and gauges below stay
+                # what doctor and `obs summarize` read
+                for i, batch in enumerate(
+                        profiling.annotated(feed, "train.next_batch"), start):
                     if max_steps and i >= max_steps:
                         break
                     gstep = epoch * steps_per_epoch + i
@@ -419,12 +426,13 @@ def _epoch_loop(
                     # CPU test mesh the pre-existing per-step fence runs
                     # inside the span, so step spans are device-honest
                     # exactly where the smoke run reads them.
-                    with tracer.span(
-                        "train_step", step=epoch * steps_per_epoch + i
-                    ) as sp:
-                        state, metrics = train_step(state, batch, rng)
+                    with profiling.step_annotate("train", gstep), \
+                            tracer.span("train_step", step=gstep) as sp:
+                        with profiling.annotate("train.dispatch"):
+                            state, metrics = train_step(state, batch, rng)
                         if fence_every_step:
-                            jax.block_until_ready(metrics)
+                            with profiling.annotate("train.fetch"):
+                                jax.block_until_ready(metrics)
                     device_metrics.append(metrics)  # on device until epoch end
                     last_batch = batch
                     # histogram/EMA/counters only: on a lazy backend
@@ -467,7 +475,8 @@ def _epoch_loop(
                 # timer — and before the profiler capture closes, so
                 # traces are complete
                 if device_metrics:
-                    host_fence(device_metrics[-1])
+                    with profiling.annotate("train.fetch"):
+                        host_fence(device_metrics[-1])
                 duration = time.perf_counter() - t0  # train-only
                 ep_span.set(epoch=epoch + 1, steps=len(device_metrics))
             # per-epoch telemetry: memory high-water, MFU against the

@@ -3,11 +3,23 @@
 Reference profiling is wall-clock brackets + memory counters only
 (SURVEY §5.1: `benchmarking.py:37-49`, memory probes throughout; no
 torch.profiler/rocprof integration anywhere). The TPU-native upgrade is
-`jax.profiler` trace capture: XLA emits per-op device timelines viewable
-in TensorBoard/XProf, which is how real TPU perf work is done.
+`jax.profiler` trace capture: XLA emits per-op device timelines, and the
+program's own host spans ride the same file on the same clock.
 
-`capture()` wraps any code region; trainers expose it via
-`--profile-dir` so one flag turns a training epoch into a trace.
+This module starts and stops traces and opens spans; nothing else does:
+
+  * `capture()` wraps any code region; trainers expose it via
+    `--profile-dir` so one flag turns a training epoch into a trace.
+    `on_demand_trace()` is the live server's (`obs profile`).
+  * `annotate(name, **args)` is a span on the profiler's clock
+    (`jax.profiler.TraceAnnotation`): the engine's step segments
+    (`serve.step/*`, through `obs/tickprof.py`) and the trainer's loop
+    (`train.next_batch`, `train.dispatch`, `train.fetch` inside a
+    `StepTraceAnnotation("train")`) are written with it. With no trace
+    being taken a span costs well under a microsecond.
+
+`obs/xprof.py` turns the trace these leave into numbers (`obs profile
+--summarize`).
 """
 
 from __future__ import annotations
@@ -29,15 +41,43 @@ def capture(trace_dir: str | Path | None):
         return
     trace_dir = Path(trace_dir)
     trace_dir.mkdir(parents=True, exist_ok=True)
-    with jax.profiler.trace(str(trace_dir)):
+    jax.profiler.start_trace(str(trace_dir), profiler_options=_options())
+    try:
         yield trace_dir
-    print(f"[profiling] trace written to {trace_dir} "
-          f"(view: tensorboard --logdir {trace_dir})")
+    finally:
+        jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named sub-region inside a capture (shows as a span in the trace)."""
-    return jax.profiler.TraceAnnotation(name)
+def _options():
+    """Host spans yes, Python calls no: the Python tracer slows the very
+    loop being traced and swamps the host lines with frames."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+def annotate(name: str, **args):
+    """Named span on the profiler's clock; `args` ride it as the span's
+    arguments (`tick=17`, `bucket=2048`)."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def step_annotate(name: str, step_num: int):
+    """A whole training step, as the profiler's tools know steps."""
+    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+
+
+def annotated(iterable, name: str):
+    """`iterable`, each `next()` of it inside the span `name`: how a
+    `for` loop's wait for its next item becomes visible in a trace."""
+    it = iter(iterable)
+    while True:
+        with annotate(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 # on-demand tracing (the `obs profile` control verb): one trace at a
@@ -60,7 +100,7 @@ def on_demand_trace(out_dir: str | Path, seconds: float) -> dict:
             return {"status": "busy", "dir": _TRACE_ACTIVE[0]}
         try:
             Path(out).mkdir(parents=True, exist_ok=True)
-            jax.profiler.start_trace(out)
+            jax.profiler.start_trace(out, profiler_options=_options())
         except Exception as e:  # noqa: BLE001 — answer, don't raise
             return {"status": "unsupported", "error": repr(e)[:300]}
         _TRACE_ACTIVE.append(out)

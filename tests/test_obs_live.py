@@ -699,6 +699,225 @@ class TestTickProfiler:
         assert snap["dominant_frac"] is None and snap["segments"] == {}
 
 
+class _FakeSpans:
+    """Stands in for `utils/profiling.annotate`: records what opened
+    and closed, in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        outer = self
+
+        class _Span:
+            def __enter__(self):
+                outer.log.append(("open", name, args))
+
+            def __exit__(self, *exc):
+                outer.log.append(("close", name))
+
+        return _Span()
+
+
+class TestTickSegments:
+    """`with prof.tick(n)` / `with prof.seg(key)`: the one way a step's
+    stretches are timed, and spans on the profiler's clock meanwhile."""
+
+    def _prof(self, **kw):
+        from hyperion_tpu.obs.tickprof import TickProfiler
+
+        clk = VirtualClock()
+        return TickProfiler(wall=clk.wall, clock=clk, **kw), clk
+
+    def test_segments_land_in_the_record_with_the_steps_wall(self):
+        tp, clk = self._prof()
+        with tp.tick(4) as tk:
+            with tp.seg("queue_pop"):
+                clk.advance(0.001)
+            clk.advance(0.002)              # nobody's: `other`
+            with tp.seg("device"):
+                clk.advance(0.010)
+            tk.count(kv_tokens=120, prefill_tokens=64)
+        with tp.tick(5) as tk:
+            clk.advance(0.001)
+            tk.count(kv_tokens=121, prefill_tokens=8)
+        rec, _ = tp.tail(2)
+        # filed under the number the step was opened with, its span's
+        assert rec["tick"] == 4 and rec["total_s"] == pytest.approx(0.013)
+        assert rec["s"] == {"queue_pop": 0.001, "device": 0.01}
+        assert rec["c"] == {"kv_tokens": 120, "prefill_tokens": 64}
+        snap = tp.snapshot(now=clk.wall())
+        assert snap["segments"]["other"]["s"] == pytest.approx(0.003)
+        # the level as of the newest step, the flow summed over the window
+        assert snap["counters"] == {"kv_tokens": 121, "prefill_tokens": 72}
+
+    def test_a_segment_inside_another_is_netted_out_of_it(self):
+        # journal and sink writes inside `accept`, the table upload
+        # inside `device`: the arithmetic `Engine.step` did by hand
+        tp, clk = self._prof()
+        with tp.tick(0):
+            with tp.seg("accept") as acc:
+                clk.advance(0.003)
+                for _ in range(2):
+                    with tp.seg("journal"):
+                        clk.advance(0.004)
+                    with tp.seg("sink") as sk:
+                        clk.advance(0.001)
+        assert acc.gross == pytest.approx(0.013)
+        assert acc.s == pytest.approx(0.003) and sk.s == pytest.approx(0.001)
+        assert tp.tail(1)[0]["s"] == {
+            "accept": 0.003, "journal": 0.008, "sink": 0.002}
+
+    def test_children_ride_beside_their_parent_and_out_of_every_sum(self):
+        tp, clk = self._prof()
+        with tp.tick(0):
+            with tp.seg("device") as dev:
+                with tp.seg("bt_upload"):
+                    clk.advance(0.001)
+                with tp.seg("device/dispatch"):
+                    clk.advance(0.002)
+                with tp.seg("device/fetch"):
+                    clk.advance(0.007)
+            clk.advance(0.004)
+        s = tp.tail(1)[0]["s"]
+        assert s == {"bt_upload": 0.001, "device": 0.009,
+                     "device/dispatch": 0.002, "device/fetch": 0.007}
+        assert dev.gross == pytest.approx(0.010)
+        assert s["device/dispatch"] + s["device/fetch"] <= s["device"] + 1e-9
+        snap = tp.snapshot(now=clk.wall())
+        assert set(snap["segments"]) == {"device", "bt_upload", "other"}
+        assert snap["segments"]["other"]["s"] == pytest.approx(0.004)
+        assert snap["children"] == {"device/fetch": 0.007,
+                                    "device/dispatch": 0.002}
+        assert snap["dominant"] == "device"
+
+    def test_a_segment_of_its_own_inside_a_child_leaves_both(self):
+        tp, clk = self._prof()
+        with tp.tick(0):
+            with tp.seg("admit"):
+                with tp.seg("admit/fetch"):
+                    clk.advance(0.002)
+                    with tp.seg("sink"):
+                        clk.advance(0.005)
+        assert tp.tail(1)[0]["s"] == {
+            "admit": 0.002, "admit/fetch": 0.002, "sink": 0.005}
+
+    def test_outside_a_step_or_unrecorded_a_segment_is_a_stopwatch(self):
+        tp, clk = self._prof()
+        with tp.seg("admit/dispatch") as sg:      # warm-up: no step open
+            clk.advance(0.5)
+        assert sg.gross == pytest.approx(0.5) and tp.ticks_recorded == 0
+        with tp.tick(0):
+            with tp.seg("sink", record=False) as sg:  # another thread's
+                clk.advance(0.25)
+        assert sg.gross == pytest.approx(0.25)
+        assert tp.tail(1)[0]["s"] == {}
+        assert tp.tail(1)[0]["total_s"] == pytest.approx(0.25)
+
+    def test_a_step_that_raises_leaves_no_record(self):
+        tp, clk = self._prof()
+        with pytest.raises(RuntimeError):
+            with tp.tick(0):
+                with tp.seg("device"):
+                    clk.advance(0.01)
+                    raise RuntimeError("chaos")
+        assert tp.ticks_recorded == 0
+        with tp.tick(1):          # and the next one starts clean
+            with tp.seg("slo"):
+                clk.advance(0.001)
+        assert tp.tail(1)[0]["s"] == {"slo": 0.001}
+
+    def test_segments_are_spans_named_after_their_keys(self):
+        spans = _FakeSpans()
+        tp, clk = self._prof(annotate=spans)
+        with tp.tick(17):
+            with tp.seg("admit"):
+                with tp.seg("admit/upload", bucket=2048, start=16):
+                    clk.advance(0.001)
+        with tp.seg("device/fetch"):            # outside a step: still one
+            pass
+        assert spans.log == [
+            ("open", "serve.step", {"tick": 17}),
+            ("open", "serve.step/admit", {}),
+            ("open", "serve.step/admit/upload",
+             {"bucket": 2048, "start": 16}),
+            ("close", "serve.step/admit/upload"),
+            ("close", "serve.step/admit"),
+            ("close", "serve.step"),
+            ("open", "serve.step/device/fetch", {}),
+            ("close", "serve.step/device/fetch"),
+        ]
+
+    def _slow_admission_profile(self):
+        """Ten steps whose `admit` owns the wall, most of it in the
+        `admit/blocks` child, with the counters a step hands over."""
+        tp, clk = self._prof()
+        for n in range(10):
+            with tp.tick(n) as tk:
+                with tp.seg("admit"):
+                    with tp.seg("admit/blocks"):
+                        clk.advance(0.030)
+                    with tp.seg("admit/upload"):
+                        clk.advance(0.010)
+                with tp.seg("device"):
+                    clk.advance(0.020)
+                tk.count(kv_tokens=1000 + n, prefill_tokens=64)
+        return tp.snapshot(now=clk.wall())
+
+    def test_doctor_names_the_child_and_reads_the_counters(self, tmp_path):
+        """The readers of `children` and `counters`: doctor's incident
+        says which call inside the dominant segment holds the time, and
+        its profile row what the steps counted."""
+        from hyperion_tpu.obs import doctor
+
+        snap = self._slow_admission_profile()
+        assert doctor._largest_child(snap, "admit") == \
+            " (mostly `admit/blocks`, 75% of it)"
+        assert doctor._largest_child(snap, "device") == ""
+        assert doctor._largest_child({"dominant": "admit"}, "admit") == ""
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        d = doctor.diagnose(tmp_path)
+        assert d["host_segment_incidents"] == [
+            "host segment 'admit' (mostly `admit/blocks`, 75% of it) "
+            "owns 67% of tick time over the last 10 tick(s) — "
+            "prefill/admission host work"]
+        row = next(ln for ln in doctor.render_markdown(d).splitlines()
+                   if ln.startswith("| host tick profile"))
+        assert "`admit` (mostly `admit/blocks`, 75% of it) 67%" in row
+        assert "1009 KV tokens live, 640 prefilled" in row
+
+    def test_top_shows_live_kv_tokens(self):
+        snap = self._slow_admission_profile()
+        assert "kv_tokens" in top_mod.ROW_KEYS
+        row = {k: None for k in top_mod.ROW_KEYS}
+        out = top_mod._row_from_exposition(
+            dict(row), {"phase": "serve", "tick": 9, "tickprof": snap})
+        assert out["kv_tokens"] == 1009
+        assert out["dominant_segment"] == "admit"
+        # a process that predates the counter leaves the cell empty
+        old = top_mod._row_from_exposition(
+            dict(row), {"phase": "serve", "tickprof": {"dominant": "slo"}})
+        assert old["kv_tokens"] is None
+        out.update(name="process", dir="x", source="socket", state="live",
+                   alerts=[], age_s=0.0)
+        text = top_mod.render([out], "x", window_s=60.0, color=False)
+        assert "kv tok" in text and " 1009 " in text
+
+    def test_module_stays_free_of_jax(self):
+        import subprocess
+        import sys
+
+        code = ("import sys; import hyperion_tpu.obs.tickprof as t; "
+                "p = t.TickProfiler(); "
+                "c = p.tick(0); c.__enter__(); p.seg('slo').__enter__(); "
+                "print('jax' in sys.modules)")
+        out = subprocess.run([sys.executable, "-c", code], check=True,
+                             capture_output=True, text=True, timeout=60)
+        assert out.stdout.strip() == "False"
+
+
 class TestFlightRecorder:
     def test_first_spill_due_then_cadence(self, tmp_path):
         from hyperion_tpu.obs.tickprof import FlightRecorder

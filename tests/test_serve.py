@@ -2254,6 +2254,143 @@ class TestIntrospection:
         assert "slow disk" in d["reason"]
         assert "host-bound" in doctor.render_markdown(d)
 
+    def test_step_segments_are_spans_in_a_trace(self, llama, tmp_path):
+        """A tiny engine stepped under the profiler leaves `serve.step`
+        with its tick, a span for every segment that ran and the
+        children of `device` and `admit`, each inside its parent in
+        time — and compiles nothing for it."""
+        from hyperion_tpu.obs import xprof
+        from hyperion_tpu.obs.tickprof import SEGMENTS
+        from hyperion_tpu.utils import profiling
+
+        eng = _engine(llama)
+        eng.warmup([8, 16])
+        stats0 = eng.compile_stats()
+        before = eng.tickprof.ticks_recorded
+        with profiling.capture(tmp_path / "trace"):
+            for i, p in enumerate(_prompts([5, 11], seed=26)):
+                eng.submit(Request(prompt_ids=p, max_new_tokens=4,
+                                   id=f"span{i}"))
+            _drain(eng)
+        assert eng.compile_stats() == stats0
+        recs = eng.tickprof.tail(eng.tickprof.ticks_recorded - before)
+        ran = {k for r in recs for k in r["s"]}
+        assert {"admit", "device", "accept", "slo"} <= ran
+        prof = xprof.load(xprof.xplane_path(tmp_path / "trace"))
+        spans: dict[str, list] = {}
+        for plane in prof.planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("serve.step"):
+                        spans.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns,
+                             dict(e.stats)))
+        steps = spans["serve.step"]
+        assert len(steps) == len(recs)
+        # one number names a step in the trace and in its record: the
+        # span that holds a record's moment carries the record's tick
+        assert [st["tick"] for _, _, st in sorted(steps)] == \
+            [r["tick"] for r in recs]
+        assert len({r["tick"] for r in recs if "device" in r["s"]}) == \
+            sum("device" in r["s"] for r in recs)
+        # every key a record holds is a span of that name, children too
+        for key in ran:
+            assert f"serve.step/{key}" in spans, key
+        assert {k for k in ran if "/" not in k} <= set(SEGMENTS)
+        children = {"device/dispatch", "device/fetch", "admit/gate",
+                    "admit/blocks", "admit/upload", "admit/dispatch",
+                    "admit/fetch"}
+        assert children <= {k[len("serve.step/"):] for k in spans}
+
+        def inside(name, parent):
+            return all(any(ps <= s and e <= pe for ps, pe, _ in spans[parent])
+                       for s, e, _ in spans[name])
+
+        for child in children:
+            parent = "serve.step/" + child.split("/")[0]
+            assert inside("serve.step/" + child, parent), child
+            assert inside(parent, "serve.step")
+        # a prefill's three children say which bucket and which hit
+        for name in ("upload", "dispatch", "fetch"):
+            for _, _, st in spans[f"serve.step/admit/{name}"]:
+                assert st["bucket"] in (8, 16) and st["start"] == 0
+
+    def test_tick_record_keeps_its_keys_and_gains_children(self, llama):
+        """The record's contract: every segment key it had, seconds
+        each; children beside them, never more than their parent and
+        never in `other`."""
+        from hyperion_tpu.obs.tickprof import SEGMENTS
+
+        eng = _engine(llama)
+        eng.warmup([8])
+        eng.submit(Request(prompt_ids=_prompts([6], seed=27)[0],
+                           max_new_tokens=5, id="keys0"))
+        _drain(eng)
+        recs = eng.tickprof.tail(32)
+        first = recs[0]["s"]        # the step that admitted and ticked
+        for key in ("admit", "bt_upload", "device", "accept", "slo",
+                    "admit/blocks", "admit/upload", "admit/dispatch",
+                    "admit/fetch", "device/dispatch", "device/fetch"):
+            assert first.get(key, 0) > 0, (key, first)
+        for rec in recs:
+            s = rec["s"]
+            top = {k: v for k, v in s.items() if "/" not in k}
+            assert set(top) <= set(SEGMENTS)
+            assert sum(top.values()) <= rec["total_s"] + 1e-5
+            for parent in ("admit", "device", "chunk"):
+                kids = sum(v for k, v in s.items()
+                           if k.startswith(parent + "/"))
+                assert kids <= s.get(parent, 0.0) + 1e-5, (parent, s)
+        snap = eng.tickprof.snapshot()
+        assert not any("/" in k for k in snap["segments"])
+        named = sum(v["s"] for k, v in snap["segments"].items()
+                    if k != "other")
+        assert snap["segments"].get("other", {"s": 0.0})["s"] == \
+            pytest.approx(snap["total_s"] - named, abs=1e-5)
+        assert "device/fetch" in snap["children"]
+        # the exposition and the flight record carry both
+        assert set(eng.exposition()["tickprof"]["counters"]) == {
+            "kv_tokens", "prefill_tokens"}
+        assert set(eng._flight_payload()["ticks"][-1]["c"]) == {
+            "kv_tokens", "prefill_tokens"}
+
+    def test_tick_counters_follow_the_slots(self, llama):
+        """`kv_tokens` is host bookkeeping of what the live slots hold in
+        the pool: a request's prompt and what it generated, less its
+        newest token (sampled, written by the next tick) — through
+        admission, decode, finish and preemption."""
+        eng = _engine(llama, slots=3, block_size=8, num_blocks=8,
+                      admission="optimistic", queue_capacity=16)
+        eng.warmup()
+        rng = np.random.default_rng(28)
+        reqs = [Request(prompt_ids=rng.integers(1, 250, 6 + i).astype(
+                            np.int32),
+                        max_new_tokens=7 + 5 * (i % 3), id=f"cnt{i}")
+                for i in range(7)]
+        for r in reqs:
+            ok, _ = eng.submit(r)
+            assert ok
+        steps = 0
+        seen_finish = False
+        while not eng.idle:
+            done_before = sum(r.status == "done" for r in reqs)
+            eng.step()
+            steps += 1
+            assert steps < 400
+            rec = eng.tickprof.tail(1)[0]
+            c = rec["c"]
+            live = [r for r in eng._slots if r is not None]
+            assert c["kv_tokens"] == sum(
+                len(r.prompt_ids) + len(r.tokens) - 1 for r in live)
+            assert len(live) == eng.n_active
+            # padded tokens of this step's prefills: a power of two each
+            admitted = sum(k.startswith("admit/dispatch") for k in rec["s"])
+            assert (c["prefill_tokens"] > 0) == bool(admitted)
+            seen_finish |= sum(r.status == "done"
+                               for r in reqs) > done_before
+        assert seen_finish and eng.metrics.summary()["preempted"] > 0
+        assert eng.tickprof.tail(1)[0]["c"]["kv_tokens"] == 0
+
     def test_profiled_run_compiles_nothing(self, llama, tmp_path):
         """The acceptance criterion: `compile_stats()` flat across a
         profiled run — bracketing jax.profiler around live ticks adds

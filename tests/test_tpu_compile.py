@@ -80,6 +80,10 @@ def _compile(fn, one_chip, *avals):
             for a in avals]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    # the kernel's own count of its work reaches the compiler (and, on
+    # the chip, a trace's `flops` / `bytes_accessed`): without it a
+    # program's cost is blind where the kernel is
+    assert 'cost_estimate":{"flops":"' in text
     return text
 
 
