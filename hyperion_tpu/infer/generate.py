@@ -81,12 +81,26 @@ def sample_token(logits: jax.Array, rng: jax.Array | None,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+def sampling_tier(temperature: jax.Array, top_k: jax.Array,
+                  top_p: jax.Array) -> jax.Array:
+    """The most sampling work any row asks for, an int32 scalar:
+    0 = every row is greedy (temperature <= 0); 1 = some row samples and
+    none of those restricts its support; 2 = some sampling row has
+    top_k > 0 or top_p < 1. A greedy row's top_k / top_p are never read,
+    so they cannot lift the tier."""
+    samples = temperature > 0
+    restricts = samples & ((top_k > 0) | (top_p < 1.0))
+    return (samples.any().astype(jnp.int32)
+            + restricts.any().astype(jnp.int32))
+
+
 def sample_token_slots(
     logits: jax.Array,
     keys: jax.Array,
     temperature: jax.Array,
     top_k: jax.Array,
     top_p: jax.Array,
+    live: jax.Array | None = None,
 ) -> jax.Array:
     """Per-row sampling for the serve engine: logits [S, V] with
     per-slot params (each [S]) → token ids [S], one fully vectorized
@@ -99,24 +113,55 @@ def sample_token_slots(
     rescale, restrict support by that row's top_k (0 = off; dynamic per
     row, so the k-th threshold comes from a full sort rather than
     `lax.top_k`) then top_p (1.0 = an exact no-op), and draw with that
-    row's key. `keys` is a [S] typed PRNG key array."""
+    row's key. `keys` is a [S] typed PRNG key array.
+
+    The work follows what the rows ask for, in three tiers
+    (`sampling_tier`) that one `lax.switch` picks ON THE DEVICE, inside
+    the one executable, so the mix may change from tick to tick with no
+    recompile: 0 = the argmax alone; 1 = rescale and one categorical
+    draw, no sort; 2 = the full path (two sorts of [S, V], softmax,
+    cumsum, the un-sort scatter). Every tier yields each row's
+    `sample_token` tokens; a tick pays tier 2 for all S rows as soon as
+    one row restricts. On a v5e at [48, 32000] tier 2 costs 25.5 ms a
+    tick, tier 1 under 0.1 ms and tier 0 0.006 ms (PERF.md section 6,
+    PR 25; SERVING.md "Sampling tiers"). `live` ([S] bool) marks the
+    rows whose result is read: a row that is not live (a freed slot
+    keeps its last request's parameters) is treated as greedy and
+    cannot lift the tier. The index depends on the per-slot parameters alone, never on
+    `logits` or `keys`: under a `vmap` over those (the speculative
+    tick's window positions) it stays unbatched and the switch stays a
+    conditional instead of a select that would run every branch."""
     V = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     t = temperature.astype(logits.dtype)
-    scaled = logits / jnp.where(t > 0, t, 1.0)[:, None]
-    # per-row top-k: threshold = the clip(k-1)-th value of the row
-    # sorted descending, applied only where k > 0
-    k = jnp.clip(top_k, 0, V)
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    kth = jnp.take_along_axis(
-        sorted_desc, jnp.clip(k - 1, 0, V - 1)[:, None], axis=-1
+    if live is not None:
+        t = jnp.where(live, t, 0)
+
+    def draw():
+        scaled = logits / jnp.where(t > 0, t, 1.0)[:, None]
+        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+        return jnp.where(t > 0, sampled.astype(jnp.int32), greedy)
+
+    def restrict_and_draw():
+        scaled = logits / jnp.where(t > 0, t, 1.0)[:, None]
+        # per-row top-k: threshold = the clip(k-1)-th value of the row
+        # sorted descending, applied only where k > 0
+        k = jnp.clip(top_k, 0, V)
+        sorted_desc = -jnp.sort(-scaled, axis=-1)
+        kth = jnp.take_along_axis(
+            sorted_desc, jnp.clip(k - 1, 0, V - 1)[:, None], axis=-1
+        )
+        restricted = jnp.where(
+            (k > 0)[:, None] & (scaled < kth), -jnp.inf, scaled
+        )
+        restricted = _mask_top_p(restricted, top_p[:, None])
+        sampled = jax.vmap(jax.random.categorical)(keys, restricted)
+        return jnp.where(t > 0, sampled.astype(jnp.int32), greedy)
+
+    return jax.lax.switch(
+        sampling_tier(t, top_k, top_p),
+        (lambda: greedy, draw, restrict_and_draw),
     )
-    restricted = jnp.where(
-        (k > 0)[:, None] & (scaled < kth), -jnp.inf, scaled
-    )
-    restricted = _mask_top_p(restricted, top_p[:, None])
-    sampled = jax.vmap(jax.random.categorical)(keys, restricted)
-    return jnp.where(t > 0, sampled.astype(jnp.int32), greedy)
 
 
 def _cfg_attr(cfg, name: str):

@@ -1222,6 +1222,14 @@ def render_markdown(d: dict) -> str:
         counted = (f"; {_fmt(c.get('kv_tokens'))} KV tokens live, "
                    f"{_fmt(c.get('prefill_tokens'))} prefilled"
                    if c else "")
+        # a tick whose rows restrict their support (top_k / top_p) runs
+        # the sampled path's sorts for every row: worth a sentence
+        st = tp.get("sampling_tiers") or {}
+        if st.get("sorted"):
+            lo, hi = st["restricted_rows"]
+            rows = str(lo) if lo == hi else f"{lo}-{hi}"
+            counted += (f"; {st['sorted']} of {st['ticks']} ticks sorted "
+                        f"the vocabulary for {rows} restricted row(s)")
         lines.append(
             f"| host tick profile | dominant `{tp['dominant']}`"
             f"{_largest_child(tp, tp['dominant'])}{share} over "

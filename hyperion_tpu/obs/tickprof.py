@@ -17,7 +17,10 @@ writes, SLO evaluation. This module is the one way to time them:
     totals/fractions plus the DOMINANT segment, riding the exposition
     payload so `obs top` can show each row's hot segment and `obs
     doctor` can name it when tokens/s degrades ("journal owns 61% of
-    tick time — slow disk").
+    tick time — slow disk"). The same roll-up says how many of the
+    window's decode ticks ran each tier of `sample_token_slots`
+    (`sampling_tiers`, from the records' `sampling_rows` /
+    `restricted_rows` counters).
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -241,6 +244,21 @@ class TickProfiler:
                 "kv_tokens": recs[-1]["c"].get("kv_tokens"),
                 "prefill_tokens": sum(r.get("c", {}).get("prefill_tokens", 0)
                                       for r in recs)}
+        # which tier of `sample_token_slots` each decode tick of the
+        # window ran, from what its rows asked for (a step with no
+        # `device` segment ran no tick)
+        asked = [(r["c"]["sampling_rows"], r["c"].get("restricted_rows", 0))
+                 for r in recs
+                 if "device" in r["s"] and "sampling_rows" in r.get("c", {})]
+        if asked:
+            restricted = [k for _, k in asked if k]
+            out["sampling_tiers"] = {
+                "ticks": len(asked),
+                "greedy": sum(not n for n, _ in asked),
+                "drawn": sum(bool(n) and not k for n, k in asked),
+                "sorted": len(restricted),
+                "restricted_rows": ([min(restricted), max(restricted)]
+                                    if restricted else None)}
         return out
 
 

@@ -147,7 +147,7 @@ def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
         keys = jax.vmap(jax.random.fold_in)(st["keys"], st["lengths"])
         nxt = sample_token_slots(
             logits[:, 0], keys,
-            st["temperature"], st["top_k"], st["top_p"],
+            st["temperature"], st["top_k"], st["top_p"], live=act,
         )
     with jax.named_scope("slot_state"):
         nxt = jnp.where(act, nxt, jnp.int32(pad_id))
@@ -201,7 +201,8 @@ def _spec_tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live,
             st["keys"], pos)
         t_arr = jax.vmap(
             lambda lg, ky: sample_token_slots(
-                lg, ky, st["temperature"], st["top_k"], st["top_p"]),
+                lg, ky, st["temperature"], st["top_k"], st["top_p"],
+                live=act),
             in_axes=1, out_axes=1,
         )(logits, keys)  # [S, k+1]
         m, v = accept_draft(drafts, t_arr)
@@ -595,6 +596,8 @@ class Engine:
         self.flight = (FlightRecorder(flight_path, run=self.tracer.run)
                        if flight_path else null_flight_recorder())
         self._prefill_tokens = 0  # padded tokens prefilled this step
+        # rows of this step's decode tick that sample / that restrict
+        self._sampling_rows = self._restricted_rows = 0
         self._last_prefill_bucket: int | None = None
         # `.nbytes` is shape metadata — summing it syncs nothing
         self._param_bytes = int(sum(
@@ -1759,7 +1762,13 @@ class Engine:
                 # the pool: host bookkeeping, no device read
                 kv_tokens=sum(q.n_filled for q in self._seqs
                               if q is not None),
-                prefill_tokens=self._prefill_tokens)
+                prefill_tokens=self._prefill_tokens,
+                # what the tick's rows asked of `sample_token_slots`,
+                # from the requests' own parameters: 0 and 0 = the tick
+                # ran the argmax alone, any restricted row = it sorted
+                # the vocabulary for every row
+                sampling_rows=self._sampling_rows,
+                restricted_rows=self._restricted_rows)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -1770,6 +1779,7 @@ class Engine:
         now = _CLOCK()
         prof = self.tickprof
         self._prefill_tokens = 0
+        self._sampling_rows = self._restricted_rows = 0
 
         if self._governor is not None:
             tr = self._governor.update(len(self.queue))
@@ -1921,6 +1931,12 @@ class Engine:
                 self.chaos.on_tick(self._tick_no)
             spec = self._spec
             cnts = accs = None
+            for s, req in enumerate(self._slots):
+                if req is not None and req.temperature > 0 \
+                        and s not in self._chunking:
+                    self._sampling_rows += 1
+                    self._restricted_rows += \
+                        req.top_k > 0 or req.top_p < 1.0
             with prof.seg("draft"):
                 drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table

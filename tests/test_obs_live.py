@@ -888,6 +888,60 @@ class TestTickSegments:
         assert "`admit` (mostly `admit/blocks`, 75% of it) 67%" in row
         assert "1009 KV tokens live, 640 prefilled" in row
 
+    @pytest.mark.parametrize("asked,tiers,sentence", [
+        # (sampling_rows, restricted_rows) of each decode tick
+        ([(0, 0)] * 9, {"ticks": 9, "greedy": 9, "drawn": 0, "sorted": 0,
+                        "restricted_rows": None}, None),
+        ([(0, 0)] * 5 + [(4, 0)] * 3,
+         {"ticks": 8, "greedy": 5, "drawn": 3, "sorted": 0,
+          "restricted_rows": None}, None),
+        ([(0, 0)] * 4 + [(4, 0)] * 2 + [(5, 1), (6, 3), (3, 2)],
+         {"ticks": 9, "greedy": 4, "drawn": 2, "sorted": 3,
+          "restricted_rows": [1, 3]},
+         "3 of 9 ticks sorted the vocabulary for 1-3 restricted row(s)"),
+    ], ids=["all_greedy", "some_drawn", "some_sorted"])
+    def test_sampling_tiers_of_the_windows_ticks(self, tmp_path, asked,
+                                                 tiers, sentence):
+        """The tick records' `sampling_rows` / `restricted_rows` roll up
+        into ticks by tier of `sample_token_slots`; a step that ran no
+        decode tick (no `device` segment) is no tick; doctor says in
+        words when ticks sorted the vocabulary."""
+        from hyperion_tpu.obs import doctor
+
+        tp, clk = self._prof()
+        with tp.tick(0) as tk:              # admission only: no tick
+            with tp.seg("admit"):
+                clk.advance(0.010)
+            tk.count(kv_tokens=0, prefill_tokens=64, sampling_rows=0,
+                     restricted_rows=0)
+        for n, (rows, restricted) in enumerate(asked, start=1):
+            with tp.tick(n) as tk:
+                with tp.seg("device"):
+                    clk.advance(0.020)
+                tk.count(kv_tokens=100, prefill_tokens=0,
+                         sampling_rows=rows, restricted_rows=restricted)
+        snap = tp.snapshot(now=clk.wall())
+        assert snap["sampling_tiers"] == tiers
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        if sentence:
+            assert sentence in row
+        else:
+            assert "sorted the vocabulary" not in row
+
+    def test_records_without_the_counters_carry_no_tiers(self):
+        # a process that predates the counters: nothing to roll up
+        tp, clk = self._prof()
+        with tp.tick(0) as tk:
+            with tp.seg("device"):
+                clk.advance(0.020)
+            tk.count(kv_tokens=100, prefill_tokens=0)
+        assert "sampling_tiers" not in tp.snapshot(now=clk.wall())
+
     def test_top_shows_live_kv_tokens(self):
         snap = self._slow_admission_profile()
         assert "kv_tokens" in top_mod.ROW_KEYS

@@ -27,6 +27,7 @@ from hyperion_tpu.infer.generate import (
     generate,
     sample_token,
     sample_token_slots,
+    sampling_tier,
 )
 from hyperion_tpu.models.llama import Llama, init_cache, llama_tiny_config
 from hyperion_tpu.serve.engine import Engine, EngineConfig
@@ -729,6 +730,121 @@ class TestPerSlotSampling:
             seen_row1.add(int(out[1]))
         assert len(seen_row1) > 1  # p=1.0 row keeps the full support
 
+    # what six rows ask for -> the tier the call must run: every row
+    # greedy; temperature only; one row's top_k; one row's top_p; both
+    # on several rows; and one restricted row beside greedy and
+    # temperature-only rows (a greedy row's top_k / top_p are never
+    # read, as `sample_token` never reads them)
+    _T = [0.7, 0.0, 1.3, 0.0, 0.9, 1.0]
+    TIER_CASES = {
+        "greedy": ([0.0] * 6, [0] * 6, [1.0] * 6, 0),
+        "greedy_stale_restrictions": ([0.0] * 6, [5] * 6, [0.5] * 6, 0),
+        "temperature": (_T, [0] * 6, [1.0] * 6, 1),
+        "top_k": (_T, [0, 0, 5, 0, 0, 0], [1.0] * 6, 2),
+        "top_p": (_T, [0] * 6, [1.0, 1.0, 1.0, 1.0, 0.8, 1.0], 2),
+        "both": (_T, [0, 3, 40, 0, 7, 0],
+                 [0.9, 1.0, 0.5, 0.3, 0.8, 1.0], 2),
+        "mixed_one_restricted": ([0.0, 0.0, 1.1, 0.0, 0.8, 0.0],
+                                 [0, 0, 0, 9, 4, 0],
+                                 [1.0, 0.4, 1.0, 1.0, 0.7, 1.0], 2),
+    }
+
+    @staticmethod
+    def _parent_slots(logits, keys, temperature, top_k, top_p):
+        """`sample_token_slots` as it stood before the tiers (PR 24):
+        the whole sampled path for every row, whatever the rows ask."""
+        from hyperion_tpu.infer.generate import _mask_top_p
+
+        V = logits.shape[-1]
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        t = temperature.astype(logits.dtype)
+        scaled = logits / jnp.where(t > 0, t, 1.0)[:, None]
+        k = jnp.clip(top_k, 0, V)
+        sorted_desc = -jnp.sort(-scaled, axis=-1)
+        kth = jnp.take_along_axis(
+            sorted_desc, jnp.clip(k - 1, 0, V - 1)[:, None], axis=-1)
+        restricted = jnp.where(
+            (k > 0)[:, None] & (scaled < kth), -jnp.inf, scaled)
+        restricted = _mask_top_p(restricted, top_p[:, None])
+        sampled = jax.vmap(jax.random.categorical)(keys, restricted)
+        return jnp.where(t > 0, sampled.astype(jnp.int32), greedy)
+
+    @staticmethod
+    def _switch_index(*args, **kw):
+        """The index `sample_token_slots` hands its `lax.switch` for
+        these arguments: the tier the device would run."""
+        closed = jax.make_jaxpr(
+            lambda *a: sample_token_slots(*a, **kw))(*args)
+        eqns = closed.jaxpr.eqns
+        (at,) = [i for i, e in enumerate(eqns)
+                 if e.primitive.name == "cond"]
+        index = closed.jaxpr.replace(
+            eqns=eqns[:at], outvars=[eqns[at].invars[0]])
+        (tier,) = jax.core.eval_jaxpr(index, closed.consts, *args)
+        return int(tier)
+
+    def _tier_inputs(self, case, rows=6, vocab=257):
+        temps, ks, ps, tier = self.TIER_CASES[case]
+        rng = np.random.default_rng(17)
+        logits = jnp.asarray(rng.normal(size=(rows, vocab)), jnp.float32)
+        keys = jax.random.split(jax.random.key(23), rows)
+        return (logits, keys, jnp.asarray(temps, jnp.float32),
+                jnp.asarray(ks, jnp.int32),
+                jnp.asarray(ps, jnp.float32)), tier
+
+    @pytest.mark.parametrize("case", sorted(TIER_CASES))
+    def test_every_tier_yields_sample_token_row_for_row(self, case):
+        """Whatever tier the rows' parameters select, each row's token
+        is `sample_token`'s for that row with that row's key, and the
+        whole call equals the path that sorted for every row."""
+        args, tier = self._tier_inputs(case)
+        logits, keys, temps, ks, ps = args
+        assert int(sampling_tier(temps, ks, ps)) == tier
+        assert self._switch_index(*args) == tier
+        out = np.asarray(jax.jit(sample_token_slots)(*args))
+        want = [int(sample_token(
+            logits[i:i + 1], keys[i], float(temps[i]), int(ks[i]),
+            float(ps[i]))[0]) for i in range(logits.shape[0])]
+        assert out.tolist() == want
+        np.testing.assert_array_equal(
+            out, np.asarray(self._parent_slots(*args)))
+        if tier:
+            # the draw is a draw: some sampling row left its argmax
+            assert (out != np.asarray(jnp.argmax(logits, -1))).any()
+
+    @pytest.mark.parametrize("case", ["both", "mixed_one_restricted",
+                                      "temperature"])
+    def test_dead_lanes_cannot_lift_the_tier(self, case):
+        """A freed slot keeps its last request's parameters in the
+        engine's state: with the live mask, rows that are not live are
+        greedy and the tier is that of the live rows alone."""
+        args, tier = self._tier_inputs(case)
+        logits, keys, temps, ks, ps = args
+        assert tier > 0
+        greedy_rows = np.asarray(temps) <= 0
+        # only the greedy rows live: the stale sampled rows lift nothing
+        live = jnp.asarray(greedy_rows)
+        assert self._switch_index(*args, live=live) == 0
+        out = np.asarray(sample_token_slots(*args, live=live))
+        np.testing.assert_array_equal(
+            out, np.asarray(jnp.argmax(logits, -1)))
+        # every row live: the mask changes nothing
+        everyone = jnp.ones_like(live)
+        assert self._switch_index(*args, live=everyone) == tier
+        np.testing.assert_array_equal(
+            np.asarray(sample_token_slots(*args, live=everyone)),
+            np.asarray(sample_token_slots(*args)))
+        # the restricted rows dead, a temperature-only row live: tier 1
+        unrestricted = (np.asarray(temps) > 0) & (np.asarray(ks) == 0) \
+            & (np.asarray(ps) >= 1.0)
+        if unrestricted.any() and tier == 2:
+            live = jnp.asarray(unrestricted | greedy_rows)
+            assert self._switch_index(*args, live=live) == 1
+            got = np.asarray(sample_token_slots(*args, live=live))
+            want = np.asarray(sample_token_slots(*args))
+            np.testing.assert_array_equal(got[np.asarray(live)],
+                                          want[np.asarray(live)])
+
     def test_engine_temperature_deterministic_per_seed(self, llama):
         """Same seed → same sampled continuation across engine runs
         (per-slot keys fold in the position, not wall clock)."""
@@ -744,6 +860,175 @@ class TestPerSlotSampling:
             outs.append(req.tokens)
         assert outs[0] == outs[1]
         assert all(0 <= t < 256 for t in outs[0])
+
+
+# ----------------------------------------------------- sampling tiers
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _eqns(sub)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            x = getattr(x, "jaxpr", x)   # ClosedJaxpr -> Jaxpr
+            if hasattr(x, "eqns"):
+                yield x
+
+
+class TestSamplingTiers:
+    """ISSUE 25: the tick's sampling work follows what its live rows
+    ask for, by a conditional inside the one executable."""
+
+    def _programs(self, llama):
+        import functools
+
+        from hyperion_tpu.serve.engine import _spec_tick_impl, _tick_impl
+
+        eng = _engine(llama, slots=3, block_size=8, num_blocks=8,
+                      admission="optimistic", queue_capacity=16,
+                      spec_k=4, draft="ngram")
+        args = (eng.variables, eng._cache, eng._state,
+                jnp.asarray(eng._bt), jnp.asarray(eng._live_mask()))
+        drafts = jnp.zeros((3, 4), jnp.int32)
+        static = (eng.model, eng.cfg.eos_id, eng.cfg.pad_id)
+        return {
+            "tick": (functools.partial(_tick_impl, *static), args,
+                     eng._tick_jit.lower(*static, *args)),
+            "spec_tick": (functools.partial(_spec_tick_impl, *static),
+                          args + (drafts,),
+                          eng._spec_jit.lower(*static, *args, drafts)),
+        }
+
+    @pytest.mark.parametrize("program", ["tick", "spec_tick"])
+    def test_one_conditional_whose_heavy_branch_alone_sorts(
+            self, llama, program):
+        """The lowered program holds ONE conditional: its first branch
+        is empty (the argmax computed outside it), its second draws and
+        does not sort, its third alone sorts. Under the speculative
+        tick's `vmap` over window positions it is still a conditional:
+        a batched index would have made it a select that runs all
+        three."""
+        fn, args, lowered = self._programs(llama)[program]
+        text = lowered.as_text()
+        assert text.count("stablehlo.case") == 1
+        assert "stablehlo.sort" in text
+        eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+        (cond,) = [e for e in eqns if e.primitive.name == "cond"]
+        greedy, draw, restrict = (
+            [e.primitive.name for e in _eqns(b.jaxpr)]
+            for b in cond.params["branches"])
+        assert greedy == []
+        assert "sort" not in draw and "random_bits" in draw
+        assert restrict.count("sort") >= 2 and "random_bits" in restrict
+        assert "scatter" in restrict and "scatter" not in draw
+        # and nothing outside the conditional sorts or draws
+        n_all = [e.primitive.name for e in eqns]
+        assert n_all.count("sort") == restrict.count("sort")
+        assert n_all.count("random_bits") == \
+            draw.count("random_bits") + restrict.count("random_bits")
+
+    def _serve(self, eng, reqs):
+        for r in reqs:
+            ok, reason = eng.submit(r)
+            assert ok, reason
+        _drain(eng)
+        return {r.id: list(r.tokens) for r in reqs}
+
+    def _req(self, name, seed, **sampling):
+        return Request(prompt_ids=_prompts([7], seed=seed)[0],
+                       max_new_tokens=9, id=name, seed=seed, **sampling)
+
+    SAMPLED = {
+        "temperature": dict(temperature=0.9),
+        "top_k": dict(temperature=0.9, top_k=12),
+        "top_p": dict(temperature=1.1, top_p=0.8),
+        "both": dict(temperature=1.3, top_k=40, top_p=0.9),
+    }
+
+    @pytest.mark.parametrize("kind", sorted(SAMPLED))
+    def test_sampled_request_alone_and_beside_greedy_rows(
+            self, llama, kind):
+        """A seeded sampled request yields the same tokens alone (its
+        own tier on every tick) and beside greedy rows (which it lifts
+        into its tier); the greedy rows yield what they yield alone
+        (the argmax alone). One engine serves all three mixes and
+        compiles nothing after warm-up."""
+        eng = _engine(llama)
+        stats0 = eng.warmup([8])
+
+        def greedy():
+            return [self._req(f"g{i}", 30 + i) for i in (0, 1)]
+
+        def sampled():
+            return self._req("s", 77, **self.SAMPLED[kind])
+
+        g_alone = self._serve(eng, greedy())
+        tiers = eng.tickprof.snapshot()["sampling_tiers"]
+        assert tiers["greedy"] == tiers["ticks"] > 0
+        s_alone = self._serve(eng, [sampled()])
+        mixed = self._serve(eng, greedy() + [sampled()])
+        assert mixed["s"] == s_alone["s"]
+        assert {k: mixed[k] for k in g_alone} == g_alone
+        # a draw, not the argmax under another name
+        model, variables = llama
+        ref = np.asarray(generate(
+            model, variables, jnp.asarray(_prompts([7], seed=77)[0])[None],
+            9))[0].tolist()
+        assert s_alone["s"] != ref
+        assert eng.compile_stats() == stats0, (
+            "a sampled request after greedy ones recompiled the engine")
+
+    def test_tick_record_counts_the_rows_that_sample_and_restrict(
+            self, llama, tmp_path):
+        """`sampling_rows` / `restricted_rows` in the tick record's `c`
+        come from the live requests' own parameters; `tickprof` rolls
+        them up into ticks by tier, and `obs doctor` says in words when
+        ticks sorted the vocabulary."""
+        from hyperion_tpu.obs import doctor
+
+        eng = _engine(llama)
+        eng.warmup([8])
+        reqs = [self._req("g", 1),
+                self._req("t", 2, temperature=0.8),
+                self._req("r", 3, temperature=0.8, top_p=0.9)]
+        reqs[2].max_new_tokens = 4   # leaves first: the tier falls back
+        for r in reqs:
+            eng.submit(r)
+        seen = []
+        while not eng.idle:
+            eng.step()
+            rec = eng.tickprof.tail(1)[0]
+            assert "device" in rec["s"]
+            seen.append((rec["c"]["sampling_rows"],
+                         rec["c"]["restricted_rows"]))
+        assert seen[0] == (2, 1) and seen[-1] == (1, 0)
+        assert set(seen) == {(2, 1), (1, 0)}
+        snap = eng.tickprof.snapshot()
+        n_sorted = seen.count((2, 1))
+        assert snap["sampling_tiers"] == {
+            "ticks": len(seen), "greedy": 0,
+            "drawn": len(seen) - n_sorted, "sorted": n_sorted,
+            "restricted_rows": [1, 1]}
+        assert eng.exposition()["tickprof"]["sampling_tiers"] == \
+            snap["sampling_tiers"]
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        assert (f"{n_sorted} of {len(seen)} ticks sorted the vocabulary "
+                "for 1 restricted row(s)") in row
+        # an all-greedy window says nothing of the kind
+        self._serve(eng, [self._req("g2", 4)])
+        last = eng.tickprof.tail(1)[0]["c"]
+        assert (last["sampling_rows"], last["restricted_rows"]) == (0, 0)
 
 
 # --------------------------------------------------------- telemetry
@@ -2352,7 +2637,8 @@ class TestIntrospection:
         assert set(eng.exposition()["tickprof"]["counters"]) == {
             "kv_tokens", "prefill_tokens"}
         assert set(eng._flight_payload()["ticks"][-1]["c"]) == {
-            "kv_tokens", "prefill_tokens"}
+            "kv_tokens", "prefill_tokens", "sampling_rows",
+            "restricted_rows"}
 
     def test_tick_counters_follow_the_slots(self, llama):
         """`kv_tokens` is host bookkeeping of what the live slots hold in

@@ -168,3 +168,69 @@ class TestFusedNormCompiles:
         x = S((32, 128, 768), BF16)
         v = S((768,), jnp.float32)
         _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip, x, x, v, v)
+
+
+def _computations(hlo_text):
+    """{name: body} of a compiled module's computations."""
+    out, name, body = {}, None, []
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            head = line.split()
+            name = head[1] if head[0] == "ENTRY" else head[0]
+            name, body = name.lstrip("%"), []
+        elif line.startswith("}") and name is not None:
+            out[name] = "\n".join(body)
+            name = None
+        elif name is not None:
+            body.append(line)
+    return out
+
+
+class TestSamplingTiersCompile:
+    """Not a kernel: the serving tick's sampling at the benchmark's
+    shape. Lowering holds a `case`; whether the chip's compiler keeps a
+    conditional, with the sorts inside the branch that needs them, is
+    what only this compile shows (a select would run all three tiers on
+    every tick)."""
+
+    def test_conditional_survives_with_the_sorts_inside(self, one_chip):
+        from hyperion_tpu.infer.generate import sample_token_slots
+
+        rows, vocab = 48, 32000   # slots x Mistral's vocabulary
+
+        def per_row(dtype, *trailing):
+            return jax.ShapeDtypeStruct((rows, *trailing), dtype,
+                                        sharding=one_chip)
+
+        text = jax.jit(sample_token_slots).lower(
+            per_row(jnp.float32, vocab),
+            per_row(jax.eval_shape(jax.random.key, 0).dtype),
+            per_row(jnp.float32), per_row(jnp.int32),
+            per_row(jnp.float32), per_row(jnp.bool_),
+        ).compile().as_text()
+        comps = _computations(text)
+        entry = next(b for n, b in comps.items() if " conditional(" in b)
+        cond = next(ln for ln in entry.splitlines()
+                    if " conditional(" in ln)
+        branches = cond.split("branch_computations={")[1].split("}")[0]
+        branches = [b.strip().lstrip("%") for b in branches.split(",")]
+        assert len(branches) == 3
+
+        def reachable(name, seen):
+            if name in seen or name not in comps:
+                return seen
+            seen.add(name)
+            for other in comps:
+                if other != name and "%" + other in comps[name]:
+                    reachable(other, seen)
+            return seen
+
+        def sorts(name):
+            return sum(comps[c].count(" sort(")
+                       for c in reachable(name, set()))
+
+        assert sorts(branches[0]) == 0 and sorts(branches[1]) == 0
+        assert sorts(branches[2]) >= 2
+        # every sort of the program sits under the third branch
+        assert sum(b.count(" sort(") for b in comps.values()) == \
+            sorts(branches[2])
