@@ -92,6 +92,14 @@ class LlamaConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def layer_kinds(self) -> tuple[tuple[str, int], ...]:
+        """What each layer keeps in the serving cache, `(kind, window)`:
+        the engine builds one pool, block manager and block table per
+        kind (`init_paged_cache`, serve/engine.py). Every layer here is
+        `full`: it keeps every position of a request."""
+        return (("full", 0),) * self.n_layers
+
 
 def llama2_7b_config(**kw) -> LlamaConfig:
     return LlamaConfig(**kw)
@@ -243,6 +251,96 @@ def _chain_view(pool, block_tables):
     return g.swapaxes(2, 3).reshape(B, MB * bs, Hkv, D)
 
 
+def paged_kv_write(cache, k, v, block_tables, base):
+    """`kv_write`: scatter the T new positions of each row, logical
+    positions `base[b]..base[b]+T-1`, into the pooled cache
+    `{'k','v': [NB, Hkv, bs, D]}` through `block_tables` [B, MB].
+    Returns the updated (k pool, v pool). Anything the table does not
+    cover (bucket padding, inactive lanes, a block a windowed layer has
+    let go) lands in the null block 0, where garbage is harmless by
+    contract."""
+    T = k.shape[1]
+    Hkv, bs = cache["k"].shape[1], cache["k"].shape[2]
+    MB = block_tables.shape[1]
+    L = MB * bs
+    # `kv_write`: where each new position goes, then the scatter
+    with jax.named_scope("kv_write"):
+        cols = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        phys = jnp.where(
+            cols < L,
+            jnp.take_along_axis(
+                block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
+            jnp.int32(0),
+        )
+        off = cols % bs
+        # scatter D-wide rows of the pool seen as [NB*Hkv*bs, D] (a
+        # free reshape): row (phys*Hkv + h)*bs + off. Scattering
+        # [Hkv, D] windows at (phys, :, off) instead made XLA
+        # re-lay out the whole pool around the scatter for windows
+        # of 2..64 tokens — two pool-sized temporaries per call.
+        rows = ((phys[:, :, None] * Hkv
+                 + jnp.arange(Hkv, dtype=jnp.int32)) * bs
+                + off[:, :, None])                      # [B, T, Hkv]
+
+        def write(pool, new):
+            flat = pool.reshape(-1, pool.shape[-1])
+            return flat.at[rows].set(new.astype(pool.dtype)).reshape(
+                pool.shape)
+
+        return write(cache["k"], k), write(cache["v"], v)
+
+
+def window_view_blocks(window: int, T: int, block_size: int) -> int:
+    """Blocks of a chain that hold every key T successive queries of a
+    windowed layer can see: positions `p0 - window + 1 .. p0 + T - 1`
+    for a first query at p0, wherever p0 falls inside its block."""
+    return -(-(window + T - 1) // block_size) + 1
+
+
+def paged_gather_read(q, ck, cv, block_tables, base, first=None,
+                      window: int = 0):
+    """The gather read path: each row's chain gathered out of the pools
+    into a contiguous view, then the masked grouped attention over it.
+    q [B, T, H, D] at logical positions `base[b]..base[b]+T-1`.
+
+    A `full` layer (`first` None) reads the whole table: keys 0..query.
+    A windowed layer passes `first` [B], the first position its first
+    query needs (`base - window + 1`, not below 0), and `window`: the
+    read takes the `window_view_blocks` of the table that start at
+    `first`'s block, not the whole chain, and a query at p sees keys
+    `p - window < j <= p`. Rows beyond a row's frontier, and blocks the
+    engine has let go (table entry 0), are masked off by position."""
+    B, T = q.shape[0], q.shape[1]
+    Hkv, bs = ck.shape[1], ck.shape[2]
+    MB = block_tables.shape[1]
+    rep = q.shape[2] // Hkv
+    nb = MB if first is None else min(
+        MB, window_view_blocks(window, T, bs))
+    L = nb * bs
+    # gather each row's chain, [B, nb, Hkv, bs, D]; rows beyond a row's
+    # frontier are masked off exactly as in the slab layout
+    with jax.named_scope("attention"):
+        kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
+        q_pos = base[:, None, None] + \
+            jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)[None]
+        if first is None:
+            mask = kv_pos[None] <= q_pos  # [B, T, L]
+        else:
+            fb = jnp.clip(first // bs, 0, MB - nb)              # [B]
+            kv_pos = (fb * bs)[:, None, None] + kv_pos[None]
+            mask = (kv_pos <= q_pos) & (kv_pos > q_pos - window)
+    with jax.named_scope("kv_read"):
+        if first is not None:
+            block_tables = jax.vmap(
+                lambda row, f: jax.lax.dynamic_slice_in_dim(row, f, nb)
+            )(block_tables, fb)
+        # the gather and the layout copy; the cast to float32
+        # that completes the read is scoped where it happens
+        kview = _chain_view(ck, block_tables)
+        vview = _chain_view(cv, block_tables)
+    return _grouped_cache_attention(q, kview, vview, mask, rep)
+
+
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
 
@@ -304,42 +402,14 @@ class LlamaAttention(nn.Module):
                     features=c.d_model, axis=(-2, -1), name="o_proj")(out)
 
         if cache is not None and block_tables is not None:
-            B, T = x.shape[0], x.shape[1]
-            bs = cache["k"].shape[2]
-            MB = block_tables.shape[1]
-            L = MB * bs
+            if isinstance(block_tables, dict):
+                # the engine hands the tables by layer kind; every
+                # layer here is `full`
+                block_tables = block_tables["full"]
+            B = x.shape[0]
             idx = jnp.asarray(cache_index, jnp.int32)
             base = idx if idx.ndim == 1 else jnp.full((B,), idx, jnp.int32)
-            # `kv_write`: where each new position goes, then the scatter
-            with jax.named_scope("kv_write"):
-                cols = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-                # physical address of each new position; anything the table
-                # does not cover lands in the null block, where garbage
-                # (bucket padding, inactive lanes) is harmless by contract
-                phys = jnp.where(
-                    cols < L,
-                    jnp.take_along_axis(
-                        block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
-                    jnp.int32(0),
-                )
-                off = cols % bs
-                # scatter D-wide rows of the pool seen as [NB*Hkv*bs, D] (a
-                # free reshape): row (phys*Hkv + h)*bs + off. Scattering
-                # [Hkv, D] windows at (phys, :, off) instead made XLA
-                # re-lay out the whole pool around the scatter for windows
-                # of 2..64 tokens — two pool-sized temporaries per call.
-                Hkv = c.n_kv_heads
-                rows = ((phys[:, :, None] * Hkv
-                         + jnp.arange(Hkv, dtype=jnp.int32)) * bs
-                        + off[:, :, None])                      # [B, T, Hkv]
-
-                def write(pool, new):
-                    flat = pool.reshape(-1, pool.shape[-1])
-                    return flat.at[rows].set(new.astype(pool.dtype)).reshape(
-                        pool.shape)
-
-                ck = write(cache["k"], k)
-                cv = write(cache["v"], v)
+            ck, cv = paged_kv_write(cache, k, v, block_tables, base)
             if c.paged_attn_impl == "pallas":
                 # read the pools in place: the kernel walks the block
                 # table itself, so no contiguous copy is materialized.
@@ -352,20 +422,7 @@ class LlamaAttention(nn.Module):
                 with jax.named_scope("kv_read"):
                     out = paged_attention(q, ck, cv, block_tables, base)
             elif c.paged_attn_impl == "gather":
-                # gather each row's chain, [B, MB, Hkv, bs, D]; rows
-                # beyond a row's frontier are masked off exactly as in
-                # the slab layout
-                with jax.named_scope("attention"):
-                    kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, L), 1)
-                    q_pos = base[:, None, None] + \
-                        jax.lax.broadcasted_iota(jnp.int32, (T, L), 0)[None]
-                    mask = kv_pos[None] <= q_pos  # [B, T, L]
-                with jax.named_scope("kv_read"):
-                    # the gather and the layout copy; the cast to float32
-                    # that completes the read is scoped where it happens
-                    kview = _chain_view(ck, block_tables)
-                    vview = _chain_view(cv, block_tables)
-                out = _grouped_cache_attention(q, kview, vview, mask, rep)
+                out = paged_gather_read(q, ck, cv, block_tables, base)
             else:
                 raise ValueError(
                     f"unknown paged_attn_impl {c.paged_attn_impl!r} "
@@ -476,27 +533,38 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None,
     ]
 
 
-def init_paged_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
+def init_paged_cache(cfg, num_blocks, block_size: int,
                      dtype=None) -> list[dict]:
     """Per-layer pooled KV cache for block-table decoding: physical
     block 0 is the null block (serve/blocks.py routes masked writes
     there), blocks 1..num_blocks-1 are allocatable. Logical positions
     addressed through a table must still stay under cfg.max_len — the
-    rope table is the binding constraint, exactly as for `init_cache`."""
+    rope table is the binding constraint, exactly as for `init_cache`.
+
+    `num_blocks` is one number for every layer, or `{kind: number}` for
+    a model whose `cfg.layer_kinds` names more than one kind: a layer's
+    pool has its kind's size, and is addressed through its kind's
+    table."""
     dtype = dtype or cfg.compute_dtype
-    shape = (num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
-    return [
-        {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-        for _ in range(cfg.n_layers)
-    ]
+
+    def pool(kind):
+        n = num_blocks[kind] if isinstance(num_blocks, dict) else num_blocks
+        shape = (n, cfg.n_kv_heads, block_size, cfg.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    return [pool(kind) for kind, _ in cfg.layer_kinds]
 
 
-def paged_cache_block_bytes(cfg: LlamaConfig, block_size: int,
-                            dtype=None) -> int:
+def paged_cache_block_bytes(cfg, block_size: int, dtype=None,
+                            kind: str | None = None) -> int:
     """HBM bytes one physical block costs across all layers (K and V) —
-    the unit the serve cache-pressure gauges are denominated in."""
+    the unit the serve cache-pressure gauges are denominated in. With
+    `kind`, across the layers of that kind: a block of that kind's
+    pool."""
     dtype = jnp.dtype(dtype or cfg.compute_dtype)
-    return (2 * cfg.n_layers * block_size * cfg.n_kv_heads
+    n_layers = cfg.n_layers if kind is None else sum(
+        k == kind for k, _ in cfg.layer_kinds)
+    return (2 * n_layers * block_size * cfg.n_kv_heads
             * cfg.head_dim * dtype.itemsize)
 
 

@@ -1222,6 +1222,16 @@ def render_markdown(d: dict) -> str:
         counted = (f"; {_fmt(c.get('kv_tokens'))} KV tokens live, "
                    f"{_fmt(c.get('prefill_tokens'))} prefilled"
                    if c else "")
+        for k, v in sorted(c.items()):
+            if k.startswith("kv_tokens_"):
+                counted += (f" ({_fmt(v)} of them still held by the "
+                            f"`{k[len('kv_tokens_'):]}` layers)")
+        # an expert model: what its ticks sent to the experts held here
+        ex = tp.get("experts") or {}
+        if ex:
+            counted += (f"; {_fmt(ex['picks_held_per_tick'])} picks a tick "
+                        f"on {_fmt(ex['touched_per_tick'])} held experts, "
+                        f"the busiest got {_fmt(ex['load_max'])}")
         # a tick whose rows restrict their support (top_k / top_p) runs
         # the sampled path's sorts for every row: worth a sentence
         st = tp.get("sampling_tiers") or {}

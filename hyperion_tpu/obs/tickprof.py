@@ -20,7 +20,8 @@ writes, SLO evaluation. This module is the one way to time them:
     tick time — slow disk"). The same roll-up says how many of the
     window's decode ticks ran each tier of `sample_token_slots`
     (`sampling_tiers`, from the records' `sampling_rows` /
-    `restricted_rows` counters).
+    `restricted_rows` counters), and what an expert model's ticks
+    routed to the experts held here (`experts`).
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -242,8 +243,24 @@ class TickProfiler:
             # a level reads as of the newest step, a flow over the window
             out["counters"] = {
                 "kv_tokens": recs[-1]["c"].get("kv_tokens"),
+                # positions a windowed layer kind still holds of them
+                **{k: v for k, v in recs[-1]["c"].items()
+                   if k.startswith("kv_tokens_")},
                 "prefill_tokens": sum(r.get("c", {}).get("prefill_tokens", 0)
                                       for r in recs)}
+        # an expert model's decode ticks (serve/engine.py
+        # `_expert_counters`): picks that landed on the experts held
+        # here and held experts touched, a tick (both summed over the
+        # expert layers), and the busiest expert's tokens in any tick
+        picks = [r["c"] for r in recs if "expert_picks_held" in r.get("c", {})]
+        if picks:
+            out["experts"] = {
+                "ticks": len(picks),
+                "picks_held_per_tick": round(sum(
+                    c["expert_picks_held"] for c in picks) / len(picks), 3),
+                "touched_per_tick": round(sum(
+                    c["experts_touched"] for c in picks) / len(picks), 3),
+                "load_max": max(c["expert_load_max"] for c in picks)}
         # which tier of `sample_token_slots` each decode tick of the
         # window ran, from what its rows asked for (a step with no
         # `device` segment ran no tick)

@@ -582,7 +582,9 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
                 op_name = scopes.get(op.module or "", {}).get(op.instr)
                 source = "given" if op_name else "none"
             pas, scope = scope_of(op_name)
-            if scope == UNSCOPED and not op_name:
+            # no `op_name`, or one the compiler gave a custom call of its
+            # own making (`ragged-dot-none:`): no path of the program's
+            if scope == UNSCOPED and "/" not in (op_name or ""):
                 if op.name not in inherited:    # once, not once a run
                     pid = st.get("program_id")
                     inherited[op.name] = _from_neighbours(

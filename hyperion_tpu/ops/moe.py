@@ -26,6 +26,17 @@ the GShard/Switch einsum formulation the hardware wants:
     `E * Σ_e f_e · P_e` (f_e = fraction of tokens whose top-1 choice is
     e, P_e = mean router probability for e); ≈ 1.0 under uniform
     routing, grows as routing collapses.
+
+Two layers live here and serve different users. `moe_ffn` (above) is
+TRAINING's: `MoELM`'s capacity routing, which drops tokens past an
+expert's capacity and is exact only against itself. `dropless_moe`
+(below) is SERVING's (`models/afmoe.py`): token-choice routing with a
+sigmoid router over ALL of a model's experts, no capacity and no drop,
+told which contiguous run of experts this chip holds (`held`); it
+computes the part of the result those experts give, over grouped
+matrix products (`jax.lax.ragged_dot`, tokens sorted by expert), and
+what the absent experts would have added is left out. It is what an
+inference reference can be matched against; `moe_ffn` is not.
 """
 
 from __future__ import annotations
@@ -208,3 +219,73 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig,
         p_e = (probs * wt).sum(axis=(0, 1)) / denom
     aux = E * jnp.sum(f_e * p_e)
     return y.reshape(B, T, d), aux
+
+
+# --- serving: dropless token-choice routing over a held share ---------
+
+
+def sigmoid_topk_route(x: jax.Array, router: jax.Array, bias: jax.Array, *,
+                       top_k: int, route_norm: bool, route_scale: float):
+    """x [N, d] → (picked [N, k] int32 over ALL experts, weights [N, k]
+    float32). Scores are `sigmoid(x W_r)` in float32 (a bf16 product
+    flips near-ties among 256 experts); `bias` moves which experts are
+    PICKED and never the weight a pick gets; the weights are normalised
+    over all k picks, held here or not, then scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, picked = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, picked, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return picked.astype(jnp.int32), w * route_scale
+
+
+def dropless_moe(x: jax.Array, params: dict, *, held: tuple[int, int],
+                 top_k: int, route_norm: bool = True,
+                 route_scale: float = 1.0):
+    """The routed part of a token-choice expert layer, for the experts
+    `held = (first, count)` of the router's width: x [N, d] →
+    (y [N, d], load [N, count] int32).
+
+    `params`: `router` [d, E], `expert_bias` [E], and the held experts'
+    SwiGLU weights stacked `gate`, `up` [count, d, f], `down`
+    [count, f, d]. Every shape is static: the N*k (token, pick) pairs are
+    sorted by expert, pairs whose expert lives on another chip sort
+    last, and the three products run grouped over the held experts
+    (`ragged_dot` with the per-expert counts): no capacity, no token
+    dropped however uneven the routing, and no dense pass over every
+    held expert for every token. `load[n, e]` is 1 where token n picked
+    held expert e: the tick's counters are sums of it.
+
+    Device scopes (under the caller's): `router`, `dispatch`, `experts`,
+    `combine`."""
+    first, count = held
+    N = x.shape[0]
+    with jax.named_scope("router"):
+        picked, w = sigmoid_topk_route(
+            x, params["router"], params["expert_bias"], top_k=top_k,
+            route_norm=route_norm, route_scale=route_scale)
+    with jax.named_scope("dispatch"):
+        local = picked - first
+        here = (local >= 0) & (local < count)
+        flat = jnp.where(here, local, count).reshape(-1)       # [N*k]
+        order = jnp.argsort(flat, stable=True)
+        load = jnp.sum(jax.nn.one_hot(
+            jnp.where(here, local, -1), count, dtype=jnp.int32), axis=1)
+        sizes = jnp.sum(load, axis=0)                           # [count]
+    with jax.named_scope("experts"):
+        # the rows in expert order, then the three grouped products (the
+        # compiler's own custom calls, which a trace names after what
+        # they read: obs/xprof.py)
+        xs = x[order // top_k]                                  # [N*k, d]
+        gate = lax.ragged_dot(xs, params["gate"], sizes)
+        up = lax.ragged_dot(xs, params["up"], sizes)
+        ys = lax.ragged_dot(jax.nn.silu(gate) * up, params["down"], sizes)
+    with jax.named_scope("combine"):
+        # back to (token, pick) order; rows past the held groups carry
+        # whatever the grouped product left there and are zeroed
+        back = jnp.argsort(order)
+        ys = ys[back].reshape(N, top_k, -1).astype(jnp.float32)
+        y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
+    return y.astype(x.dtype), load

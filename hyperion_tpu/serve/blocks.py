@@ -173,13 +173,20 @@ class SeqAlloc:
     """One sequence's view of the pool: its block chain in logical
     order, how much of its admission-time reservation is still
     unclaimed, and its admission order (preemption picks the
-    youngest)."""
+    youngest). The engine keeps one per layer kind for a slot: the
+    `full` kind's carries `order` and `n_filled` for all of them."""
 
     blocks: list[int]
     n_shared: int = 0        # leading blocks also held by the radix trie
     reserved: int = 0        # worst-case blocks promised, not yet claimed
     order: int = 0           # admission sequence number
     n_filled: int = 0        # tokens written so far (the write frontier)
+    # a windowed layer kind's chain slides: `blocks[0]` is logical block
+    # `first` (the blocks before it fell behind every query's window and
+    # went back to the pool), and `limit` is the logical blocks the
+    # sequence can ever reach, which sizes its reservation as it slides
+    first: int = 0
+    limit: int = 0
 
 
 def fork_alloc(

@@ -22,7 +22,12 @@ once, at warmup, forever:
     whose prompts share a prefix share the physical blocks outright
     (PagedAttention — Kwon et al., SOSP '23). The table itself is a
     tiny `[S, MB]` int32 host array shipped with each jitted call, so
-    block churn never touches compiled code.
+    block churn never touches compiled code. The pool is built BY
+    LAYER KIND (`model.cfg.layer_kinds`): one pool size, one block
+    manager and one block table per kind. A `full` layer keeps every
+    position of a request; a `window` layer keeps the positions its
+    queries can still see, its leading blocks going back to the free
+    list as the request moves on (`_slide_windows`).
   * Every per-request quantity the tick needs — cache depth, eos
     latch, remaining budget, temperature/top_k/top_p, PRNG key — is a
     `[S]` device array threaded through the jitted call, so slot
@@ -139,9 +144,12 @@ def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
     # slot table shipped as a mask) still compute but write to the
     # null block and emit pad.
     act = st["active"] & live
-    logits, cache = model.apply(
+    # `tick_stats`: what a model sows for the tick record (an expert
+    # model its rows' picks, models/afmoe.py); nothing for most models
+    (logits, cache), sown = model.apply(
         variables, st["last_token"][:, None],
         cache=cache, cache_index=st["lengths"], block_tables=bt,
+        mutable=["tick_stats"],
     )
     with jax.named_scope("sampling"):
         keys = jax.vmap(jax.random.fold_in)(st["keys"], st["lengths"])
@@ -164,7 +172,25 @@ def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
             "lengths": lengths,
             "active": act & ~finished,
         }
-    return cache, st, nxt, finished
+        counted = _expert_counters(sown, act)
+    return cache, st, nxt, finished, counted
+
+
+def _expert_counters(sown, act) -> dict:
+    """The tick record's expert counters from what the expert layers
+    sowed (`expert_load` [S, held] per layer: which held experts each
+    row picked), over the rows the tick advanced: picks that landed on
+    held experts, held experts with at least one token (both summed
+    over layers), and the busiest expert's tokens. `{}` for a model
+    that sows nothing: its program is what it was."""
+    loads = jax.tree.leaves(sown)
+    if not loads:
+        return {}
+    per_expert = jnp.sum(
+        jnp.stack(loads) * act[None, :, None].astype(jnp.int32), axis=1)
+    return {"expert_picks_held": jnp.sum(per_expert),
+            "experts_touched": jnp.sum(per_expert > 0),
+            "expert_load_max": jnp.max(per_expert)}
 
 
 def _spec_tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live,
@@ -254,7 +280,7 @@ def _prefill_impl(model, eos_id, variables, cache, st, prompt, bt_row,
     # overwrites it position by position. Compiled once per bucket.
     logits, cache = model.apply(
         variables, prompt, cache=cache, cache_index=start,
-        block_tables=bt_row[None],
+        block_tables=jax.tree.map(lambda row: row[None], bt_row),
     )
     with jax.named_scope("sampling"):
         last = jax.lax.dynamic_slice_in_dim(
@@ -296,7 +322,7 @@ def _chunk_impl(model, variables, cache, window, bt_row, start):
     # shape — one executable per chunk size, forever.
     _, cache = model.apply(
         variables, window, cache=cache, cache_index=start,
-        block_tables=bt_row[None],
+        block_tables=jax.tree.map(lambda row: row[None], bt_row),
     )
     return cache
 
@@ -355,7 +381,10 @@ class EngineConfig:
     # ---- paged cache ----
     block_size: int = 16           # tokens per KV block
     num_blocks: int = 0            # pool size incl. null block (0 = auto:
-    #                                slots * ceil(L/bs) + 1, the slab equivalent)
+    #                                slots * ceil(L/bs) + 1, the slab
+    #                                equivalent); of the `full` layer kind: a
+    #                                windowed kind's pool is always its worst
+    #                                case, slots * (window + a prefill piece)
     prefix_cache: bool = True      # radix prefix reuse on/off
     # ---- tiered KV (serve/hostcache.py) ----
     # > 0 enables the host-RAM spill tier: radix eviction demotes cold
@@ -456,6 +485,7 @@ class Engine:
         from hyperion_tpu.models.llama import (
             init_paged_cache,
             paged_cache_block_bytes,
+            window_view_blocks,
         )
         from hyperion_tpu.obs import heartbeat as hb_mod
         from hyperion_tpu.obs import trace as trace_mod
@@ -485,6 +515,42 @@ class Engine:
             NgramDraft() if self._spec else None
         bs = cfg.block_size
         self._mb = blocks_for(L, bs)          # block-table width per slot
+        # the cache by layer kind: {kind: window}, 0 = every position.
+        # `self.mgr`, `self._bt`, `self._seqs` are the first kind's
+        # (`full` where the model has one): the chain that carries a
+        # slot's order and write frontier
+        self._kinds: dict[str, int] = dict(sorted(
+            mcfg.layer_kinds, key=lambda kw: kw[0] != "full"))
+        windowed = {k: w for k, w in self._kinds.items() if w}
+        self._windowed = bool(windowed)
+        if windowed:
+            # a windowed layer lets blocks go that these features count
+            # on finding again: refuse loudly, nothing silently off
+            for on, what, why in (
+                (cfg.prefix_cache, "the radix prefix cache (prefix_cache)",
+                 "a later request would share a chain whose blocks the "
+                 "window has already freed"),
+                (cfg.host_cache_mb > 0, "the host spill tier "
+                 "(host_cache_mb)", "it restores prefix chains, and needs "
+                 "the prefix cache"),
+                (self._spec, "speculative decoding (spec_k, draft)",
+                 "no test holds its verify window to a sliding chain"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{type(model).__name__} has windowed layers "
+                        f"{windowed}: the engine cannot combine them with "
+                        f"{what} yet, because {why}. Turn it off.")
+        # most blocks of a kind one slot holds at a time: the whole
+        # chain, or a window plus the longest piece one call prefills
+        piece = cfg.prefill_chunk or L
+        self._hold = {
+            k: min(self._mb, blocks_for(w + piece, bs) + 2) if w
+            else self._mb for k, w in self._kinds.items()}
+        # blocks of its table a decode tick reads for one slot
+        self._view = {
+            k: min(self._mb, window_view_blocks(w, 1, bs)) if w
+            else self._mb for k, w in self._kinds.items()}
         num_blocks = cfg.num_blocks or cfg.slots * self._mb + 1
         if num_blocks < self._mb + 1:
             raise ValueError(
@@ -552,8 +618,17 @@ class Engine:
                 fast_s=cfg.slo_fast_s or slo_mod.DEFAULT_FAST_S,
                 slow_s=cfg.slo_slow_s or slo_mod.DEFAULT_SLOW_S)
         self._slots: list[Request | None] = [None] * cfg.slots
-        self._seqs: list[SeqAlloc | None] = [None] * cfg.slots
-        self.mgr = BlockManager(num_blocks, bs)
+        first_kind = self._first_kind = next(iter(self._kinds))
+        pool_blocks = {k: cfg.slots * self._hold[k] + 1 for k in windowed}
+        pool_blocks[first_kind] = num_blocks
+        self._mgrs = {k: BlockManager(pool_blocks[k], bs)
+                      for k in self._kinds}
+        self._allocs: dict[str, list[SeqAlloc | None]] = {
+            k: [None] * cfg.slots for k in self._kinds}
+        self._bts = {k: np.zeros((cfg.slots, self._mb), np.int32)
+                     for k in self._kinds}
+        self.mgr = self._mgrs[first_kind]
+        self._seqs = self._allocs[first_kind]
         self.prefix = RadixPrefixCache(self.mgr) if cfg.prefix_cache else None
         # tiered KV: the host-RAM spill tier behind the radix cache
         # (serve/hostcache.py) — eviction demotes, admission restores
@@ -574,12 +649,17 @@ class Engine:
             # tier must read 0.00/0M instead
             self.metrics.observe_host_cache(
                 self.host.occupancy_mb, len(self.host))
-        self._bt = np.zeros((cfg.slots, self._mb), np.int32)
-        self._bt_dev = None   # device mirror of (_bt, live); None = stale
-        self._pending_reserve: dict[str, int] = {}
+        self._bt = self._bts[first_kind]
+        self._bt_dev = None   # device mirror of (_bts, live); None = stale
+        self._pending_reserve: dict[str, dict[str, int]] = {}
         self._order = itertools.count()
-        self._block_bytes = paged_cache_block_bytes(mcfg, bs)
-        self._cache = init_paged_cache(mcfg, num_blocks, bs)
+        # bytes of one block of each kind's pool, over that kind's layers
+        self._kind_block_bytes = {
+            k: paged_cache_block_bytes(mcfg, bs, kind=k)
+            for k in self._kinds}
+        self._block_bytes = self._kind_block_bytes[first_kind]
+        self._cache = init_paged_cache(
+            mcfg, pool_blocks if windowed else num_blocks, bs)
         self._state = self._init_state()
         self._tick_no = 0
         # cumulative transport-sink seconds (all requests); per-request
@@ -598,6 +678,9 @@ class Engine:
         self._prefill_tokens = 0  # padded tokens prefilled this step
         # rows of this step's decode tick that sample / that restrict
         self._sampling_rows = self._restricted_rows = 0
+        # what this step's decode tick counted on the device (an expert
+        # model's picks: `_expert_counters`), fetched with its tokens
+        self._tick_counted: dict[str, int] = {}
         self._last_prefill_bucket: int | None = None
         # `.nbytes` is shape metadata — summing it syncs nothing
         self._param_bytes = int(sum(
@@ -713,7 +796,8 @@ class Engine:
                 self._cache = self._chunk_jit(
                     self.model, self.variables, self._cache,
                     jnp.full((1, C), self.cfg.pad_id, jnp.int32),
-                    jnp.zeros((self._mb,), jnp.int32), jnp.int32(0))
+                    {k: jnp.zeros((self._mb,), jnp.int32)
+                     for k in self._kinds}, jnp.int32(0))
                 compile_s["chunk"] = round(time.perf_counter() - t0, 4)
             zero = jnp.zeros((1,), jnp.int32)
             t0 = time.perf_counter()
@@ -723,9 +807,10 @@ class Engine:
             sp.set(buckets=lens)
         self._state = self._init_state()
         self._slots = [None] * self.cfg.slots
-        self._seqs = [None] * self.cfg.slots
+        for k in self._kinds:
+            self._allocs[k][:] = [None] * self.cfg.slots
+            self._bts[k][:] = 0
         self._chunking = {}
-        self._bt[:] = 0
         self._bt_dev = None
         stats = self.compile_stats()
         total_s = round(sp.dur_s or 0.0, 4)
@@ -749,7 +834,7 @@ class Engine:
         cost = compiled_cost(
             self._tick_jit, self.model, self.cfg.eos_id, self.cfg.pad_id,
             self.variables, self._cache, self._state,
-            jnp.asarray(self._bt), jnp.asarray(live))
+            self._rows_on_device(), jnp.asarray(live))
         return {f"tick_{k}": v for k, v in (cost or {}).items()}
 
     def _prefill_call(self, req: Request, slot: int, *, start: int = 0,
@@ -771,7 +856,7 @@ class Engine:
             buf = np.full((1, Pb), self.cfg.pad_id, np.int32)
             buf[0, :P] = suffix
             args = (
-                jnp.asarray(buf), jnp.asarray(self._bt[slot]),
+                jnp.asarray(buf), self._rows_on_device(slot),
                 jnp.int32(slot), jnp.int32(start), jnp.int32(P),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 jnp.float32(req.top_p),
@@ -796,6 +881,16 @@ class Engine:
              for s, r in enumerate(self._slots)),
             bool, len(self._slots))
 
+    def _rows_on_device(self, slot: int | None = None,
+                        rows: dict | None = None) -> dict:
+        """The block tables by kind as the jits take them: every slot's
+        (the tick), one slot's rows (its prefill), or rows held aside
+        (a chunking slot's)."""
+        if rows is None:
+            rows = self._bts if slot is None else \
+                {k: t[slot] for k, t in self._bts.items()}
+        return {k: jnp.asarray(t) for k, t in rows.items()}
+
     def _tables_on_device(self) -> tuple:
         if self._bt_dev is None:
             # upload only when the table or slot liveness changed —
@@ -803,7 +898,7 @@ class Engine:
             # costs zero host->device traffic. A segment of its own:
             # `device` is the call's wall net of it
             with self.tickprof.seg("bt_upload"):
-                self._bt_dev = (jnp.asarray(self._bt),
+                self._bt_dev = (self._rows_on_device(),
                                 jnp.asarray(self._live_mask()))
         return self._bt_dev
 
@@ -811,12 +906,17 @@ class Engine:
         prof = self.tickprof
         tables = self._tables_on_device()
         with prof.seg("device/dispatch"):
-            self._cache, self._state, toks, fins = self._tick_jit(
+            self._cache, self._state, toks, fins, counted = self._tick_jit(
                 self.model, self.cfg.eos_id, self.cfg.pad_id,
                 self.variables, self._cache, self._state, *tables)
         # the host fetch is the fence: tick spans time real work
         with prof.seg("device/fetch"):
-            return np.asarray(toks), np.asarray(fins)
+            for x in counted.values():
+                # rides the tokens' fetch: on the host by the time they are
+                x.copy_to_host_async()
+            toks, fins = np.asarray(toks), np.asarray(fins)
+            self._tick_counted = {k: int(v) for k, v in counted.items()}
+            return toks, fins
 
     def _collect_drafts(self) -> np.ndarray:
         """[S, spec_k] proposals for this tick, one drafter call per
@@ -856,46 +956,113 @@ class Engine:
             return prompt, req.max_new_tokens - len(req.tokens)
         return req.prompt_ids, req.max_new_tokens
 
-    def _block_demand(self, req: Request) -> int:
-        """Exclusive new blocks this request needs — worst-case span
-        under `reserve` admission, prompt-only under `optimistic` —
-        net of blocks a radix hit would share."""
+    def _block_demand(self, req: Request) -> dict[str, int]:
+        """Exclusive new blocks this request needs of each layer kind's
+        pool — worst-case span under `reserve` admission, prompt-only
+        under `optimistic` — net of blocks a radix hit would share. A
+        windowed kind never holds more than `_hold` blocks of a slot at
+        a time, however long the span."""
         prompt, budget = self._effective(req)
         P = int(prompt.shape[0])
-        span = P + budget if self.cfg.admission == "reserve" else P
-        need = blocks_for(span, self.cfg.block_size)
+        bs = self.cfg.block_size
+        reserve = self.cfg.admission == "reserve"
+        need = {}
+        for kind, window in self._kinds.items():
+            if window:
+                piece = min(P, self.cfg.prefill_chunk or P)
+                need[kind] = min(
+                    self._hold[kind],
+                    blocks_for(P + budget if reserve else piece, bs))
+            else:
+                need[kind] = blocks_for(P + budget if reserve else P, bs)
         if self.prefix is not None:
-            need -= len(self.prefix.lookup(prompt, P - 1).blocks)
+            need[self._first_kind] -= len(
+                self.prefix.lookup(prompt, P - 1).blocks)
         return need
 
     def _can_admit(self, req: Request) -> bool:
         """Block-availability gate for the queue: pop only when the
-        demand is covered by free + evictable-radix blocks, net of
-        reservations already promised to in-flight requests. Covered
-        demand is reserved immediately (released as real blocks are
-        claimed), so one scheduling round cannot double-spend."""
+        demand is covered, in every kind's pool, by free +
+        evictable-radix blocks, net of reservations already promised
+        to in-flight requests. Covered demand is reserved immediately
+        (released as real blocks are claimed), so one scheduling round
+        cannot double-spend."""
         need = self._block_demand(req)
         evictable = self.prefix.evictable() if self.prefix else 0
-        if need > self.mgr.num_free + evictable - self.mgr.reserved:
-            return False
-        self.mgr.reserve(need)
+        for kind, n in need.items():
+            mgr = self._mgrs[kind]
+            if n > mgr.num_free + evictable - mgr.reserved:
+                return False
+            evictable = 0       # the trie holds blocks of the first kind
+        for kind, n in need.items():
+            self._mgrs[kind].reserve(n)
         self._pending_reserve[req.id] = need
         return True
 
-    def _alloc(self, n: int, seq: SeqAlloc | None = None) -> list[int] | None:
-        """Pool allocation with radix eviction backing; claims against
-        `seq`'s reservation when it holds one."""
-        blocks = self.mgr.alloc(n)
-        if blocks is None and self.prefix is not None:
-            freed = self.prefix.evict(n - self.mgr.num_free)
+    def _release_pending(self, rid: str) -> None:
+        """Give back what the gate reserved for a popped request."""
+        for kind, n in self._pending_reserve.pop(rid, {}).items():
+            self._mgrs[kind].release(n)
+
+    def _alloc(self, n: int, seq: SeqAlloc | None = None,
+               kind: str | None = None) -> list[int] | None:
+        """Allocation from a kind's pool (the first kind's by default,
+        with radix eviction backing); claims against `seq`'s
+        reservation when it holds one."""
+        mgr = self.mgr if kind is None else self._mgrs[kind]
+        blocks = mgr.alloc(n)
+        if blocks is None and self.prefix is not None and mgr is self.mgr:
+            freed = self.prefix.evict(n - mgr.num_free)
             if freed:
                 self.metrics.on_evict(freed)
-            blocks = self.mgr.alloc(n)
+            blocks = mgr.alloc(n)
         if blocks is not None and seq is not None and seq.reserved:
             take = min(seq.reserved, n)
             seq.reserved -= take
-            self.mgr.release(take)
+            mgr.release(take)
         return blocks
+
+    def _slide_windows(self, slot: int, upto: int | None = None,
+                       rows: dict | None = None) -> bool:
+        """A windowed kind's chain follows its slot: the blocks that
+        fell behind the window of every query still to come (queries
+        start at the write frontier `n_filled`) go back to the free
+        list now, and with `upto` the chain then owns blocks for every
+        position below it (the next prefill piece). `rows`: the table
+        rows to keep in step, a chunking slot's held-aside ones; the
+        live table's by default. False when a pool ran dry."""
+        bs = self.cfg.block_size
+        n = self._seqs[slot].n_filled
+        for kind, window in self._kinds.items():
+            if not window:
+                continue
+            seq, mgr = self._allocs[kind][slot], self._mgrs[kind]
+            row = self._bts[kind][slot] if rows is None else rows[kind]
+            dead = max(0, n - window + 1) // bs - seq.first
+            if dead > 0:
+                mgr.decref(seq.blocks[:dead])
+                del seq.blocks[:dead]
+                row[seq.first:seq.first + dead] = 0
+                seq.first += dead
+                if self.cfg.admission == "reserve":
+                    # what it let go it may need again further on, up
+                    # to its hold or the end of its span
+                    again = min(self._hold[kind], seq.limit - seq.first) \
+                        - len(seq.blocks) - seq.reserved
+                    seq.reserved += again
+                    mgr.reserve(again)
+                self._bt_dev = None
+            if upto is not None:
+                more = blocks_for(upto, bs) - seq.first - len(seq.blocks)
+                if more > 0:
+                    got = self._alloc(more, seq, kind)
+                    if got is None:
+                        return False
+                    at = seq.first + len(seq.blocks)
+                    row[at:at + more] = got
+                    seq.blocks.extend(got)
+                    self._bt_dev = None
+        return True
 
     def _spill_block(self, chain_tokens: tuple[int, ...],
                      block: int) -> None:
@@ -933,22 +1100,23 @@ class Engine:
         return moved
 
     def _free_slot(self, slot: int) -> None:
-        seq = self._seqs[slot]
-        if seq is not None:
-            self.mgr.release(seq.reserved)
-            self.mgr.decref(seq.blocks)
-        self._seqs[slot] = None
+        for kind, mgr in self._mgrs.items():
+            seq = self._allocs[kind][slot]
+            if seq is not None:
+                mgr.release(seq.reserved)
+                mgr.decref(seq.blocks)
+            self._allocs[kind][slot] = None
+            self._bts[kind][slot, :] = 0
         self._slots[slot] = None
         self._chunking.pop(slot, None)
-        self._bt[slot, :] = 0
         self._bt_dev = None
 
     def _claim_blocks(self, req: Request):
         """The block half of an admission: radix lookup, host-tier
         probe, pins, allocation, copy-on-write fork and restore. Returns
-        (prompt, budget, start, seq), `start` the prefix hit in tokens,
-        or None when allocation lost a race."""
-        reserve = self._pending_reserve.pop(req.id, 0)
+        (prompt, budget, start, seqs), `start` the prefix hit in tokens
+        and `seqs` the slot's chain of each layer kind, or None when
+        allocation lost a race."""
         prompt, budget = self._effective(req)
         P = int(prompt.shape[0])
         bs = self.cfg.block_size
@@ -982,9 +1150,17 @@ class Engine:
         pin = shared + ([cow_src] if cow_src is not None else [])
         self.mgr.incref(pin)
         fresh = self._alloc(need_now) if need_now else []
-        if fresh is None:
-            self.mgr.decref(pin)
-            self.mgr.release(reserve)
+        # a windowed kind starts with the blocks of the first piece the
+        # prefill writes; `_slide_windows` moves the chain on from there
+        piece = blocks_for(min(P, self.cfg.prefill_chunk or P), bs)
+        windows = {k: self._mgrs[k].alloc(piece)
+                   for k, w in self._kinds.items() if w} \
+            if fresh is not None else {}
+        if fresh is None or None in windows.values():
+            self.mgr.decref(pin + (fresh or []))
+            for k, got in windows.items():
+                self._mgrs[k].decref(got or [])
+            self._release_pending(req.id)
             return None
         # Re-derive the growth reservation instead of netting the
         # gate's estimate against need_now: an earlier admission this
@@ -993,15 +1169,22 @@ class Engine:
         # not depend on sharing at all, so computing it directly keeps
         # the reserve-mode "exhaustion impossible" ledger exact even
         # when the gate's sharing estimate went stale.
-        self.mgr.release(reserve)
+        self._release_pending(req.id)
         growth = 0
+        span = blocks_for(P + budget, bs)
         if self.cfg.admission == "reserve":
-            growth = blocks_for(P + budget, bs) - blocks_for(P, bs)
+            growth = span - blocks_for(P, bs)
             self.mgr.reserve(growth)
         seq = SeqAlloc(
             blocks=shared + fresh, n_shared=len(shared),
             reserved=growth, order=next(self._order),
         )
+        seqs = {self._first_kind: seq}
+        for k, got in windows.items():
+            ahead = min(self._hold[k], span) - piece \
+                if self.cfg.admission == "reserve" else 0
+            self._mgrs[k].reserve(ahead)
+            seqs[k] = SeqAlloc(blocks=got, reserved=ahead, limit=span)
         if cow_src is not None:
             # mid-block divergence: duplicate the agreeing block so our
             # writes (suffix prefill + decode) never touch the shared
@@ -1039,7 +1222,7 @@ class Engine:
                 else device_start,
                 host_tokens=len(host_payloads) * bs)
             self._hot_roots.note(prefix_root_digest(prompt))
-        return prompt, budget, start, seq
+        return prompt, budget, start, seqs
 
     def _admit(self, req: Request, slot: int) -> TokenEvent | None:
         """Prefill `req` into `slot` through the paged pool: radix
@@ -1050,7 +1233,8 @@ class Engine:
             claimed = self._claim_blocks(req)
         if claimed is None:
             return None
-        prompt, budget, start, seq = claimed
+        prompt, budget, start, seqs = claimed
+        seq = seqs[self._first_kind]
         P = int(prompt.shape[0])
         resumed = req.first_token_at is not None
         C = self.cfg.prefill_chunk
@@ -1065,16 +1249,18 @@ class Engine:
             # state. `_advance_chunks` runs one [1, C] segment per step
             # between decode ticks; the prefix is NOT registered in the
             # radix until the blocks actually hold it.
-            row = np.zeros((self._mb,), np.int32)
-            row[:len(seq.blocks)] = seq.blocks
-            self._bt[slot, :] = 0
+            rows = {}
+            for k, q in seqs.items():
+                rows[k] = np.zeros((self._mb,), np.int32)
+                rows[k][:len(q.blocks)] = q.blocks
+                self._bts[k][slot, :] = 0
+                self._allocs[k][slot] = q
             self._bt_dev = None
             seq.n_filled = start
             self._slots[slot] = req
-            self._seqs[slot] = seq
             self._chunking[slot] = {
                 "req": req, "prompt": prompt, "budget": budget,
-                "pos": start, "row": row, "resumed": resumed,
+                "pos": start, "rows": rows, "resumed": resumed,
             }
             self.tracer.event(
                 "prefill_chunked", request=req.id, tick=self._tick_no,
@@ -1082,8 +1268,12 @@ class Engine:
                 segments=-(-(P - start) // C), resumed=resumed,
                 **_tr(req))
             return _CHUNK_ADMIT
-        self._bt[slot, :len(seq.blocks)] = seq.blocks
-        self._bt[slot, len(seq.blocks):] = 0
+        for k, q in seqs.items():
+            self._bts[k][slot, :len(q.blocks)] = q.blocks
+            self._bts[k][slot, len(q.blocks):] = 0
+            # installed before the prefill: a slot that finishes on its
+            # first token is freed through these
+            self._allocs[k][slot] = q
         self._bt_dev = None
         with self.tracer.span("serve_prefill", step=self._tick_no) as sp:
             first, finished = self._prefill_call(
@@ -1118,9 +1308,10 @@ class Engine:
         req._sink_mark = self._sink_s
         self.metrics.count_tokens(1)  # the prefill-sampled token
         self._slots[slot] = req
-        self._seqs[slot] = seq
         if finished:
             self._free_slot(slot)
+        else:
+            self._slide_windows(slot)
         return TokenEvent(req, first, finished)
 
     def _advance_chunks(self) -> list[TokenEvent]:
@@ -1141,6 +1332,13 @@ class Engine:
         req, prompt, budget = ck["req"], ck["prompt"], ck["budget"]
         P = int(prompt.shape[0])
         pos = ck["pos"]
+        # the windowed kinds' chains move on to this piece: its blocks
+        # come (a pool that ran dry, under optimistic admission, sends
+        # the request back instead); what falls behind goes as soon as
+        # the piece is written
+        if not self._slide_windows(slot, min(P, pos + C), ck["rows"]):
+            self._preempt(slot)
+            return []
         if P - pos > C:
             prof, at = self.tickprof, {"bucket": C, "start": pos}
             self._prefill_tokens += C
@@ -1148,7 +1346,8 @@ class Engine:
             with prof.seg("chunk/upload", **at):
                 args = (jnp.asarray(np.asarray(prompt[pos:pos + C],
                                                np.int32)[None, :]),
-                        jnp.asarray(ck["row"]), jnp.int32(pos))
+                        self._rows_on_device(rows=ck["rows"]),
+                        jnp.int32(pos))
             with prof.seg("chunk/dispatch", **at):
                 self._cache = self._chunk_jit(
                     self.model, self.variables, self._cache, *args)
@@ -1163,11 +1362,13 @@ class Engine:
                 req.prefill_s += dt
             ck["pos"] = pos + C
             self._seqs[slot].n_filled = pos + C
+            self._slide_windows(slot, rows=ck["rows"])
             return []
         # final segment: install the real row — `_prefill_impl` sets
         # every state field for this slot via `.at[slot].set`, so the
         # stale-lane hazard ends here
-        self._bt[slot, :] = ck["row"]
+        for k, row in ck["rows"].items():
+            self._bts[k][slot, :] = row
         self._bt_dev = None
         del self._chunking[slot]
         resumed = ck["resumed"]
@@ -1207,6 +1408,8 @@ class Engine:
         self.metrics.count_tokens(1)  # the prefill-sampled token
         if finished:
             self._free_slot(slot)
+        else:
+            self._slide_windows(slot)
         return [TokenEvent(req, first, finished)]
 
     def _preempt(self, slot: int, reason: str = "pool_exhausted") -> None:
@@ -1271,45 +1474,50 @@ class Engine:
         retry — oldest requests always progress, so the loop
         terminates and nobody starves."""
         for s in sorted(
-            (t for t in range(self.cfg.slots) if self._slots[t] is not None),
+            # a chunking slot writes through rows held aside, and
+            # `_advance_chunks` sees to its blocks
+            (t for t in range(self.cfg.slots)
+             if self._slots[t] is not None and t not in self._chunking),
             key=lambda t: self._seqs[t].order,
         ):
-            while self._slots[s] is not None:
-                seq = self._seqs[s]
-                lookahead = 0
-                if self._spec:
-                    # the verify window writes positions n_filled ..
-                    # n_filled+k, but only positions an ACCEPTED token
-                    # can land in need real blocks (acceptance is
-                    # capped by the remaining budget; writes past the
-                    # table's chain null-route harmlessly) — so the
-                    # lookahead never exceeds the worst-case span the
-                    # reserve-mode ledger already accounts for
-                    req = self._slots[s]
-                    lookahead = max(0, min(
-                        self.cfg.spec_k,
-                        req.max_new_tokens - len(req.tokens) - 1))
-                needed = (seq.n_filled + lookahead) \
-                    // self.cfg.block_size + 1
-                if len(seq.blocks) >= needed:
-                    break
-                got = self._alloc(1, seq)
-                if got is not None:
-                    self._bt[s, len(seq.blocks)] = got[0]
-                    seq.blocks.append(got[0])
-                    self._bt_dev = None
-                    continue
-                live = [t for t in range(self.cfg.slots)
-                        if self._slots[t] is not None]
-                # batch absorbs pool pressure first: evict the
-                # youngest batch slot when one exists, the youngest
-                # overall otherwise (the starvation-freedom argument —
-                # oldest always progresses — is unchanged either way)
-                batch = [t for t in live
-                         if self._slots[t].sla_class == CLASS_BATCH]
-                victim = max(batch or live,
-                             key=lambda t: self._seqs[t].order)
-                self._preempt(victim)
+            for kind in self._kinds:
+                while self._slots[s] is not None:
+                    seq = self._allocs[kind][s]
+                    lookahead = 0
+                    if self._spec:
+                        # the verify window writes positions n_filled ..
+                        # n_filled+k, but only positions an ACCEPTED token
+                        # can land in need real blocks (acceptance is
+                        # capped by the remaining budget; writes past the
+                        # table's chain null-route harmlessly) — so the
+                        # lookahead never exceeds the worst-case span the
+                        # reserve-mode ledger already accounts for
+                        req = self._slots[s]
+                        lookahead = max(0, min(
+                            self.cfg.spec_k,
+                            req.max_new_tokens - len(req.tokens) - 1))
+                    needed = (self._seqs[s].n_filled + lookahead) \
+                        // self.cfg.block_size + 1
+                    if seq.first + len(seq.blocks) >= needed:
+                        break
+                    got = self._alloc(1, seq, kind)
+                    if got is not None:
+                        self._bts[kind][
+                            s, seq.first + len(seq.blocks)] = got[0]
+                        seq.blocks.append(got[0])
+                        self._bt_dev = None
+                        continue
+                    live = [t for t in range(self.cfg.slots)
+                            if self._slots[t] is not None]
+                    # batch absorbs pool pressure first: evict the
+                    # youngest batch slot when one exists, the youngest
+                    # overall otherwise (the starvation-freedom argument —
+                    # oldest always progresses — is unchanged either way)
+                    batch = [t for t in live
+                             if self._slots[t].sla_class == CLASS_BATCH]
+                    victim = max(batch or live,
+                                 key=lambda t: self._seqs[t].order)
+                    self._preempt(victim)
 
     # ------------------------------------------------------------ events
 
@@ -1665,18 +1873,25 @@ class Engine:
         KV pool's full and in-use footprint, host RSS. Pure host
         arithmetic (`.nbytes` is metadata, `_block_bytes` a cached
         int), so any thread may ask."""
-        bb = self._block_bytes
+        # by layer kind: a kind's block costs its own layers' bytes
+        by_kind = {
+            k: {"pool_bytes": int(m.num_blocks * self._kind_block_bytes[k]),
+                "in_use_bytes": int(m.in_use * self._kind_block_bytes[k])}
+            for k, m in self._mgrs.items()}
         # HBM the paged-read strategy copies per decode tick: the
-        # gather path materializes every slot's full [MB] chain
-        # (mapped or null) into a contiguous view; the pallas kernel
-        # reads the pools in place, so the copy is zero.
+        # gather path materializes every slot's chain (mapped or null;
+        # of a windowed kind the slice of it a query can see) into a
+        # contiguous view; the pallas kernel reads the pools in place,
+        # so the copy is zero.
         impl = getattr(self.model.cfg, "paged_attn_impl", "gather")
-        gather = 0 if impl == "pallas" else \
-            int(self.cfg.slots * self._mb * bb)
+        gather = 0 if impl == "pallas" else int(self.cfg.slots * sum(
+            self._view[k] * bb for k, bb in self._kind_block_bytes.items()))
         return {
             "param_bytes": self._param_bytes,
-            "kv_pool_bytes": int(self.cfg.num_blocks * bb),
-            "blocks_in_use_bytes": int(self.mgr.in_use * bb),
+            "kv_pool_bytes": sum(v["pool_bytes"] for v in by_kind.values()),
+            "blocks_in_use_bytes": sum(
+                v["in_use_bytes"] for v in by_kind.values()),
+            "kv_by_kind": by_kind,
             "kv_gather_bytes_per_tick": gather,
             # the host tier's occupancy rides the same ledger the HBM
             # numbers do — spilled KV is memory too, just cheaper
@@ -1762,6 +1977,15 @@ class Engine:
                 # the pool: host bookkeeping, no device read
                 kv_tokens=sum(q.n_filled for q in self._seqs
                               if q is not None),
+                # and those among them a windowed layer kind still holds
+                # (its leading blocks went back to the pool): per kind
+                **{f"kv_tokens_{k}": sum(
+                    q.n_filled - self._allocs[k][s].first
+                    * self.cfg.block_size
+                    for s, q in enumerate(self._seqs) if q is not None)
+                   for k, w in self._kinds.items() if w},
+                # an expert model's picks on this step's decode tick
+                **self._tick_counted,
                 prefill_tokens=self._prefill_tokens,
                 # what the tick's rows asked of `sample_token_slots`,
                 # from the requests' own parameters: 0 and 0 = the tick
@@ -1780,6 +2004,7 @@ class Engine:
         prof = self.tickprof
         self._prefill_tokens = 0
         self._sampling_rows = self._restricted_rows = 0
+        self._tick_counted = {}
 
         if self._governor is not None:
             tr = self._governor.update(len(self.queue))
@@ -1905,7 +2130,7 @@ class Engine:
                         self.tracer.event(
                             "request_requeued", request=r.id,
                             tick=self._tick_no, reason="alloc_race")
-                        self.mgr.release(self._pending_reserve.pop(r.id, 0))
+                        self._release_pending(r.id)
                         self.queue.push_front(r)
                     break
                 self._emit(ev)
@@ -1970,6 +2195,10 @@ class Engine:
                     if n == 0:
                         continue
                     self._seqs[s].n_filled += n
+                    if self._windowed:
+                        # what fell behind every window is free again
+                        # within the step
+                        self._slide_windows(s)
                     gap_from = getattr(req, "_last_emit_at", None)
                     if gap_from is not None:
                         # the gap is wall time shared by every slot: net it
