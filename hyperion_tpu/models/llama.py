@@ -50,14 +50,17 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     attention_impl: str = "xla"
     norm_impl: str = "xla"        # xla | pallas (fused_rmsnorm kernel)
-    # Decode-time paged-cache read strategy. "gather" materializes
-    # pool[block_tables] into a contiguous [B, L, Hkv, D] view every
-    # tick (an HBM copy of the whole mapped chain per token); "pallas"
-    # routes through ops.pallas.paged_attention, which walks the block
-    # table in-kernel and reads the pools in place. Identical masking
-    # contract; pinned-tolerance numerics (online softmax — see the
-    # kernel docstring). Ignored outside the paged (block_tables) path.
-    paged_attn_impl: str = "gather"
+    # Paged-cache read strategy. "gather" materializes
+    # pool[block_tables] into a contiguous [B, L, Hkv, D] view (an HBM
+    # copy of every table column per call); "pallas" routes through
+    # ops.pallas.paged_attention, which walks each row's live blocks
+    # in-kernel and reads the pools in place; "auto" resolves per call
+    # from its static shape and the backend (`select_paged_attn_impl`:
+    # the kernel for a few-token window on a TPU, the gather otherwise).
+    # Identical masking contract; pinned-tolerance numerics (online
+    # softmax, see the kernel docstring). Ignored outside the paged
+    # (block_tables) path.
+    paged_attn_impl: str = "auto"
     # "none" | "int8": weight-only int8 inference (precision/quant.py) —
     # dense kernels become int8+scale (half bf16's HBM traffic, int8
     # MXU matmuls); params come from quantize_params_for() on a trained
@@ -91,6 +94,16 @@ class LlamaConfig:
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
+
+    def paged_attn_for(self, window: int) -> str:
+        """The paged read, "pallas" or "gather", of a call whose rows
+        each carry `window` query positions: `paged_attn_impl`, with
+        "auto" resolved from that width and the process's backend. The
+        attention module asks per call; the engine asks for its tick."""
+        if self.paged_attn_impl != "auto":
+            return self.paged_attn_impl
+        return select_paged_attn_impl(
+            window, self.n_heads // self.n_kv_heads, jax.default_backend())
 
     @property
     def layer_kinds(self) -> tuple[tuple[str, int], ...]:
@@ -242,6 +255,29 @@ def _grouped_cache_attention(q, ck, cv, mask, rep):
         return out.reshape(B, T, H, D).astype(q.dtype)
 
 
+# The widest query window, in rows of one KV head's group (`T * rep`),
+# that "auto" sends to the paged-attention kernel. Set from the two
+# shapes measured on a v5e (PERF.md section 6, PR 27): a decode tick
+# [48, 1] at rep 4 (4 rows) and a verify window [48, 4] (16 rows), both
+# several times faster than the gather. Above it lie prompt-length
+# windows ([1, 8] at rep 4 is 32 rows): one slot's chain, gathered once
+# for many queries, and a window the kernel's VMEM plan has to cut into
+# head groups; not measured, so they stay on the gather.
+PAGED_KERNEL_MAX_ROWS = 16
+
+
+def select_paged_attn_impl(window: int, rep: int, backend: str) -> str:
+    """Resolve `paged_attn_impl="auto"` to "pallas" or "gather" for one
+    call, from its static shape (`window` query positions a row, `rep`
+    query heads a KV head) and the backend. Resolved at trace time, as
+    `ops.attention.select_attention_impl` is: jit sees one branch. Off
+    a TPU the kernel would run through the interpreter, which is an
+    oracle and not a read path."""
+    if backend != "tpu":
+        return "gather"
+    return "pallas" if window * rep <= PAGED_KERNEL_MAX_ROWS else "gather"
+
+
 def _chain_view(pool, block_tables):
     """Each row's block chain gathered out of the pool
     `[NB, Hkv, bs, D]` into the contiguous `[B, MB*bs, Hkv, D]` view
@@ -366,8 +402,10 @@ class LlamaAttention(nn.Module):
         Writes scatter through the table; reads either gather each
         row's blocks back into a contiguous [B, MB*bs] view for the
         same masked grouped attention (`paged_attn_impl="gather"`) or
-        walk the table in-kernel against the pools in place
-        (`"pallas"`, ops.pallas.paged_attention — no contiguous copy).
+        walk each row's live blocks in-kernel against the pools in
+        place (`"pallas"`, ops.pallas.paged_attention: no contiguous
+        copy); `"auto"`, the default, chooses per call from the window's
+        width and the backend (`select_paged_attn_impl`).
         Out-of-range or unmapped positions
         route to physical block 0 (the serve engine's null block), so
         bucket padding can never corrupt a neighbour's blocks.
@@ -410,23 +448,25 @@ class LlamaAttention(nn.Module):
             idx = jnp.asarray(cache_index, jnp.int32)
             base = idx if idx.ndim == 1 else jnp.full((B,), idx, jnp.int32)
             ck, cv = paged_kv_write(cache, k, v, block_tables, base)
-            if c.paged_attn_impl == "pallas":
-                # read the pools in place: the kernel walks the block
-                # table itself, so no contiguous copy is materialized.
-                # Its read and its product are one kernel: all of it is
-                # `kv_read`, the name the gather path's copies have
+            impl = c.paged_attn_for(x.shape[1])
+            if impl == "pallas":
+                # read the pools in place: the kernel walks each row's
+                # live blocks itself, so no contiguous copy is
+                # materialized. Its read and its product are one
+                # kernel: all of it is `kv_read`, the name the gather
+                # path's copies have
                 from hyperion_tpu.ops.pallas.paged_attention import (
                     paged_attention,
                 )
 
                 with jax.named_scope("kv_read"):
                     out = paged_attention(q, ck, cv, block_tables, base)
-            elif c.paged_attn_impl == "gather":
+            elif impl == "gather":
                 out = paged_gather_read(q, ck, cv, block_tables, base)
             else:
                 raise ValueError(
                     f"unknown paged_attn_impl {c.paged_attn_impl!r} "
-                    "(want 'gather' or 'pallas')"
+                    "(want 'auto', 'gather' or 'pallas')"
                 )
             return o_proj(out), {"k": ck, "v": cv}
 

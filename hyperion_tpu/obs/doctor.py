@@ -1226,6 +1226,11 @@ def render_markdown(d: dict) -> str:
             if k.startswith("kv_tokens_"):
                 counted += (f" ({_fmt(v)} of them still held by the "
                             f"`{k[len('kv_tokens_'):]}` layers)")
+        # how the decode ticks read the cache: the blocks the paged-
+        # attention kernel walked, of the entries a gather copies
+        if c.get("kv_table_entries"):
+            counted += (f"; read in place: {_fmt(c.get('kv_blocks_walked'))} "
+                        f"of {_fmt(c['kv_table_entries'])} table entries")
         # an expert model: what its ticks sent to the experts held here
         ex = tp.get("experts") or {}
         if ex:

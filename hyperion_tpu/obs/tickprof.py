@@ -20,8 +20,10 @@ writes, SLO evaluation. This module is the one way to time them:
     tick time — slow disk"). The same roll-up says how many of the
     window's decode ticks ran each tier of `sample_token_slots`
     (`sampling_tiers`, from the records' `sampling_rows` /
-    `restricted_rows` counters), and what an expert model's ticks
-    routed to the experts held here (`experts`).
+    `restricted_rows` counters), what an expert model's ticks
+    routed to the experts held here (`experts`), and how much of the
+    block tables the ticks' reads touched (`kv_blocks_walked` of
+    `kv_table_entries`).
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -248,6 +250,13 @@ class TickProfiler:
                    if k.startswith("kv_tokens_")},
                 "prefill_tokens": sum(r.get("c", {}).get("prefill_tokens", 0)
                                       for r in recs)}
+            # of the table entries the window's decode ticks could have
+            # gathered a layer, the blocks the paged-attention kernel
+            # walked instead (0: the ticks gathered)
+            if any("kv_table_entries" in r.get("c", {}) for r in recs):
+                for key in ("kv_blocks_walked", "kv_table_entries"):
+                    out["counters"][key] = sum(
+                        r.get("c", {}).get(key, 0) for r in recs)
         # an expert model's decode ticks (serve/engine.py
         # `_expert_counters`): picks that landed on the experts held
         # here and held experts touched, a tick (both summed over the

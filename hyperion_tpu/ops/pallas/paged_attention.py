@@ -1,81 +1,73 @@
-"""Paged decode attention — the Pallas kernel that kills the KV gather.
+"""Paged decode attention: the kernel that reads the KV pool in place.
 
-Role in the stack (ROADMAP item 1, vLLM §4): the paged branch of
-`models/llama.py` historically materialized `pool[block_tables]` into a
-contiguous `[B, L, Hkv, D]` view every decode tick, so each generated
-token paid an HBM round trip over the slot's ENTIRE mapped KV chain —
-2 * S * MB * block_size * Hkv * D * itemsize bytes per layer per tick —
-before a single FLOP of attention ran. That copy exists only to satisfy
-`_grouped_cache_attention`'s contiguous-layout expectation. This kernel
-reads the pools in place instead: the `[S, MB]` block table rides in as
-a scalar-prefetch operand, and the BlockSpec index_map of the K/V pool
-operands dereferences it per grid step, so the DMA engine fetches each
-mapped `[block_size, D]` tile straight from its pooled home.
+Role in the stack (ROADMAP S3, vLLM §4): the gather read path of
+`models/llama.py` copies `pool[block_tables]` into a contiguous view
+every decode tick, copies that view into another layout and reads it a
+third time as float32: every table column of every slot, mapped or not,
+moved several times over before one product runs. This kernel reads the
+pools where they lie, and only each slot's LIVE blocks: a call's bytes
+follow the tokens the slots hold, not the table's width.
 
-Design:
+The walk:
 
-  * Grid `(S, Hkv, MB)` — one program per (slot, KV-head group), the MB
-    axis innermost and marked "arbitrary": the block sweep for one slot
-    revisits VMEM scratch (m, l, acc) with the classic online-softmax
-    recurrence, finalizing `o = acc / l` on the last block. VMEM holds
-    one `[block_size, D]` K/V tile pair at a time.
-  * Block-table walk: `pltpu.PrefetchScalarGridSpec` with
-    `num_scalar_prefetch=2` (block table + per-slot base positions).
-    Scalar-prefetch refs are visible to index_maps, so the pool specs
-    map grid step `(b, g, j)` to physical block `bt_ref[b, j]` — the
-    data-dependent indexing the plain BlockSpec grid cannot express.
-  * GQA rides inside the program: q `[B, T, H, D]` is regrouped to
-    `[B, Hkv, T*rep, D]` so one program handles a whole query-head
-    group; the flattened row r corresponds to token `r // rep`, which
-    is all the masking needs to know.
-  * Masking contract — identical to the gather path: kv position
-    `j*bs + col` attends iff `<= base[b] + row//rep` (per-row causal
-    frontier over the filled prefix). Beyond-length positions and the
-    serve engine's null block 0 (where unmapped/bucket-padding
-    positions scatter) are thereby invisible: every block-table entry
-    at or before the frontier is a real mapped block, and everything
-    after is masked. Blocks that start wholly past the frontier are
-    skipped outright (`pl.when`) — the win that makes short sequences
-    in deep tables cheap.
-  * One compiled executable serves all three engine geometries —
-    sequential decode `[S, 1]`, speculative verify `[S, k+1]`, chunked
-    prefill `[1, C]` — because geometry only changes static shapes the
-    engine already buckets; table contents and bases are runtime data
-    and never retrace.
+  * Grid `(S, Hkv // Hg)`: one step is one slot and `Hg` of its KV
+    heads; at decode and verify widths `Hg = Hkv`, so a call of 48
+    slots is 48 steps. (The kernel this replaces ran one 4 KB tile a
+    step, 49,152 steps a call, and was bound by step overhead: PERF.md
+    section 6, PR 24.)
+  * The pools stay in HBM (`memory_space=pl.ANY`). Inside a step a loop
+    walks the slot's chain from block `lo` (0: a full layer) to
+    `ceil((base + T) / bs)`, read from the scalar-prefetched `base`,
+    and stops there: unmapped table columns are never fetched.
+  * One DMA a block and pool: a block `[Hkv, bs, D]` is contiguous in
+    the pool's layout (32 KB at Mistral's widths), so one descriptor
+    moves all its heads; it lands head-major in a VMEM scratch
+    `[Hg, G * bs, D]`, where each head's keys of the whole group are
+    one contiguous operand. The DMAs go out `G` blocks (a group) at a
+    time into one of two buffers, and the next group (the slot's next,
+    or the NEXT step's first) is in flight while this one is
+    multiplied. Which buffer a step starts in rides an SMEM scratch
+    across steps, so the grid is "arbitrary" (sequential).
+  * Every head of a fetched group is consumed from VMEM: the slot's
+    regrouped query window `[Hg, T * rep, D]` rides in whole, and an
+    online softmax carries `m`, `l` (lane-replicated) and the
+    accumulator per head across groups.
+  * `_plan` chooses `Hg` and `G` from the call's static shape under a
+    VMEM budget: prompt-length windows get fewer heads a step and
+    smaller groups; they compile and are right, but the model sends
+    them down the gather path (`select_paged_attn_impl`).
 
-Numerics: matmuls run fp32-accumulated (`preferred_element_type`);
-softmax statistics and the output accumulator are fp32, matching
-`_grouped_cache_attention`'s fp32 einsum math. The online softmax
+Masking contract, identical to the gather path: kv position `p`
+attends iff `p <= base[b] + row // rep` (per-row causal frontier over
+the filled prefix). Positions past the frontier inside the last live
+block, and whatever a buffer holds beyond the live blocks of a short
+group, get weight exactly 0 (finite NEG_INF); both buffers are zeroed
+once, before the first DMA, so nothing uninitialised is ever
+multiplied. The null block 0 is fetched only where a table names
+it at or before the frontier, which no live slot's table does; an
+inactive lane (an all-null table, whatever length its last occupant
+left in `base`) walks one block of it and its row is ignored by the
+caller.
+
+Numerics: operands are cast to float32 in VMEM, products accumulate in
+float32, softmax statistics and the accumulator are float32, matching
+`_grouped_cache_attention`'s float32 einsums. The online softmax
 reorders the reduction, so outputs are NOT bit-identical to the
-one-shot softmax of the gather path. Model-level bounds vs the gather
+gather path's one-shot softmax. Model-level bounds vs the gather
 oracle: fp32 params+cache <= 2e-5 abs/rel on the 2-layer d=64 test
-model (asserted in tests/test_pallas_kernels.py; ~1e-7 at kernel
-level). With bf16 compute and a bf16 cache no fixed bound carries
-across sizes: on a v5e at Llama-2-7B widths and 16 layers the two
-paths' logits differ by up to 0.25 (std 1.28) while each sits the same
-5.2 % RMS from an fp32 reference forward (chip_smoke.py, PERF.md PR 21)
-— that is bf16 rounding carried through the depth, and greedy token
-streams of the two paths part at near-ties. chip_smoke.py therefore
-holds each path to the reference, not to the other. Masked logits use
-the shared finite NEG_INF — `-inf` would produce NaN via
-`exp(-inf - -inf)` in the rescale when a row's first visited block is
-fully masked.
+model (tests/test_pallas_kernels.py; ~1e-7 at kernel level). With bf16
+compute and a bf16 cache no fixed bound carries across sizes: on a v5e
+at 7B widths and 16 layers the two paths' logits differ by up to 0.25
+(std 1.28) while each sits the same 5.2 % RMS from an fp32 reference
+(chip_smoke.py, PERF.md PR 21); greedy streams part at near-ties, so
+each path is held to the reference, not to the other. `-inf` masking
+would make NaN in the rescale (`exp(-inf - -inf)`); NEG_INF is finite.
 
-On the CPU backend the kernel runs in interpret mode (same posture
-as flash_attention.py), so tier-1 exercises the real block walk; on a
+On the CPU backend the kernel runs through the Pallas interpreter,
+DMAs and semaphores included, so tier-1 exercises the real walk; on a
 TPU it is compiled, and any other backend is refused.
-
-TPU compile note: the pools are `[num_blocks, Hkv, block_size, D]`, so
-the pool BlockSpec `(None, None, bs, D)` covers the array's last two
-dims whole (block dim == array dim is legal whatever the (8, 128)
-tiling) and squeezes the physical-block and KV-head axes out of the
-kernel refs. With `Hkv` behind `block_size`, as the pools once were,
-the squeezed head axis was the second-to-last array dim and Mosaic
-refused the block at every Llama width. The softmax statistics are
-carried lane-replicated `[rows, 128]` (a 1-D `(rows,)` scratch has no
-agreed tiling at rows=1), and the query window is padded to a whole
-sublane tile of rows. `tests/test_tpu_compile.py` compiles MHA and GQA
-widths for a described v5e in all three geometries.
+`tests/test_tpu_compile.py` compiles it for a described v5e at the
+benchmark cell's geometry and in all three engine window shapes.
 """
 
 from __future__ import annotations
@@ -99,81 +91,168 @@ from hyperion_tpu.ops.pallas.backend import (
 # Performance-relevant revision, stamped into the decode_attention bench
 # probe rows so offline readers can tell a capture of THIS kernel from a
 # stale one. Bump on any change that moves measured throughput.
-KERNEL_REV = 2
+KERNEL_REV = 3
+
+# The VMEM plan (`_plan`): what a step's resident blocks (query window
+# in and out, double-buffered by the pipeline; accumulator; statistics)
+# may take, what the two pools' double buffers may take, and how large
+# one head's score tile may grow before a group is cut. 8 MB of buffers
+# are groups of 64 blocks at Mistral's widths, the fastest measured on
+# a v5e (PERF.md section 6, PR 27: 0.40-0.44 ms a call at 48 slots half
+# filled; groups of 32 0.48-0.52, of 16 and of 8 0.67-0.68): the DMAs
+# bound the kernel, not the products, and a longer group keeps more of
+# them in flight.
+_STEP_BUDGET = 4 << 20
+_KV_BUDGET = 8 << 20
+_SCORE_BUDGET = 256 << 10
 
 
 def _interpret() -> bool:
     return interpret_on_backend()
 
 
-def _compiler_params():
-    if _interpret():
-        return None
-    # Slot and group programs are independent; the block sweep carries
-    # the online-softmax scratch and must run in order.
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
+def _plan(rows_p: int, Hkv: int, bs: int, D: int, itemsize: int,
+          MB: int) -> tuple[int, int]:
+    """(KV heads a grid step, blocks a DMA group) for a call's static
+    shape. Decode and verify windows take every head in one step and
+    groups of 64 blocks at Mistral's widths; a prompt-length window
+    takes fewer heads and smaller groups so its step stays in VMEM."""
+    per_head = rows_p * D * (4 * itemsize + 4) + 2 * rows_p * LANES * 4
+    Hg = max(h for h in range(1, Hkv + 1)
+             if Hkv % h == 0 and (h == 1 or h * per_head <= _STEP_BUDGET))
+    G = min(MB,
+            _KV_BUDGET // (4 * Hg * bs * D * itemsize),
+            _SCORE_BUDGET // (rows_p * bs * 4))
+    return Hg, max(1, G)
 
 
-def _decode_kernel(bt_ref, base_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, bs, mb, rep, t):
-    """One (slot, group) program; grid step j sweeps the slot's blocks.
+def _walk_kernel(bt_ref, base_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref,
+                 *, bs, mb, rep, t, hg, G):
+    """One (slot, head group) step: walk the slot's live blocks a group
+    at a time.
 
-    q_ref [rows_p, D] is the slot's whole regrouped query window
-    (T * rep rows, padded to a sublane multiple); k_ref/v_ref [bs, D]
-    is physical block `bt_ref[b, j]` of this group's pool, DMA'd in by
-    the index_map. m_ref/l_ref [rows_p, LANES] hold each row's running
-    max and sum replicated across the lanes."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    q_ref/o_ref [hg, rows_p, D]: the slot's regrouped query window of
+    these heads (row r is token r // rep) and its output. k_hbm/v_hbm
+    [NB, Hkv, bs, D]: the pools, in HBM. k_buf/v_buf [2, hg, G*bs, D]:
+    the two group buffers; sems [2 (k, v), 2 (buffer)]. slot_ref [1]
+    (SMEM): the buffer this step's first group was fetched into.
+    m_ref/l_ref [hg, rows_p, LANES], acc_ref [hg, rows_p, D]: float32
+    running max, sum and accumulator per head."""
+    b, c = pl.program_id(0), pl.program_id(1)
+    S, NC = pl.num_programs(0), pl.num_programs(1)
+    D = q_ref.shape[-1]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def span(slot_b):
+        """(first, one past the last) block of a slot's walk. The walk
+        starts at the chain's block 0: every layer here is `full`; a
+        windowed layer would start at its first live block."""
+        hi = (base_ref[slot_b] + t + bs - 1) // bs
+        # a lane the tick masks out keeps its last occupant's length
+        # over an all-null table: one block of it, not a stale chain
+        hi = jnp.where(bt_ref[slot_b, 0] == 0, 1, hi)
+        return 0, jnp.clip(hi, 1, mb)
 
+    def group_copies(slot_b, heads, j0, hi, buf, fn):
+        """`fn` (start or wait) on the DMAs of blocks j0..min(j0+G, hi)
+        of slot `slot_b`'s chain into buffer `buf`."""
+        def one(i, _):
+            phys = bt_ref[slot_b, j0 + i]
+            rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            for kv, (hbm, vm) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                fn(pltpu.make_async_copy(
+                    hbm.at[phys, heads],
+                    vm.at[buf, :, rows, :],
+                    sems.at[kv, buf]))
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(hi - j0, G), one, 0)
+
+    def heads_of(ci):
+        return pl.ds(pl.multiple_of(ci * hg, hg), hg)
+
+    @pl.when((b == 0) & (c == 0))
+    def _first():
+        # nothing uninitialised may meet a zero weight, or the query of
+        # a padded row or of an inactive lane: a slot shorter than a
+        # group leaves the rest of its buffer as it found it
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        lo0, hi0 = span(0)
+        group_copies(0, heads_of(0), lo0, hi0, 0,
+                     lambda cp: cp.start())
+
+    lo, hi = span(b)
+    ng = (hi - lo + G - 1) // G
+    buf0 = slot_ref[0]
+    # the step after this one: the slot's next heads, or the next slot
+    wraps = c + 1 == NC
+    nb = jnp.where(wraps, jnp.minimum(b + 1, S - 1), b)
+    nc = jnp.where(wraps, 0, c + 1)
+    more = jnp.logical_not(wraps & (b + 1 == S))
+    nlo, nhi = span(nb)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     base = base_ref[b]
-    # Skip blocks that start past the deepest query position
-    # base + T - 1 — unmapped (null-block) table entries all live there.
-    relevant = j * bs <= base + (t - 1)
+    scale = 1.0 / np.sqrt(D)
 
-    @pl.when(relevant)
-    def _update():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        scale = 1.0 / np.sqrt(q.shape[-1])
-        s = jax.lax.dot_general(
-            q * scale, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, bs]
-        q_pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // rep
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
-        m_prev = m_ref[...]                                   # [rows, LANES]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+    def group(g, _):
+        buf = (buf0 + g) % 2
+        j0 = lo + g * G
 
-    @pl.when(j == mb - 1)
-    def _done():
-        # l > 0 always: at j == 0, kv position 0 satisfies the mask for
-        # every query row (q_pos = base + t >= 0), so the first visited
-        # block contributes at least one unmasked column per row.
-        # (padded rows, base + row//rep beyond the window, are ordinary
-        # causal rows and sliced off by the caller)
-        o_ref[...] = (acc_ref[...] / l_ref[...][:, :1]).astype(o_ref.dtype)
+        @pl.when(g + 1 < ng)
+        def _next_group():
+            group_copies(b, heads_of(c), j0 + G, hi, 1 - buf,
+                         lambda cp: cp.start())
+
+        @pl.when((g + 1 == ng) & more)
+        def _next_step():
+            group_copies(nb, heads_of(nc), nlo, nhi, 1 - buf,
+                         lambda cp: cp.start())
+
+        group_copies(b, heads_of(c), j0, hi, buf, lambda cp: cp.wait())
+
+        def head(h, _):
+            q = q_ref[h].astype(jnp.float32) * scale        # [rows_p, D]
+            k = k_buf[buf, h].astype(jnp.float32)           # [G*bs, D]
+            v = v_buf[buf, h].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [rows_p, G*bs]
+            q_pos = base + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) // rep
+            kv_pos = j0 * bs + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
+            m_prev = m_ref[h]                               # [rows_p, LANES]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha[:, :1] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = m_new
+            return 0
+
+        jax.lax.fori_loop(0, hg, head, 0, unroll=True)
+        return 0
+
+    jax.lax.fori_loop(0, ng, group, 0)
+    slot_ref[0] = (buf0 + ng) % 2
+    # l > 0 always: the walk starts at block `lo`, whose first position
+    # every query row sees (padded rows, base + row // rep beyond the
+    # window, are ordinary causal rows and sliced off by the caller)
+    o_ref[...] = (acc_ref[...] / l_ref[...][:, :, :1]).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, base):
+def paged_attention(q, k_pool, v_pool, block_tables, base, *,
+                    blocks_per_group: int | None = None):
     """Decode attention straight against the paged KV pools.
 
     Args:
@@ -185,6 +264,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, base):
       block_tables: [B, MB] int32 physical-block chain per slot;
         unmapped tail entries are 0 (the null block).
       base: [B] int32 first logical position of the window per slot.
+      blocks_per_group: blocks a DMA group, in place of the plan's
+        (tests walk several groups of a short table with it).
 
     Returns [B, T, H, D] in q's dtype.
     """
@@ -199,13 +280,30 @@ def paged_attention(q, k_pool, v_pool, block_tables, base):
             f"table/base batch mismatch: q {B}, "
             f"tables {block_tables.shape}, base {base.shape}"
         )
-    rep = H // Hkv
-    bs = k_pool.shape[2]
+    return _paged_attention(
+        q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
+        jnp.asarray(base, jnp.int32),
+        blocks_per_group=blocks_per_group, interpret=_interpret())
+
+
+# A jit of its own: a model calls the kernel once a layer with the same
+# shapes, and under one jit the calls share one trace of the kernel's
+# body and one lowering of it to a Mosaic module. Traced and lowered
+# inline, sixteen layers' calls added 6 s to every start of the server,
+# cached executable or not (PERF.md section 6, PR 27).
+@functools.partial(jax.jit, static_argnames=("blocks_per_group", "interpret"))
+def _paged_attention(q, k_pool, v_pool, block_tables, base, *,
+                     blocks_per_group, interpret):
+    B, T, H, D = q.shape
+    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
     MB = block_tables.shape[1]
+    rep = H // Hkv
     rows = T * rep
     rows_p = -(-rows // SUBLANES) * SUBLANES
-    # [B, T, H, D] -> [B, Hkv, T*rep, D]: one program per KV-head group
-    # sees its whole query group; row r is token r // rep.
+    Hg, G = _plan(rows_p, Hkv, bs, D, k_pool.dtype.itemsize, MB)
+    G = blocks_per_group or G
+    # [B, T, H, D] -> [B, Hkv, T*rep, D]: a step sees each KV head's
+    # whole query group; row r is token r // rep.
     qg = (
         q.reshape(B, T, Hkv, rep, D)
         .transpose(0, 2, 1, 3, 4)
@@ -213,50 +311,49 @@ def paged_attention(q, k_pool, v_pool, block_tables, base):
     )
     if rows_p != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
+    window = pl.BlockSpec(
+        (None, Hg, rows_p, D),
+        lambda b, c, bt_ref, base_ref: (b, c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, MB),
+        grid=(B, Hkv // Hg),
         in_specs=[
-            pl.BlockSpec(
-                (None, None, rows_p, D),
-                lambda b, g, j, bt_ref, base_ref: (b, g, 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, None, bs, D),
-                lambda b, g, j, bt_ref, base_ref: (bt_ref[b, j], g, 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, None, bs, D),
-                lambda b, g, j, bt_ref, base_ref: (bt_ref[b, j], g, 0, 0),
-            ),
+            window,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (None, None, rows_p, D),
-            lambda b, g, j, bt_ref, base_ref: (b, g, 0, 0),
-        ),
+        out_specs=window,
         scratch_shapes=[
-            pltpu.VMEM((rows_p, LANES), jnp.float32),
-            pltpu.VMEM((rows_p, LANES), jnp.float32),
-            pltpu.VMEM((rows_p, D), jnp.float32),
+            pltpu.VMEM((2, Hg, G * bs, D), k_pool.dtype),
+            pltpu.VMEM((2, Hg, G * bs, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((Hg, rows_p, LANES), jnp.float32),
+            pltpu.VMEM((Hg, rows_p, LANES), jnp.float32),
+            pltpu.VMEM((Hg, rows_p, D), jnp.float32),
         ],
     )
     out_shape = jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype)
-    # every table entry's block of K and of V is read once (unmapped
-    # entries read the null block), never the pools whole; two products
-    # of rows x bs x D per block, one exponential per score
+    # The static worst case, every table entry live: a block of K and
+    # of V once per entry, two products of rows x bs x D per block and
+    # head, one exponential per score. What a call moves follows the
+    # slots' lengths (`base`), which no static estimate can see.
     chain = B * MB * Hkv * bs
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, bs=bs, mb=MB, rep=rep, t=T),
+        functools.partial(_walk_kernel, bs=bs, mb=MB, rep=rep, t=T,
+                          hg=Hg, G=G),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        # a step hands the next its first group in flight and the
+        # buffer it lies in: the steps run in order
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
         cost_estimate=cost(
             4 * chain * rows * D, chain * rows,
             qg, out_shape, block_tables, base,
             extra_bytes=2 * chain * D * k_pool.dtype.itemsize),
-    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(base, jnp.int32),
-      qg, k_pool, v_pool)
+    )(block_tables, base, qg, k_pool, v_pool)
     return (
         out[:, :, :rows].reshape(B, Hkv, T, rep, D)
         .transpose(0, 2, 1, 3, 4)

@@ -551,6 +551,14 @@ class Engine:
         self._view = {
             k: min(self._mb, window_view_blocks(w, 1, bs)) if w
             else self._mb for k, w in self._kinds.items()}
+        # how the decode tick reads the pools, "pallas" (in place, the
+        # paged-attention kernel) or "gather": the model's choice,
+        # resolved as the model resolves it for the tick's window. None
+        # for a model without the choice (it calls the gather itself,
+        # by layer kind).
+        self._tick_width = cfg.spec_k + 1 if self._spec else 1
+        self._tick_read = mcfg.paged_attn_for(self._tick_width) \
+            if hasattr(mcfg, "paged_attn_for") else None
         num_blocks = cfg.num_blocks or cfg.slots * self._mb + 1
         if num_blocks < self._mb + 1:
             raise ValueError(
@@ -678,6 +686,9 @@ class Engine:
         self._prefill_tokens = 0  # padded tokens prefilled this step
         # rows of this step's decode tick that sample / that restrict
         self._sampling_rows = self._restricted_rows = 0
+        # blocks the paged-attention kernel walks in this step's decode
+        # tick, a layer; table entries the gather would have copied
+        self._blocks_walked = self._table_entries = 0
         # what this step's decode tick counted on the device (an expert
         # model's picks: `_expert_counters`), fetched with its tokens
         self._tick_counted: dict[str, int] = {}
@@ -1881,10 +1892,9 @@ class Engine:
         # HBM the paged-read strategy copies per decode tick: the
         # gather path materializes every slot's chain (mapped or null;
         # of a windowed kind the slice of it a query can see) into a
-        # contiguous view; the pallas kernel reads the pools in place,
-        # so the copy is zero.
-        impl = getattr(self.model.cfg, "paged_attn_impl", "gather")
-        gather = 0 if impl == "pallas" else int(self.cfg.slots * sum(
+        # contiguous view; the paged-attention kernel reads the pools
+        # in place, so the copy is zero.
+        gather = 0 if self._tick_read == "pallas" else int(self.cfg.slots * sum(
             self._view[k] * bb for k, bb in self._kind_block_bytes.items()))
         return {
             "param_bytes": self._param_bytes,
@@ -1992,7 +2002,12 @@ class Engine:
                 # ran the argmax alone, any restricted row = it sorted
                 # the vocabulary for every row
                 sampling_rows=self._sampling_rows,
-                restricted_rows=self._restricted_rows)
+                restricted_rows=self._restricted_rows,
+                # how much of the table the tick's read touched: the
+                # blocks the kernel's loops visit a layer (0 = the tick
+                # gathered), of the entries a gather copies a layer
+                kv_blocks_walked=self._blocks_walked,
+                kv_table_entries=self._table_entries)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -2004,6 +2019,7 @@ class Engine:
         prof = self.tickprof
         self._prefill_tokens = 0
         self._sampling_rows = self._restricted_rows = 0
+        self._blocks_walked = self._table_entries = 0
         self._tick_counted = {}
 
         if self._governor is not None:
@@ -2162,6 +2178,18 @@ class Engine:
                     self._sampling_rows += 1
                     self._restricted_rows += \
                         req.top_k > 0 or req.top_p < 1.0
+            if self._tick_read:
+                self._table_entries = self.cfg.slots * self._mb
+            if self._tick_read == "pallas":
+                # the kernel's walk, from the lengths it will read: a
+                # live slot's chain up to its window's last position,
+                # one (null) block of a lane the tick masks out
+                self._blocks_walked = sum(
+                    min(self._mb, blocks_for(
+                        self._seqs[s].n_filled + self._tick_width,
+                        self.cfg.block_size))
+                    if req is not None and s not in self._chunking else 1
+                    for s, req in enumerate(self._slots))
             with prof.seg("draft"):
                 drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table

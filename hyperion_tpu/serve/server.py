@@ -461,16 +461,21 @@ def build_parser() -> argparse.ArgumentParser:
                         "prefix-sharing opportunities, larger = smaller "
                         "block tables; need not divide max_len (the "
                         "table rounds up to whole blocks)")
-    p.add_argument("--paged-attn", choices=("gather", "pallas"),
-                   default="gather",
+    p.add_argument("--paged-attn", choices=("auto", "gather", "pallas"),
+                   default="auto",
                    help="paged-cache read strategy: 'gather' copies "
-                        "each slot's whole block chain into a "
-                        "contiguous view every tick; 'pallas' walks "
-                        "the block table in-kernel and reads the KV "
-                        "pools in place (ops/pallas/paged_attention, "
-                        "interpret-mode off-TPU). Streams stay "
-                        "deterministic; memory ledger shows the saved "
-                        "copy as kv_gather_bytes_per_tick=0")
+                        "every column of each slot's block table into "
+                        "a contiguous view on every call; 'pallas' "
+                        "walks each slot's live blocks in-kernel and "
+                        "reads the KV pools in place "
+                        "(ops/pallas/paged_attention; through the "
+                        "interpreter off a TPU); 'auto' chooses per "
+                        "call: the kernel for the decode tick's and "
+                        "the verify window's few tokens on a TPU, the "
+                        "gather for prompt-length windows and off a "
+                        "TPU. Streams stay deterministic; the memory "
+                        "ledger shows the saved copy as "
+                        "kv_gather_bytes_per_tick=0")
     p.add_argument("--num-blocks", type=int, default=0,
                    help="KV pool size in blocks incl. the null block "
                         "(0 = auto: slots x ceil(max_len/block_size) + 1, "
@@ -780,7 +785,7 @@ def main(argv=None) -> int:
         tracer.close()
         return 2
 
-    if args.paged_attn != "gather":
+    if args.paged_attn != model.cfg.paged_attn_impl:
         # same architecture + params, different paged-read strategy —
         # a config-only swap, so every engine jit keeps its signature
         import dataclasses as _dc

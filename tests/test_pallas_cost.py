@@ -156,6 +156,9 @@ def test_paged_attention_reads_chains_not_pools(Tw):
             q, k, v, tables, base), q, pool, pool)
     rows = Tw * Hq // Hkv                       # a KV head's query group
     rows_p = -(-rows // SUBLANES) * SUBLANES
+    # the static worst case, every table entry live: the walk stops at
+    # each slot's frontier, which is runtime data no estimate can see
+    # (here `base` is 0 and the call would move one block a slot)
     chain = Bs * MB * Hkv * bs                  # key rows the tables name
     assert est.flops == 4 * chain * rows * Dh
     assert est.transcendentals == chain * rows

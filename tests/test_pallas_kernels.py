@@ -508,6 +508,80 @@ class TestPagedAttention:
         np.testing.assert_array_equal(np.asarray(clean),
                                       np.asarray(poisoned))
 
+    # The walk's edges at GQA rep 4, groups of G = 4 blocks over a table
+    # of 12 (block size 4): `base` of each of three rows, T = 1. A row's
+    # live blocks are ceil((base + 1) / 4); None is an inactive lane (an
+    # all-null table, whatever length its last occupant left behind).
+    WALK_EDGES = {
+        "ends_inside_a_group": (21, 5, 30),        # 6, 2, 8 blocks
+        "ends_on_a_group_edge": (15, 31, 7),       # 4, 8, 2: whole groups
+        "one_position": (0, 0, 0),                 # 1 block, 1 key
+        "fills_the_table": (47, 47, 47),           # 12 blocks, 3 groups
+        "inactive_lane_base_0": (None, 17, None),
+        "inactive_lane_stale_base": (None, 9, None),
+        "fewer_blocks_than_a_group": (8, 3, 11),   # 3, 1, 3 blocks
+        "group_edge_plus_one_block": (16, 32, 33),  # 5, 9, 9 blocks
+    }
+
+    def _edge_geometry(self, bases, stale=0, T=1, H=8, Hkv=2, D=16,
+                       bs=4, MB=12, seed=7):
+        B = len(bases)
+        NB = B * MB + 1
+        ks = jax.random.split(jax.random.key(seed), 3)
+        q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
+        kp = jax.random.normal(ks[1], (NB, Hkv, bs, D), jnp.float32)
+        vp = jax.random.normal(ks[2], (NB, Hkv, bs, D), jnp.float32)
+        bt = np.zeros((B, MB), np.int32)
+        base = np.zeros((B,), np.int32)
+        for b, n in enumerate(bases):
+            if n is None:
+                base[b] = stale
+                continue
+            base[b] = n
+            live = (n + T + bs - 1) // bs
+            bt[b, :live] = 1 + b * MB + np.arange(live)
+        return q, kp, vp, jnp.asarray(bt), jnp.asarray(base)
+
+    @pytest.mark.parametrize("edge", sorted(WALK_EDGES))
+    def test_walk_edges(self, edge):
+        from hyperion_tpu.ops.pallas.paged_attention import paged_attention
+
+        bases = self.WALK_EDGES[edge]
+        q, kp, vp, bt, base = self._edge_geometry(
+            bases, stale=29 if "stale" in edge else 0)
+        out = np.asarray(paged_attention(q, kp, vp, bt, base,
+                                         blocks_per_group=4))
+        ref = np.asarray(self._ref(q, kp, vp, bt, base))
+        live = [b for b, n in enumerate(bases) if n is not None]
+        np.testing.assert_allclose(out[live], ref[live],
+                                   atol=2e-5, rtol=2e-5)
+        # an inactive lane's row is the caller's to ignore, but it is
+        # a number: nothing uninitialised was multiplied
+        assert np.isfinite(out).all()
+
+    def test_garbage_past_the_frontier_never_leaks(self):
+        """Garbage in the null block AND in the mapped positions past
+        each row's frontier inside its last live block (a previous
+        occupant's keys): outputs BIT-identical to clean pools, over
+        several groups and with a short last group."""
+        from hyperion_tpu.ops.pallas.paged_attention import paged_attention
+
+        bases, bs = (21, 5, 30), 4
+        q, kp, vp, bt, base = self._edge_geometry(bases)
+        clean = paged_attention(q, kp, vp, bt, base, blocks_per_group=4)
+        kd, vd = np.array(kp), np.array(vp)
+        kd[0], vd[0] = 1e4, -1e4
+        poisoned = 0
+        for b, n in enumerate(bases):
+            last = int(bt[b, n // bs])
+            kd[last, :, n % bs + 1:] = 1e4
+            vd[last, :, n % bs + 1:] = -1e4
+            poisoned += bs - 1 - n % bs
+        assert poisoned > 0
+        dirty = paged_attention(q, jnp.asarray(kd), jnp.asarray(vd), bt,
+                                base, blocks_per_group=4)
+        np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+
     def test_model_level_matches_gather(self):
         """Full Llama tiny (GQA rep 2) through all three engine window
         shapes, caches threaded forward per impl: chunked prefill

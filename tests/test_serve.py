@@ -245,9 +245,120 @@ class TestPagedAttnPallas:
     def test_gather_ledger_reports_copy_bytes(self, llama):
         eng = _engine(llama, slots=2, max_len=32, block_size=8)
         led = eng.memory_ledger()
-        # slots x blocks-per-table x block bytes, and strictly positive
+        # slots x blocks-per-table x block bytes, and strictly positive:
+        # the default `auto` resolves to the gather off a TPU
+        assert llama[0].cfg.paged_attn_impl == "auto"
         assert led["kv_gather_bytes_per_tick"] == \
             2 * eng._mb * eng._block_bytes > 0
+
+    @pytest.mark.parametrize("window, rep, backend, want", [
+        (1, 4, "tpu", "pallas"),       # the decode tick [S, 1]
+        (4, 4, "tpu", "pallas"),       # a verify window [S, k+1]
+        (5, 1, "tpu", "pallas"),       # the same without GQA
+        (8, 4, "tpu", "gather"),       # the smallest prefill bucket
+        (512, 4, "tpu", "gather"),     # a chunk [1, 512]
+        (2048, 4, "tpu", "gather"),    # the largest prefill bucket
+        (1, 4, "cpu", "gather"),       # the interpreter is no read path
+        (4, 4, "cpu", "gather"),
+        (2048, 4, "cpu", "gather"),
+    ])
+    def test_auto_selects_from_shape_and_backend(
+            self, window, rep, backend, want):
+        from hyperion_tpu.models.llama import (
+            PAGED_KERNEL_MAX_ROWS,
+            select_paged_attn_impl,
+        )
+
+        assert select_paged_attn_impl(window, rep, backend) == want
+        assert (window * rep <= PAGED_KERNEL_MAX_ROWS) == \
+            (select_paged_attn_impl(window, rep, "tpu") == "pallas")
+
+    def test_auto_lowers_the_gather_programs_on_cpu(self, llama):
+        """Off a TPU `auto` is the gather: the tick and the prefill the
+        engine lowers are the programs `gather` lowers, text for text
+        (tier-1's programs and their time are what they were)."""
+        import dataclasses
+
+        model, variables = llama
+        texts = {}
+        for impl in ("auto", "gather"):
+            m = Llama(dataclasses.replace(model.cfg, paged_attn_impl=impl))
+            eng = Engine(m, variables, EngineConfig(
+                slots=2, max_len=32, eos_id=None, block_size=8))
+            assert eng._tick_read == "gather"
+            tick = eng._tick_jit.lower(
+                m, None, eng.cfg.pad_id, variables, eng._cache,
+                eng._state, *eng._tables_on_device()).as_text()
+            prefill = eng._prefill_jit.lower(
+                m, None, variables, eng._cache, eng._state,
+                jnp.zeros((1, 8), jnp.int32), eng._rows_on_device(0),
+                jnp.int32(0), jnp.int32(0), jnp.int32(5),
+                jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
+                jnp.int32(4), jax.random.key(0)).as_text()
+            texts[impl] = (tick, prefill)
+        assert texts["auto"] == texts["gather"]
+        assert "pallas" not in texts["auto"][0]
+
+    def test_tick_record_counts_the_blocks_walked(self, llama, tmp_path):
+        """`kv_blocks_walked`: what the kernel's loops visit in the
+        step's decode tick, a layer, from the slots' own lengths (a
+        live slot's chain up to the position it writes, one null block
+        of a masked lane), beside the entries a gather copies; 0 when
+        the tick gathered. `obs doctor` says it in words."""
+        import dataclasses
+
+        from hyperion_tpu.obs import doctor
+
+        model, variables = llama
+        pmodel = Llama(dataclasses.replace(
+            model.cfg, paged_attn_impl="pallas"))
+        bs = 8
+        eng = Engine(pmodel, variables,
+                     EngineConfig(slots=3, max_len=32, eos_id=None,
+                                  block_size=bs))
+        eng.warmup([8])
+        for i, n in enumerate((5, 9)):      # two of three slots live
+            eng.submit(Request(
+                prompt_ids=np.arange(1, n + 1, dtype=np.int32),
+                max_new_tokens=4 + 6 * i, id=f"w{i}"))
+        ticks = 0
+        while not eng.idle:
+            eng.step()
+            rec = eng.tickprof.tail(1)[0]
+            c = rec["c"]
+            if "device" not in rec["s"]:
+                assert c["kv_blocks_walked"] == c["kv_table_entries"] == 0
+                continue
+            ticks += 1
+            assert c["kv_table_entries"] == 3 * eng._mb
+            # after the tick a slot it advanced holds one position
+            # more: the one the walk ended on. A request that finished
+            # in this step has left its slot by now: count by tokens
+            held = [len(r.prompt_ids) + len(r.tokens) - 1
+                    for r in eng._slots if r is not None]
+            walked_live = sum(-(-n // bs) for n in held)
+            assert c["kv_blocks_walked"] >= walked_live + (3 - len(held))
+            assert c["kv_blocks_walked"] <= walked_live + 3 * (32 // bs)
+        assert ticks >= 9
+        snap = eng.tickprof.snapshot()
+        n, m = (snap["counters"]["kv_blocks_walked"],
+                snap["counters"]["kv_table_entries"])
+        assert 0 < n < m == ticks * 3 * eng._mb
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        assert f"read in place: {n} of {m} table entries" in row
+        # the gather path walks nothing and says so
+        geng = _engine(llama, slots=2, max_len=32, block_size=bs)
+        geng.warmup([8])
+        geng.submit(Request(prompt_ids=np.arange(1, 6, dtype=np.int32),
+                            max_new_tokens=3, id="g"))
+        _drain(geng)
+        gc = geng.tickprof.snapshot()["counters"]
+        assert gc["kv_blocks_walked"] == 0 < gc["kv_table_entries"]
 
 
 # ------------------------------------------------- paged KV cache
@@ -2635,10 +2746,11 @@ class TestIntrospection:
         assert "device/fetch" in snap["children"]
         # the exposition and the flight record carry both
         assert set(eng.exposition()["tickprof"]["counters"]) == {
-            "kv_tokens", "prefill_tokens"}
+            "kv_tokens", "prefill_tokens", "kv_blocks_walked",
+            "kv_table_entries"}
         assert set(eng._flight_payload()["ticks"][-1]["c"]) == {
             "kv_tokens", "prefill_tokens", "sampling_rows",
-            "restricted_rows"}
+            "restricted_rows", "kv_blocks_walked", "kv_table_entries"}
 
     def test_tick_counters_follow_the_slots(self, llama):
         """`kv_tokens` is host bookkeeping of what the live slots hold in

@@ -275,6 +275,50 @@ class TestCompileCache:
         assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
         assert dict(os.environ) == before   # in-process only
 
+    def test_a_kernel_program_does_not_name_its_checkout(self, tmp_path):
+        """A program that holds a Pallas kernel carries the kernel's
+        module, source locations and all, inside its custom call, where
+        the cache's key hashes it. After `place_compile_cache()` the
+        text lowered for a TPU from two checkout directories is the
+        same text; without it the directory is in there (PR 21's
+        finding, which PR 27's tick would have paid in `setup_s`)."""
+        import shutil
+        import subprocess
+        import sys
+
+        repo = Path(__file__).resolve().parents[1]
+        moved = tmp_path / "elsewhere"
+        shutil.copytree(
+            repo / "hyperion_tpu", moved / "hyperion_tpu",
+            ignore=shutil.ignore_patterns("__pycache__", "_build", "*.so"))
+        script = (
+            "import hashlib, sys\n"
+            "import jax, jax.numpy as jnp\n"
+            "from hyperion_tpu.utils.compile_cache import "
+            "place_compile_cache\n"
+            "if sys.argv[1] == 'place': place_compile_cache()\n"
+            "import hyperion_tpu.ops.pallas.paged_attention as pm\n"
+            "pm._interpret = lambda: False\n"
+            "S = jax.ShapeDtypeStruct\n"
+            "pool = S((64, 8, 16, 128), jnp.bfloat16)\n"
+            "low = jax.jit(pm.paged_attention).trace(\n"
+            "    S((4, 1, 32, 128), jnp.bfloat16), pool, pool,\n"
+            "    S((4, 8), jnp.int32), S((4,), jnp.int32)\n"
+            ").lower(lowering_platforms=('tpu',))\n"
+            "print(hashlib.sha256(low.as_text().encode()).hexdigest())\n")
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+
+        def lowered(root, how):
+            env["PYTHONPATH"] = str(root)
+            return subprocess.run(
+                [sys.executable, "-c", script, how], cwd=root, env=env,
+                check=True, capture_output=True, text=True,
+                timeout=120).stdout.split()[-1]
+
+        assert lowered(repo, "place") == lowered(moved, "place")
+        assert lowered(repo, "bare") != lowered(moved, "bare")
+
     @pytest.mark.parametrize("name", [
         "--compile-cache", "HYPERION_COMPILE_CACHE"])
     def test_the_old_names_are_gone(self, name):

@@ -113,6 +113,26 @@ class TestPagedAttentionCompiles:
         )
 
 
+    @pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify"])
+    def test_benchmark_cell_geometry(self, mosaic, one_chip, window):
+        """`mistral7b.chat-saturated`'s tick: 48 slots, 32 heads on 8 KV
+        heads of 128, 16-token blocks, 128 table columns, a pool of
+        6144 blocks; and a verify window of 4 over the same slots. The
+        plan takes every KV head in one step."""
+        S_, H, Hkv, D, bs, MB, NB = 48, 32, 8, 128, 16, 128, 6144
+        rows_p = -(-window * H // Hkv // 8) * 8
+        assert paged_mod._plan(rows_p, Hkv, bs, D, 2, MB) == (Hkv, 64)
+        pool = S((NB, Hkv, bs, D), BF16)
+        text = _compile(
+            paged_mod.paged_attention, one_chip,
+            S((S_, window, H, D), BF16), pool, pool,
+            S((S_, MB), jnp.int32), S((S_,), jnp.int32),
+        )
+        # the pools are read where they lie: no operand of the kernel
+        # is a gathered or re-laid-out copy of them
+        assert "gather" not in text
+
+
 class TestFlashAttentionCompiles:
     @pytest.mark.parametrize("shape", [
         (8, 1024, 12, 64),     # the reference LM's heads at seq 1024
