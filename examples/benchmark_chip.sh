@@ -1,6 +1,8 @@
 #!/usr/bin/env bash
-# Reproduce the perf story on your chip: hardware sweep, model
-# baselines, compile tiers, decode throughput, headline JSON line.
+# The exploration command lines on your chip: hardware sweep, model
+# baselines, compile tiers, decode throughput. The benchmark itself is
+# BENCHMARK.json + `python3 benchmarks/run.py --workload <cell> --seed
+# <n> --seconds 30 --trace <0|1>` (benchmarks/README.md).
 #
 #   examples/benchmark_chip.sh [outdir]
 #
@@ -17,6 +19,5 @@ python -m hyperion_tpu.bench.baseline --scaling \
   --precisions float32 bfloat16 --out "$OUT/baseline"
 python -m hyperion_tpu.bench.compile_bench --train-step --out "$OUT/compilation"
 python -m hyperion_tpu.bench.decode_bench --out "$OUT/decode"
-python bench.py
 
 python scripts/compare_to_reference.py --root "$OUT"

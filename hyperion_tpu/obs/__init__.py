@@ -2,7 +2,7 @@
 
 The reference earned its results by measuring everything (per-phase
 fwd/bwd/opt time, peak memory, throughput — SURVEY §5); this subsystem
-is that discipline made continuous: every entry point (train, bench,
+is that discipline made continuous: every entry point (train, serve,
 infer) streams step-level spans and per-epoch metric snapshots to one
 append-only JSONL file, and `hyperion obs summarize <telemetry.jsonl>`
 turns any run's stream into a markdown report (p50/p99 step time, MFU,
@@ -34,8 +34,7 @@ Consumer/health half (PR 2 — the stream diagnosing its own runs):
                   hung/stalled/diverged) from telemetry + heartbeat,
                   with evidence.
   * `diff`      — `obs diff <a> <b>`: percent-delta comparison of two
-                  run summaries with a regression threshold, plus
-                  `--history` trajectory tables over e.g. BENCH_r*.json.
+                  run summaries with a regression threshold.
   * `timeline`  — `obs trace <dir>`: per-request waterfalls
                   reconstructed from the serve path's lifecycle events,
                   Chrome trace-event/Perfetto export, worst-k exemplar

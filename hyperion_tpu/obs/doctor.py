@@ -13,9 +13,8 @@ Verdicts, in evidence order (first match wins):
   diverged  — fatal `health` events (non-finite loss/grads) or a
               health-abort in the stream
   failed    — the run said goodbye while REPORTING failure (a terminal
-              event carrying failed=true / an error attr — bench.py's
-              failed publish before it exits non-zero): completed, but
-              not healthy
+              event carrying failed=true / an error attr): completed,
+              but not healthy
   healthy   — a terminal lifecycle event landed (train_end /
               generate_done / publish); the run said goodbye
   crashed   — no terminal event AND the stream ends mid-write (the
@@ -408,9 +407,8 @@ def diagnose(
             + ("; run aborted by health policy" if aborted else "")
         )
     elif any(e.get("failed") or e.get("error") for e in terminal):
-        # the run completed its lifecycle but REPORTED failure (e.g.
-        # bench.py's publish with failed=true) — a reported failure
-        # must not read as healthy
+        # the run completed its lifecycle but REPORTED failure — a
+        # reported failure must not read as healthy
         bad = [e for e in terminal if e.get("failed") or e.get("error")][-1]
         verdict = "failed"
         reason = (f"terminal event {bad.get('name')!r} reported failure"

@@ -3,7 +3,7 @@
 The failure mode it exists for is runs that die *silently*: a hung
 backend init looks exactly like a slow compile from the outside, and a
 watcher's only recourse is killing and re-running stages on a timer. The heartbeat closes that gap: every
-entry point (trainers, bench.py, the generation CLI) rewrites a small
+entry point (trainers, the server, the generation CLI) rewrites a small
 `heartbeat.json` next to its telemetry stream — run id, pid, process
 index, last step, phase, monotonic + wall timestamps — so an external
 reader can distinguish

@@ -1,20 +1,19 @@
 """Compile ledger — the recompile-free invariant as a RUNTIME signal.
 
-Tier-1 asserts `compile_stats()` stays flat after warmup; production
-had no equivalent until now — a shape that slipped past the bucket
-ladder would retrace silently, and the only symptom would be a
-latency cliff nobody could attribute. The ledger closes that gap:
+Tier-1 asserts `compile_stats()` stays flat after warmup; in
+production a shape that slipped past the bucket ladder would retrace
+silently, and the only symptom would be a latency cliff nobody could
+attribute. The ledger makes it a signal:
 
   * `record_warmup()` captures the one-shot warmup story — per-
-    executable compile wall-time and (opt-in) `cost_analysis()`
-    FLOPs/bytes — which the engine emits as a `compile_ledger` event.
+    executable compile wall-time — which the engine emits as a
+    `compile_ledger` event.
   * `set_baseline()` pins the post-warmup executable counts.
   * `check()` runs every tick on the host ints `compile_stats()`
     already returns (4 dict reads, no device interaction): any growth
     returns the named executables so the engine can raise the
     `serve_recompiles` counter, a `recompile_after_warmup` event with
-    churn context, and a flight-recorder note — the `obs diff` gate
-    pins the counter at zero.
+    churn context, and a flight-recorder note.
 
 Caveat, documented rather than papered over: the jit caches are
 process-wide (`engine._shared_jits`), so a SECOND engine warming new
@@ -44,15 +43,12 @@ class CompileLedger:
         return dict(self._last_seen)
 
     def record_warmup(self, stats: dict, *, compile_s: dict | None = None,
-                      costs: dict | None = None,
                       total_s: float | None = None) -> dict:
         """One-shot warmup record: final counts + per-executable wall
-        seconds + optional AOT cost analysis. Returns the event-ready
-        dict (flat keys, JSON-safe)."""
+        seconds. Returns the event-ready dict (flat keys, JSON-safe)."""
         self.warmup = {
             "stats": dict(stats),
             "compile_s": dict(compile_s or {}),
-            "costs": dict(costs or {}),
             "total_s": total_s,
         }
         return self.warmup

@@ -290,9 +290,8 @@ def observe_step(
     """One step's duration (+ what it processed) into the step-time
     histogram/EMA and the work counters.
 
-    CAVEAT (the same one `bench.py` is built around): under async
-    dispatch a per-step host duration is dispatch latency, not device
-    time — so this feeds the histogram and counters but NOT the
+    CAVEAT: under async dispatch a per-step host duration is dispatch
+    latency, not device time — so this feeds the histogram and counters but NOT the
     throughput gauges. Throughput comes from `observe_throughput` with
     a FENCED duration (the trainers' end-of-epoch host_fence); callers
     whose per-step duration is already fenced (CPU test mesh, the
@@ -368,31 +367,6 @@ def compiled_flops(jitted, *args, **kwargs) -> float | None:
             ca = ca[0] if ca else {}
         flops = ca.get("flops") if hasattr(ca, "get") else None
         return float(flops) if flops and flops > 0 else None
-    except Exception:  # noqa: BLE001 — telemetry must never kill a run
-        return None
-
-
-def compiled_cost(jitted, *args, **kwargs) -> dict | None:
-    """FLOPs AND bytes accessed of one execution, same machinery as
-    `compiled_flops` but returning every positive numeric the backend's
-    `cost_analysis()` exposes (keys vary by backend/version: "flops",
-    "bytes accessed", ...). Keys are slug-cased for JSON friendliness;
-    None when the backend offers no analysis."""
-    try:
-        ca = jitted.lower(*args, **kwargs).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if not hasattr(ca, "items"):
-            return None
-        out = {}
-        for k, v in ca.items():
-            try:
-                v = float(v)
-            except (TypeError, ValueError):
-                continue
-            if v > 0 and ("flops" in k or "bytes" in k):
-                out[k.replace(" ", "_").replace("{", "").replace("}", "")] = v
-        return out or None
     except Exception:  # noqa: BLE001 — telemetry must never kill a run
         return None
 

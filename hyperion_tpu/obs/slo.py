@@ -27,7 +27,7 @@ unit-testable without an engine; the engine/router loops call
 `evaluate()` (internally rate-limited) and hand the transitions to
 `publish()`, which emits the standard `alert_raised`/`alert_cleared`
 telemetry events and bumps the `*_alerts_raised`/`*_alerts_cleared`
-counters `obs doctor`, `obs diff`, and the bench serving row read.
+counters `obs doctor` and the load reports read.
 """
 
 from __future__ import annotations
@@ -225,7 +225,7 @@ def publish(transitions: list[dict], tracer, registry, *,
     `alert_raised`/`alert_cleared` event each (eagerly flushed, like
     every event) plus the `{prefix}_alerts_raised`/`_cleared` counters
     and the `{prefix}_alerts_active` gauge the snapshot consumers
-    (doctor evidence, diff gate, bench rows) read back. `active` (the
+    (doctor evidence, load reports) read back. `active` (the
     monitor's post-transition active count) refreshes the gauge."""
     if active is not None:
         registry.gauge(f"{prefix}_alerts_active").set(float(active))

@@ -1,11 +1,12 @@
 """Host-tick profiler + crash flight recorder — where a tick's host
 time goes, and what the last ticks looked like when a process died.
 
-The engine's recompile-free / zero-device-sync invariants make the
-DEVICE side of a tick boring by construction; what actually moves
-tokens/s run to run is the HOST side — queue pops, draft building,
-block-table uploads, the accept loop, journal fsyncs, client sink
-writes, SLO evaluation. This module is the one way to time them:
+On the chip most of a tick is the DEVICE's (PERF.md section 5 has the
+newest breakdown), and the profiler's trace says where that goes. The
+HOST side — queue pops, draft building, block-table uploads,
+the accept loop, journal fsyncs, client sink writes, SLO evaluation —
+is where a slow disk, a slow client or a long queue shows, and this
+module is the one way to time it:
 
   * `TickProfiler` — a bounded ring of per-tick segment records. The
     engine runs a step inside `with prof.tick(n):` and each stretch of

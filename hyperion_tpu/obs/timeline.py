@@ -318,7 +318,7 @@ def dominant_of(components: dict, other: float) -> str | None:
     """THE definition of "dominant phase": argmax over the named
     components, demoted to "other" when the unattributed remainder
     outweighs every one of them. Shared by `_cohort_row` and by
-    loadgen's bench `dominant_phase_p99`, so the bench serving row and
+    loadgen's `dominant_phase_p99`, so a load report and
     `obs trace`/`obs doctor` can never name different culprits for the
     same run."""
     if not components:
@@ -333,7 +333,7 @@ def cohort_dominant(values_s: list, phases_s: list,
     entries whose value is at-or-beyond the percentile, total their
     phases, and apply `dominant_of`. `values_s[i]` and `phases_s[i]`
     (a `{phase: seconds}` dict) describe the same request. This is the
-    cohort rule `attribution()` uses, exported so loadgen's bench
+    cohort rule `attribution()` uses, exported so loadgen's
     `dominant_phase_p99` runs the identical math on its live requests."""
     if not values_s:
         return None

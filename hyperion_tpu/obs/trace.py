@@ -140,9 +140,9 @@ class Tracer:
         if proc is None:
             # only an ENABLED tracer may pay the dist lookup: the dist
             # module imports jax, and on a multi-host box process_index
-            # can initialize the backend — a null tracer inside e.g.
-            # bench.py's parent driver (which never touches jax by
-            # design) must stay import-free.
+            # can initialize the backend — a null tracer inside a parent
+            # that never touches jax by design (`--supervise`, `hyperion
+            # route`) must stay import-free.
             proc = 0
             if self.enabled:
                 try:
@@ -182,8 +182,7 @@ class Tracer:
         with self._lock:
             self._file().write(line + "\n")
             # events are rare lifecycle marks whose whole value is
-            # surviving a killed process (bench's probe/deadline chain);
-            # flush them eagerly. Hot-loop span records stay buffered.
+            # surviving a killed process; flush them eagerly. Hot-loop span records stay buffered.
             if rec.get("kind") == "event":
                 self._f.flush()
 

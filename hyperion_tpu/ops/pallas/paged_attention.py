@@ -88,11 +88,6 @@ from hyperion_tpu.ops.pallas.backend import (
     interpret_on_backend,
 )
 
-# Performance-relevant revision, stamped into the decode_attention bench
-# probe rows so offline readers can tell a capture of THIS kernel from a
-# stale one. Bump on any change that moves measured throughput.
-KERNEL_REV = 3
-
 # The VMEM plan (`_plan`): what a step's resident blocks (query window
 # in and out, double-buffered by the pipeline; accumulator; statistics)
 # may take, what the two pools' double buffers may take, and how large

@@ -165,9 +165,9 @@ def _single_process() -> bool:
     #   3. Backend not yet initialized and the launch env declares one
     #      process: the rank is 0 by construction. Asking jax here
     #      would *initialize* the backend — and so take the chip, which
-    #      belongs to one process at a time: a `--supervise` parent or
-    #      the bench parent asking for its rank would starve the child
-    #      it is about to start — for an answer that is already known.
+    #      belongs to one process at a time: a `--supervise` parent
+    #      asking for its rank would starve the child it is about to
+    #      start — for an answer that is already known.
     if _INITIALIZED or int(_env_first(_ENV_NUM_PROCESSES) or 1) > 1:
         return False
     # libtpu pod-worker env (set by Cloud TPU on every pod host) is

@@ -433,11 +433,6 @@ class EngineConfig:
     slo_availability: float = 0.0  # windowed completed/(completed+failed) floor
     slo_fast_s: float = 0.0        # fast burn window (0 = obs/slo default 60s)
     slo_slow_s: float = 0.0        # slow burn window (0 = obs/slo default 600s)
-    # ---- introspection (obs/ledger.py, obs/tickprof.py) ----
-    # opt-in AOT cost_analysis at warmup: `lower().compile()` compiles
-    # AGAIN outside the jit cache — real wall time bench pays once per
-    # round but the test suite must not pay hundreds of times
-    ledger_costs: bool = False
 
 
 @dataclasses.dataclass
@@ -814,7 +809,6 @@ class Engine:
             t0 = time.perf_counter()
             self._cache = self._copy_jit(self._cache, zero, zero)
             compile_s["copy"] = round(time.perf_counter() - t0, 4)
-            costs = self._warmup_costs() if self.cfg.ledger_costs else None
             sp.set(buckets=lens)
         self._state = self._init_state()
         self._slots = [None] * self.cfg.slots
@@ -825,28 +819,13 @@ class Engine:
         self._bt_dev = None
         stats = self.compile_stats()
         total_s = round(sp.dur_s or 0.0, 4)
-        self.ledger.record_warmup(stats, compile_s=compile_s, costs=costs,
+        self.ledger.record_warmup(stats, compile_s=compile_s,
                                   total_s=total_s)
         self.ledger.set_baseline(stats)
         self.tracer.event("serve_warmup_done", **stats)
         self.tracer.event("compile_ledger", total_s=total_s,
-                          compile_s=compile_s, costs=costs or {}, **stats)
+                          compile_s=compile_s, **stats)
         return stats
-
-    def _warmup_costs(self) -> dict:
-        """Opt-in AOT `cost_analysis()` of the decode-tick executable —
-        FLOPs/bytes per tick for the ledger. `lower().compile()` builds
-        a SECOND executable outside the jit call cache (doesn't grow
-        `compile_stats()`, but costs real compile wall time), hence the
-        `ledger_costs` gate: bench pays it once per round, tests never."""
-        from hyperion_tpu.obs.registry import compiled_cost
-        live = np.fromiter((r is not None for r in self._slots),
-                           bool, len(self._slots))
-        cost = compiled_cost(
-            self._tick_jit, self.model, self.cfg.eos_id, self.cfg.pad_id,
-            self.variables, self._cache, self._state,
-            self._rows_on_device(), jnp.asarray(live))
-        return {f"tick_{k}": v for k, v in (cost or {}).items()}
 
     def _prefill_call(self, req: Request, slot: int, *, start: int = 0,
                       prompt: np.ndarray | None = None,

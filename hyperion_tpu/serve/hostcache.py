@@ -49,16 +49,6 @@ PREFIX_ROOT_TOKENS = 32
 # heartbeat record stays a single atomic write.
 TOP_ROOTS = 8
 
-# Tier report keys the serving row PROMISES to `obs diff` — the
-# check_diff_gates guard fails tier-1 when any of these is missing
-# from the diff gate table (a promised-but-ungated key is a metric
-# nobody would ever see regress).
-TIER_GATED = (
-    "serve_tier_hit_rate_host",
-    "serve_restore_bytes_per_s",
-    "serve_prefill_tokens_saved",
-)
-
 INDEX_NAME = "index.json"
 CHAINS_NAME = "chains.bin"
 
@@ -280,9 +270,3 @@ class HostBlockStore:
             if self.put(ent["tokens"], payload.copy()):
                 loaded += 1
         return loaded
-
-
-def ungated_tier_keys(diff_metrics: dict) -> list[str]:
-    """Tier keys promised by `TIER_GATED` but absent from the obs diff
-    gate table — `scripts/check_diff_gates.py` fails tier-1 on any."""
-    return sorted(k for k in TIER_GATED if k not in diff_metrics)

@@ -9,10 +9,8 @@ engine: the driver and the serve loop share one thread, alternating
 submit-due-requests with `engine.step()`, so a run is reproducible —
 no wall-clock race decides which tick a request joins.
 
-Used by `bench.py --child-serving` (the `serving` probe riding the
-headline line) and the slow soak test; both report the same keys, so
-`obs diff` tracks serving regressions exactly like the PR-4
-`input_pipeline` probe.
+Used by the serve, router and crash tests and the slow soak test, which
+assert on the report `run_load` returns.
 """
 
 from __future__ import annotations
@@ -34,35 +32,6 @@ from hyperion_tpu.serve.queue import (
     Request,
 )
 
-# THE serving-row vocabulary: every key a `run_load` report carries
-# that `obs diff`'s normalize() may consume. `scripts/check_diff_gates.py`
-# cross-checks the gated metric names against this tuple so a gate can
-# never outlive (or precede) the emitter that feeds it.
-SERVING_REPORT_KEYS = (
-    "requests", "completed", "rejected", "timed_out", "reject_rate",
-    "tokens", "tokens_per_s", "ttft_p50_ms", "ttft_p99_ms",
-    "e2e_p50_ms", "e2e_p99_ms", "elapsed_s", "arrival_rate_hz", "slots",
-    "shared_prefix_tokens", "prefix_hit_rate", "prefill_tokens_saved",
-    "preempted", "cow_copies", "blocks_in_use", "hbm_per_req_mb",
-    "accept_rate", "tokens_per_tick", "spec_drafted", "spec_accepted",
-    "spec_rejected", "shed", "brownout_clamped", "shed_rate",
-    "clamp_rate",
-    # tiered KV cache (PR 20, serve/hostcache.py): where prefix
-    # lookups landed (device radix / host spill tier / miss) and what
-    # the host tier moved — the `rehit` workload's verdict keys
-    "tier_hits_device", "tier_hits_host", "tier_miss",
-    "tier_hit_rate_host", "restore_bytes_per_s", "host_cache_mb",
-    *(f"{p}_p99_ms" for p in PHASES),
-    "dominant_phase_p99", "ttft_p99_windowed_ms", "tpot_p99_windowed_ms",
-    "alerts_raised", "alerts_active", "recompiles",
-    # per-SLO-class isolation keys (PR 14): the `@class` bench
-    # dimension's verdict row — interactive latency must hold while
-    # batch absorbs the sheds
-    *(f"{cls}_{k}" for cls in SLA_CLASSES
-      for k in ("ttft_p99_ms", "tpot_p99_ms", "completed", "shed",
-                "shed_rate")),
-)
-
 
 @dataclasses.dataclass(frozen=True)
 class LoadSpec:
@@ -79,11 +48,11 @@ class LoadSpec:
     # prompt_lens become the per-request TAIL lengths — the workload
     # shape that makes the engine's radix prefix cache earn its keep
     # (the first request prefills the prefix, every later one reuses
-    # its blocks). The bench `serving` probe runs this mode.
+    # its blocks).
     shared_prefix_tokens: int = 0
     # --- SLO-class mix (PR 14) ---
     # > 0: every batch_every-th request is class=batch — the mixed
-    # workload the isolation drill and the bench `@class` dimension run
+    # workload the isolation drill runs
     batch_every: int = 0
     # --- adversarial tenant (PR 14) ---
     # one deterministic hostile tenant rides the base workload:
@@ -108,8 +77,7 @@ class LoadSpec:
     # small device pool; the tail of the workload then re-asks for the
     # original prefix. With --host-cache-mb the re-hit restores from
     # the host spill tier (tier_hits_host > 0, prefill skipped); with
-    # the tier off it is a full re-prefill — the delta `obs diff`
-    # gates. Churn prompts come from their OWN rng (seed + 0x0C0C),
+    # the tier off it is a full re-prefill. Churn prompts come from their OWN rng (seed + 0x0C0C),
     # after the base draws, so enabling churn never shifts the pinned
     # base schedule (same discipline as the adversary shaping).
     rehit_churn: int = 0
@@ -118,8 +86,8 @@ class LoadSpec:
 
 def request_id(seed: int, i: int) -> str:
     """Deterministic, seed-derived request id: the same spec produces
-    the same ids run-to-run, so trace fixtures and bench attribution
-    keys line up across rounds (and across machines)."""
+    the same ids run-to-run, so trace fixtures and attribution keys
+    line up across runs (and across machines)."""
     return f"load_s{seed}_{i:03d}"
 
 
@@ -130,7 +98,7 @@ def build_workload(spec: LoadSpec):
     arrival schedule, prompts, budgets, and seeds on either path. The
     rng draw ORDER is pinned (inter-arrivals, shared prefix, then per
     request: tail length, tail, budget, seed) — reordering it would
-    silently shift every bench serving row across rounds."""
+    silently shift every seeded workload the tests pin."""
     rng = np.random.default_rng(spec.seed)
     inter = rng.exponential(1.0 / spec.rate_hz, spec.n_requests)
     arrivals = np.cumsum(inter)
@@ -264,8 +232,8 @@ def run_load(engine, spec: LoadSpec) -> dict:
 
     # per-phase tail attribution over the completed requests (the same
     # numbers `request_finished` events carry; see obs/timeline.py for
-    # the phase definitions) — p99s ride the bench serving row so
-    # `obs diff` gates WHERE the tail went, not just how long it was
+    # the phase definitions): WHERE the tail went, not just how long
+    # it was
     def _p99_ms(vals) -> float | None:
         vals = [v for v in vals if v is not None]
         return round(percentile(vals, 99), 3) if vals else None
@@ -277,8 +245,8 @@ def run_load(engine, spec: LoadSpec) -> dict:
     # dominant phase with COHORT semantics (the same math as obs
     # trace/doctor: average the requests at-or-beyond the e2e p99) —
     # the independent per-phase p99s above can each come from a
-    # different request, and naming their max would let bench disagree
-    # with the trace tools about the same run
+    # different request, and naming their max would let this report
+    # disagree with the trace tools about the same run
     dominant = cohort_dominant(
         [r.finished_at - r.submitted_at for r in done],
         [r.phases_s() for r in done])
@@ -324,24 +292,20 @@ def run_load(engine, spec: LoadSpec) -> dict:
         "arrival_rate_hz": spec.rate_hz,
         "slots": engine.cfg.slots,
         "shared_prefix_tokens": spec.shared_prefix_tokens,
-        # paged-cache pressure keys (engine metrics roll-up) — these
-        # ride the bench `serving` row so `obs diff` gates cache
-        # regressions exactly like throughput regressions
+        # paged-cache pressure keys (engine metrics roll-up)
         **{k: cache.get(k)
            for k in ("prefix_hit_rate", "prefill_tokens_saved",
                      "preempted", "cow_copies", "blocks_in_use",
                      "hbm_per_req_mb")},
         # tiered KV cache (serve/hostcache.py): lookup tier split and
-        # host-tier motion — the `rehit` workload's verdict keys, gated
-        # by `obs diff` (hit rate higher-is-better, saved tokens delta)
+        # host-tier motion — the `rehit` workload's verdict keys
         **{k: cache.get(k)
            for k in ("tier_hits_device", "tier_hits_host", "tier_miss",
                      "tier_hit_rate_host", "restore_bytes_per_s",
                      "host_cache_mb")},
         # speculative decoding (serve/draft.py): acceptance quality +
-        # effective per-slot advance — `obs diff` gates both as
-        # higher-is-better on spec-enabled rows (accept_rate is None
-        # on a spec-off run, which diff treats as "not measured")
+        # effective per-slot advance (accept_rate is None on a
+        # spec-off run: not measured)
         "accept_rate": (round(cache["accept_rate"], 4)
                         if cache.get("accept_rate") is not None else None),
         "tokens_per_tick": (round(cache["tokens_per_tick"], 4)
@@ -350,8 +314,8 @@ def run_load(engine, spec: LoadSpec) -> dict:
         "spec_drafted": cache.get("spec_drafted", 0),
         "spec_accepted": cache.get("spec_accepted", 0),
         "spec_rejected": cache.get("spec_rejected", 0),
-        # overload brownout (PR 8): shed/clamp events as rates so
-        # `obs diff` gates them across rounds at any request count
+        # overload brownout (PR 8): shed/clamp events as rates, so
+        # they compare at any request count
         "shed": cache.get("shed", 0),
         "brownout_clamped": cache.get("brownout_clamped", 0),
         "shed_rate": round(cache.get("shed", 0) / spec.n_requests, 4)
@@ -363,15 +327,13 @@ def run_load(engine, spec: LoadSpec) -> dict:
         "dominant_phase_p99": dominant,
         # live-plane keys (PR 10): the WINDOWED p99s `obs top` shows —
         # over the engine's last-60s ring, which for a short probe run
-        # is the whole run — and the SLO alert counters, so a bench
-        # round that fired alerts says so on its serving row and
-        # `obs diff` can gate serve_alerts_raised lower-is-better
+        # is the whole run — and the SLO alert counters
         "ttft_p99_windowed_ms": _win_p99(engine, "ttft_ms"),
         "tpot_p99_windowed_ms": _win_p99(engine, "tpot_ms"),
         "alerts_raised": cache.get("alerts_raised", 0),
         "alerts_active": cache.get("alerts_active", 0),
         # compile ledger (obs/ledger.py): post-warmup jit-cache growth
-        # during the run — `obs diff` pins this at zero (ZERO_PINNED)
+        # during the run; the healthy value is exactly 0
         "recompiles": cache.get("recompiles", 0),
     }
 
@@ -399,7 +361,7 @@ def run_load_socket(socket_path: str, spec: LoadSpec, *,
 
     The report carries the client-observable subset of `run_load`'s
     keys (no engine internals — those belong to the server's own
-    telemetry), so `obs diff` reads both shapes."""
+    telemetry)."""
     import threading
 
     from hyperion_tpu.serve.client import ServeClient
@@ -447,7 +409,7 @@ def run_load_socket(socket_path: str, spec: LoadSpec, *,
                         # exactly-once audit off the wire's stream
                         # index: an index below the expected one is a
                         # DUPLICATE delivery (a failover/resume dedup
-                        # bug) — `obs diff` zero-pins the total
+                        # bug): the only healthy total is 0
                         si = rec.get("i")
                         if isinstance(si, int):
                             if si < expected:
@@ -502,7 +464,7 @@ def run_load_socket(socket_path: str, spec: LoadSpec, *,
     # replica-attributed TTFT the done record carried. Everything the
     # router + wire added — placement, WAL, dispatch gap, relay copies
     # — and nothing the engine did. Directly comparable across fleet
-    # sizes, and gated in `obs diff` as serve_router_overhead_p99_ms.
+    # sizes.
     overhead_ms = [
         max(0.0, (r["first_token_at"] - r["submitted_at"]) * 1e3
             - r["replica_ttft_ms"])

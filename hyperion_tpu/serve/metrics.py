@@ -57,9 +57,8 @@ class ServeMetrics:
                      # engine's spec tick): drafted = accepted+rejected
                      "serve_spec_drafted", "serve_spec_accepted",
                      "serve_spec_rejected",
-                     # compile ledger (obs/ledger.py): pinned at zero
-                     # by the obs diff gate — any value > 0 is a broken
-                     # recompile-free invariant
+                     # compile ledger (obs/ledger.py): any value > 0
+                     # is a broken recompile-free invariant
                      "serve_recompiles",
                      # tiered KV (serve/hostcache.py): every radix walk
                      # lands in exactly one tier bucket — host when the
@@ -287,8 +286,7 @@ class ServeMetrics:
         # effective tokens per SLOT-tick (one live slot in one tick):
         # decode emissions over slot-ticks, prefill firsts excluded.
         # The sequential tick's ceiling is exactly 1.0 — anything
-        # above is speculation actually landing, which is why the
-        # bench/diff gate reads this gauge and not raw throughput
+        # above is speculation actually landing
         self._ticks += slot_ticks if slot_ticks is not None \
             else tokens_emitted
         self._tick_tokens += tokens_emitted
@@ -411,7 +409,7 @@ class ServeMetrics:
             "spec_rejected": int(c.get("serve_spec_rejected", 0)),
             "accept_rate": g.get("serve_spec_accept_rate"),
             "tokens_per_tick": g.get("serve_tokens_per_tick"),
-            # compile ledger (obs/ledger.py): the zero-pinned diff gate
+            # compile ledger (obs/ledger.py): healthy at exactly 0
             "recompiles": int(c.get("serve_recompiles", 0)),
         }
 
@@ -424,7 +422,7 @@ class RouterMetrics:
     ejection fire". Unlike ServeMetrics (single engine-thread writer),
     these instruments are hit from MANY relay threads concurrently, so
     every mutation takes the lock — a lost increment here would skew
-    the fairness/scaleup numbers bench reads back from router_end."""
+    the fairness numbers `router_end` carries."""
 
     def __init__(self, registry: MetricsRegistry | None = None):
         import threading
@@ -563,8 +561,7 @@ class RouterMetrics:
     def on_fleet_alerts(self, n_new: int) -> None:
         """`n_new` alert names appeared on replica heartbeats since the
         last monitor sweep (serve/router.py counts the transitions —
-        this is the fleet-wide raise tally bench's serving_scale row
-        reads back from router_end)."""
+        this is the fleet-wide raise tally `router_end` carries)."""
         if n_new:
             with self._lock:
                 self.reg.counter("fleet_alerts_raised").inc(n_new)
@@ -605,12 +602,11 @@ class RouterMetrics:
             "scale_down": int(c.get("router_scale_down", 0)),
             "class_brownouts": int(c.get("class_brownouts_ordered", 0)),
             "steered_now": int(g.get("fleet_steered") or 0),
-            # router crash safety (rides router_end for bench/doctor)
+            # router crash safety (rides router_end for doctor)
             "resumes": int(c.get("route_resumes", 0)),
             "orphans_recovered": int(c.get("route_orphans_recovered", 0)),
             "adopted": int(c.get("route_adopted", 0)),
             # failover-gap tail (ms): 0.0 when no failover fired, so
-            # the bench `serving_scale` row and the diff gate stay live
-            # on healthy runs instead of going missing
+            # the key is present on healthy runs too
             "failover_gap_p99_ms": failover_gap_p99_ms,
         }

@@ -154,7 +154,7 @@ class Request:
         """Per-phase totals keyed by the canonical phase vocabulary
         (`obs/timeline.py:PHASES`). THE field→phase mapping: every
         producer (the `request_finished` event, the phase histograms,
-        loadgen's bench attribution) builds from this one dict, so a
+        loadgen's attribution) builds from this one dict, so a
         new phase is wired in here once or the reporters silently
         disagree."""
         return {

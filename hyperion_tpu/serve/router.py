@@ -1451,9 +1451,8 @@ class Router:
         summary["per_replica_restarts"] = {
             str(r.index): r.restarts for r in self.replicas}
         self.tracer.snapshot(self.metrics.reg)
-        # the full summary rides the terminal event — nested per-replica
-        # dicts included, they are what the bench probe reads back for
-        # its fairness and affinity keys
+        # the full summary rides the terminal event, nested per-replica
+        # dicts included
         self.tracer.event("router_end", **summary)
         self.hb.close(phase="done",
                       dispatched=summary["dispatched"],

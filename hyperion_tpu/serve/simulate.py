@@ -315,9 +315,8 @@ SCENARIOS: dict[str, dict] = {
     },
 }
 
-# Canonical report vocabulary (see report()); bench + obs diff key off
-# this tuple, so adding a key here is a schema change the diff-gate
-# guard (scripts/check_diff_gates.py) will notice.
+# Canonical report vocabulary (see report()): what the `sim_report`
+# event carries and the scenarios' asserts may name.
 REPORT_KEYS = (
     "requests", "completed", "completed_rate",
     "interactive_completed_rate",
@@ -329,23 +328,6 @@ REPORT_KEYS = (
     "steers", "steer_reversals", "ejections", "readmits",
     "scale_up", "scale_down", "dispatched", "redispatched",
 )
-
-# The subset obs diff gates per pinned bench scenario (bench.py
-# fleet_sim probe): key name in diff = sim_<scenario>_<key>, except a
-# key already carrying the scenario prefix collapses (failover's
-# failover_gap_p99_ms gates as sim_failover_gap_p99_ms).
-DIFF_GATED = {
-    "herd": ("shed_rate", "completed_rate", "interactive_ttft_p99_ms",
-             "alerts_raised", "duplicate_tokens"),
-    "failover": ("completed_rate", "interactive_ttft_p99_ms",
-                 "failover_gap_p99_ms", "steer_reversals",
-                 "duplicate_tokens"),
-}
-
-
-def diff_key(scenario: str, key: str) -> str:
-    return (f"sim_{key}" if key.startswith(scenario + "_")
-            else f"sim_{scenario}_{key}")
 
 
 def _merged(scn: dict) -> dict:

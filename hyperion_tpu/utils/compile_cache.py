@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — decided outside.
 
-One rule for every entry point (trainer CLI, `serve`, `bench.py`
-children, `chip_smoke.py`): where `JAX_COMPILATION_CACHE_DIR` is set,
+One rule for every entry point (trainer CLI, `serve`,
+`benchmarks/run.py`, `chip_smoke.py`): where `JAX_COMPILATION_CACHE_DIR` is set,
 JAX reads it itself and the program sets nothing; where it is not, the
 cache goes to `<repo>/.jax_cache` (git-ignored). The path is part of
 the cache's key, so it must not move between runs. Children inherit

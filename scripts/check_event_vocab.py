@@ -20,7 +20,7 @@ build with the file:line of the call site.
 
 Exit 0: every emitted event is consumed. Exit 1: orphans named on
 stderr. Pure source scan — no imports of jax, no devices; tier-1 runs
-this via tests/test_obs_live.py next to check_diff_gates.py.
+this via tests/test_obs_live.py.
 """
 
 from __future__ import annotations

@@ -109,7 +109,7 @@ def compile_table(root: Path) -> None:
         print("(compilation/compilation_benchmark.csv not captured yet)\n")
         return
     # rows: model, variant (op_by_op / jit / jit_pallas), mean_ms, ...
-    # (compile_bench.py writes mean_ms=nan for a failed variant — drop it)
+    # (`bench.compile_bench` writes mean_ms=nan for a failed variant — drop it)
     import math
 
     by_model: dict[str, dict[str, float]] = {}

@@ -10,7 +10,7 @@ c@b chain built a different way; all share the folded-rescale operand
     python scripts/mfu_probe.py [--size 8192] [--k 48]
 
 Variants:
-  scan       lax.scan threading c (the current bench.py/hw_explore shape)
+  scan       lax.scan threading c (the current hw_explore shape)
   unroll     python-unrolled chain inside one jit (no scan machinery,
              XLA sees k literal dots and can software-pipeline across them)
   donate     scan chain, but the jit donates the carry operand so XLA

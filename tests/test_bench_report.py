@@ -179,7 +179,7 @@ class TestCompareToReference:
 
 
 class TestAttentionBench:
-    """Long-seq attention scaling bench (bench/attention_bench.py):
+    """Long-seq attention scaling bench (`bench.attention_bench`):
     row shape, CSV union-fieldnames, and error rows must not kill the
     sweep (an OOM row is the finding, not a crash)."""
 
@@ -295,7 +295,7 @@ class TestTier1DurationGuard:
             "30.00s call     tests/test_router.py::test_drill\n"
             "12.00s setup    tests/test_router.py::test_drill\n"
             "25.00s call     tests/test_serve.py::test_smoke\n"
-            "20.00s call     tests/test_bench.py::test_scale\n"
+            "20.00s call     tests/test_scaling.py::test_scale\n"
             "1.50s teardown  tests/test_serve.py::test_smoke\n"
             "9.00s call     tests/test_obs.py::test_minor\n"
         )
@@ -303,7 +303,7 @@ class TestTier1DurationGuard:
         assert top == [
             (42.0, "tests/test_router.py::test_drill"),
             (26.5, "tests/test_serve.py::test_smoke"),
-            (20.0, "tests/test_bench.py::test_scale"),
+            (20.0, "tests/test_scaling.py::test_scale"),
         ]
         # and main() narrates the share on every run, not just failures
         log = tmp_path / "t1.log"

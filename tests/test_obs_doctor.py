@@ -22,7 +22,6 @@ from hyperion_tpu.obs.trace import Tracer
 from hyperion_tpu.utils.clock import VirtualClock
 
 FIXTURES = Path(__file__).parent / "data" / "telemetry"
-REPO = Path(__file__).resolve().parents[1]
 
 ALL_FIXTURES = ("healthy", "nan", "stalled", "hung", "crashed", "serve",
                 "slo")
@@ -39,8 +38,9 @@ SIM_FIXTURES = ("sim",)
 
 def write_run(path, run: str, step_ms: float, *, steps: int = 8,
               tokens_per_s: float = 4096.0, wall0: float = 1_000.0,
-              terminal: bool = True):
-    """One synthetic healthy-shaped run appended to `path`."""
+              terminal: bool = True, gauges: dict | None = None):
+    """One synthetic healthy-shaped run appended to `path`; `gauges`
+    sets or overrides what the final snapshot carries."""
     clk, wall = VirtualClock(100.0), VirtualClock(wall0)
     t = Tracer(path, run=run, proc=0, clock=clk, wall=wall)
     t.event("train_start", job="language_ddp")
@@ -54,6 +54,8 @@ def write_run(path, run: str, step_ms: float, *, steps: int = 8,
     reg.gauge("tokens_per_s").set(tokens_per_s)
     reg.gauge("mfu").set(0.3)
     reg.gauge("hbm_peak_mb").set(512.0)
+    for name, value in (gauges or {}).items():
+        reg.gauge(name).set(value)
     t.snapshot(reg, step=steps)
     if terminal:
         t.event("train_end", preempted=False)
@@ -173,9 +175,8 @@ class TestDoctorFixtures:
         assert "verdict: healthy" in capsys.readouterr().out
 
     def test_failed_publish_is_not_healthy(self, tmp_path):
-        # a bench.py run whose child failed completes its lifecycle but
-        # publishes failed=true before exiting non-zero — it must not
-        # classify healthy
+        # a run that completes its lifecycle but says failed=true on its
+        # terminal event must not classify healthy
         t = Tracer(tmp_path / "telemetry.jsonl", run="bench_x", proc=0)
         t.event("bench_start", metric="matmul")
         t.event("publish", value=0.0, failed=True, error="--child-matmul timed out")
@@ -704,6 +705,43 @@ class TestRecordContract:
 # ----------------------------------------------------------------- diff
 
 
+# one case per `obs diff` gate: the metric and the shape it arrives in —
+# a telemetry stream, `obs summarize --json` of one, or a trainer
+# `*_summary.json` (the shapes `normalize` documents)
+GATE_CASES = [
+    ("step_time_p50_ms", "stream"),
+    ("step_time_p99_ms", "summarize_json"),
+    ("step_time_mean_ms", "trainer_summary"),
+    ("tokens_per_s", "trainer_summary"),
+    ("samples_per_s", "stream"),
+    ("mfu", "summarize_json"),
+    ("hbm_peak_mb", "trainer_summary"),
+]
+_TRAINER_SUMMARY_KEY = {"step_time_mean_ms": "step_ms",
+                        "tokens_per_s": "tokens_per_s",
+                        "hbm_peak_mb": "peak_hbm_mb"}
+
+
+def _gate_input(shape, where, metric, value, capsys):
+    """One `obs diff` input in `shape` whose `metric` reads `value`."""
+    where.mkdir()
+    if shape == "trainer_summary":
+        path = where / "run_summary.json"
+        path.write_text(json.dumps({_TRAINER_SUMMARY_KEY[metric]: value}))
+        return path
+    stream = where / "telemetry.jsonl"
+    if metric.startswith("step_time_"):
+        write_run(stream, "run", value)
+    else:
+        write_run(stream, "run", 10.0, gauges={metric: value})
+    if shape == "stream":
+        return stream
+    assert report.main(["summarize", str(stream), "--json"]) == 0
+    path = where / "summary.json"
+    path.write_text(capsys.readouterr().out)
+    return path
+
+
 class TestDiff:
     def test_injected_step_time_regression_flagged(self, tmp_path, capsys):
         """The acceptance bar: a >=10%% injected step-time regression
@@ -744,33 +782,7 @@ class TestDiff:
         write_run(b, "run_b", 10.5)
         assert obs_diff.main([str(a), str(b), "--threshold", "0.01"]) == 1
 
-    def test_normalize_bench_line(self):
-        m = obs_diff.normalize({
-            "metric": "matmul_bf16_8192_tflops", "value": 175.75,
-            "vs_baseline": 1.452,
-            "extra": {"lm_step_ms": 61.9, "lm_tokens_per_s": 66150.0},
-        })
-        assert m["headline_tflops"] == 175.75
-        assert m["vs_baseline"] == 1.452
-        assert m["lm_step_ms"] == 61.9
-
-    def test_normalize_input_pipeline_probe(self):
-        """bench.py's input_pipeline row rides the standard bench shape,
-        so `obs diff --history` tracks it across BENCH_r*.json."""
-        m = obs_diff.normalize({
-            "metric": "matmul_bf16_8192_tflops", "value": 100.0,
-            "input_pipeline": {"sync_batches_per_s": 376.6,
-                               "prefetch_batches_per_s": 434.2,
-                               "speedup": 1.15},
-        })
-        assert m["input_sync_batches_per_s"] == 376.6
-        assert m["input_prefetch_batches_per_s"] == 434.2
-        assert obs_diff.METRICS["input_prefetch_batches_per_s"] == "higher"
-
-    def test_normalize_round_wrapper_and_trainer_summary(self):
-        m = obs_diff.normalize({"rc": 0, "parsed": {
-            "metric": "x", "value": 120.0, "vs_baseline": 1.0}})
-        assert m["headline_tflops"] == 120.0
+    def test_normalize_trainer_summary(self):
         m = obs_diff.normalize({"step_ms": 42.0, "tokens_per_s": 1000.0,
                                 "peak_hbm_mb": 13580.0})
         assert m["step_time_mean_ms"] == 42.0
@@ -780,27 +792,34 @@ class TestDiff:
         assert obs_diff.normalize({"tokens_per_s": float("nan"),
                                    "unknown_key": 3}) == {}
 
-    def test_history_over_bench_records(self, capsys):
-        # round records in the driver's wrapper shape; synthetic values
-        # (tests/data/bench_history/), the file name is the time axis
-        rc = obs_diff.main(["--history", str(
-            REPO / "tests" / "data" / "bench_history" / "BENCH_r0*.json")])
-        out = capsys.readouterr().out
-        assert rc == 0
-        for n in range(1, 4):
-            assert f"BENCH_r0{n}.json" in out
-        assert "headline_tflops" in out
-
-    def test_history_no_match_exits_2(self, tmp_path, capsys):
-        assert obs_diff.main(["--history",
-                              str(tmp_path / "none_*.json")]) == 2
-        assert "matched no files" in capsys.readouterr().err
-
     def test_unreadable_input_exits_2(self, tmp_path, capsys):
         good = tmp_path / "a.jsonl"
         write_run(good, "r", 10.0)
         assert obs_diff.main([str(good),
                               str(tmp_path / "missing.json")]) == 2
+
+    @pytest.mark.parametrize("metric,shape", GATE_CASES)
+    def test_gate_flags_only_its_bad_direction(self, tmp_path, capsys,
+                                               metric, shape):
+        """Every gate, fed in a shape a command of this repo writes: a
+        25% move in the metric's bad direction is flagged and flips the
+        exit code, the same move in its good direction is quiet."""
+        base = 0.4 if metric == "mfu" else 100.0
+        lo = _gate_input(shape, tmp_path / "lo", metric, base, capsys)
+        hi = _gate_input(shape, tmp_path / "hi", metric, base * 1.25,
+                         capsys)
+        good, bad = ((hi, lo), (lo, hi)) \
+            if obs_diff.METRICS[metric] == "lower" else ((lo, hi), (hi, lo))
+        assert obs_diff.main([*map(str, bad), "--json"]) == 1
+        assert metric in json.loads(capsys.readouterr().out)["regressions"]
+        assert obs_diff.main([*map(str, good), "--json"]) == 0
+        assert json.loads(capsys.readouterr().out)["regressions"] == []
+
+    def test_every_gate_has_a_producing_shape(self):
+        """The rule at the top of `METRICS`: a gate exists only if a
+        command in this repo emits it. A new gate needs a case above,
+        built in a shape some command writes, or this fails."""
+        assert sorted(m for m, _ in GATE_CASES) == sorted(obs_diff.METRICS)
 
 
 # ------------------------------------------- summarize failure satellite

@@ -1028,25 +1028,6 @@ class TestObsIntegration:
         assert not d["fleet_incidents"]
         assert all(r["state"] in ("beating", "done") for r in d["fleet"])
 
-    def test_diff_gates_serving_scale_keys(self, tmp_path):
-        from hyperion_tpu.obs import diff as obs_diff
-
-        def line(tps, scaleup, fair, aff):
-            return {"metric": "matmul_bf16_8192_tflops", "value": 100.0,
-                    "serving_scale": {"tokens_per_s": tps,
-                                      "scaleup": scaleup,
-                                      "fairness": fair,
-                                      "affinity_hit_rate": aff}}
-
-        a, b = tmp_path / "a.json", tmp_path / "b.json"
-        a.write_text(json.dumps(line(700.0, 1.8, 1.0, 0.8)))
-        b.write_text(json.dumps(line(400.0, 1.1, 0.4, 0.2)))
-        d = obs_diff.diff(obs_diff.load_summary(a),
-                          obs_diff.load_summary(b))
-        assert {"serve_scale_tokens_per_s", "serve_scale_scaleup",
-                "serve_scale_fairness",
-                "serve_affinity_hit_rate"} <= set(d["regressions"])
-
     def test_timeline_tags_replica_runs(self):
         from hyperion_tpu.obs.timeline import replica_of_run
 

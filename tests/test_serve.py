@@ -1257,25 +1257,6 @@ class TestServeTelemetry:
                      if r["metric"] == "e2e" and r["q"] == 99)
         assert e2e99["dominant"] == "client_write"
 
-    def test_serving_probe_shape_diffs(self, tmp_path):
-        """The bench `serving` row diffs like the input_pipeline probe:
-        a slower/more-rejecting run regresses in the right metrics."""
-        from hyperion_tpu.obs import diff as obs_diff
-
-        def line(tps, p50, p99, rej):
-            return {"metric": "matmul_bf16_8192_tflops", "value": 100.0,
-                    "serving": {"tokens_per_s": tps, "ttft_p50_ms": p50,
-                                "ttft_p99_ms": p99, "reject_rate": rej}}
-
-        a, b = tmp_path / "a.json", tmp_path / "b.json"
-        a.write_text(json.dumps(line(500.0, 10.0, 30.0, 0.05)))
-        b.write_text(json.dumps(line(300.0, 25.0, 90.0, 0.4)))
-        d = obs_diff.diff(obs_diff.load_summary(a),
-                          obs_diff.load_summary(b))
-        assert {"serve_tokens_per_s", "serve_ttft_p50_ms",
-                "serve_ttft_p99_ms",
-                "serve_reject_rate"} <= set(d["regressions"])
-
     def test_rejections_counted_and_evented(self, tmp_path, llama):
         from hyperion_tpu.obs.trace import Tracer
 
@@ -1503,7 +1484,7 @@ class TestLoadGenerator:
         assert a["completed"] + a["rejected"] + a["timed_out"] == 10
         if a["completed"]:
             assert a["ttft_p50_ms"] is not None
-            # the attribution keys obs diff gates ride every report
+            # the attribution keys ride every report
             for k in ("queue_wait_p99_ms", "prefill_p99_ms",
                       "decode_p99_ms", "preempt_replay_p99_ms",
                       "client_write_p99_ms"):
@@ -1541,8 +1522,7 @@ class TestLoadGenerator:
     def test_shared_prefix_workload_exercises_prefix_cache(self, llama):
         """The loadgen satellite: --shared-prefix-tokens emits requests
         with a common system prompt, so the report's cache keys go
-        green — hit rate and tokens saved above zero — and the keys
-        ride the serving row for `obs diff`."""
+        green — hit rate and tokens saved above zero."""
         eng = _engine(llama, slots=2, block_size=8, queue_capacity=16,
                       prefill_budget=64)
         spec = LoadSpec(n_requests=8, rate_hz=500.0, prompt_lens=(3, 5),

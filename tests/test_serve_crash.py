@@ -807,22 +807,6 @@ class TestObsIntegration:
                    for o in d["overload"])
         assert any("journal" in o for o in d["overload"])
 
-    def test_diff_gates_shed_and_clamp_rates(self, tmp_path):
-        from hyperion_tpu.obs import diff as obs_diff
-
-        def line(shed, clamp):
-            return {"metric": "matmul_bf16_8192_tflops", "value": 100.0,
-                    "serving": {"tokens_per_s": 500.0,
-                                "shed_rate": shed, "clamp_rate": clamp}}
-
-        a, b = tmp_path / "a.json", tmp_path / "b.json"
-        a.write_text(json.dumps(line(0.01, 0.01)))
-        b.write_text(json.dumps(line(0.4, 0.5)))
-        d = obs_diff.diff(obs_diff.load_summary(a),
-                          obs_diff.load_summary(b))
-        assert {"serve_shed_rate", "serve_clamp_rate"} \
-            <= set(d["regressions"])
-
     def test_smoke_script_has_kill_and_resume_round_trip(self):
         """The CI satellite: serve_smoke.sh must carry the supervised
         kill-and-resume leg (its flags are drift-guarded by

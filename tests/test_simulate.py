@@ -7,7 +7,7 @@ The simulator's promise is twofold and both halves are pinned here:
   monitor) on a virtual clock — deterministically, at fleet scale, in
   seconds of wall time;
 * everything it does lands on the standard telemetry stream, so the
-  unmodified obs plane (`obs doctor`, `obs diff`, the golden-fixture
+  unmodified obs plane (`obs doctor`, the golden-fixture
   contract) consumes a simulated fleet exactly like a live one.
 
 Scenario soaks at design size run under `-m slow`; tier-1 keeps the
@@ -24,7 +24,6 @@ from pathlib import Path
 import pytest
 
 from hyperion_tpu.obs import doctor
-from hyperion_tpu.obs import diff as obs_diff
 from hyperion_tpu.serve import simulate
 from hyperion_tpu.utils.clock import SYSTEM, Clock, VirtualClock
 
@@ -204,36 +203,6 @@ class TestObsPlaneConsumption:
         assert {"router_start", "router_end", "replica_ready",
                 "route_dispatch", "route_complete",
                 "replica_ejected"} <= names
-
-    def test_diff_normalizes_fleet_sim_row(self):
-        doc = {"metric": "synthetic", "value": 1.0,
-               "fleet_sim": {simulate.diff_key(s, k): 1.0
-                             for s, keys in simulate.DIFF_GATED.items()
-                             for k in keys}}
-        out = obs_diff.normalize(doc)
-        for s, keys in simulate.DIFF_GATED.items():
-            for k in keys:
-                assert simulate.diff_key(s, k) in out
-
-    def test_diff_flags_simulated_policy_regression(self):
-        """A duplicate delivery appearing in the sim row regresses the
-        diff even from a zero base (ZERO_PINNED)."""
-        base = {"label": "base", "metrics":
-                {"sim_failover_duplicate_tokens": 0.0,
-                 "sim_failover_completed_rate": 1.0}}
-        cand = {"label": "cand", "metrics":
-                {"sim_failover_duplicate_tokens": 2.0,
-                 "sim_failover_completed_rate": 1.0}}
-        d = obs_diff.diff(base, cand)
-        row = {r["metric"]: r for r in d["rows"]}
-        assert row["sim_failover_duplicate_tokens"]["regression"] is True
-        assert "sim_failover_duplicate_tokens" in d["regressions"]
-
-    def test_every_diff_gated_key_is_gated(self):
-        for s, keys in simulate.DIFF_GATED.items():
-            for k in keys:
-                assert simulate.diff_key(s, k) in obs_diff.METRICS
-
 
 # --------------------------------------------------------- CLI + guards
 

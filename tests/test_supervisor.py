@@ -110,8 +110,7 @@ def run_cli(*args, timeout=300):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONFAULTHANDLER="1")
     # hermetic children: a persistent compile cache shared across test
     # subprocesses is both unrealistic for these scenarios and broken on
-    # this CPU backend (reloading a cached executable aborts) — and any
-    # test that imports bench.py must not be able to leak one in here
+    # this CPU backend (reloading a cached executable aborts)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     return subprocess.run(
         [sys.executable, "-m", "hyperion_tpu.cli.main", *args],

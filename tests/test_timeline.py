@@ -1,7 +1,6 @@
 """`obs trace` consumer: timeline reconstruction from the golden serve
 fixture, Chrome trace-event export validity, tail-attribution math, the
-doctor's named serving incidents, and the new `obs diff` attribution
-gates. Everything here is host-only JSONL parsing — zero jit compiles
+doctor's named serving incidents. Everything here is host-only JSONL parsing — zero jit compiles
 (the live producer↔consumer round trip lives in tests/test_serve.py,
 riding shapes the suite already compiled).
 """
@@ -15,8 +14,6 @@ from pathlib import Path
 import pytest
 
 from hyperion_tpu.obs import timeline
-from hyperion_tpu.obs.diff import diff as obs_diff
-from hyperion_tpu.obs.diff import normalize
 from hyperion_tpu.obs.doctor import diagnose
 from hyperion_tpu.obs.report import read_records
 
@@ -189,42 +186,6 @@ class TestDoctorIncidents:
         assert d["verdict"] == "healthy"
         assert d["tail_attribution"] == []
         assert d["tail_incidents"] == []
-
-
-class TestDiffGates:
-    def _serving_doc(self, **over):
-        srv = {"tokens_per_s": 500.0, "ttft_p50_ms": 10.0,
-               "ttft_p99_ms": 40.0, "reject_rate": 0.0,
-               "queue_wait_p99_ms": 30.0, "gate_wait_p99_ms": 1.0,
-               "prefill_p99_ms": 5.0, "decode_p99_ms": 8.0,
-               "preempt_replay_p99_ms": 2.0, "client_write_p99_ms": 0.5}
-        srv.update(over)
-        return {"metric": "matmul_8192_tflops", "value": 100.0,
-                "serving": srv}
-
-    def test_attribution_keys_normalized(self):
-        m = normalize(self._serving_doc())
-        for k in ("serve_queue_wait_p99_ms", "serve_prefill_p99_ms",
-                  "serve_decode_p99_ms", "serve_preempt_replay_p99_ms",
-                  "serve_client_write_p99_ms", "serve_gate_wait_p99_ms"):
-            assert k in m, f"{k} not normalized"
-
-    def test_tail_moving_between_phases_is_gated(self):
-        """A tail that MOVES (queue doubles, prefill halves, aggregate
-        ttft flat) must still regress — the reason the components are
-        gated at all."""
-        a = {"label": "a", "metrics": normalize(self._serving_doc())}
-        b = {"label": "b", "metrics": normalize(self._serving_doc(
-            queue_wait_p99_ms=65.0, prefill_p99_ms=2.0))}
-        d = obs_diff(a, b, threshold=0.10)
-        assert "serve_queue_wait_p99_ms" in d["regressions"]
-        assert "serve_ttft_p99_ms" not in d["regressions"]
-
-    def test_improvement_not_flagged(self):
-        a = {"label": "a", "metrics": normalize(self._serving_doc())}
-        b = {"label": "b", "metrics": normalize(self._serving_doc(
-            queue_wait_p99_ms=10.0))}
-        assert not obs_diff(a, b, threshold=0.10)["regressions"]
 
 
 # --------------------------------------------------------- CLI + drift
