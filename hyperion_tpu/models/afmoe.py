@@ -4,8 +4,9 @@ Source: `huggingface.co/arcee-ai/Trinity-Large-Preview` `config.json`
 (`model_type: afmoe`); what its keys do not carry follows Hugging
 Face's `modeling_afmoe.py`. The block differs from `models/llama.py`'s
 in norm order, gate and feed-forward, so it is a file of its own; it
-shares `RMSNorm`, `apply_rope`, the paged write and the gather read
-with it. With `x` the residual stream `[T, d]`:
+shares `RMSNorm`, `apply_rope`, the paged write, the two paged reads
+and the choice between them with it. With `x` the residual stream
+`[T, d]`:
 
   * Model: `h = embed(ids) * sqrt(d)` (`mup_enabled`); the layers;
     `logits = lm_head(RMSNorm(h))`, untied.
@@ -33,8 +34,12 @@ for the absent chips.
 cache=, cache_index=, block_tables=)` with `cache` from
 `llama.init_paged_cache(cfg, {kind: blocks}, bs)` and `block_tables`
 `{kind: [B, MB]}` (`cfg.layer_kinds`: `full`, or `window` with its
-size). Only the gather read path is offered. Without a cache the call
-is one full forward (what the tests hold against the reference).
+size). A call reads its kind's pool as `llama.select_paged_attn_impl`
+says for its shape and the backend: the decode tick on a TPU in place,
+through the paged-attention kernel with the kind's table and window;
+chunks, prefills and every other backend through the gather. Without a
+cache the call is one full forward (what the tests hold against the
+reference).
 
 Device scopes: `layer_*/{full,window}/attn/{qkv_proj, qk_norm, rope,
 kv_write, kv_read, attention, gate, o_proj}`, `layer_*/mlp`,
@@ -56,8 +61,9 @@ from hyperion_tpu.models.llama import (
     RMSNorm,
     _grouped_cache_attention,
     apply_rope,
-    paged_gather_read,
     paged_kv_write,
+    paged_read,
+    select_paged_attn_impl,
 )
 from hyperion_tpu.ops.moe import dropless_moe
 
@@ -189,8 +195,12 @@ class AfmoeAttention(nn.Module):
             base = idx if idx.ndim == 1 else jnp.full((B,), idx, jnp.int32)
             table = block_tables[name]
             ck, cv = paged_kv_write(cache, k, v, table, base)
-            first = jnp.maximum(base - window + 1, 0) if window else None
-            a = paged_gather_read(q, ck, cv, table, base, first, window)
+            # the kind's pool through the kind's table: in place for a
+            # few-row window on a TPU (the decode tick), gathered for
+            # a chunk, a prefill and every other backend
+            impl = select_paged_attn_impl(
+                T, H // Hkv, jax.default_backend())
+            a = paged_read(impl, q, ck, cv, table, base, window)
             new_cache = {"k": ck, "v": cv}
         with jax.named_scope("gate"):
             a = a * jax.nn.sigmoid(_dense(c, (H, D), "gate_proj")(u))

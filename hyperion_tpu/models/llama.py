@@ -34,7 +34,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hyperion_tpu.ops.attention import dot_product_attention
+from hyperion_tpu.ops.attention import (
+    dot_product_attention,
+    window_view_blocks,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -326,13 +329,6 @@ def paged_kv_write(cache, k, v, block_tables, base):
         return write(cache["k"], k), write(cache["v"], v)
 
 
-def window_view_blocks(window: int, T: int, block_size: int) -> int:
-    """Blocks of a chain that hold every key T successive queries of a
-    windowed layer can see: positions `p0 - window + 1 .. p0 + T - 1`
-    for a first query at p0, wherever p0 falls inside its block."""
-    return -(-(window + T - 1) // block_size) + 1
-
-
 def paged_gather_read(q, ck, cv, block_tables, base, first=None,
                       window: int = 0):
     """The gather read path: each row's chain gathered out of the pools
@@ -375,6 +371,31 @@ def paged_gather_read(q, ck, cv, block_tables, base, first=None,
         kview = _chain_view(ck, block_tables)
         vview = _chain_view(cv, block_tables)
     return _grouped_cache_attention(q, kview, vview, mask, rep)
+
+
+def paged_read(impl: str, q, ck, cv, block_tables, base, window: int = 0):
+    """A paged layer's read by the path `impl` names ("pallas" or
+    "gather": `select_paged_attn_impl`'s answer for the call, or a
+    config's explicit value): q [B, T, H, D] at positions
+    `base[b]..base[b]+T-1` against the pools through one layer kind's
+    table, a full layer's (`window` 0) or a windowed one's (a query at
+    p sees keys `p - window < j <= p`)."""
+    if impl == "pallas":
+        # read the pools in place: the kernel walks each row's live
+        # blocks itself, so no contiguous copy is materialized. Its
+        # read and its product are one kernel: all of it is `kv_read`,
+        # the name the gather path's copies have
+        from hyperion_tpu.ops.pallas.paged_attention import paged_attention
+
+        with jax.named_scope("kv_read"):
+            return paged_attention(q, ck, cv, block_tables, base,
+                                   window=window)
+    if impl != "gather":
+        raise ValueError(
+            f"unknown paged read {impl!r} (want 'gather' or 'pallas'; "
+            "a config's 'auto' resolves to one of them)")
+    first = jnp.maximum(base - window + 1, 0) if window else None
+    return paged_gather_read(q, ck, cv, block_tables, base, first, window)
 
 
 class LlamaAttention(nn.Module):
@@ -448,26 +469,8 @@ class LlamaAttention(nn.Module):
             idx = jnp.asarray(cache_index, jnp.int32)
             base = idx if idx.ndim == 1 else jnp.full((B,), idx, jnp.int32)
             ck, cv = paged_kv_write(cache, k, v, block_tables, base)
-            impl = c.paged_attn_for(x.shape[1])
-            if impl == "pallas":
-                # read the pools in place: the kernel walks each row's
-                # live blocks itself, so no contiguous copy is
-                # materialized. Its read and its product are one
-                # kernel: all of it is `kv_read`, the name the gather
-                # path's copies have
-                from hyperion_tpu.ops.pallas.paged_attention import (
-                    paged_attention,
-                )
-
-                with jax.named_scope("kv_read"):
-                    out = paged_attention(q, ck, cv, block_tables, base)
-            elif impl == "gather":
-                out = paged_gather_read(q, ck, cv, block_tables, base)
-            else:
-                raise ValueError(
-                    f"unknown paged_attn_impl {c.paged_attn_impl!r} "
-                    "(want 'auto', 'gather' or 'pallas')"
-                )
+            out = paged_read(c.paged_attn_for(x.shape[1]),
+                             q, ck, cv, block_tables, base)
             return o_proj(out), {"k": ck, "v": cv}
 
         if cache is not None:

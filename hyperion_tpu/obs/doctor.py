@@ -46,6 +46,7 @@ from hyperion_tpu.obs.heartbeat import heartbeat_age_s, read_heartbeat
 from hyperion_tpu.obs.registry import percentile
 from hyperion_tpu.obs.tickprof import (
     FLIGHT_NAME,
+    WALK_COUNTERS,
     flight_final_tick,
     read_flight,
 )
@@ -1225,10 +1226,14 @@ def render_markdown(d: dict) -> str:
                 counted += (f" ({_fmt(v)} of them still held by the "
                             f"`{k[len('kv_tokens_'):]}` layers)")
         # how the decode ticks read the cache: the blocks the paged-
-        # attention kernel walked, of the entries a gather copies
-        if c.get("kv_table_entries"):
-            counted += (f"; read in place: {_fmt(c.get('kv_blocks_walked'))} "
-                        f"of {_fmt(c['kv_table_entries'])} table entries")
+        # attention kernel walked, of the entries a gather copies,
+        # summed over the layer kinds
+        walked, entries = (
+            sum(v or 0 for k, v in c.items() if k.startswith(name))
+            for name in WALK_COUNTERS)
+        if entries:
+            counted += (f"; read in place: {_fmt(walked)} "
+                        f"of {_fmt(entries)} table entries")
         # an expert model: what its ticks sent to the experts held here
         ex = tp.get("experts") or {}
         if ex:

@@ -24,7 +24,8 @@ module is the one way to time it:
     `restricted_rows` counters), what an expert model's ticks
     routed to the experts held here (`experts`), and how much of the
     block tables the ticks' reads touched (`kv_blocks_walked` of
-    `kv_table_entries`).
+    `kv_table_entries`, and a windowed layer kind's under the same
+    names with `_<kind>` behind).
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -63,6 +64,10 @@ CHILD_SEP = "/"
 # the span of a whole step in a profiler trace; a segment's span is
 # `serve.step/<key>`
 STEP_SPAN = "serve.step"
+# the tick record's walk counters (serve/engine.py `_count_walk`): the
+# full layer kind's under these names, a windowed kind's with `_<kind>`
+# behind; flows, summed over a window's ticks
+WALK_COUNTERS = ("kv_blocks_walked", "kv_table_entries")
 
 
 class _Seg:
@@ -253,11 +258,11 @@ class TickProfiler:
                                       for r in recs)}
             # of the table entries the window's decode ticks could have
             # gathered a layer, the blocks the paged-attention kernel
-            # walked instead (0: the ticks gathered)
-            if any("kv_table_entries" in r.get("c", {}) for r in recs):
-                for key in ("kv_blocks_walked", "kv_table_entries"):
-                    out["counters"][key] = sum(
-                        r.get("c", {}).get(key, 0) for r in recs)
+            # walked instead (0: the ticks gathered), by layer kind
+            for key in sorted({k for r in recs for k in r.get("c", {})
+                               if k.startswith(WALK_COUNTERS)}):
+                out["counters"][key] = sum(
+                    r.get("c", {}).get(key, 0) for r in recs)
         # an expert model's decode ticks (serve/engine.py
         # `_expert_counters`): picks that landed on the experts held
         # here and held experts touched, a tick (both summed over the

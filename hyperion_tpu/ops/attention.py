@@ -63,6 +63,15 @@ def select_attention_impl(
     return "pallas" if seq_len >= PALLAS_MIN_SEQ else "xla"
 
 
+def window_view_blocks(window: int, T: int, block_size: int) -> int:
+    """Blocks of a chain that hold every key T successive queries of a
+    windowed layer can see: positions `p0 - window + 1 .. p0 + T - 1`
+    for a first query at p0, wherever p0 falls inside its block. What
+    the gather read copies of a windowed kind's table a slot, and the
+    most the paged-attention kernel walks of it."""
+    return -(-(window + T - 1) // block_size) + 1
+
+
 def causal_mask(q_len: int, kv_len: int, dtype=jnp.bool_) -> jax.Array:
     """[q_len, kv_len] lower-triangular mask (True = attend), aligned to
     the *end* of the kv sequence (supports queries shorter than kv, as in

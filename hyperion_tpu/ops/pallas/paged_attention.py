@@ -16,9 +16,14 @@ The walk:
     step, 49,152 steps a call, and was bound by step overhead: PERF.md
     section 6, PR 24.)
   * The pools stay in HBM (`memory_space=pl.ANY`). Inside a step a loop
-    walks the slot's chain from block `lo` (0: a full layer) to
-    `ceil((base + T) / bs)`, read from the scalar-prefetched `base`,
-    and stops there: unmapped table columns are never fetched.
+    walks the slot's chain from block `lo` to `ceil((base + T) / bs)`,
+    both read from the scalar-prefetched `base`, and stops there:
+    unmapped table columns are never fetched. `lo` is 0 for a full
+    layer (`window` 0) and the block of the first position the slot's
+    first query can see for a windowed one,
+    `max(base - window + 1, 0) // bs`: the table stays indexed by
+    logical block, and the blocks the engine has let go (null entries
+    before `lo`) are never fetched either.
   * One DMA a block and pool: a block `[Hkv, bs, D]` is contiguous in
     the pool's layout (32 KB at Mistral's widths), so one descriptor
     moves all its heads; it lands head-major in a VMEM scratch
@@ -39,15 +44,16 @@ The walk:
 
 Masking contract, identical to the gather path: kv position `p`
 attends iff `p <= base[b] + row // rep` (per-row causal frontier over
-the filled prefix). Positions past the frontier inside the last live
+the filled prefix) and, in a windowed layer, `p > base[b] + row // rep
+- window`. Positions past the frontier inside the last live
 block, and whatever a buffer holds beyond the live blocks of a short
 group, get weight exactly 0 (finite NEG_INF); both buffers are zeroed
 once, before the first DMA, so nothing uninitialised is ever
 multiplied. The null block 0 is fetched only where a table names
-it at or before the frontier, which no live slot's table does; an
-inactive lane (an all-null table, whatever length its last occupant
-left in `base`) walks one block of it and its row is ignored by the
-caller.
+it at or after `lo` and at or before the frontier, which no live
+slot's table does; an inactive lane (an all-null table, whatever
+length its last occupant left in `base`) walks one block of it and its
+row is ignored by the caller.
 
 Numerics: operands are cast to float32 in VMEM, products accumulate in
 float32, softmax statistics and the accumulator are float32, matching
@@ -67,7 +73,8 @@ On the CPU backend the kernel runs through the Pallas interpreter,
 DMAs and semaphores included, so tier-1 exercises the real walk; on a
 TPU it is compiled, and any other backend is refused.
 `tests/test_tpu_compile.py` compiles it for a described v5e at the
-benchmark cell's geometry and in all three engine window shapes.
+benchmark cells' geometries (Mistral's tick; Trinity's, full and
+windowed) and in all three engine window shapes.
 """
 
 from __future__ import annotations
@@ -80,7 +87,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hyperion_tpu.ops.attention import NEG_INF
+from hyperion_tpu.ops.attention import NEG_INF, window_view_blocks
 from hyperion_tpu.ops.pallas.backend import (
     LANES,      # softmax statistics are carried lane-replicated
     SUBLANES,   # query rows are padded to a whole fp32 sublane tile
@@ -107,15 +114,16 @@ def _interpret() -> bool:
 
 
 def _plan(rows_p: int, Hkv: int, bs: int, D: int, itemsize: int,
-          MB: int) -> tuple[int, int]:
+          walk: int) -> tuple[int, int]:
     """(KV heads a grid step, blocks a DMA group) for a call's static
-    shape. Decode and verify windows take every head in one step and
-    groups of 64 blocks at Mistral's widths; a prompt-length window
-    takes fewer heads and smaller groups so its step stays in VMEM."""
+    shape; `walk` is the most blocks a slot's walk spans. Decode and
+    verify windows take every head in one step and groups of 64 blocks
+    at Mistral's widths; a prompt-length window takes fewer heads and
+    smaller groups so its step stays in VMEM."""
     per_head = rows_p * D * (4 * itemsize + 4) + 2 * rows_p * LANES * 4
     Hg = max(h for h in range(1, Hkv + 1)
              if Hkv % h == 0 and (h == 1 or h * per_head <= _STEP_BUDGET))
-    G = min(MB,
+    G = min(walk,
             _KV_BUDGET // (4 * Hg * bs * D * itemsize),
             _SCORE_BUDGET // (rows_p * bs * 4))
     return Hg, max(1, G)
@@ -123,7 +131,7 @@ def _plan(rows_p: int, Hkv: int, bs: int, D: int, itemsize: int,
 
 def _walk_kernel(bt_ref, base_ref, q_ref, k_hbm, v_hbm, o_ref,
                  k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref,
-                 *, bs, mb, rep, t, hg, G):
+                 *, bs, mb, rep, t, hg, G, window):
     """One (slot, head group) step: walk the slot's live blocks a group
     at a time.
 
@@ -139,14 +147,21 @@ def _walk_kernel(bt_ref, base_ref, q_ref, k_hbm, v_hbm, o_ref,
     D = q_ref.shape[-1]
 
     def span(slot_b):
-        """(first, one past the last) block of a slot's walk. The walk
-        starts at the chain's block 0: every layer here is `full`; a
-        windowed layer would start at its first live block."""
+        """(first, one past the last) block of a slot's walk: from the
+        chain's block 0 in a full layer, from the block that holds the
+        first position the slot's first query sees in a windowed one,
+        to the block of the window's last position."""
         hi = (base_ref[slot_b] + t + bs - 1) // bs
+        lo = 0
+        if window:
+            lo = jnp.clip(
+                (base_ref[slot_b] - window + 1) // bs, 0, mb - 1)
         # a lane the tick masks out keeps its last occupant's length
-        # over an all-null table: one block of it, not a stale chain
-        hi = jnp.where(bt_ref[slot_b, 0] == 0, 1, hi)
-        return 0, jnp.clip(hi, 1, mb)
+        # over an all-null table: one block of it, not a stale chain.
+        # (A live slot's entry `lo` is mapped: the engine lets go only
+        # the blocks wholly behind the window of the next query.)
+        hi = jnp.where(bt_ref[slot_b, lo] == 0, lo + 1, hi)
+        return lo, jnp.clip(hi, lo + 1, mb)
 
     def group_copies(slot_b, heads, j0, hi, buf, fn):
         """`fn` (start or wait) on the DMAs of blocks j0..min(j0+G, hi)
@@ -222,7 +237,10 @@ def _walk_kernel(bt_ref, base_ref, q_ref, k_hbm, v_hbm, o_ref,
                 jnp.int32, s.shape, 0) // rep
             kv_pos = j0 * bs + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
+            seen = kv_pos <= q_pos
+            if window:
+                seen &= kv_pos > q_pos - window
+            s = jnp.where(seen, s, NEG_INF)
             m_prev = m_ref[h]                               # [rows_p, LANES]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -240,14 +258,19 @@ def _walk_kernel(bt_ref, base_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     jax.lax.fori_loop(0, ng, group, 0)
     slot_ref[0] = (buf0 + ng) % 2
-    # l > 0 always: the walk starts at block `lo`, whose first position
-    # every query row sees (padded rows, base + row // rep beyond the
-    # window, are ordinary causal rows and sliced off by the caller)
+    # l > 0 always: a full layer's walk starts at block 0, whose first
+    # position every query row sees; a windowed walk starts at block
+    # `lo`, which holds the first position of the first query's window,
+    # `max(base - window + 1, 0)`. A later token's window may start
+    # beyond a short first group: its sums there are over masked scores
+    # only, and the first group it does see (its own position lies
+    # inside the walk) rescales them by exp(NEG_INF - m) = 0. Padded
+    # rows are ordinary rows of the same mask, sliced off by the caller.
     o_ref[...] = (acc_ref[...] / l_ref[...][:, :, :1]).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, base, *,
-                    blocks_per_group: int | None = None):
+                    window: int = 0, blocks_per_group: int | None = None):
     """Decode attention straight against the paged KV pools.
 
     Args:
@@ -259,6 +282,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, base, *,
       block_tables: [B, MB] int32 physical-block chain per slot;
         unmapped tail entries are 0 (the null block).
       base: [B] int32 first logical position of the window per slot.
+      window: 0 for a full layer; a windowed layer's size: a query at p
+        sees keys `p - window < j <= p`, and the walk starts at the
+        block of `base - window + 1`. The table is still indexed by
+        logical block; entries before that block may be null.
       blocks_per_group: blocks a DMA group, in place of the plan's
         (tests walk several groups of a short table with it).
 
@@ -270,6 +297,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, base, *,
         raise ValueError(f"n_heads {H} not a multiple of n_kv_heads {Hkv}")
     if v_pool.shape != k_pool.shape:
         raise ValueError(f"pool shapes differ: {k_pool.shape} vs {v_pool.shape}")
+    if window < 0:
+        raise ValueError(f"window must be >= 0, got {window}")
     if block_tables.shape[0] != B or base.shape != (B,):
         raise ValueError(
             f"table/base batch mismatch: q {B}, "
@@ -277,7 +306,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, base, *,
         )
     return _paged_attention(
         q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(base, jnp.int32),
+        jnp.asarray(base, jnp.int32), window=int(window),
         blocks_per_group=blocks_per_group, interpret=_interpret())
 
 
@@ -285,17 +314,22 @@ def paged_attention(q, k_pool, v_pool, block_tables, base, *,
 # shapes, and under one jit the calls share one trace of the kernel's
 # body and one lowering of it to a Mosaic module. Traced and lowered
 # inline, sixteen layers' calls added 6 s to every start of the server,
-# cached executable or not (PERF.md section 6, PR 27).
-@functools.partial(jax.jit, static_argnames=("blocks_per_group", "interpret"))
+# cached executable or not (PERF.md section 6, PR 27). A model whose
+# layers are of two kinds holds two: `window` is static.
+@functools.partial(
+    jax.jit, static_argnames=("window", "blocks_per_group", "interpret"))
 def _paged_attention(q, k_pool, v_pool, block_tables, base, *,
-                     blocks_per_group, interpret):
+                     window, blocks_per_group, interpret):
     B, T, H, D = q.shape
     Hkv, bs = k_pool.shape[1], k_pool.shape[2]
     MB = block_tables.shape[1]
     rep = H // Hkv
     rows = T * rep
     rows_p = -(-rows // SUBLANES) * SUBLANES
-    Hg, G = _plan(rows_p, Hkv, bs, D, k_pool.dtype.itemsize, MB)
+    # the most blocks one slot's walk spans: the table's width, or of a
+    # windowed layer what a window can span (a group is never longer)
+    walk = min(MB, window_view_blocks(window, T, bs)) if window else MB
+    Hg, G = _plan(rows_p, Hkv, bs, D, k_pool.dtype.itemsize, walk)
     G = blocks_per_group or G
     # [B, T, H, D] -> [B, Hkv, T*rep, D]: a step sees each KV head's
     # whole query group; row r is token r // rep.
@@ -306,18 +340,18 @@ def _paged_attention(q, k_pool, v_pool, block_tables, base, *,
     )
     if rows_p != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
-    window = pl.BlockSpec(
+    q_block = pl.BlockSpec(
         (None, Hg, rows_p, D),
         lambda b, c, bt_ref, base_ref: (b, c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, Hkv // Hg),
         in_specs=[
-            window,
+            q_block,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=window,
+        out_specs=q_block,
         scratch_shapes=[
             pltpu.VMEM((2, Hg, G * bs, D), k_pool.dtype),
             pltpu.VMEM((2, Hg, G * bs, D), v_pool.dtype),
@@ -329,14 +363,15 @@ def _paged_attention(q, k_pool, v_pool, block_tables, base, *,
         ],
     )
     out_shape = jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype)
-    # The static worst case, every table entry live: a block of K and
-    # of V once per entry, two products of rows x bs x D per block and
-    # head, one exponential per score. What a call moves follows the
-    # slots' lengths (`base`), which no static estimate can see.
-    chain = B * MB * Hkv * bs
+    # The static worst case, every table entry live (of a windowed
+    # layer: every entry a window can span): a block of K and of V once
+    # per entry, two products of rows x bs x D per block and head, one
+    # exponential per score. What a call moves follows the slots'
+    # lengths (`base`), which no static estimate can see.
+    chain = B * walk * Hkv * bs
     out = pl.pallas_call(
         functools.partial(_walk_kernel, bs=bs, mb=MB, rep=rep, t=T,
-                          hg=Hg, G=G),
+                          hg=Hg, G=G, window=window),
         grid_spec=grid_spec,
         out_shape=out_shape,
         # a step hands the next its first group in flight and the

@@ -100,6 +100,7 @@ from hyperion_tpu.obs.export import DEFAULT_WINDOW_S
 from hyperion_tpu.obs.heartbeat import host_rss_mb as hb_host_rss_mb
 from hyperion_tpu.obs.ledger import CompileLedger
 from hyperion_tpu.obs.tickprof import (
+    WALK_COUNTERS,
     FlightRecorder,
     TickProfiler,
     null_flight_recorder,
@@ -480,8 +481,9 @@ class Engine:
         from hyperion_tpu.models.llama import (
             init_paged_cache,
             paged_cache_block_bytes,
-            window_view_blocks,
+            select_paged_attn_impl,
         )
+        from hyperion_tpu.ops.attention import window_view_blocks
         from hyperion_tpu.obs import heartbeat as hb_mod
         from hyperion_tpu.obs import trace as trace_mod
 
@@ -548,12 +550,14 @@ class Engine:
             else self._mb for k, w in self._kinds.items()}
         # how the decode tick reads the pools, "pallas" (in place, the
         # paged-attention kernel) or "gather": the model's choice,
-        # resolved as the model resolves it for the tick's window. None
-        # for a model without the choice (it calls the gather itself,
-        # by layer kind).
+        # resolved as the model resolves it for the tick's window; a
+        # model without an option of its own asks the selector, as its
+        # attention does per call.
         self._tick_width = cfg.spec_k + 1 if self._spec else 1
         self._tick_read = mcfg.paged_attn_for(self._tick_width) \
-            if hasattr(mcfg, "paged_attn_for") else None
+            if hasattr(mcfg, "paged_attn_for") else select_paged_attn_impl(
+                self._tick_width, mcfg.n_heads // mcfg.n_kv_heads,
+                jax.default_backend())
         num_blocks = cfg.num_blocks or cfg.slots * self._mb + 1
         if num_blocks < self._mb + 1:
             raise ValueError(
@@ -681,9 +685,17 @@ class Engine:
         self._prefill_tokens = 0  # padded tokens prefilled this step
         # rows of this step's decode tick that sample / that restrict
         self._sampling_rows = self._restricted_rows = 0
-        # blocks the paged-attention kernel walks in this step's decode
-        # tick, a layer; table entries the gather would have copied
-        self._blocks_walked = self._table_entries = 0
+        # by layer kind, the blocks the paged-attention kernel walks in
+        # this step's decode tick, a layer, and the table entries the
+        # gather would have copied, under the tick record's names
+        # (walked, entries): the full kind's bare, a windowed kind's
+        # with its name behind
+        self._walk_names = {
+            k: tuple(f"{name}_{k}" if w else name for name in WALK_COUNTERS)
+            for k, w in self._kinds.items()}
+        self._no_walk = {
+            name: 0 for names in self._walk_names.values() for name in names}
+        self._walk_counted = self._no_walk
         # what this step's decode tick counted on the device (an expert
         # model's picks: `_expert_counters`), fetched with its tokens
         self._tick_counted: dict[str, int] = {}
@@ -870,6 +882,29 @@ class Engine:
             (r is not None and s not in self._chunking
              for s, r in enumerate(self._slots)),
             bool, len(self._slots))
+
+    def _count_walk(self) -> dict[str, int]:
+        """The tick record's walk counters for the tick about to be
+        dispatched, by layer kind (`_no_walk`'s names): the entries a
+        gather copies of the kind's table a layer, and, where the tick
+        reads in place, the blocks the kernel's loops visit a layer,
+        from the lengths it will read: a live slot's chain from the
+        block of the first position its query sees (block 0 of a full
+        kind) to the block of its window's last position, one (null)
+        block of a lane the tick masks out. Host arithmetic."""
+        bs, width = self.cfg.block_size, self._tick_width
+        counted = dict(self._no_walk)
+        for k, (_, entries) in self._walk_names.items():
+            counted[entries] = self.cfg.slots * self._view[k]
+        if self._tick_read == "pallas":
+            live = [self._seqs[s].n_filled
+                    for s, on in enumerate(self._live_mask()) if on]
+            for k, w in self._kinds.items():
+                counted[self._walk_names[k][0]] = sum(
+                    min(self._mb, blocks_for(n + width, bs))
+                    - (max(n - w + 1, 0) // bs if w else 0)
+                    for n in live) + self.cfg.slots - len(live)
+        return counted
 
     def _rows_on_device(self, slot: int | None = None,
                         rows: dict | None = None) -> dict:
@@ -1982,11 +2017,10 @@ class Engine:
                 # the vocabulary for every row
                 sampling_rows=self._sampling_rows,
                 restricted_rows=self._restricted_rows,
-                # how much of the table the tick's read touched: the
-                # blocks the kernel's loops visit a layer (0 = the tick
-                # gathered), of the entries a gather copies a layer
-                kv_blocks_walked=self._blocks_walked,
-                kv_table_entries=self._table_entries)
+                # how much of each kind's table the tick's read touched:
+                # the blocks the kernel's loops visit a layer (0 = the
+                # tick gathered), of the entries a gather copies a layer
+                **self._walk_counted)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -1998,7 +2032,7 @@ class Engine:
         prof = self.tickprof
         self._prefill_tokens = 0
         self._sampling_rows = self._restricted_rows = 0
-        self._blocks_walked = self._table_entries = 0
+        self._walk_counted = self._no_walk
         self._tick_counted = {}
 
         if self._governor is not None:
@@ -2157,18 +2191,7 @@ class Engine:
                     self._sampling_rows += 1
                     self._restricted_rows += \
                         req.top_k > 0 or req.top_p < 1.0
-            if self._tick_read:
-                self._table_entries = self.cfg.slots * self._mb
-            if self._tick_read == "pallas":
-                # the kernel's walk, from the lengths it will read: a
-                # live slot's chain up to its window's last position,
-                # one (null) block of a lane the tick masks out
-                self._blocks_walked = sum(
-                    min(self._mb, blocks_for(
-                        self._seqs[s].n_filled + self._tick_width,
-                        self.cfg.block_size))
-                    if req is not None and s not in self._chunking else 1
-                    for s, req in enumerate(self._slots))
+            self._walk_counted = self._count_walk()
             with prof.seg("draft"):
                 drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table

@@ -5,6 +5,7 @@ tiny sizes (window 8, contexts to 56, 8-16 experts)."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -335,6 +336,131 @@ def test_a_windowed_chain_lets_blocks_go_within_the_step(tiny):
         assert len(full.blocks) >= -(-full.n_filled // 4)   # full keeps all
 
 
+@contextlib.contextmanager
+def as_on_a_tpu():
+    """The one selector, answering as it does in a process whose backend
+    is a TPU: the call's shape decides, and the kernel it chooses runs
+    through the interpreter here. No option of the model or the engine
+    is involved: there is none."""
+    from hyperion_tpu.models import afmoe, llama
+
+    select = llama.select_paged_attn_impl
+
+    def forced(window, rep, backend):
+        return select(window, rep, "tpu")
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(afmoe, "select_paged_attn_impl", forced)
+        mp.setattr(llama, "select_paged_attn_impl", forced)
+        yield
+
+
+def kernel_twin(cfg):
+    """The same model under another identity (a longer rotary table,
+    which no engine here reaches into): the jits are shared process-wide
+    and keyed by the model, so the twin holds traces of its own."""
+    return Afmoe(dataclasses.replace(cfg, max_len=80))
+
+
+def test_the_tick_reads_both_pools_in_place_and_serves_the_same_tokens(tiny):
+    """The decode tick through the paged-attention kernel, each layer
+    with its kind's table and window, against the tick through the
+    gather: the same greedy streams over requests that slide well past
+    the window of 8; nothing is gathered a tick (`memory_ledger`), and
+    the tick record counts each kind's walk as the tables themselves
+    show it."""
+    cfg, model, params = tiny
+    ecfg = EngineConfig(slots=3, max_len=64, block_size=4, prefill_chunk=16,
+                        prefix_cache=False)
+    bs, W = 4, cfg.sliding_window
+
+    def requests():
+        rng = np.random.default_rng(5)
+        return [Request(prompt_ids=rng.integers(1, cfg.vocab_size, n)
+                        .astype(np.int32), max_new_tokens=g, id=f"r{i}")
+                for i, (n, g) in enumerate(
+                    [(5, 30), (37, 20), (3, 44), (20, 12), (9, 3)])]
+
+    gathered = requests()
+    eng = Engine(model, {"params": params}, ecfg)
+    assert eng._tick_read == "gather"
+    serve(eng, gathered)
+
+    checked = []
+    with as_on_a_tpu():
+        eng = Engine(kernel_twin(cfg), {"params": params}, ecfg)
+        assert eng._tick_read == "pallas"
+        assert eng.memory_ledger()["kv_gather_bytes_per_tick"] == 0
+        # five layers of two kinds: two traces of the kernel, called five
+        # times, not a trace and a lowering a layer
+        text = eng._tick_jit.lower(
+            eng.model, eng.cfg.eos_id, eng.cfg.pad_id, eng.variables,
+            eng._cache, eng._state, eng._rows_on_device(),
+            jnp.asarray(eng._live_mask())).as_text()
+        assert text.count("func.func private @_paged_attention") == 2
+        assert text.count("call @_paged_attention") == 5
+        eng.warmup([16])
+        compiled = eng.compile_stats()
+        tick = eng._tick_device
+
+        def spy():
+            # at dispatch the mapped entries of a live row ARE its walk
+            live = eng._live_mask()
+            masked = int((~live).sum())
+            rows = {k: t[live] for k, t in eng._bts.items()}
+            n = np.array([q.n_filled
+                          for q, on in zip(eng._seqs, live) if on])
+            hi = -(-(n + 1) // bs)
+            c = eng._walk_counted
+            assert c["kv_blocks_walked"] == hi.sum() + masked \
+                == np.count_nonzero(rows["full"]) + masked
+            assert c["kv_blocks_walked_window"] == masked + \
+                (hi - np.maximum(n - W + 1, 0) // bs).sum() \
+                == np.count_nonzero(rows["window"]) + masked
+            assert c["kv_table_entries"] == 3 * eng._mb == 3 * 16
+            assert c["kv_table_entries_window"] == \
+                3 * window_view_blocks(W, 1, bs)
+            checked.append(
+                (c["kv_blocks_walked"], c["kv_blocks_walked_window"]))
+            return tick()
+
+        eng._tick_device = spy
+        in_place = requests()
+        serve(eng, in_place)
+        assert eng.compile_stats() == compiled
+    for a, b in zip(gathered, in_place):
+        assert a.status == b.status == "done"
+        assert a.tokens == b.tokens, a.id
+    assert len(checked) >= 40
+    # slots slid past the window: the windowed walk fell behind the full
+    assert any(w < f for f, w in checked)
+    recs = [r["c"] for r in eng.tickprof.tail(256) if "device" in r["s"]]
+    assert [(c["kv_blocks_walked"], c["kv_blocks_walked_window"])
+            for c in recs] == checked[-len(recs):]
+    snap = eng.tickprof.snapshot(window_s=3600)["counters"]
+    assert snap["kv_blocks_walked_window"] == sum(w for _, w in checked)
+    assert snap["kv_table_entries_window"] == 9 * len(checked)
+
+
+def test_off_a_tpu_the_tick_gathers_and_says_so(tiny):
+    cfg, model, params = tiny
+    eng = Engine(model, {"params": params}, EngineConfig(
+        slots=3, max_len=64, block_size=4, prefill_chunk=16,
+        prefix_cache=False))
+    assert eng._tick_read == "gather"
+    by = eng._kind_block_bytes
+    assert eng.memory_ledger()["kv_gather_bytes_per_tick"] == 3 * (
+        16 * by["full"] + 3 * by["window"])
+    eng.submit(Request(prompt_ids=np.arange(1, 12, dtype=np.int32),
+                       max_new_tokens=6, id="g"))
+    serve(eng, [])
+    c = [r["c"] for r in eng.tickprof.tail(64) if "device" in r["s"]]
+    assert c and all(
+        x["kv_blocks_walked"] == x["kv_blocks_walked_window"] == 0
+        and x["kv_table_entries"] == 48 and x["kv_table_entries_window"] == 9
+        for x in c)
+
+
 @pytest.mark.parametrize("feature, over", [
     ("prefix cache", dict(prefix_cache=True)),
     ("host spill tier", dict(prefix_cache=False, host_cache_mb=1)),
@@ -416,6 +542,37 @@ def test_doctor_row_reads_the_expert_and_kind_counters(tmp_path):
     assert "4100 of them still held by the `window` layers" in row
     assert "47.50 picks a tick on 40.20 held experts" in row
     assert "busiest got 5" in row
+
+
+@pytest.mark.parametrize("counters, says", [
+    # both kinds read in place: the row sums them
+    ({"kv_blocks_walked": 4000, "kv_table_entries": 18432,
+      "kv_blocks_walked_window": 2400, "kv_table_entries_window": 6168},
+     "read in place: 6400 of 24600 table entries"),
+    # the ticks gathered
+    ({"kv_blocks_walked": 0, "kv_table_entries": 18432,
+      "kv_blocks_walked_window": 0, "kv_table_entries_window": 6168},
+     "read in place: 0 of 24600 table entries"),
+    # a model of one kind: the row is what it was
+    ({"kv_blocks_walked": 3137, "kv_table_entries": 6144},
+     "read in place: 3137 of 6144 table entries"),
+])
+def test_doctor_row_sums_the_walk_over_the_kinds(tmp_path, counters, says):
+    import json
+
+    from hyperion_tpu.obs import doctor
+
+    tp = {"dominant": "device", "dominant_frac": 0.8, "ticks": 10,
+          "window_s": 60.0, "total_s": 1.25,
+          "segments": {"device": {"s": 1.0, "frac": 0.8}},
+          "counters": {"kv_tokens": 9000, "prefill_tokens": 0, **counters}}
+    (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+        {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+         "tickprof": tp}) + "\n")
+    row = next(ln for ln in doctor.render_markdown(
+        doctor.diagnose(tmp_path)).splitlines()
+        if ln.startswith("| host tick profile"))
+    assert says in row
 
 
 def test_config_layer_kinds():

@@ -169,3 +169,33 @@ def test_paged_attention_reads_chains_not_pools(Tw):
         + _nbytes(*[((Bs, Hkv, rows_p, Dh), BF16)] * 2,
                   ((Bs, MB), jnp.int32), ((Bs,), jnp.int32)))
     assert est.bytes_accessed < _nbytes((pool.shape, BF16))
+
+
+@pytest.mark.parametrize("Tw, window, walk", [
+    (1, 64, 5),       # 64 positions anywhere over 16-token blocks: 5
+    (3, 64, 6),       # three queries' windows: 66 positions, 6 blocks
+    (1, 4096, 12),    # a window wider than the table: the table
+])
+def test_windowed_paged_attention_counts_the_window_not_the_table(
+        Tw, window, walk):
+    Bs, Hq, Hkv, Dh, bs, MB, blocks = 4, 8, 2, 128, 16, 12, 64
+    q = jnp.zeros((Bs, Tw, Hq, Dh), BF16)
+    pool = jnp.zeros((blocks, Hkv, bs, Dh), BF16)
+    tables = jnp.zeros((Bs, MB), jnp.int32)
+    base = jnp.zeros((Bs,), jnp.int32)
+    (est,) = _estimates(
+        lambda q, k, v: paged_attention.paged_attention(
+            q, k, v, tables, base, window=window), q, pool, pool)
+    assert walk == min(MB, paged_attention.window_view_blocks(
+        window, Tw, bs))
+    rows = Tw * Hq // Hkv
+    rows_p = -(-rows // SUBLANES) * SUBLANES
+    # the static worst case of a windowed call: the blocks a window can
+    # span a slot, whatever the table's width
+    chain = Bs * walk * Hkv * bs
+    assert est.flops == 4 * chain * rows * Dh
+    assert est.transcendentals == chain * rows
+    assert est.bytes_accessed == (
+        2 * chain * Dh * 2
+        + _nbytes(*[((Bs, Hkv, rows_p, Dh), BF16)] * 2,
+                  ((Bs, MB), jnp.int32), ((Bs,), jnp.int32)))

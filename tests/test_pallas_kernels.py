@@ -582,6 +582,84 @@ class TestPagedAttention:
                                 base, blocks_per_group=4)
         np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
 
+    # The windowed walk (a sliding-attention layer's pool), window 20
+    # over blocks of 4 in a table of 16: `base` of each of three rows.
+    # A row's table is what the engine leaves of it: entries before the
+    # block of `base - window + 1` are null (let go), entries up to the
+    # block of `base + T - 1` mapped. None is a lane the tick masks out.
+    WINDOW = 20
+    WINDOW_WALKS = {
+        "shorter_than_the_window": (3, 0, 12),
+        "ends_on_a_block_edge": (19, 39, 59),      # base + T: whole blocks
+        "several_windows_long": (55, 41, 30),      # leading entries null
+        "masked_out_lane": (None, 44, None),       # stale base 57
+    }
+
+    def _window_geometry(self, bases, T, stale=57, bs=4, MB=16):
+        q, kp, vp, bt, base = self._edge_geometry(
+            [None if n is None else n + T - 1 for n in bases],
+            stale=stale, T=1, bs=bs, MB=MB)
+        q = jnp.tile(q, (1, T, 1, 1)) * (
+            1 + jnp.arange(T, dtype=jnp.float32))[None, :, None, None]
+        bt, base = np.array(bt), np.array(base)
+        for b, n in enumerate(bases):
+            if n is not None:
+                base[b] = n
+                bt[b, :max(n - self.WINDOW + 1, 0) // bs] = 0
+        # whatever the null block holds is never seen
+        return (q, kp.at[0].set(1e4), vp.at[0].set(-1e4),
+                jnp.asarray(bt), jnp.asarray(base))
+
+    # blocks a DMA group: 1 (a later token's window can start beyond
+    # the first group), 2, and the plan's (the whole walk)
+    @pytest.mark.parametrize("G", [1, 2, None])
+    @pytest.mark.parametrize("T", [1, 4])
+    @pytest.mark.parametrize("walk", sorted(WINDOW_WALKS))
+    def test_windowed_walk(self, walk, T, G):
+        """`window > 0` against the gather read of a windowed layer
+        (`paged_gather_read(first=, window=)`): the walk starts at the
+        block of the first position the first query sees, entries
+        before it may be null, and a query at p sees p - window < j <=
+        p. With groups of 1 or 2 blocks a slot's walk (up to 7) is
+        several groups, the last one short."""
+        from hyperion_tpu.models.llama import paged_gather_read
+        from hyperion_tpu.ops.pallas.paged_attention import paged_attention
+
+        bases, W = self.WINDOW_WALKS[walk], self.WINDOW
+        if walk == "ends_on_a_block_edge":
+            bases = tuple(n + 1 - T for n in bases)
+        q, kp, vp, bt, base = self._window_geometry(bases, T)
+        live = [b for b, n in enumerate(bases) if n is not None]
+        if walk == "several_windows_long":
+            assert all(int(bt[b, 0]) == 0 for b in live)
+        out = np.asarray(paged_attention(
+            q, kp, vp, bt, base, window=W, blocks_per_group=G))
+        ref = np.asarray(paged_gather_read(
+            q, kp, vp, bt, base, jnp.maximum(base - W + 1, 0), W))
+        np.testing.assert_allclose(out[live], ref[live],
+                                   atol=2e-5, rtol=2e-5)
+        assert np.isfinite(out).all()
+        # and the window term is there: the full walk of the same
+        # chains answers otherwise once a context passes the window
+        if walk == "several_windows_long":
+            full = np.asarray(self._ref(q, kp, vp, bt, base))
+            assert np.abs(out - full).max() > 1e-3
+
+    @pytest.mark.parametrize("edge", sorted(WALK_EDGES))
+    def test_window_0_is_the_full_walk(self, edge):
+        """`window=0` is the call without the keyword, and a window no
+        context outgrows changes nothing either: the same walk from
+        block 0, bit for bit."""
+        from hyperion_tpu.ops.pallas.paged_attention import paged_attention
+
+        q, kp, vp, bt, base = self._edge_geometry(
+            self.WALK_EDGES[edge], stale=29 if "stale" in edge else 0)
+        today = np.asarray(paged_attention(q, kp, vp, bt, base,
+                                           blocks_per_group=4))
+        for window in (0, bt.shape[1] * kp.shape[2]):
+            np.testing.assert_array_equal(today, np.asarray(paged_attention(
+                q, kp, vp, bt, base, window=window, blocks_per_group=4)))
+
     def test_model_level_matches_gather(self):
         """Full Llama tiny (GQA rep 2) through all three engine window
         shapes, caches threaded forward per impl: chunked prefill

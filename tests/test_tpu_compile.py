@@ -132,6 +132,28 @@ class TestPagedAttentionCompiles:
         # is a gathered or re-laid-out copy of them
         assert "gather" not in text
 
+    @pytest.mark.parametrize("kind", ["full", "window"])
+    def test_trinity_tick_geometry(self, mosaic, one_chip, kind):
+        """`trinity.longmix-saturated`'s tick, the two calls its five
+        layers make: 24 slots of one token, 48 heads on 8 KV heads of
+        128 (6 query rows a KV head), 16-token blocks, tables of 768
+        columns; the full kind's pool of 18433 blocks walked from block
+        0, the windowed kind's of 6961 from the block of `base - 4095`.
+        One plan for both: every KV head in a step, groups of 64."""
+        S_, H, Hkv, D, bs, MB = 24, 48, 8, 128, 16, 768
+        window, NB = {"full": (0, 18433), "window": (4096, 6961)}[kind]
+        walk = paged_mod.window_view_blocks(window, 1, bs) if window else MB
+        assert walk == {"full": 768, "window": 257}[kind]
+        assert paged_mod._plan(8, Hkv, bs, D, 2, walk) == (Hkv, 64)
+        pool = S((NB, Hkv, bs, D), BF16)
+        text = _compile(
+            lambda *a: paged_mod.paged_attention(*a, window=window),
+            one_chip,
+            S((S_, 1, H, D), BF16), pool, pool,
+            S((S_, MB), jnp.int32), S((S_,), jnp.int32),
+        )
+        assert "gather" not in text
+
 
 class TestFlashAttentionCompiles:
     @pytest.mark.parametrize("shape", [
