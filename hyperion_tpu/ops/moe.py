@@ -29,13 +29,21 @@ the GShard/Switch einsum formulation the hardware wants:
 
 Two layers live here and serve different users. `moe_ffn` (above) is
 TRAINING's: `MoELM`'s capacity routing, which drops tokens past an
-expert's capacity and is exact only against itself. `dropless_moe`
-(below) is SERVING's (`models/afmoe.py`): token-choice routing with a
-sigmoid router over ALL of a model's experts, no capacity and no drop,
-told which contiguous run of experts this chip holds (`held`); it
-computes the part of the result those experts give, over grouped
-matrix products (`jax.lax.ragged_dot`, tokens sorted by expert), and
-what the absent experts would have added is left out. It is what an
+expert's capacity and is exact only against itself. What follows it is
+SERVING's: token-choice routing over ALL of a model's experts, no
+capacity and no drop, in two steps a model puts together. A ROUTER
+says which experts each token picked and with what weight, `(picked
+[N, k] int32, w [N, k] float32)`: `sigmoid_topk_route` (sigmoid scores,
+a selection bias, normalised and scaled: `models/afmoe.py`) or
+`softmax_topk_route` (a softmax over the picked logits:
+`models/smallthinker.py`, which routes on the layer's input, before
+attention). ONE expert step, `grouped_experts`, takes the tokens the
+experts read, the picks, the weights, which contiguous run of experts
+this chip holds (`held`) and the gate's activation, and computes the
+part of the result those experts give, over grouped matrix products
+(`jax.lax.ragged_dot`, tokens sorted by expert); what the absent
+experts would have added is left out. `dropless_moe` is the two
+composed on one input, as `afmoe` calls them. That is what an
 inference reference can be matched against; `moe_ffn` is not.
 """
 
@@ -224,6 +232,14 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig,
 # --- serving: dropless token-choice routing over a held share ---------
 
 
+def _router_logits(x: jax.Array, router: jax.Array) -> jax.Array:
+    """x [N, d] @ router [d, E] in float32 (a bf16 product flips
+    near-ties among many experts)."""
+    return jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST)
+
+
 def sigmoid_topk_route(x: jax.Array, router: jax.Array, bias: jax.Array, *,
                        top_k: int, route_norm: bool, route_scale: float):
     """x [N, d] → (picked [N, k] int32 over ALL experts, weights [N, k]
@@ -231,9 +247,7 @@ def sigmoid_topk_route(x: jax.Array, router: jax.Array, bias: jax.Array, *,
     flips near-ties among 256 experts); `bias` moves which experts are
     PICKED and never the weight a pick gets; the weights are normalised
     over all k picks, held here or not, then scaled."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    scores = jax.nn.sigmoid(_router_logits(x, router))
     _, picked = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, picked, axis=-1)
     if route_norm:
@@ -241,31 +255,39 @@ def sigmoid_topk_route(x: jax.Array, router: jax.Array, bias: jax.Array, *,
     return picked.astype(jnp.int32), w * route_scale
 
 
-def dropless_moe(x: jax.Array, params: dict, *, held: tuple[int, int],
-                 top_k: int, route_norm: bool = True,
-                 route_scale: float = 1.0):
-    """The routed part of a token-choice expert layer, for the experts
-    `held = (first, count)` of the router's width: x [N, d] →
-    (y [N, d], load [N, count] int32).
+def softmax_topk_route(x: jax.Array, router: jax.Array, *, top_k: int):
+    """x [N, d] → (picked [N, k] int32 over ALL experts, weights [N, k]
+    float32): the `top_k` largest logits `x W_r` (float32) are picked
+    and the weights are a softmax over the picked logits alone, which
+    is the softmax over all experts renormalised over the picks. No
+    bias, no scale. `x` is whatever the model routes on, not
+    necessarily what the experts read."""
+    top, picked = lax.top_k(_router_logits(x, router), top_k)
+    return picked.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
-    `params`: `router` [d, E], `expert_bias` [E], and the held experts'
-    SwiGLU weights stacked `gate`, `up` [count, d, f], `down`
-    [count, f, d]. Every shape is static: the N*k (token, pick) pairs are
-    sorted by expert, pairs whose expert lives on another chip sort
-    last, and the three products run grouped over the held experts
-    (`ragged_dot` with the per-expert counts): no capacity, no token
-    dropped however uneven the routing, and no dense pass over every
-    held expert for every token. `load[n, e]` is 1 where token n picked
-    held expert e: the tick's counters are sums of it.
 
-    Device scopes (under the caller's): `router`, `dispatch`, `experts`,
+def grouped_experts(x: jax.Array, picked: jax.Array, w: jax.Array,
+                    params: dict, *, held: tuple[int, int],
+                    act=jax.nn.silu):
+    """The expert step every router shares: x [N, d], a router's
+    `picked` and `w` [N, k] → (y [N, d], load [N, count] int32), the
+    part of `sum_k w_k expert_{picked_k}(x)` that the experts `held =
+    (first, count)` give, `expert(x) = (act(x G) * (x U)) D`.
+
+    `params`: the held experts' weights stacked, `gate`, `up`
+    [count, d, f], `down` [count, f, d]. Every shape is static: the N*k
+    (token, pick) pairs are sorted by expert, pairs whose expert lives
+    on another chip sort last, and the three products run grouped over
+    the held experts (`ragged_dot` with the per-expert counts): no
+    capacity, no token dropped however uneven the routing, and no dense
+    pass over every held expert for every token. `load[n, e]` is 1
+    where token n picked held expert e: the tick's counters are sums of
+    it.
+
+    Device scopes (under the caller's): `dispatch`, `experts`,
     `combine`."""
     first, count = held
-    N = x.shape[0]
-    with jax.named_scope("router"):
-        picked, w = sigmoid_topk_route(
-            x, params["router"], params["expert_bias"], top_k=top_k,
-            route_norm=route_norm, route_scale=route_scale)
+    N, top_k = picked.shape
     with jax.named_scope("dispatch"):
         local = picked - first
         here = (local >= 0) & (local < count)
@@ -281,7 +303,7 @@ def dropless_moe(x: jax.Array, params: dict, *, held: tuple[int, int],
         xs = x[order // top_k]                                  # [N*k, d]
         gate = lax.ragged_dot(xs, params["gate"], sizes)
         up = lax.ragged_dot(xs, params["up"], sizes)
-        ys = lax.ragged_dot(jax.nn.silu(gate) * up, params["down"], sizes)
+        ys = lax.ragged_dot(act(gate) * up, params["down"], sizes)
     with jax.named_scope("combine"):
         # back to (token, pick) order; rows past the held groups carry
         # whatever the grouped product left there and are zeroed
@@ -289,3 +311,18 @@ def dropless_moe(x: jax.Array, params: dict, *, held: tuple[int, int],
         ys = ys[back].reshape(N, top_k, -1).astype(jnp.float32)
         y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
     return y.astype(x.dtype), load
+
+
+def dropless_moe(x: jax.Array, params: dict, *, held: tuple[int, int],
+                 top_k: int, route_norm: bool = True,
+                 route_scale: float = 1.0):
+    """The routed part of `afmoe`'s expert layer: the sigmoid router on
+    x [N, d], then `grouped_experts` (SwiGLU) on the same x, for the
+    experts `held` → (y [N, d], load [N, count] int32). `params`:
+    `router` [d, E], `expert_bias` [E] and the stacked `gate`, `up`,
+    `down`. Device scopes: `router`, then the expert step's."""
+    with jax.named_scope("router"):
+        picked, w = sigmoid_topk_route(
+            x, params["router"], params["expert_bias"], top_k=top_k,
+            route_norm=route_norm, route_scale=route_scale)
+    return grouped_experts(x, picked, w, params, held=held)
