@@ -290,43 +290,110 @@ def _chain_view(pool, block_tables):
     return g.swapaxes(2, 3).reshape(B, MB * bs, Hkv, D)
 
 
+def _kv_write_rows(k_pool, v_pool, k, v, block_tables, base):
+    """`paged_kv_write` a position at a time: D-wide rows of the pool
+    seen as [NB*Hkv*bs, D] (a free reshape), row
+    `(phys*Hkv + h)*bs + off`. Right for any window; the grain of the
+    windows that fill no block: the decode tick's 48 rows at 48
+    unrelated places, a verify window, a bucket under a block. (A
+    scatter of [Hkv, D] windows at (phys, :, off) made XLA re-lay out
+    the whole pool around it for windows of 2..64 tokens: two
+    pool-sized temporaries a call.) A row costs a v5e 68 ns whatever
+    it holds, so a 2048-token prompt pays 1.1 ms a pool here (PERF.md
+    section 6, PR 32)."""
+    T = k.shape[1]
+    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    MB = block_tables.shape[1]
+    L = MB * bs
+    cols = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    phys = jnp.where(
+        cols < L,
+        jnp.take_along_axis(
+            block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
+        jnp.int32(0),
+    )
+    off = cols % bs
+    rows = ((phys[:, :, None] * Hkv
+             + jnp.arange(Hkv, dtype=jnp.int32)) * bs
+            + off[:, :, None])                      # [B, T, Hkv]
+
+    def write(pool, new):
+        flat = pool.reshape(-1, pool.shape[-1])
+        return flat.at[rows].set(new.astype(pool.dtype)).reshape(
+            pool.shape)
+
+    return write(k_pool, k), write(v_pool, v)
+
+
+def _kv_write_blocks(k_pool, v_pool, k, v, block_tables, base):
+    """`paged_kv_write` a block at a time, for a window of whole blocks
+    (`T % bs == 0`) whose every `base` is a multiple of `bs`: the new
+    positions regrouped to the pool's own `[Hkv, bs, D]` blocks and
+    scattered along its leading axis, one whole block a table entry
+    (128 copies of 32 KiB for a 2048-token prompt at Mistral's widths
+    where the rows are 16,384 of 256 B). No window is partial, so the
+    pool keeps its layout. Entries the table does not cover or names
+    null (bucket padding, a block a windowed layer let go, an inactive
+    lane) are dropped, not written to block 0: every index left is a
+    block of its own."""
+    B, T = k.shape[0], k.shape[1]
+    NB, Hkv, bs, D = k_pool.shape
+    MB = block_tables.shape[1]
+    nb = T // bs
+    idx = (base // bs)[:, None] + jnp.arange(nb, dtype=jnp.int32)[None, :]
+    phys = jnp.where(
+        idx < MB,
+        jnp.take_along_axis(
+            block_tables, jnp.clip(idx, 0, MB - 1), axis=1),
+        jnp.int32(0),
+    ).reshape(-1)                                   # [B * nb]
+    # past the pool's end, each at a place of its own: dropped
+    phys = jnp.where(
+        phys > 0, phys, NB + jnp.arange(B * nb, dtype=jnp.int32))
+
+    def write(pool, new):
+        blocks = (new.astype(pool.dtype).reshape(B, nb, bs, Hkv, D)
+                  .swapaxes(2, 3).reshape(B * nb, Hkv, bs, D))
+        return pool.at[phys].set(
+            blocks, mode="drop", unique_indices=True)
+
+    return write(k_pool, k), write(v_pool, v)
+
+
+def kv_write_by_block(T: int, bs: int, base):
+    """Whether `paged_kv_write` puts a window of `T` positions from
+    `base` into a pool of `bs`-position blocks a block at a time: the
+    window is whole blocks (static) and every `base` is a multiple of
+    `bs` (a value: a traced array in the program, the host's own
+    number where the engine counts what a call will do)."""
+    return T % bs == 0 and (base % bs == 0).all()
+
+
 def paged_kv_write(cache, k, v, block_tables, base):
-    """`kv_write`: scatter the T new positions of each row, logical
+    """`kv_write`: put the T new positions of each row, logical
     positions `base[b]..base[b]+T-1`, into the pooled cache
     `{'k','v': [NB, Hkv, bs, D]}` through `block_tables` [B, MB].
     Returns the updated (k pool, v pool). Anything the table does not
     cover (bucket padding, inactive lanes, a block a windowed layer has
-    let go) lands in the null block 0, where garbage is harmless by
-    contract."""
-    T = k.shape[1]
-    Hkv, bs = cache["k"].shape[1], cache["k"].shape[2]
-    MB = block_tables.shape[1]
-    L = MB * bs
-    # `kv_write`: where each new position goes, then the scatter
+    let go) lands in the null block 0 or nowhere; what block 0 holds is
+    garbage by contract.
+
+    The grain follows the window. One that fills no whole block (`T`
+    under `bs` or no multiple of it: the tick, a verify window, the
+    smallest bucket) goes row by row. A window of whole blocks (a
+    prompt's bucket, a chunk) goes block by block when every `base` is
+    a multiple of `bs`, which the program asks at run time: a prompt
+    starts at 0 or at a chunk's multiple of `bs`, but a prefix-cache
+    hit that ends mid-block starts it inside one, and then the rows
+    write it. Both leave every block but 0 the same, bit for bit."""
+    T, bs = k.shape[1], cache["k"].shape[2]
+    args = (cache["k"], cache["v"], k, v, block_tables, base)
     with jax.named_scope("kv_write"):
-        cols = base[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        phys = jnp.where(
-            cols < L,
-            jnp.take_along_axis(
-                block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
-            jnp.int32(0),
-        )
-        off = cols % bs
-        # scatter D-wide rows of the pool seen as [NB*Hkv*bs, D] (a
-        # free reshape): row (phys*Hkv + h)*bs + off. Scattering
-        # [Hkv, D] windows at (phys, :, off) instead made XLA
-        # re-lay out the whole pool around the scatter for windows
-        # of 2..64 tokens — two pool-sized temporaries per call.
-        rows = ((phys[:, :, None] * Hkv
-                 + jnp.arange(Hkv, dtype=jnp.int32)) * bs
-                + off[:, :, None])                      # [B, T, Hkv]
-
-        def write(pool, new):
-            flat = pool.reshape(-1, pool.shape[-1])
-            return flat.at[rows].set(new.astype(pool.dtype)).reshape(
-                pool.shape)
-
-        return write(cache["k"], k), write(cache["v"], v)
+        by_block = kv_write_by_block(T, bs, base)
+        if by_block is False:       # statically: no window of whole blocks
+            return _kv_write_rows(*args)
+        return jax.lax.cond(
+            by_block, _kv_write_blocks, _kv_write_rows, *args)
 
 
 def paged_gather_read(q, ck, cv, block_tables, base, first=None,

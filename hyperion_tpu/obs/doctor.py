@@ -47,6 +47,7 @@ from hyperion_tpu.obs.registry import percentile
 from hyperion_tpu.obs.tickprof import (
     FLIGHT_NAME,
     WALK_COUNTERS,
+    WRITE_COUNTERS,
     flight_final_tick,
     read_flight,
 )
@@ -1228,12 +1229,21 @@ def render_markdown(d: dict) -> str:
         # how the decode ticks read the cache: the blocks the paged-
         # attention kernel walked, of the entries a gather copies,
         # summed over the layer kinds
-        walked, entries = (
-            sum(v or 0 for k, v in c.items() if k.startswith(name))
-            for name in WALK_COUNTERS)
+        def over_kinds(names):
+            return (sum(v or 0 for k, v in c.items() if k.startswith(name))
+                    for name in names)
+
+        walked, entries = over_kinds(WALK_COUNTERS)
         if entries:
             counted += (f"; read in place: {_fmt(walked)} "
                         f"of {_fmt(entries)} table entries")
+        # how the steps wrote it: whole blocks (a prompt's window that
+        # starts on a block) and positions row by row (the ticks' rows,
+        # a prompt that starts inside a block), summed over the kinds
+        blocks, rows = over_kinds(WRITE_COUNTERS)
+        if blocks or rows:
+            counted += (f"; written by block: {_fmt(blocks)} block(s), "
+                        f"row by row: {_fmt(rows)} position(s)")
         # an expert model: what its ticks sent to the experts held here
         ex = tp.get("experts") or {}
         if ex:

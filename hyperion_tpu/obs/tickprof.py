@@ -25,7 +25,9 @@ module is the one way to time it:
     routed to the experts held here (`experts`), and how much of the
     block tables the ticks' reads touched (`kv_blocks_walked` of
     `kv_table_entries`, and a windowed layer kind's under the same
-    names with `_<kind>` behind).
+    names with `_<kind>` behind), and how the steps wrote the pools
+    (`kv_blocks_written` whole blocks, `kv_rows_written` positions
+    row by row).
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -68,6 +70,12 @@ STEP_SPAN = "serve.step"
 # full layer kind's under these names, a windowed kind's with `_<kind>`
 # behind; flows, summed over a window's ticks
 WALK_COUNTERS = ("kv_blocks_walked", "kv_table_entries")
+# and its write counters (`Engine._count_write`), named the same way:
+# the whole blocks a step's prefill or chunk put into a kind's pool a
+# layer through `paged_kv_write`'s block path, and the positions that
+# went row by row (the tick's live rows, a prompt that starts inside a
+# block, a bucket under a block)
+WRITE_COUNTERS = ("kv_blocks_written", "kv_rows_written")
 
 
 class _Seg:
@@ -259,8 +267,10 @@ class TickProfiler:
             # of the table entries the window's decode ticks could have
             # gathered a layer, the blocks the paged-attention kernel
             # walked instead (0: the ticks gathered), by layer kind
+            # and how the window's steps wrote it: by block, by row
             for key in sorted({k for r in recs for k in r.get("c", {})
-                               if k.startswith(WALK_COUNTERS)}):
+                               if k.startswith(
+                                   WALK_COUNTERS + WRITE_COUNTERS)}):
                 out["counters"][key] = sum(
                     r.get("c", {}).get(key, 0) for r in recs)
         # an expert model's decode ticks (serve/engine.py

@@ -101,6 +101,7 @@ from hyperion_tpu.obs.heartbeat import host_rss_mb as hb_host_rss_mb
 from hyperion_tpu.obs.ledger import CompileLedger
 from hyperion_tpu.obs.tickprof import (
     WALK_COUNTERS,
+    WRITE_COUNTERS,
     FlightRecorder,
     TickProfiler,
     null_flight_recorder,
@@ -690,12 +691,21 @@ class Engine:
         # gather would have copied, under the tick record's names
         # (walked, entries): the full kind's bare, a windowed kind's
         # with its name behind
-        self._walk_names = {
-            k: tuple(f"{name}_{k}" if w else name for name in WALK_COUNTERS)
-            for k, w in self._kinds.items()}
+        def by_kind(names):
+            return {k: tuple(f"{name}_{k}" if w else name for name in names)
+                    for k, w in self._kinds.items()}
+
+        self._walk_names = by_kind(WALK_COUNTERS)
+        self._write_names = by_kind(WRITE_COUNTERS)
         self._no_walk = {
             name: 0 for names in self._walk_names.values() for name in names}
         self._walk_counted = self._no_walk
+        # and, named the same way, how this step's calls put keys and
+        # values into the pools (written by block, by row): summed over
+        # the step's prefills, its chunk and its tick
+        self._no_write = {
+            name: 0 for names in self._write_names.values() for name in names}
+        self._write_counted = dict(self._no_write)
         # what this step's decode tick counted on the device (an expert
         # model's picks: `_expert_counters`), fetched with its tokens
         self._tick_counted: dict[str, int] = {}
@@ -853,6 +863,8 @@ class Engine:
         Pb = bucket_len or self.bucket(P)
         self._last_prefill_bucket = Pb   # churn context for the ledger
         self._prefill_tokens += Pb
+        self._count_write(
+            {k: t[slot] for k, t in self._bts.items()}, start, Pb)
         prof, at = self.tickprof, {"bucket": Pb, "start": start}
         with prof.seg(f"{seg}/upload", **at):
             buf = np.full((1, Pb), self.cfg.pad_id, np.int32)
@@ -882,6 +894,32 @@ class Engine:
             (r is not None and s not in self._chunking
              for s, r in enumerate(self._slots)),
             bool, len(self._slots))
+
+    def _count_write(self, rows: dict, start: int, T: int) -> None:
+        """Add to the step's write counters what a call that writes the
+        `T` positions from `start` through a slot's table `rows` (by
+        kind) puts into each kind's pool, a layer: whole blocks where
+        `paged_kv_write` will take its block path (the program asks the
+        same question of the same numbers), else positions row by row;
+        either way only what lands in a mapped block (bucket padding
+        past the chain and blocks a windowed kind let go land nowhere
+        that counts). Host arithmetic."""
+        from hyperion_tpu.models.llama import kv_write_by_block
+
+        bs = self.cfg.block_size
+        by_block = kv_write_by_block(T, bs, np.int32(start))
+        lo, hi = start // bs, min(blocks_for(start + T, bs), self._mb)
+        span = np.arange(lo, hi)
+        # positions of the window inside each block it touches
+        held = np.minimum(start + T, (span + 1) * bs) \
+            - np.maximum(start, span * bs)
+        for k, row in rows.items():
+            blocks, positions = self._write_names[k]
+            live = np.asarray(row[lo:hi]) != 0
+            if by_block:
+                self._write_counted[blocks] += int(live.sum())
+            else:
+                self._write_counted[positions] += int(held[live].sum())
 
     def _count_walk(self) -> dict[str, int]:
         """The tick record's walk counters for the tick about to be
@@ -1367,6 +1405,7 @@ class Engine:
         if P - pos > C:
             prof, at = self.tickprof, {"bucket": C, "start": pos}
             self._prefill_tokens += C
+            self._count_write(ck["rows"], pos, C)
             t0 = _CLOCK()
             with prof.seg("chunk/upload", **at):
                 args = (jnp.asarray(np.asarray(prompt[pos:pos + C],
@@ -2020,7 +2059,11 @@ class Engine:
                 # how much of each kind's table the tick's read touched:
                 # the blocks the kernel's loops visit a layer (0 = the
                 # tick gathered), of the entries a gather copies a layer
-                **self._walk_counted)
+                **self._walk_counted,
+                # how the step's prefills, its chunk and its tick put
+                # keys and values into the pools, a layer of each kind:
+                # whole blocks, and positions row by row
+                **self._write_counted)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -2033,6 +2076,7 @@ class Engine:
         self._prefill_tokens = 0
         self._sampling_rows = self._restricted_rows = 0
         self._walk_counted = self._no_walk
+        self._write_counted = dict(self._no_write)
         self._tick_counted = {}
 
         if self._governor is not None:
@@ -2192,6 +2236,9 @@ class Engine:
                     self._restricted_rows += \
                         req.top_k > 0 or req.top_p < 1.0
             self._walk_counted = self._count_walk()
+            # the tick writes each live slot's window row by row
+            for _, positions in self._write_names.values():
+                self._write_counted[positions] += n_live * self._tick_width
             with prof.seg("draft"):
                 drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table

@@ -890,6 +890,56 @@ class TestTickSegments:
         else:
             assert "sorted the vocabulary" not in row
 
+    @pytest.mark.parametrize("steps,summed,sentence", [
+        # a model of one layer kind: a prefill by block, then two ticks
+        ([{"kv_blocks_written": 96, "kv_rows_written": 47},
+          {"kv_blocks_written": 0, "kv_rows_written": 48},
+          {"kv_blocks_written": 0, "kv_rows_written": 48}],
+         {"kv_blocks_written": 96, "kv_rows_written": 143},
+         "written by block: 96 block(s), row by row: 143 position(s)"),
+        # two kinds: the row sums them; the windowed kind's chunk lost
+        # the blocks its window let go
+        ([{"kv_blocks_written": 32, "kv_rows_written": 20,
+           "kv_blocks_written_window": 24, "kv_rows_written_window": 20}],
+         {"kv_blocks_written": 32, "kv_rows_written": 20,
+          "kv_blocks_written_window": 24, "kv_rows_written_window": 20},
+         "written by block: 56 block(s), row by row: 40 position(s)"),
+        # a prompt that a mid-block prefix hit started inside a block
+        ([{"kv_blocks_written": 0, "kv_rows_written": 512 + 3}],
+         {"kv_blocks_written": 0, "kv_rows_written": 515},
+         "written by block: 0 block(s), row by row: 515 position(s)"),
+        # a process that predates the counters says nothing
+        ([{}], {}, None),
+    ], ids=["one_kind", "two_kinds", "mid_block_hit", "older_records"])
+    def test_write_counters_of_the_windows_steps(self, tmp_path, steps,
+                                                 summed, sentence):
+        """The tick records' `kv_blocks_written` / `kv_rows_written`
+        (and a windowed kind's, `_<kind>` behind) are flows: the
+        snapshot sums each over the window's steps, and doctor's row
+        says both, summed over the kinds."""
+        from hyperion_tpu.obs import doctor
+
+        tp, clk = self._prof()
+        for n, c in enumerate(steps):
+            with tp.tick(n) as tk:
+                with tp.seg("device"):
+                    clk.advance(0.020)
+                tk.count(kv_tokens=100, prefill_tokens=0, **c)
+        snap = tp.snapshot(now=clk.wall())
+        got = {k: v for k, v in snap["counters"].items()
+               if "written" in k}
+        assert got == summed
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        if sentence:
+            assert sentence in row
+        else:
+            assert "written by block" not in row
+
     def test_records_without_the_counters_carry_no_tiers(self):
         # a process that predates the counters: nothing to roll up
         tp, clk = self._prof()

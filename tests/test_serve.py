@@ -2727,10 +2727,11 @@ class TestIntrospection:
         # the exposition and the flight record carry both
         assert set(eng.exposition()["tickprof"]["counters"]) == {
             "kv_tokens", "prefill_tokens", "kv_blocks_walked",
-            "kv_table_entries"}
+            "kv_table_entries", "kv_blocks_written", "kv_rows_written"}
         assert set(eng._flight_payload()["ticks"][-1]["c"]) == {
             "kv_tokens", "prefill_tokens", "sampling_rows",
-            "restricted_rows", "kv_blocks_walked", "kv_table_entries"}
+            "restricted_rows", "kv_blocks_walked", "kv_table_entries",
+            "kv_blocks_written", "kv_rows_written"}
 
     def test_tick_counters_follow_the_slots(self, llama):
         """`kv_tokens` is host bookkeeping of what the live slots hold in
@@ -2768,6 +2769,46 @@ class TestIntrospection:
                                for r in reqs) > done_before
         assert seen_finish and eng.metrics.summary()["preempted"] > 0
         assert eng.tickprof.tail(1)[0]["c"]["kv_tokens"] == 0
+
+    def test_write_counters_follow_the_grain(self, llama):
+        """`kv_blocks_written` / `kv_rows_written` against hand
+        arithmetic: a prompt from position 0 goes in by whole blocks
+        (the mapped entries its bucket covers), one that a mid-block
+        prefix hit starts inside a block goes row by row (its bucket is
+        whole blocks: the program's run-time branch), and a tick writes
+        a row a live slot."""
+        eng = _engine(llama, block_size=8)
+        eng.warmup([26])
+        rng = np.random.default_rng(33)
+        A = rng.integers(1, 250, 24).astype(np.int32)
+        B = np.concatenate([A[:20], rng.integers(1, 250, 6).astype(np.int32)])
+
+        def counted():
+            c = eng.tickprof.tail(1)[0]["c"]
+            return c["kv_blocks_written"], c["kv_rows_written"]
+
+        eng.submit(Request(prompt_ids=A, max_new_tokens=4, id="wA"))
+        eng.step()
+        # 24 positions from 0 in a bucket of 32: three mapped blocks of
+        # 8 (the bucket's fourth is padding: dropped), then the tick's
+        # one row
+        assert counted() == (3, 1)
+        eng.step()
+        assert counted() == (0, 1)
+        _drain(eng)
+        eng.submit(Request(prompt_ids=B, max_new_tokens=4, id="wB"))
+        eng.step()
+        # the radix cache holds A's two whole blocks and forks its third
+        # at the 20th token: the prefill starts at 20, inside a block;
+        # its 6 positions ride a bucket of 8, all inside mapped blocks
+        assert eng.metrics.summary()["cow_copies"] == 1
+        assert counted() == (0, 8 + 1)
+        _drain(eng)
+        # the exposition sums the window's steps; the doctor says both
+        snap = eng.exposition()["tickprof"]["counters"]
+        ticks = sum("device" in r["s"] for r in eng.tickprof.tail(64))
+        assert snap["kv_blocks_written"] == 3
+        assert snap["kv_rows_written"] == 8 + ticks
 
     def test_profiled_run_compiles_nothing(self, llama, tmp_path):
         """The acceptance criterion: `compile_stats()` flat across a

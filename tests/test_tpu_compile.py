@@ -276,3 +276,145 @@ class TestSamplingTiersCompile:
         # every sort of the program sits under the third branch
         assert sum(b.count(" sort(") for b in comps.values()) == \
             sorts(branches[2])
+
+
+def _parent_paged_kv_write(cache, k, v, block_tables, base):
+    """`models/llama.py` `paged_kv_write` as it stood before PR 32: the
+    row scatter alone, whatever the window."""
+    from hyperion_tpu.models import llama
+
+    with jax.named_scope("kv_write"):
+        return llama._kv_write_rows(
+            cache["k"], cache["v"], k, v, block_tables, base)
+
+
+class TestPrefillKvWriteCompiles:
+    """Not a kernel: a prompt's `kv_write` inside the Mistral cell's
+    `[1, 2048]` prefill: two layers of the cell's sixteen at its widths
+    and its pool of 6144 blocks, over a vocabulary of 512 (the layers
+    repeat; the sampler's sorts over 32000 ids are most of the whole
+    program's 40 s compile and none of this test's business; PERF.md
+    has the whole programs). What only the chip's compiler shows: that the
+    run-time choice between the two grains stays a conditional with the
+    row scatter in one branch and the block scatter in the other, that
+    the pools stay aliased through it, and what it does to the
+    program's temporaries."""
+
+    LAYERS = 2
+
+    def _prefill(self, one_chip, twin=False):
+        from hyperion_tpu.models.llama import (
+            Llama,
+            LlamaConfig,
+            init_paged_cache,
+        )
+        from hyperion_tpu.serve import engine as E
+
+        class Twin(Llama):
+            """The same model under another identity: a jit's traces
+            are keyed by the model, so the twin is traced afresh."""
+
+        cfg = LlamaConfig(
+            vocab_size=512, d_model=4096, n_layers=self.LAYERS,
+            n_heads=32, n_kv_heads=8, ff_dim=14336, max_len=2048,
+            rope_theta=1e6, remat=False, dtype="bfloat16")
+        model = (Twin if twin else Llama)(cfg)
+        slots, bucket, mb = 48, 2048, 128
+
+        def on_chip(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        params = on_chip(jax.eval_shape(
+            lambda: model.init_params(jax.random.key(0))))
+        cache = on_chip(jax.eval_shape(
+            lambda: init_paged_cache(cfg, 6144, 16)))
+        st = on_chip(jax.eval_shape(lambda: {
+            "lengths": jnp.zeros((slots,), jnp.int32),
+            "active": jnp.zeros((slots,), bool),
+            "last_token": jnp.zeros((slots,), jnp.int32),
+            "generated": jnp.zeros((slots,), jnp.int32),
+            "budget": jnp.ones((slots,), jnp.int32),
+            "temperature": jnp.zeros((slots,), jnp.float32),
+            "top_k": jnp.zeros((slots,), jnp.int32),
+            "top_p": jnp.ones((slots,), jnp.float32),
+            "keys": jax.random.split(jax.random.key(0), slots)}))
+
+        def of(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        i32, f32 = jnp.int32, jnp.float32
+        compiled = jax.jit(
+            E._prefill_impl, static_argnums=(0, 1), donate_argnums=(3, 4),
+        ).lower(
+            model, None, {"params": params}, cache, st,
+            of(i32, 1, bucket), {"full": of(i32, mb)}, of(i32), of(i32),
+            of(i32), of(f32), of(i32), of(f32), of(i32),
+            on_chip(jax.eval_shape(lambda: jax.random.key(0))),
+        ).compile()
+        pools = sum(a.size * a.dtype.itemsize
+                    for a in jax.tree.leaves(cache))
+        return compiled, pools
+
+    def test_conditional_survives_and_the_pools_stay_in_place(
+            self, one_chip, monkeypatch):
+        from hyperion_tpu.models import llama
+
+        compiled, pools = self._prefill(one_chip)
+        comps = _computations(compiled.as_text())
+
+        def reachable(name, seen):
+            if name in seen or name not in comps:
+                return seen
+            seen.add(name)
+            for other in comps:
+                if other != name and "%" + other in comps[name]:
+                    reachable(other, seen)
+            return seen
+
+        def scatters(name, operand):
+            # the row scatter writes the pool seen as rows of D, the
+            # block scatter the pool as it lies
+            return sum(
+                operand in ln for c in reachable(name, set())
+                for ln in comps[c].splitlines() if " scatter(" in ln)
+
+        rows, blocks = "bf16[786432,128]", "bf16[6144,8,16,128]"
+        conds = [ln for b in comps.values() for ln in b.splitlines()
+                 if " conditional(" in ln and blocks in ln]
+        assert len(conds) == self.LAYERS
+        for cond in conds:
+            if "branch_computations={" in cond:
+                names = cond.split("branch_computations={")[1].split("}")[0]
+                names = [n.strip().lstrip("%") for n in names.split(",")]
+            else:
+                names = [cond.split(key + "=")[1].split(",")[0].split(")")[0]
+                         .strip().lstrip("%")
+                         for key in ("false_computation", "true_computation")]
+            assert len(names) == 2
+            # one branch row by row, the other block by block: two
+            # pools each, and neither grain in the other's branch
+            assert [scatters(n, rows) for n in names] == [2, 0]
+            assert [scatters(n, blocks) for n in names] == [0, 2]
+        # every pool scatter of the program sits under a conditional
+        assert sum(ln.count(rows) > 0 or ln.count(blocks) > 0
+                   for b in comps.values() for ln in b.splitlines()
+                   if " scatter(" in ln) == 4 * self.LAYERS
+        # in place: every pool is aliased input to output, and nothing
+        # of a pool's size (402 MB here) is among the temporaries
+        ma = compiled.memory_analysis()
+        assert ma.alias_size_in_bytes >= pools
+        one_pool = pools // (2 * self.LAYERS)
+        # against the program the parent lowered: the row scatter alone
+        monkeypatch.setattr(llama, "paged_kv_write", _parent_paged_kv_write)
+        parent, _ = self._prefill(one_chip, twin=True)
+        assert " conditional(" not in "".join(
+            ln for b in _computations(parent.as_text()).values()
+            for ln in b.splitlines() if blocks in ln)
+        grown = ma.temp_size_in_bytes \
+            - parent.memory_analysis().temp_size_in_bytes
+        # the new keys' and values' block forms and the conditional's
+        # operands (4 MiB each at this bucket) may lie in HBM where the
+        # parent's lay in VMEM: 16 MiB at sixteen layers (PERF.md
+        # section 6, PR 32), never a pool
+        assert grown <= 4 * (2048 * 8 * 128 * 2) < one_pool // 8
