@@ -188,10 +188,17 @@ class RMSNorm(nn.Module):
             from hyperion_tpu.ops.pallas.fused_norm import fused_rmsnorm
 
             return fused_rmsnorm(x, w, eps=self.eps)
-        # variance in fp32 (bf16 squares underflow), scale in compute dtype
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-        normed = x * jax.lax.rsqrt(var + self.eps).astype(x.dtype)
-        return normed * w.astype(self.dtype)
+        return rms_norm(x, w, self.eps, self.dtype)
+
+
+def rms_norm(x, w, eps: float, dtype):
+    """`RMSNorm`'s arithmetic on a scale `w` handed in: for a model
+    whose layers run inside a `lax` loop over stacked weights, where no
+    module can be called."""
+    # variance in fp32 (bf16 squares underflow), scale in compute dtype
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
+    normed = x * jax.lax.rsqrt(var + eps).astype(x.dtype)
+    return normed * w.astype(dtype)
 
 
 def rope_frequencies(head_dim: int, max_len: int, theta: float) -> jax.Array:
@@ -290,7 +297,7 @@ def _chain_view(pool, block_tables):
     return g.swapaxes(2, 3).reshape(B, MB * bs, Hkv, D)
 
 
-def _kv_write_rows(k_pool, v_pool, k, v, block_tables, base):
+def _kv_write_rows(k_pool, v_pool, k, v, block_tables, base, shift=None):
     """`paged_kv_write` a position at a time: D-wide rows of the pool
     seen as [NB*Hkv*bs, D] (a free reshape), row
     `(phys*Hkv + h)*bs + off`. Right for any window; the grain of the
@@ -312,6 +319,10 @@ def _kv_write_rows(k_pool, v_pool, k, v, block_tables, base):
             block_tables, jnp.clip(cols // bs, 0, MB - 1), axis=1),
         jnp.int32(0),
     )
+    if shift is not None:
+        # after the null routing: an uncovered position lands in the
+        # segment's own null block
+        phys = phys + shift
     off = cols % bs
     rows = ((phys[:, :, None] * Hkv
              + jnp.arange(Hkv, dtype=jnp.int32)) * bs
@@ -325,7 +336,7 @@ def _kv_write_rows(k_pool, v_pool, k, v, block_tables, base):
     return write(k_pool, k), write(v_pool, v)
 
 
-def _kv_write_blocks(k_pool, v_pool, k, v, block_tables, base):
+def _kv_write_blocks(k_pool, v_pool, k, v, block_tables, base, shift=None):
     """`paged_kv_write` a block at a time, for a window of whole blocks
     (`T % bs == 0`) whose every `base` is a multiple of `bs`: the new
     positions regrouped to the pool's own `[Hkv, bs, D]` blocks and
@@ -349,7 +360,8 @@ def _kv_write_blocks(k_pool, v_pool, k, v, block_tables, base):
     ).reshape(-1)                                   # [B * nb]
     # past the pool's end, each at a place of its own: dropped
     phys = jnp.where(
-        phys > 0, phys, NB + jnp.arange(B * nb, dtype=jnp.int32))
+        phys > 0, phys if shift is None else phys + shift,
+        NB + jnp.arange(B * nb, dtype=jnp.int32))
 
     def write(pool, new):
         blocks = (new.astype(pool.dtype).reshape(B, nb, bs, Hkv, D)
@@ -369,14 +381,24 @@ def kv_write_by_block(T: int, bs: int, base):
     return T % bs == 0 and (base % bs == 0).all()
 
 
-def paged_kv_write(cache, k, v, block_tables, base):
+def segment_shift(pool, segment, segments: int):
+    """What `paged_kv_write` and `paged_read` add to a table's entries
+    to address segment `segment` (a traced scalar inside a loop) of a
+    pool `[segments * NB, Hkv, bs, D]` that holds `segments` caches
+    behind one table (`init_paged_cache`, a config's `cache_segments`):
+    `segment * NB`. Block `segment * NB` is the segment's null block."""
+    return jnp.asarray(segment, jnp.int32) * (pool.shape[0] // segments)
+
+
+def paged_kv_write(cache, k, v, block_tables, base, shift=None):
     """`kv_write`: put the T new positions of each row, logical
     positions `base[b]..base[b]+T-1`, into the pooled cache
     `{'k','v': [NB, Hkv, bs, D]}` through `block_tables` [B, MB].
     Returns the updated (k pool, v pool). Anything the table does not
     cover (bucket padding, inactive lanes, a block a windowed layer has
     let go) lands in the null block 0 or nowhere; what block 0 holds is
-    garbage by contract.
+    garbage by contract. `shift` (`segment_shift`) moves every entry,
+    the null one too, into one segment of a pool that holds several.
 
     The grain follows the window. One that fills no whole block (`T`
     under `bs` or no multiple of it: the tick, a verify window, the
@@ -388,6 +410,8 @@ def paged_kv_write(cache, k, v, block_tables, base):
     write it. Both leave every block but 0 the same, bit for bit."""
     T, bs = k.shape[1], cache["k"].shape[2]
     args = (cache["k"], cache["v"], k, v, block_tables, base)
+    if shift is not None:
+        args += (shift,)
     with jax.named_scope("kv_write"):
         by_block = kv_write_by_block(T, bs, base)
         if by_block is False:       # statically: no window of whole blocks
@@ -440,13 +464,19 @@ def paged_gather_read(q, ck, cv, block_tables, base, first=None,
     return _grouped_cache_attention(q, kview, vview, mask, rep)
 
 
-def paged_read(impl: str, q, ck, cv, block_tables, base, window: int = 0):
+def paged_read(impl: str, q, ck, cv, block_tables, base, window: int = 0,
+               shift=None):
     """A paged layer's read by the path `impl` names ("pallas" or
     "gather": `select_paged_attn_impl`'s answer for the call, or a
     config's explicit value): q [B, T, H, D] at positions
     `base[b]..base[b]+T-1` against the pools through one layer kind's
     table, a full layer's (`window` 0) or a windowed one's (a query at
-    p sees keys `p - window < j <= p`)."""
+    p sees keys `p - window < j <= p`). `shift` (`segment_shift`) reads
+    one segment of pools that hold several: both paths are handed the
+    shifted table and stay as they are (a null entry reads the
+    segment's null block, masked by position as block 0 is)."""
+    if shift is not None:
+        block_tables = block_tables + shift
     if impl == "pallas":
         # read the pools in place: the kernel walks each row's live
         # blocks itself, so no contiguous copy is materialized. Its
@@ -654,15 +684,26 @@ def init_paged_cache(cfg, num_blocks, block_size: int,
     `num_blocks` is one number for every layer, or `{kind: number}` for
     a model whose `cfg.layer_kinds` names more than one kind: a layer's
     pool has its kind's size, and is addressed through its kind's
-    table."""
+    table. A model that applies its layers `cfg.cache_steps` times a
+    token (every other model: once) keeps that many caches a layer,
+    and one that runs its layers as a `lax` loop over stacked weights
+    keeps the caches of `cfg.pool_layers` layers in one array, because
+    a loop's body cannot pick an array by a traced index. Its pools
+    are `[cfg.cache_segments * n, ...]` (`cache_steps x pool_layers`
+    segments): each segment (blocks `s * n ..`, the first of them the
+    segment's null block) holds one layer's keys and values of one
+    step, all behind the one table (`segment_shift`)."""
     dtype = dtype or cfg.compute_dtype
+    segments = getattr(cfg, "cache_segments", 1)
 
     def pool(kind):
         n = num_blocks[kind] if isinstance(num_blocks, dict) else num_blocks
-        shape = (n, cfg.n_kv_heads, block_size, cfg.head_dim)
+        shape = (segments * n, cfg.n_kv_heads, block_size, cfg.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
-    return [pool(kind) for kind, _ in cfg.layer_kinds]
+    # one pool a layer, or one a run of `pool_layers` layers
+    return [pool(kind) for kind, _ in
+            cfg.layer_kinds[::getattr(cfg, "pool_layers", 1)]]
 
 
 def paged_cache_block_bytes(cfg, block_size: int, dtype=None,
@@ -670,10 +711,13 @@ def paged_cache_block_bytes(cfg, block_size: int, dtype=None,
     """HBM bytes one physical block costs across all layers (K and V) —
     the unit the serve cache-pressure gauges are denominated in. With
     `kind`, across the layers of that kind: a block of that kind's
-    pool."""
+    pool. A block id names `block_size` positions in every one of the
+    model's `cache_steps` segments, so it costs that many times a
+    layer's bytes."""
     dtype = jnp.dtype(dtype or cfg.compute_dtype)
     n_layers = cfg.n_layers if kind is None else sum(
         k == kind for k, _ in cfg.layer_kinds)
+    n_layers *= getattr(cfg, "cache_steps", 1)
     return (2 * n_layers * block_size * cfg.n_kv_heads
             * cfg.head_dim * dtype.itemsize)
 

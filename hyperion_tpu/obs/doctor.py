@@ -1250,6 +1250,12 @@ def render_markdown(d: dict) -> str:
             counted += (f"; {_fmt(ex['picks_held_per_tick'])} picks a tick "
                         f"on {_fmt(ex['touched_per_tick'])} held experts, "
                         f"the busiest got {_fmt(ex['load_max'])}")
+        # a looped model: how often a tick ran its layers over a row
+        lp = tp.get("loop") or {}
+        if lp:
+            counted += (f"; {_fmt(lp['steps'])} passes over "
+                        f"{_fmt(lp['layer_passes'] // lp['steps'])} layers "
+                        f"a tick ({_fmt(lp['layer_passes'])} cache layers)")
         # a tick whose rows restrict their support (top_k / top_p) runs
         # the sampled path's sorts for every row: worth a sentence
         st = tp.get("sampling_tiers") or {}

@@ -286,6 +286,14 @@ class TickProfiler:
                 "touched_per_tick": round(sum(
                     c["experts_touched"] for c in picks) / len(picks), 3),
                 "load_max": max(c["expert_load_max"] for c in picks)}
+        # a looped model's decode ticks (serve/engine.py): the steps a
+        # tick ran every row through its layers, and the cache layers
+        # it wrote and read (steps x layers), as of the newest tick
+        loops = [r["c"] for r in recs if "loop_steps" in r.get("c", {})]
+        if loops:
+            out["loop"] = {"ticks": len(loops),
+                           "steps": loops[-1]["loop_steps"],
+                           "layer_passes": loops[-1]["layer_passes"]}
         # which tier of `sample_token_slots` each decode tick of the
         # window ran, from what its rows asked for (a step with no
         # `device` segment ran no tick)

@@ -513,6 +513,21 @@ class Engine:
             NgramDraft() if self._spec else None
         bs = cfg.block_size
         self._mb = blocks_for(L, bs)          # block-table width per slot
+        # a looped model (models/ouro.py) applies its layers
+        # `cache_steps` times a token and keeps a cache a step and
+        # layer, `cache_segments` of them in each pool array, all behind
+        # the one table; every other model 1 and 1. A block id then
+        # names `bs` positions in every segment: block bytes, the
+        # copy-on-write copy and the host tier's payload follow
+        self._steps: int = getattr(mcfg, "cache_steps", 1)
+        self._segments: int = getattr(mcfg, "cache_segments", 1)
+        # what a decode tick of such a model adds to its record: the
+        # steps it ran a row and the cache layers it wrote and read,
+        # from the config (no device read); nothing for the others
+        self._loop_per_tick = {
+            "loop_steps": self._steps,
+            "layer_passes": self._steps * mcfg.n_layers,
+        } if self._steps > 1 else {}
         # the cache by layer kind: {kind: window}, 0 = every position.
         # `self.mgr`, `self._bt`, `self._seqs` are the first kind's
         # (`full` where the model has one): the chain that carries a
@@ -709,6 +724,7 @@ class Engine:
         # what this step's decode tick counted on the device (an expert
         # model's picks: `_expert_counters`), fetched with its tokens
         self._tick_counted: dict[str, int] = {}
+        self._loop_counted: dict[str, int] = {}
         self._last_prefill_bucket: int | None = None
         # `.nbytes` is shape metadata — summing it syncs nothing
         self._param_bytes = int(sum(
@@ -827,7 +843,7 @@ class Engine:
                     {k: jnp.zeros((self._mb,), jnp.int32)
                      for k in self._kinds}, jnp.int32(0))
                 compile_s["chunk"] = round(time.perf_counter() - t0, 4)
-            zero = jnp.zeros((1,), jnp.int32)
+            zero = self._in_every_segment([0])
             t0 = time.perf_counter()
             self._cache = self._copy_jit(self._cache, zero, zero)
             compile_s["copy"] = round(time.perf_counter() - t0, 4)
@@ -1127,6 +1143,16 @@ class Engine:
                     self._bt_dev = None
         return True
 
+    def _in_every_segment(self, blocks) -> jax.Array:
+        """Block ids as the pools hold them: of a model whose pool
+        arrays hold `cache_segments` caches one id a segment,
+        segment-major (segment `s` starts at `s * num_blocks`:
+        `init_paged_cache`); of every other model the ids themselves."""
+        ids = np.asarray(blocks, np.int32)
+        starts = self.cfg.num_blocks * np.arange(
+            self._segments, dtype=np.int32)
+        return jnp.asarray((starts[:, None] + ids[None, :]).reshape(-1))
+
     def _spill_block(self, chain_tokens: tuple[int, ...],
                      block: int) -> None:
         """Radix eviction's demotion hook (blocks.py `_drop`): read the
@@ -1134,10 +1160,13 @@ class Engine:
         array and hand it to the host tier keyed by its full chain
         prefix. Eager per-layer D2H reads — none of the engine's
         tracked jits are involved, so `compile_stats()` stays flat."""
+        ids = self._in_every_segment([block])
         payload = np.stack([
-            np.stack([np.asarray(layer["k"][block]),
-                      np.asarray(layer["v"][block])])
-            for layer in self._cache])  # [L, 2, H, bs, D]
+            np.stack([np.asarray(layer["k"][ids]),
+                      np.asarray(layer["v"][ids])], axis=1)
+            for layer in self._cache])  # [pools, segments, 2, H, bs, D]
+        # a cache layer an entry
+        payload = payload.reshape(-1, *payload.shape[2:])
         if self.host.put(chain_tokens, payload):
             self.metrics.on_host_spill(payload.nbytes)
             self.metrics.observe_host_cache(
@@ -1151,13 +1180,17 @@ class Engine:
         D2H/H2D round trip in the pool's own dtype is bit-exact, so a
         restored stream matches the never-evicted run. Returns bytes
         moved."""
-        ids = jnp.asarray(np.asarray(blocks, np.int32))
-        stacked = np.stack(payloads)  # [n, L, 2, H, bs, D]
+        ids = self._in_every_segment(blocks)    # segment-major
+        stacked = np.stack(payloads)  # [n, pools * segments, 2, H, bs, D]
         moved = int(stacked.nbytes)
+        n, segs = len(blocks), self._segments
         dev = jax.device_put(stacked)
+        # [pools, 2, segments * n, H, bs, D]: a pool's blocks in `ids`' order
+        dev = dev.reshape(n, -1, segs, *dev.shape[2:]).transpose(
+            1, 3, 2, 0, 4, 5, 6).reshape(-1, 2, segs * n, *dev.shape[3:])
         self._cache = [
-            {"k": layer["k"].at[ids].set(dev[:, li, 0]),
-             "v": layer["v"].at[ids].set(dev[:, li, 1])}
+            {"k": layer["k"].at[ids].set(dev[li, 0]),
+             "v": layer["v"].at[ids].set(dev[li, 1])}
             for li, layer in enumerate(self._cache)
         ]
         return moved
@@ -1252,9 +1285,9 @@ class Engine:
             # mid-block divergence: duplicate the agreeing block so our
             # writes (suffix prefill + decode) never touch the shared
             # original — the copy-on-write half of the design
-            idx = jnp.asarray([cow_src], jnp.int32)
             self._cache = self._copy_jit(
-                self._cache, idx, jnp.asarray([fresh[0]], jnp.int32))
+                self._cache, self._in_every_segment([cow_src]),
+                self._in_every_segment([fresh[0]]))
             self.mgr.decref([cow_src])  # the pin; the copy is ours now
             self.metrics.on_cow()
         if host_payloads:
@@ -1955,6 +1988,10 @@ class Engine:
             "blocks_in_use_bytes": sum(
                 v["in_use_bytes"] for v in by_kind.values()),
             "kv_by_kind": by_kind,
+            # what one position costs over every layer (and every step
+            # of a looped model's cache)
+            "kv_bytes_per_token": sum(
+                self._kind_block_bytes.values()) // self.cfg.block_size,
             "kv_gather_bytes_per_tick": gather,
             # the host tier's occupancy rides the same ledger the HBM
             # numbers do — spilled KV is memory too, just cheaper
@@ -2049,6 +2086,9 @@ class Engine:
                    for k, w in self._kinds.items() if w},
                 # an expert model's picks on this step's decode tick
                 **self._tick_counted,
+                # a looped model's decode tick: the steps it ran a row
+                # and the cache layers it wrote and read (steps x layers)
+                **self._loop_counted,
                 prefill_tokens=self._prefill_tokens,
                 # what the tick's rows asked of `sample_token_slots`,
                 # from the requests' own parameters: 0 and 0 = the tick
@@ -2078,6 +2118,7 @@ class Engine:
         self._walk_counted = self._no_walk
         self._write_counted = dict(self._no_write)
         self._tick_counted = {}
+        self._loop_counted = {}
 
         if self._governor is not None:
             tr = self._governor.update(len(self.queue))
@@ -2236,6 +2277,7 @@ class Engine:
                     self._restricted_rows += \
                         req.top_k > 0 or req.top_p < 1.0
             self._walk_counted = self._count_walk()
+            self._loop_counted = self._loop_per_tick
             # the tick writes each live slot's window row by row
             for _, positions in self._write_names.values():
                 self._write_counted[positions] += n_live * self._tick_width

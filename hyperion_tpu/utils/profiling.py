@@ -106,11 +106,15 @@ def on_demand_trace(out_dir: str | Path, seconds: float) -> dict:
         _TRACE_ACTIVE.append(out)
 
     def _stop():
+        # outside the lock: stopping writes the trace out, which takes
+        # seconds on a busy host, and a request that arrives meanwhile
+        # is answered `busy` at once (the trace is still the active
+        # one) instead of waiting on the lock past its client's timeout
+        try:
+            jax.profiler.stop_trace()
+        except Exception:  # noqa: BLE001 — best-effort teardown
+            pass
         with _TRACE_LOCK:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — best-effort teardown
-                pass
             _TRACE_ACTIVE.clear()
 
     t = threading.Timer(seconds, _stop)

@@ -493,6 +493,36 @@ class TestPagedAttention:
     def test_prefix_shared_chain(self):
         self._check(3, 2, 4, 4, seed=4, share_prefix=True)
 
+    @pytest.mark.parametrize("segment", [0, 1, 2])
+    def test_one_query_row_a_head_through_a_shifted_table(self, segment):
+        """A looped model's tick (models/ouro.py): plain multi-head, 16
+        query heads on 16 KV heads (ONE query row a KV head, padded to
+        a sublane tile), and pools that hold three segments behind one
+        table: `paged_read` hands the kernel the table shifted by
+        `segment * NB`, null entries too, and the kernel stays as it
+        is. Against the gather through the same shifted table, and
+        against the segment cut out of the pools and read through the
+        table as it was."""
+        from hyperion_tpu.models.llama import paged_read, segment_shift
+
+        B, H, MB, segs = 3, 16, 8, 3
+        q, kp, vp, bt, base = self._geometry(B, 1, H, H, MB=MB, seed=7)
+        NB = kp.shape[0]
+        ks = jax.random.split(jax.random.key(8), 2)
+        kp = jax.random.normal(ks[0], (segs * NB, *kp.shape[1:]))
+        vp = jax.random.normal(ks[1], (segs * NB, *vp.shape[1:]))
+        shift = segment_shift(kp, jnp.int32(segment), segs)
+        assert int(shift) == segment * NB
+        out = paged_read("pallas", q, kp, vp, bt, base, shift=shift)
+        ref = paged_read("gather", q, kp, vp, bt, base, shift=shift)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        cut = slice(segment * NB, (segment + 1) * NB)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(self._ref(q, kp[cut], vp[cut], bt, base)),
+            atol=2e-5, rtol=2e-5)
+
     def test_null_block_garbage_never_leaks(self):
         """Poison the null block with huge garbage: outputs must be
         BIT-identical to a zeroed null block — masked positions

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import io
 import json
+import threading
 import time
 from pathlib import Path
 
@@ -2829,6 +2830,45 @@ class TestIntrospection:
             time.sleep(0.45)    # let the daemon timer stop the trace
         assert eng.compile_stats() == stats0
         assert eng.ledger.recompiles == 0
+
+    def test_a_trace_being_written_out_answers_busy_at_once(
+            self, tmp_path, monkeypatch):
+        """Stopping a trace writes it out, seconds on a busy host; a
+        request that arrives meanwhile gets `busy` at once and does not
+        wait for the writer (it waited on the lock, past the control
+        client's 5 s: the answer the driver's loaded run never got)."""
+        from hyperion_tpu.utils import profiling
+
+        stopping, release = threading.Event(), threading.Event()
+
+        # a list of this test's own: an earlier test's trace may still
+        # be written out (over 10 s on the driver's loaded run), and its
+        # thread clears the list it knows
+        monkeypatch.setattr(profiling, "_TRACE_ACTIVE", [])
+
+        def idle():
+            for _ in range(200):
+                if not profiling._TRACE_ACTIVE:
+                    return True
+                time.sleep(0.05)
+            return False
+
+        def slow_stop():
+            stopping.set()
+            release.wait(10.0)
+
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda *a, **kw: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+        first = profiling.on_demand_trace(tmp_path / "a", 0.1)
+        assert first["status"] == "started"
+        assert stopping.wait(5.0)
+        t0 = time.monotonic()
+        second = profiling.on_demand_trace(tmp_path / "b", 0.1)
+        assert second == {"status": "busy", "dir": str(tmp_path / "a")}
+        assert time.monotonic() - t0 < 1.0
+        release.set()
+        assert idle()
 
     def test_profile_control_verb_answers(self, llama, tmp_path):
         """`obs profile` end to end minus the CLI: the control request

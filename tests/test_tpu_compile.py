@@ -155,6 +155,100 @@ class TestPagedAttentionCompiles:
         assert "gather" not in text
 
 
+    def test_ouro_tick_geometry(self, mosaic, one_chip):
+        """`ouro.shortchat-saturated`'s tick, the call each of its three
+        scans makes: 8 slots of one token, 16 heads on 16 KV heads of
+        128 (ONE query row a KV head: a block's copy is 64 KiB), tables
+        of 48 columns, a pool that holds 16 layers x 4 steps segments
+        of 385 blocks. Every KV head in a step, a slot's whole chain in
+        one group."""
+        S_, H, D, bs, MB, NB = 8, 16, 128, 16, 48, 64 * 385
+        assert paged_mod._plan(8, H, bs, D, 2, MB) == (H, 32)
+        pool = S((NB, H, bs, D), BF16)
+        assert NB * H * bs * D * 2 < 2 ** 31        # a pool's bytes
+        text = _compile(
+            paged_mod.paged_attention, one_chip,
+            S((S_, 1, H, D), BF16), pool, pool,
+            S((S_, MB), jnp.int32), S((S_,), jnp.int32),
+        )
+        assert "gather" not in text
+
+
+class TestOuroProgramsStayInPlace:
+    """The looped model's tick and prefill at the published widths, one
+    scan of 16 layers (the cell runs three such scans, one after the
+    other: a program's temporaries are those of one): the steps and the
+    layers are loops, the pools go through them aliased input to
+    output, and nothing of a pool's size is among the temporaries."""
+
+    def _compile(self, one_chip, program):
+        from hyperion_tpu.models.llama import init_paged_cache
+        from hyperion_tpu.models.ouro import Ouro, OuroConfig
+        from hyperion_tpu.serve import engine as E
+
+        slots, L, bs = 8, 768, 16
+        mb = L // bs
+        cfg = OuroConfig(
+            n_layers=16, pool_layers=16, max_len=L,
+            paged_attn_impl="pallas" if program == "tick" else "gather")
+        model = Ouro(cfg)
+
+        def on_chip(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: jax.ShapeDtypeStruct(
+                a.shape, jnp.float32 if path[-1].key == "weight" else BF16,
+                sharding=one_chip),
+            jax.eval_shape(lambda: model.init_params(jax.random.key(0))))
+        cache = on_chip(jax.eval_shape(
+            lambda: init_paged_cache(cfg, slots * mb + 1, bs)))
+        st = on_chip(jax.eval_shape(lambda: {
+            "lengths": jnp.zeros((slots,), jnp.int32),
+            "active": jnp.zeros((slots,), bool),
+            "last_token": jnp.zeros((slots,), jnp.int32),
+            "generated": jnp.zeros((slots,), jnp.int32),
+            "budget": jnp.ones((slots,), jnp.int32),
+            "temperature": jnp.zeros((slots,), jnp.float32),
+            "top_k": jnp.zeros((slots,), jnp.int32),
+            "top_p": jnp.ones((slots,), jnp.float32),
+            "keys": jax.random.split(jax.random.key(0), slots)}))
+
+        def of(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        i32, f32 = jnp.int32, jnp.float32
+        if program == "tick":
+            lowered = jax.jit(
+                E._tick_impl, static_argnums=(0, 1, 2),
+                donate_argnums=(4, 5),
+            ).lower(model, None, 0, {"params": params}, cache, st,
+                    {"full": of(i32, slots, mb)}, of(bool, slots))
+        else:
+            lowered = jax.jit(
+                E._prefill_impl, static_argnums=(0, 1),
+                donate_argnums=(3, 4),
+            ).lower(model, None, {"params": params}, cache, st,
+                    of(i32, 1, 512), {"full": of(i32, mb)}, of(i32),
+                    of(i32), of(i32), of(f32), of(i32), of(f32), of(i32),
+                    on_chip(jax.eval_shape(lambda: jax.random.key(0))))
+        pools = [a.size * a.dtype.itemsize for a in jax.tree.leaves(cache)]
+        return lowered.compile(), pools
+
+    @pytest.mark.parametrize("program", ["tick", "prefill"])
+    def test_no_pool_sized_temporary(self, mosaic, one_chip, program):
+        compiled, pools = self._compile(one_chip, program)
+        assert len(pools) == 2 and pools[0] == 64 * 385 * 16 * 16 * 128 * 2
+        ma = compiled.memory_analysis()
+        assert ma.alias_size_in_bytes >= sum(pools)
+        # 5-6 MiB at 48 layers (PERF.md section 6, PR 34): not a pool
+        # (1.5 GiB), not a scan's q, k, v kernels transposed (384 MiB)
+        assert ma.temp_size_in_bytes < 64 * 2 ** 20
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == (program == "tick")
+
+
 class TestFlashAttentionCompiles:
     @pytest.mark.parametrize("shape", [
         (8, 1024, 12, 64),     # the reference LM's heads at seq 1024
