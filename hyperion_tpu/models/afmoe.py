@@ -37,9 +37,12 @@ cache=, cache_index=, block_tables=)` with `cache` from
 size). A call reads its kind's pool as `llama.select_paged_attn_impl`
 says for its shape and the backend: the decode tick on a TPU in place,
 through the paged-attention kernel with the kind's table and window;
-chunks, prefills and every other backend through the gather. Without a
-cache the call is one full forward (what the tests hold against the
-reference).
+chunks, prefills and every other backend through the gather. The
+expert layers' grouped products take the form
+`ops.moe.select_grouped_impl` names for the call's rows, the held
+experts and their matrices' bytes (the grouped-matmul kernel or
+`ragged_dot`; no knob here). Without a cache the call is one full
+forward (what the tests hold against the reference).
 
 Device scopes: `layer_*/{full,window}/attn/{qkv_proj, qk_norm, rope,
 kv_write, kv_read, attention, gate, o_proj}`, `layer_*/mlp`,
@@ -120,6 +123,17 @@ class AfmoeConfig:
         every position."""
         return tuple(("window", self.sliding_window) if t == SLIDING
                      else ("full", 0) for t in self.layer_types)
+
+    @property
+    def expert_step(self) -> dict:
+        """The static shape of an expert layer's `grouped_experts` call
+        but for its rows (`top_k` a token, held here or not): what
+        `ops.moe.select_grouped_impl` is asked with, here and by the
+        engine's counters."""
+        return {"layers": self.n_layers - self.n_dense_layers,
+                "groups": self.experts_held[1], "top_k": self.top_k,
+                "k": self.d_model, "n": self.moe_ff_dim,
+                "itemsize": self.compute_dtype.itemsize}
 
 
 def afmoe_tiny_config(**kw) -> AfmoeConfig:

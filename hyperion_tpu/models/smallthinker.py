@@ -37,7 +37,11 @@ cache=, cache_index=, block_tables=)` with `cache` from
 `llama.init_paged_cache(cfg, {kind: blocks}, bs)` and `block_tables`
 `{kind: [B, MB]}` (`cfg.layer_kinds`). A call reads its kind's pool as
 `llama.select_paged_attn_impl` says for its shape and the backend (7
-query heads a KV head: the decode tick on a TPU takes the kernel).
+query heads a KV head: the decode tick on a TPU takes the kernel),
+and multiplies its sorted rows with the experts' matrices as
+`ops.moe.select_grouped_impl` says for its shape and the backend (the
+grouped-matmul kernel for the tick's few rows an expert on a TPU,
+`ragged_dot` otherwise).
 Without a cache the call is one full forward.
 
 Device scopes: `layer_*/router`, `layer_*/{full,window}/attn/{qkv_proj,
@@ -110,6 +114,16 @@ class SmallthinkerConfig:
         every position."""
         return tuple(("window", self.sliding_window) if s else ("full", 0)
                      for s in self.sliding_window_layout)
+
+    @property
+    def expert_step(self) -> dict:
+        """The static shape of a layer's `grouped_experts` call but for
+        its rows (`top_k` a token): what `ops.moe.select_grouped_impl`
+        is asked with, here and by the engine's counters."""
+        return {"layers": self.n_layers, "groups": self.n_experts,
+                "top_k": self.top_k, "k": self.d_model,
+                "n": self.moe_ff_dim,
+                "itemsize": self.compute_dtype.itemsize}
 
 
 def smallthinker_tiny_config(**kw) -> SmallthinkerConfig:

@@ -45,6 +45,7 @@ from pathlib import Path
 from hyperion_tpu.obs.heartbeat import heartbeat_age_s, read_heartbeat
 from hyperion_tpu.obs.registry import percentile
 from hyperion_tpu.obs.tickprof import (
+    EXPERT_ROW_COUNTERS,
     FLIGHT_NAME,
     WALK_COUNTERS,
     WRITE_COUNTERS,
@@ -1244,6 +1245,14 @@ def render_markdown(d: dict) -> str:
         if blocks or rows:
             counted += (f"; written by block: {_fmt(blocks)} block(s), "
                         f"row by row: {_fmt(rows)} position(s)")
+        # an expert model: which form of the grouped products its
+        # steps' (token, pick) rows went through, over the expert layers
+        if any(k in c for k in EXPERT_ROW_COUNTERS):
+            by_kernel, by_ragged = (c.get(k) or 0
+                                    for k in EXPERT_ROW_COUNTERS)
+            counted += (f"; experts: {_fmt(by_kernel)} row(s) through the "
+                        f"grouped kernel, {_fmt(by_ragged)} through "
+                        f"ragged_dot")
         # an expert model: what its ticks sent to the experts held here
         ex = tp.get("experts") or {}
         if ex:

@@ -27,7 +27,9 @@ module is the one way to time it:
     `kv_table_entries`, and a windowed layer kind's under the same
     names with `_<kind>` behind), and how the steps wrote the pools
     (`kv_blocks_written` whole blocks, `kv_rows_written` positions
-    row by row).
+    row by row), and which form of the grouped products an expert
+    model's steps sent their rows through (`expert_rows_kernel`,
+    `expert_rows_ragged`).
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -76,6 +78,11 @@ WALK_COUNTERS = ("kv_blocks_walked", "kv_table_entries")
 # went row by row (the tick's live rows, a prompt that starts inside a
 # block, a bucket under a block)
 WRITE_COUNTERS = ("kv_blocks_written", "kv_rows_written")
+# an expert model's steps (`Engine._count_experts`): the (token, pick)
+# rows a step's tick, chunk and prefills sent through the grouped-matmul
+# kernel and through `ragged_dot`, summed over the expert layers, as
+# `ops.moe.select_grouped_impl` chose for each call's shape
+EXPERT_ROW_COUNTERS = ("expert_rows_kernel", "expert_rows_ragged")
 
 
 class _Seg:
@@ -267,10 +274,12 @@ class TickProfiler:
             # of the table entries the window's decode ticks could have
             # gathered a layer, the blocks the paged-attention kernel
             # walked instead (0: the ticks gathered), by layer kind
-            # and how the window's steps wrote it: by block, by row
+            # and how the window's steps wrote it: by block, by row;
+            # an expert model: its steps' rows by the products' form
             for key in sorted({k for r in recs for k in r.get("c", {})
                                if k.startswith(
-                                   WALK_COUNTERS + WRITE_COUNTERS)}):
+                                   WALK_COUNTERS + WRITE_COUNTERS
+                                   + EXPERT_ROW_COUNTERS)}):
                 out["counters"][key] = sum(
                     r.get("c", {}).get(key, 0) for r in recs)
         # an expert model's decode ticks (serve/engine.py

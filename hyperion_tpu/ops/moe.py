@@ -41,10 +41,15 @@ attention). ONE expert step, `grouped_experts`, takes the tokens the
 experts read, the picks, the weights, which contiguous run of experts
 this chip holds (`held`) and the gate's activation, and computes the
 part of the result those experts give, over grouped matrix products
-(`jax.lax.ragged_dot`, tokens sorted by expert); what the absent
-experts would have added is left out. `dropless_moe` is the two
-composed on one input, as `afmoe` calls them. That is what an
-inference reference can be matched against; `moe_ffn` is not.
+(tokens sorted by expert); what the absent experts would have added is
+left out. The three products take one of two forms, chosen per call
+from its static shape and the backend (`select_grouped_impl`, resolved
+at trace time): the Pallas kernel that streams each touched expert's
+matrix once (`ops/pallas/grouped_matmul.py`) at the shapes it was
+measured to win at on a TPU, `jax.lax.ragged_dot` everywhere else and
+on every other backend. `dropless_moe` is the two composed on one
+input, as `afmoe` calls them. That is what an inference reference can
+be matched against; `moe_ffn` is not.
 """
 
 from __future__ import annotations
@@ -266,6 +271,57 @@ def softmax_topk_route(x: jax.Array, router: jax.Array, *, top_k: int):
     return picked.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
+# What `select_grouped_impl` sends to the kernel on a TPU: the shapes
+# probed on a v5e (PERF.md section 6, PR 35), where the kernel read the
+# touched matrices at 82-91 % of the HBM roofline and `ragged_dot` at
+# 31-81 %. Matrices of 3.75 MiB (SmallThinker's) and 18 MiB (Trinity's)
+# were measured, nothing smaller: below a few megabytes a group's copy
+# no longer hides a grid step, so smaller ones stay where they are.
+# Rows from 32 to 3072, up to 64 a group by the static shape (a
+# 512-token chunk: 48 a group over SmallThinker's 64 experts, all held;
+# 64 over Trinity's 32, an eighth of them held); well past that a
+# visit's product outgrows its copy.
+GROUPED_KERNEL_MIN_MATRIX_BYTES = 3 << 20
+GROUPED_KERNEL_MAX_ROWS_A_GROUP = 64
+GROUPED_KERNEL_ROW_GRAIN = 16      # the kernel's smallest row tile
+
+
+def select_grouped_impl(rows: int, groups: int, k: int, n: int,
+                        backend: str, itemsize: int = 2) -> str:
+    """"kernel" or "ragged" for one expert step's grouped products,
+    from the call's static shape (`rows` sorted (token, pick) rows
+    over `groups` held experts, matrices `[k, n]` of `itemsize` bytes
+    an element) and the backend. Resolved at trace time, as
+    `models.llama.select_paged_attn_impl` is: jit sees one branch. Off
+    a TPU the kernel would run through the interpreter, which is an
+    oracle and not a path, and every program keeps the text it had.
+    On a TPU the kernel takes the calls whose work is streaming the
+    matrices: each at least `GROUPED_KERNEL_MIN_MATRIX_BYTES`, at most
+    `GROUPED_KERNEL_MAX_ROWS_A_GROUP` rows a group on average, the
+    rows in whole row tiles."""
+    if backend != "tpu":
+        return "ragged"
+    streams = k * n * itemsize >= GROUPED_KERNEL_MIN_MATRIX_BYTES
+    few_rows = rows <= GROUPED_KERNEL_MAX_ROWS_A_GROUP * groups
+    whole_tiles = rows % GROUPED_KERNEL_ROW_GRAIN == 0
+    return "kernel" if streams and few_rows and whole_tiles else "ragged"
+
+
+def _grouped_product(impl: str, sizes: jax.Array, rows: int):
+    """`(lhs [rows, K], rhs [G, K, N]) -> [rows, N]` over the groups of
+    `sizes`, in the form `impl` names. The kernel's walk of the groups
+    is listed here, once for the products that share `sizes`."""
+    if impl == "ragged":
+        return lambda lhs, rhs: lax.ragged_dot(lhs, rhs, sizes)
+    from hyperion_tpu.ops.pallas.grouped_matmul import (
+        group_visits,
+        grouped_matmul,
+        row_tile,
+    )
+    visits = group_visits(sizes, rows, row_tile(rows))
+    return lambda lhs, rhs: grouped_matmul(lhs, rhs, sizes, visits=visits)
+
+
 def grouped_experts(x: jax.Array, picked: jax.Array, w: jax.Array,
                     params: dict, *, held: tuple[int, int],
                     act=jax.nn.silu):
@@ -275,14 +331,18 @@ def grouped_experts(x: jax.Array, picked: jax.Array, w: jax.Array,
     (first, count)` give, `expert(x) = (act(x G) * (x U)) D`.
 
     `params`: the held experts' weights stacked, `gate`, `up`
-    [count, d, f], `down` [count, f, d]. Every shape is static: the N*k
-    (token, pick) pairs are sorted by expert, pairs whose expert lives
-    on another chip sort last, and the three products run grouped over
-    the held experts (`ragged_dot` with the per-expert counts): no
-    capacity, no token dropped however uneven the routing, and no dense
-    pass over every held expert for every token. `load[n, e]` is 1
-    where token n picked held expert e: the tick's counters are sums of
-    it.
+    [count, d, f], `down` [count, f, d], read as stored. Every shape is
+    static: the N*k (token, pick) pairs are sorted by expert, pairs
+    whose expert lives on another chip sort last, and the three
+    products run grouped over the held experts with the per-expert
+    counts, in the form `select_grouped_impl` names for the call (the
+    grouped-matmul kernel, whose walk of the groups is listed once and
+    shared by the three, or `ragged_dot`; operands as stored, float32
+    accumulation, the operands' dtype between gate/up and down either
+    way): no capacity, no token dropped however uneven the routing,
+    and no dense pass over every held expert for every token.
+    `load[n, e]` is 1 where token n picked held expert e: the tick's
+    counters are sums of it.
 
     Device scopes (under the caller's): `dispatch`, `experts`,
     `combine`."""
@@ -297,13 +357,19 @@ def grouped_experts(x: jax.Array, picked: jax.Array, w: jax.Array,
             jnp.where(here, local, -1), count, dtype=jnp.int32), axis=1)
         sizes = jnp.sum(load, axis=0)                           # [count]
     with jax.named_scope("experts"):
-        # the rows in expert order, then the three grouped products (the
-        # compiler's own custom calls, which a trace names after what
-        # they read: obs/xprof.py)
+        # the rows in expert order, then the three grouped products:
+        # the kernel (a Pallas call carries this scope) or the
+        # compiler's own `ragged_dot` custom calls, which a trace names
+        # after what they read (obs/xprof.py)
         xs = x[order // top_k]                                  # [N*k, d]
-        gate = lax.ragged_dot(xs, params["gate"], sizes)
-        up = lax.ragged_dot(xs, params["up"], sizes)
-        ys = lax.ragged_dot(act(gate) * up, params["down"], sizes)
+        product = _grouped_product(
+            select_grouped_impl(N * top_k, count, *params["gate"].shape[1:],
+                                jax.default_backend(),
+                                params["gate"].dtype.itemsize),
+            sizes, N * top_k)
+        gate = product(xs, params["gate"])
+        up = product(xs, params["up"])
+        ys = product(act(gate) * up, params["down"])
     with jax.named_scope("combine"):
         # back to (token, pick) order; rows past the held groups carry
         # whatever the grouped product left there and are zeroed

@@ -1,4 +1,4 @@
-"""What the four kernels share: the TPU's fp32 tile, and which way a
+"""What the kernels share: the TPU's fp32 tile, and which way a
 kernel runs on the process's default backend."""
 
 from __future__ import annotations

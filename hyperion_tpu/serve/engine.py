@@ -100,6 +100,7 @@ from hyperion_tpu.obs.export import DEFAULT_WINDOW_S
 from hyperion_tpu.obs.heartbeat import host_rss_mb as hb_host_rss_mb
 from hyperion_tpu.obs.ledger import CompileLedger
 from hyperion_tpu.obs.tickprof import (
+    EXPERT_ROW_COUNTERS,
     WALK_COUNTERS,
     WRITE_COUNTERS,
     FlightRecorder,
@@ -721,6 +722,14 @@ class Engine:
         self._no_write = {
             name: 0 for names in self._write_names.values() for name in names}
         self._write_counted = dict(self._no_write)
+        # an expert model: the static shape of its expert step, and the
+        # (token, pick) rows this step's calls sent through each form
+        # of the grouped products, summed over the expert layers
+        # (`_count_experts`); nothing for a model without experts
+        self._expert_step: dict | None = getattr(mcfg, "expert_step", None)
+        self._no_expert_rows = {} if self._expert_step is None \
+            else dict.fromkeys(EXPERT_ROW_COUNTERS, 0)
+        self._expert_rows = dict(self._no_expert_rows)
         # what this step's decode tick counted on the device (an expert
         # model's picks: `_expert_counters`), fetched with its tokens
         self._tick_counted: dict[str, int] = {}
@@ -881,6 +890,7 @@ class Engine:
         self._prefill_tokens += Pb
         self._count_write(
             {k: t[slot] for k, t in self._bts.items()}, start, Pb)
+        self._count_experts(Pb)
         prof, at = self.tickprof, {"bucket": Pb, "start": start}
         with prof.seg(f"{seg}/upload", **at):
             buf = np.full((1, Pb), self.cfg.pad_id, np.int32)
@@ -936,6 +946,23 @@ class Engine:
                 self._write_counted[blocks] += int(live.sum())
             else:
                 self._write_counted[positions] += int(held[live].sum())
+
+    def _count_experts(self, tokens: int) -> None:
+        """Add to the step's `expert_rows_kernel` / `expert_rows_ragged`
+        the (token, pick) rows a call over `tokens` positions sends
+        through the expert step, every expert layer, under the form
+        `select_grouped_impl` names for its shape: the question the
+        program itself asked at trace time. Host arithmetic."""
+        if self._expert_step is None:
+            return
+        from hyperion_tpu.ops import moe
+
+        e = self._expert_step
+        rows = tokens * e["top_k"]
+        form = moe.select_grouped_impl(
+            rows, e["groups"], e["k"], e["n"], jax.default_backend(),
+            e["itemsize"])
+        self._expert_rows[f"expert_rows_{form}"] += e["layers"] * rows
 
     def _count_walk(self) -> dict[str, int]:
         """The tick record's walk counters for the tick about to be
@@ -1439,6 +1466,7 @@ class Engine:
             prof, at = self.tickprof, {"bucket": C, "start": pos}
             self._prefill_tokens += C
             self._count_write(ck["rows"], pos, C)
+            self._count_experts(C)
             t0 = _CLOCK()
             with prof.seg("chunk/upload", **at):
                 args = (jnp.asarray(np.asarray(prompt[pos:pos + C],
@@ -2103,7 +2131,11 @@ class Engine:
                 # how the step's prefills, its chunk and its tick put
                 # keys and values into the pools, a layer of each kind:
                 # whole blocks, and positions row by row
-                **self._write_counted)
+                **self._write_counted,
+                # an expert model: the (token, pick) rows the step's
+                # tick, chunk and prefills sent through each form of
+                # the grouped products, over the expert layers
+                **self._expert_rows)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -2117,6 +2149,7 @@ class Engine:
         self._sampling_rows = self._restricted_rows = 0
         self._walk_counted = self._no_walk
         self._write_counted = dict(self._no_write)
+        self._expert_rows = dict(self._no_expert_rows)
         self._tick_counted = {}
         self._loop_counted = {}
 
@@ -2281,6 +2314,9 @@ class Engine:
             # the tick writes each live slot's window row by row
             for _, positions in self._write_names.values():
                 self._write_counted[positions] += n_live * self._tick_width
+            # the tick runs every slot's row through the experts, live
+            # or masked out
+            self._count_experts(self.cfg.slots * self._tick_width)
             with prof.seg("draft"):
                 drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table

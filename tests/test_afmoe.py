@@ -336,6 +336,34 @@ def test_a_windowed_chain_lets_blocks_go_within_the_step(tiny):
         assert len(full.blocks) >= -(-full.n_filled // 4)   # full keeps all
 
 
+def test_a_held_shares_steps_count_every_pick_row_by_form(tiny):
+    """`expert_rows_kernel` / `expert_rows_ragged` of `afmoe`: the rows
+    of the grouped products are every (token, pick) pair, held here or
+    not (rows for absent experts sort last and are never multiplied),
+    over the EXPERT layers only (the leading dense layer has none).
+    Off a TPU all of them go through `ragged_dot`."""
+    cfg, model, params = tiny
+    assert cfg.expert_step == {
+        "layers": cfg.n_layers - cfg.n_dense_layers,
+        "groups": cfg.experts_held[1], "top_k": cfg.top_k,
+        "k": cfg.d_model, "n": cfg.moe_ff_dim, "itemsize": 4}
+    eng = Engine(model, {"params": params}, EngineConfig(
+        slots=3, max_len=64, block_size=4, prefill_chunk=8,
+        prefix_cache=False))
+    rng = np.random.default_rng(1)
+    serve(eng, [Request(prompt_ids=rng.integers(1, cfg.vocab_size, n)
+                        .astype(np.int32), max_new_tokens=g, id=f"r{i}")
+                for i, (n, g) in enumerate([(5, 6), (21, 4)])])
+    per = cfg.expert_step["layers"] * cfg.top_k
+    recs = eng.tickprof.tail(256)
+    assert any(r["c"]["prefill_tokens"] for r in recs)
+    for r in recs:
+        c = r["c"]
+        assert c["expert_rows_kernel"] == 0
+        assert c["expert_rows_ragged"] == per * (
+            c["prefill_tokens"] + 3 * ("device" in r["s"]))
+
+
 @contextlib.contextmanager
 def as_on_a_tpu():
     """The one selector, answering as it does in a process whose backend

@@ -286,3 +286,176 @@ class TestMoELM:
                 state, metrics = step(state, batch, rng)
                 losses.append(float(metrics["loss"]))
             assert losses[-1] < losses[0], losses
+
+
+# ------------- serving: the expert step's two forms (ops/moe.py)
+
+
+def _expert_layer(experts=8, d=32, f=16, tokens=20, top_k=3, seed=0):
+    from hyperion_tpu.ops import moe
+
+    k = jax.random.split(jax.random.key(seed), 5)
+    p = {"gate": jax.random.normal(k[0], (experts, d, f)) / 4,
+         "up": jax.random.normal(k[1], (experts, d, f)) / 4,
+         "down": jax.random.normal(k[2], (experts, f, d)) / 4}
+    x = jax.random.normal(k[3], (tokens, d))
+    picked, w = moe.softmax_topk_route(
+        x, jax.random.normal(k[4], (d, experts)), top_k=top_k)
+    return p, x, picked, w
+
+
+def _share(p, first, count):
+    return {k: v[first:first + count] for k, v in p.items()}
+
+
+class _Forced:
+    """`select_grouped_impl` answering "kernel" for every shape, as it
+    does on a TPU for the shapes of its rule: the kernel runs through
+    the interpreter here. No option of the program is involved: there
+    is none."""
+
+    def __enter__(self):
+        from hyperion_tpu.ops import moe
+
+        self.mp = pytest.MonkeyPatch()
+        self.asked = []
+
+        def forced(rows, groups, k, n, backend, itemsize=2):
+            self.asked.append((rows, groups, k, n, backend, itemsize))
+            return "kernel"
+
+        self.mp.setattr(moe, "select_grouped_impl", forced)
+        return self
+
+    def __exit__(self, *exc):
+        self.mp.undo()
+
+
+class TestGroupedExpertsForms:
+    @pytest.mark.parametrize("act", [jax.nn.silu, jax.nn.relu],
+                             ids=["swiglu", "relu_gate"])
+    @pytest.mark.parametrize("held", [(0, 8), (2, 4), (6, 2)],
+                             ids=["every_expert", "a_middle_share",
+                                  "the_last_two"])
+    def test_the_kernel_gives_what_ragged_dot_gives(self, act, held):
+        """`grouped_experts` whole, both forms: the same result to
+        float32 round-off and the same `load`, for a share whose rows
+        for absent experts lie past `sum(sizes)` too (what the kernel
+        leaves there is selected around, never read)."""
+        from hyperion_tpu.ops import moe
+
+        p, x, picked, w = _expert_layer()
+        p = _share(p, *held)
+        want, load = moe.grouped_experts(x, picked, w, p, held=held, act=act)
+        with _Forced() as sel:
+            got, load_k = moe.grouped_experts(
+                x, picked, w, p, held=held, act=act)
+        # asked once a call, with the call's own static shape
+        assert sel.asked == [(20 * 3, held[1], 32, 16, "cpu", 4)]
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+        assert bool((load == load_k).all())
+        assert float(jnp.abs(want).max()) > 0.05
+
+    def test_the_shares_add_up_to_the_whole_through_the_kernel(self):
+        from hyperion_tpu.ops import moe
+
+        p, x, picked, w = _expert_layer(tokens=24)
+        whole, _ = moe.grouped_experts(x, picked, w, p, held=(0, 8))
+        with _Forced():
+            parts = [moe.grouped_experts(
+                x, picked, w, _share(p, first, 2), held=(first, 2))
+                for first in (0, 2, 4, 6)]
+        np.testing.assert_allclose(
+            np.asarray(sum(y for y, _ in parts)), np.asarray(whole),
+            atol=1e-5, rtol=1e-5)
+        assert sum(int(ld.sum()) for _, ld in parts) == 24 * 3
+
+    def test_bf16_between_gate_up_and_down_in_both_forms(self):
+        """bf16 operands: each product accumulates in float32 and hands
+        on bf16, in the kernel as in `ragged_dot`; the two agree within
+        bf16 steps of the result."""
+        from hyperion_tpu.ops import moe
+
+        p, x, picked, w = _expert_layer(d=128, f=128)
+        p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+        x = x.astype(jnp.bfloat16)
+        want, _ = moe.grouped_experts(x, picked, w, p, held=(0, 8))
+        with _Forced():
+            got, _ = moe.grouped_experts(x, picked, w, p, held=(0, 8))
+        assert got.dtype == want.dtype == jnp.bfloat16
+        scale = float(jnp.abs(want.astype(jnp.float32)).max())
+        assert float(jnp.abs(got.astype(jnp.float32)
+                             - want.astype(jnp.float32)).max()) < 0.02 * scale
+
+    def test_off_a_tpu_the_program_is_ragged_dots_text(self):
+        """The selector sends nothing to the kernel off a TPU: the
+        traced expert step holds three `ragged_dot`s and no Pallas
+        call; sent to the kernel, it holds no `ragged_dot`."""
+        from hyperion_tpu.ops import moe
+
+        p, x, picked, w = _expert_layer()
+        text = str(jax.make_jaxpr(lambda *a: moe.grouped_experts(
+            *a, held=(0, 8)))(x, picked, w, p))
+        assert text.count("ragged_dot_general[") == 3
+        assert "pallas_call" not in text
+        with _Forced():
+            text = str(jax.make_jaxpr(lambda *a: moe.grouped_experts(
+                *a, held=(0, 8)))(x, picked, w, p))
+        assert "ragged_dot_general[" not in text
+        assert "_grouped_matmul" in text
+
+
+# (rows, groups, k, n) of the cells' expert steps: SmallThinker's tick
+# (48 slots x 6 picks over 64 experts of [2560, 768]), its 512-token
+# chunk, its smallest prefill bucket; Trinity's tick (24 slots x 4
+# picks, 32 held experts of [3072, 3072]) and chunk
+CELL_SHAPES = {
+    "smallthinker_tick": (288, 64, 2560, 768),
+    "smallthinker_chunk": (3072, 64, 2560, 768),
+    "smallthinker_bucket_8": (48, 64, 2560, 768),
+    "trinity_tick": (96, 32, 3072, 3072),
+    "trinity_chunk": (2048, 32, 3072, 3072),
+}
+
+
+@pytest.mark.parametrize("shape, backend, itemsize, want", [
+    # off a TPU: never the kernel, whatever the shape
+    *[pytest.param(s, b, 2, "ragged", id=f"{name}_on_{b}")
+      for name, s in CELL_SHAPES.items() for b in ("cpu", "gpu")],
+    # on a TPU: the shapes the probe measured the kernel to win at
+    *[pytest.param(s, "tpu", 2, "kernel", id=f"{name}_on_tpu")
+      for name, s in CELL_SHAPES.items()],
+    # and nothing it did not measure: small matrices (a copy under a
+    # few megabytes no longer hides a grid step), many rows a group (a
+    # visit's product outgrows its copy), rows that fill no row tile
+    pytest.param((288, 64, 512, 768), "tpu", 2, "ragged",
+                 id="matrices_under_3_MiB"),
+    pytest.param((288, 64, 2560, 768), "tpu", 1, "ragged",
+                 id="the_same_in_one_byte_elements"),
+    pytest.param((288, 64, 1024, 768), "tpu", 4, "kernel",
+                 id="float32_matrices_of_3_MiB"),
+    pytest.param((64 * 80, 64, 2560, 768), "tpu", 2, "ragged",
+                 id="80_rows_a_group"),
+    pytest.param((3072, 32, 2560, 768), "tpu", 2, "ragged",
+                 id="half_the_groups_for_the_same_rows"),
+    pytest.param((6, 64, 2560, 768), "tpu", 2, "ragged",
+                 id="one_token_fills_no_row_tile"),
+    pytest.param((40, 64, 2560, 768), "tpu", 2, "ragged",
+                 id="rows_not_in_whole_tiles"),
+])
+def test_select_grouped_impl(shape, backend, itemsize, want):
+    """The selector's table: a rule on the rows, the groups and the
+    matrices' bytes, and the backend; no model's name in it."""
+    from hyperion_tpu.ops.moe import select_grouped_impl
+
+    from hyperion_tpu.ops import moe
+    from hyperion_tpu.ops.pallas import grouped_matmul
+
+    # the grain the rule asks of the rows is the kernel's smallest tile
+    assert moe.GROUPED_KERNEL_ROW_GRAIN == grouped_matmul._ROW_TILES[-1] \
+        == grouped_matmul._ROW_GRAIN
+    assert select_grouped_impl(*shape, backend, itemsize) == want
+    if itemsize == 2:
+        assert select_grouped_impl(*shape, backend) == want

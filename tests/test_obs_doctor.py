@@ -854,3 +854,51 @@ class TestSummarizeEmptyStreams:
         p.write_text("")
         assert report.main(["summarize", str(p), "--json"]) == 1
         assert capsys.readouterr().out == ""
+
+
+class TestExpertRowsByForm:
+    """An expert model's tick records carry `expert_rows_kernel` /
+    `expert_rows_ragged` (serve/engine.py `_count_experts`): flows the
+    snapshot sums over the window's steps, and a sentence of doctor's
+    `host tick profile` row."""
+
+    @pytest.mark.parametrize("steps, summed, sentence", [
+        # a tick of 48 slots x 6 picks x 8 layers through the kernel; a
+        # step that also ran a 512-token chunk on `ragged_dot`
+        ([{"expert_rows_kernel": 2304, "expert_rows_ragged": 0},
+          {"expert_rows_kernel": 2304, "expert_rows_ragged": 24576}],
+         {"expert_rows_kernel": 4608, "expert_rows_ragged": 24576},
+         "experts: 4608 row(s) through the grouped kernel, "
+         "24576 through ragged_dot"),
+        # off a TPU: every row through `ragged_dot`
+        ([{"expert_rows_kernel": 0, "expert_rows_ragged": 144}] * 3,
+         {"expert_rows_kernel": 0, "expert_rows_ragged": 432},
+         "experts: 0 row(s) through the grouped kernel, "
+         "432 through ragged_dot"),
+        # a model without experts, or a process that predates the
+        # counters: nothing is said
+        ([{}], {}, None),
+    ], ids=["tick_on_the_kernel", "all_on_ragged_dot", "no_experts"])
+    def test_rolled_up_and_said_in_words(self, tmp_path, steps, summed,
+                                         sentence):
+        from hyperion_tpu.obs.tickprof import TickProfiler
+
+        clk = VirtualClock()
+        tp = TickProfiler(wall=clk)
+        for n, c in enumerate(steps):
+            tp.record(n, {"device": 0.020}, 0.021,
+                      {"kv_tokens": 100, "prefill_tokens": 0, **c})
+            clk.advance(0.021)
+        snap = tp.snapshot(now=clk.t)
+        assert {k: v for k, v in snap["counters"].items()
+                if k.startswith("expert_rows")} == summed
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        if sentence:
+            assert sentence in row
+        else:
+            assert "grouped kernel" not in row

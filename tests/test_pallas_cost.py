@@ -18,7 +18,12 @@ import numpy as np
 import pytest
 
 import hyperion_tpu.ops.pallas.flash_attention  # noqa: F401
-from hyperion_tpu.ops.pallas import fused_ce, fused_norm, paged_attention
+from hyperion_tpu.ops.pallas import (
+    fused_ce,
+    fused_norm,
+    grouped_matmul,
+    paged_attention,
+)
 from hyperion_tpu.ops.pallas.backend import LANES, SUBLANES, cost
 
 flash = sys.modules["hyperion_tpu.ops.pallas.flash_attention"]
@@ -199,3 +204,31 @@ def test_windowed_paged_attention_counts_the_window_not_the_table(
         2 * chain * Dh * 2
         + _nbytes(*[((Bs, Hkv, rows_p, Dh), BF16)] * 2,
                   ((Bs, MB), jnp.int32), ((Bs,), jnp.int32)))
+
+
+@pytest.mark.parametrize("M, G, K, N, tiling", [
+    pytest.param(64, 8, 256, 128, (16, 128), id="gate_up_orientation"),
+    pytest.param(64, 8, 128, 256, (32, 128), id="down_cut_along_columns"),
+    pytest.param(50, 4, 128, 128, (16, 128), id="rows_padded_to_a_tile"),
+])
+def test_grouped_matmul_counts_rows_once_and_every_matrix_once(
+        M, G, K, N, tiling):
+    lhs = jnp.zeros((M, K), BF16)
+    rhs = jnp.zeros((G, K, N), BF16)
+    sizes = jnp.zeros((G,), jnp.int32)
+    (est,) = _estimates(
+        lambda a, b: grouped_matmul.grouped_matmul(
+            a, b, sizes, tiling=tiling), lhs, rhs)
+    Mp = -(-M // tiling[0]) * tiling[0]
+    visits = Mp // tiling[0] + G - 1
+    # the rows' own products, not the row tiles a visit multiplies
+    assert est.flops == 2 * Mp * K * N
+    assert est.transcendentals == 0
+    # the static worst case, every group touched: rows in and result
+    # out once, every group's matrix once (what a call reads follows
+    # `sizes`, runtime data), and the walk's lists; no tiling's
+    # re-reads
+    assert est.bytes_accessed == _nbytes(
+        ((Mp, K), BF16), ((G, K, N), BF16), ((Mp, N), BF16),
+        ((G + 1,), jnp.int32), *[((visits,), jnp.int32)] * 2,
+        ((1,), jnp.int32))

@@ -745,3 +745,139 @@ class TestPagedAttention:
         ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 1)),
                           jnp.int32)
         step(ids, jnp.asarray([9, 3], jnp.int32), bt)     # [S, 1]
+
+
+class TestGroupedMatmul:
+    """`ops/pallas/grouped_matmul.py` through the interpreter against
+    `lax.ragged_dot`, on the rows that belong to a group: what lies
+    past `sum(sizes)` holds no number in either."""
+
+    @staticmethod
+    def operands(M, G, K, N, seed=0, dtype=jnp.float32):
+        k = jax.random.split(jax.random.key(seed), 2)
+        return (jax.random.normal(k[0], (M, K), dtype),
+                jax.random.normal(k[1], (G, K, N), dtype) / 8)
+
+    @classmethod
+    def agree(cls, M, G, K, N, sizes, tiling, dtype=jnp.float32, tol=2e-5):
+        from hyperion_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+        lhs, rhs = cls.operands(M, G, K, N, dtype=dtype)
+        sizes = jnp.asarray(sizes, jnp.int32)
+        want = jax.lax.ragged_dot(lhs, rhs, sizes)
+        got = grouped_matmul(lhs, rhs, sizes, tiling=tiling)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        n = int(sizes.sum())
+        np.testing.assert_allclose(
+            np.asarray(got[:n], np.float32), np.asarray(want[:n], np.float32),
+            atol=tol, rtol=tol)
+        assert float(jnp.abs(want[:n].astype(jnp.float32)).max()) > 0.5
+        return got
+
+    @pytest.mark.parametrize("sizes", [
+        pytest.param([10, 20, 30, 4], id="every_group_touched"),
+        pytest.param([0, 0, 40, 24], id="none_at_the_start"),
+        pytest.param([12, 0, 0, 52], id="none_in_the_middle"),
+        pytest.param([33, 31, 0, 0], id="none_at_the_end"),
+        pytest.param([0, 64, 0, 0], id="all_rows_in_one_group"),
+        pytest.param([0, 0, 0, 64], id="all_rows_in_the_last_group"),
+        pytest.param([16, 16, 16, 16], id="groups_end_on_tile_edges"),
+        pytest.param([1, 1, 1, 1], id="one_row_a_group"),
+    ])
+    @pytest.mark.parametrize("tm", [16, 32])
+    def test_groups(self, sizes, tm):
+        self.agree(64, 4, 128, 256, sizes, (tm, 256))
+
+    @pytest.mark.parametrize("sizes, M", [
+        pytest.param([3, 0, 5, 0, 7, 1, 0, 0], 64, id="a_few_rows_held"),
+        pytest.param([0, 0, 0, 0, 0, 0, 0, 3], 64, id="only_the_last_group"),
+        pytest.param([16, 0, 0, 0, 0, 0, 0, 0], 48, id="one_whole_tile"),
+        pytest.param([0, 0, 0, 0, 0, 0, 0, 0], 32, id="no_row_at_all"),
+    ])
+    def test_rows_past_the_groups_are_never_visited(self, sizes, M):
+        """A held share whose rows for absent experts sort last: the
+        walk ends at `sum(sizes)`; tiles past it are neither read nor
+        written (their visits are not listed)."""
+        from hyperion_tpu.ops.pallas.grouped_matmul import group_visits
+
+        v = group_visits(jnp.asarray(sizes, jnp.int32), M, 16)
+        n = sum(sizes)
+        touched = sum(s > 0 for s in sizes)
+        # a group's visits: the tiles its rows lie in
+        ends = np.cumsum(sizes)
+        want = sum(-(-e // 16) - (e - s) // 16 for s, e in
+                   zip(sizes, ends) if s) if n else 0
+        assert int(v.count[0]) == want >= touched
+        ids = np.asarray(v.tile_ids)[:want]
+        assert (np.diff(ids) >= 0).all() and (ids <= max(n - 1, 0) // 16).all()
+        if n:
+            self.agree(M, 8, 256, 128, sizes, (16, 128))
+
+    @pytest.mark.parametrize("M", [16, 32, 48, 50, 17])
+    def test_rows_at_the_row_tiles_edge(self, M):
+        """M one tile, whole tiles, and not a multiple (padded up)."""
+        sizes = [M // 3, 0, M - M // 3 - M // 4, M // 4]
+        self.agree(M, 4, 128, 128, sizes, (16, 128))
+        self.agree(M, 4, 128, 128, sizes, (32, 128))
+
+    @pytest.mark.parametrize("K, N, tn", [
+        pytest.param(256, 128, 128, id="gate_up_orientation"),
+        pytest.param(128, 256, 256, id="down_orientation"),
+        pytest.param(128, 384, 128, id="columns_cut_in_three"),
+    ])
+    def test_orientations_and_column_tiles(self, K, N, tn):
+        self.agree(96, 6, K, N, [20, 0, 31, 9, 30, 6], (32, tn))
+
+    def test_bf16_operands_float32_accumulation(self):
+        """bf16 in, bf16 out, the sum in float32: within a bf16 step of
+        `ragged_dot`'s, and far from a bf16 accumulation's."""
+        got = self.agree(64, 4, 512, 128, [10, 20, 30, 4], (16, 128),
+                         dtype=jnp.bfloat16, tol=1e-2)
+        assert got.dtype == jnp.bfloat16
+
+    def test_one_walk_serves_the_three_products(self):
+        """`group_visits` once, shared by products over the same rows."""
+        from hyperion_tpu.ops.pallas.grouped_matmul import (
+            group_visits,
+            grouped_matmul,
+        )
+
+        lhs, rhs = self.operands(64, 4, 128, 256)
+        down = jnp.swapaxes(rhs, 1, 2)
+        sizes = jnp.asarray([5, 0, 40, 11], jnp.int32)
+        v = group_visits(sizes, 64, 16)
+        a = grouped_matmul(lhs, rhs, sizes, visits=v)
+        b = grouped_matmul(a, down, sizes, visits=v)
+        want = jax.lax.ragged_dot(
+            jax.lax.ragged_dot(lhs, rhs, sizes), down, sizes)
+        np.testing.assert_allclose(np.asarray(b[:56]), np.asarray(want[:56]),
+                                   atol=1e-3, rtol=1e-4)
+
+    @pytest.mark.parametrize("bad", ["rows", "columns", "sizes", "visits"])
+    def test_refuses_what_it_cannot_cut(self, bad):
+        from hyperion_tpu.ops.pallas.grouped_matmul import (
+            group_visits,
+            grouped_matmul,
+        )
+
+        lhs, rhs = self.operands(64, 4, 128, 256)
+        sizes = jnp.asarray([16, 16, 16, 16], jnp.int32)
+        with pytest.raises(ValueError):
+            if bad == "rows":
+                grouped_matmul(lhs, rhs, sizes, tiling=(24, 256))
+            elif bad == "columns":
+                grouped_matmul(lhs, rhs, sizes, tiling=(16, 96))
+            elif bad == "sizes":
+                grouped_matmul(lhs, rhs, sizes[:3])
+            else:
+                grouped_matmul(lhs, rhs, sizes,
+                               visits=group_visits(sizes, 32, 16))
+
+    def test_plan_tiles(self):
+        """A whole matrix a copy where two fit the budget; the widest
+        column tile that divides N and fits, where they do not."""
+        from hyperion_tpu.ops.pallas.grouped_matmul import plan_tiles
+
+        assert plan_tiles(288, 2560, 768, 2)[1] == 768      # 3.9 MB
+        assert plan_tiles(288, 768, 2560, 2)[1] == 2560
+        assert plan_tiles(96, 3072, 3072, 2)[1] == 1024     # 18.9 MB

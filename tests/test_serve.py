@@ -2895,3 +2895,32 @@ class TestIntrospection:
         if res["status"] == "started":
             time.sleep(0.35)
         assert eng.compile_stats() == stats0
+
+
+@pytest.mark.parametrize("family", ["llama", "ouro"])
+def test_a_model_without_experts_counts_no_expert_rows(family):
+    """`expert_rows_kernel` / `expert_rows_ragged` belong to a model
+    whose config states an expert step (`afmoe`, `smallthinker`:
+    tests/test_smallthinker.py): every other model's records, its
+    exposition and its doctor row are what they were."""
+    from hyperion_tpu.obs.tickprof import EXPERT_ROW_COUNTERS
+
+    if family == "llama":
+        from hyperion_tpu.models.llama import Llama, llama_tiny_config
+        model = Llama(llama_tiny_config())
+    else:
+        from hyperion_tpu.models.ouro import Ouro, ouro_tiny_config
+        model = Ouro(ouro_tiny_config())
+    assert not hasattr(model.cfg, "expert_step")
+    params = model.init_params(jax.random.key(0))
+    eng = Engine(model, {"params": params},
+                 EngineConfig(slots=3, max_len=64, block_size=4))
+    eng.submit(Request(prompt_ids=np.arange(1, 6, dtype=np.int32),
+                       max_new_tokens=3, id="a"))
+    while not eng.idle:
+        eng.step()
+    recs = eng.tickprof.tail(16)
+    assert recs and not any(
+        k in r["c"] for r in recs for k in EXPERT_ROW_COUNTERS)
+    assert not set(EXPERT_ROW_COUNTERS) & set(
+        eng.exposition()["tickprof"]["counters"])

@@ -418,6 +418,72 @@ def test_the_tick_reads_both_pools_in_place_at_seven_rows_a_kv_head(tiny):
                for x in c)
 
 
+@pytest.mark.parametrize("where", ["off_a_tpu", "the_tick_on_the_kernel"])
+def test_the_tick_record_counts_the_expert_rows_by_form(tiny, where):
+    """`expert_rows_kernel` + `expert_rows_ragged` of a step are
+    expert layers x top_k x the positions its programs ran (the tick's
+    slots, live or not; a chunk; a prefill's bucket), each under the
+    form `select_grouped_impl` names for that call's rows: the question
+    `grouped_experts` asked at trace time. Off a TPU every row goes
+    through `ragged_dot`; with a selector that sends the tick's shape
+    to the kernel (through the interpreter here) the tick's rows move
+    over, the tokens stay the same, and a layer's three products share
+    one trace of the kernel."""
+    cfg, model, params = tiny
+    ecfg = EngineConfig(**ENGINE)
+    shapes = [(5, 9), (30, 6), (3, 12), (19, 5)]
+    per = cfg.n_layers * cfg.top_k
+    tick_rows = ecfg.slots * cfg.top_k
+    assert cfg.expert_step == {
+        "layers": 8, "groups": 16, "top_k": 6, "k": 32, "n": 16,
+        "itemsize": 4}
+
+    def checked(eng, form_of_the_tick):
+        recs = eng.tickprof.tail(256)
+        assert any("chunk" in r["s"] for r in recs)
+        for r in recs:
+            c, ticked = r["c"], "device" in r["s"]
+            want = {"expert_rows_kernel": 0, "expert_rows_ragged":
+                    per * c["prefill_tokens"]}
+            want[f"expert_rows_{form_of_the_tick}"] += \
+                per * ecfg.slots * ticked
+            assert {k: c[k] for k in want} == want, r
+        total = eng.exposition()["tickprof"]["counters"]
+        assert total["expert_rows_kernel"] + total["expert_rows_ragged"] \
+            == sum(r["c"]["expert_rows_kernel"] + r["c"]["expert_rows_ragged"]
+                   for r in recs)
+
+    plain = requests(cfg, shapes, seed=7)
+    eng = Engine(model, {"params": params}, ecfg)
+    serve(eng, plain)
+    checked(eng, "ragged")
+    if where == "off_a_tpu":
+        assert "grouped_matmul" not in lowered(eng, "tick")
+        return
+
+    def the_tick_only(rows, groups, k, n, backend, itemsize=2):
+        assert (groups, k, n, itemsize) == (16, 32, 16, 4)
+        return "kernel" if rows == tick_rows else "ragged"
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "select_grouped_impl", the_tick_only)
+        # another identity: the jits are shared process-wide
+        twin = Smallthinker(dataclasses.replace(cfg, max_len=72))
+        eng = Engine(twin, {"params": params}, ecfg)
+        text = lowered(eng, "tick")
+        # eight layers, three products each, one trace of the kernel for
+        # each orientation
+        assert text.count("func.func private @_grouped_matmul") == 2
+        assert text.count("call @_grouped_matmul") == 24
+        assert "grouped_matmul" not in lowered(eng, "chunk")
+        through_the_kernel = requests(cfg, shapes, seed=7)
+        serve(eng, through_the_kernel)
+    checked(eng, "kernel")
+    for a, b in zip(plain, through_the_kernel):
+        assert a.status == b.status == "done"
+        assert a.tokens == b.tokens, a.id
+
+
 @pytest.mark.parametrize("feature, over", [
     ("prefix cache", dict(prefix_cache=True)),
     ("host spill tier", dict(prefix_cache=False, host_cache_mb=1)),

@@ -30,6 +30,7 @@ from jax.sharding import SingleDeviceSharding
 import hyperion_tpu.ops.pallas.flash_attention  # noqa: F401
 import hyperion_tpu.ops.pallas.fused_ce as ce_mod
 import hyperion_tpu.ops.pallas.fused_norm as norm_mod
+import hyperion_tpu.ops.pallas.grouped_matmul as grouped_mod
 import hyperion_tpu.ops.pallas.paged_attention as paged_mod
 
 # the package re-exports the flash_attention function under the module's
@@ -69,9 +70,9 @@ def one_chip(topo):
 @pytest.fixture
 def mosaic(monkeypatch):
     """The session's backend is the CPU, where `_interpret()` picks the
-    interpreter; steer the four kernels to the compiled path here, in
+    interpreter; steer the five kernels to the compiled path here, in
     the test, rather than through an option of the program."""
-    for mod in (flash_mod, ce_mod, norm_mod, paged_mod):
+    for mod in (flash_mod, ce_mod, norm_mod, paged_mod, grouped_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -247,6 +248,113 @@ class TestOuroProgramsStayInPlace:
         assert ma.temp_size_in_bytes < 64 * 2 ** 20
         text = compiled.as_text()
         assert text.count("tpu_custom_call") == (program == "tick")
+
+
+class TestGroupedMatmulCompiles:
+    """The grouped-matmul kernel at the shapes `select_grouped_impl`
+    sends it on a TPU, and the tick that holds it."""
+
+    # (rows, groups, d, f) of the cells' expert steps
+    SMALLTHINKER_TICK = (48 * 6, 64, 2560, 768)
+    SMALLTHINKER_CHUNK = (512 * 6, 64, 2560, 768)
+    TRINITY_TICK = (24 * 4, 32, 3072, 3072)
+    TRINITY_CHUNK = (512 * 4, 32, 3072, 3072)
+
+    def test_the_selector_sends_the_ticks_shape_of_smallthinker(self):
+        from hyperion_tpu.ops.moe import select_grouped_impl
+
+        assert select_grouped_impl(*self.SMALLTHINKER_TICK, "tpu") == "kernel"
+
+    @pytest.mark.parametrize("shape", [
+        "SMALLTHINKER_TICK", "SMALLTHINKER_CHUNK", "TRINITY_TICK",
+        "TRINITY_CHUNK"])
+    def test_a_layers_three_products(self, mosaic, one_chip, shape):
+        """One layer's gate, up and down at the cell's widths with the
+        plan's own tiling, the walk listed once: compiles whether or
+        not the selector sends the shape (a shape it leaves on
+        `ragged_dot` today stays a shape the kernel can take)."""
+        rows, groups, d, f = getattr(self, shape)
+
+        def layer(xs, gate, up, down, sizes):
+            v = grouped_mod.group_visits(
+                sizes, rows, grouped_mod.row_tile(rows))
+            g = grouped_mod.grouped_matmul(xs, gate, sizes, visits=v)
+            u = grouped_mod.grouped_matmul(xs, up, sizes, visits=v)
+            return grouped_mod.grouped_matmul(
+                jax.nn.relu(g) * u, down, sizes, visits=v)
+
+        text = _compile(
+            layer, one_chip, S((rows, d), BF16), S((groups, d, f), BF16),
+            S((groups, d, f), BF16), S((groups, f, d), BF16),
+            S((groups,), jnp.int32))
+        assert text.count("tpu_custom_call") >= 3
+
+    def test_smallthinkers_tick_holds_it_and_copies_no_expert_stack(
+            self, mosaic, one_chip, monkeypatch):
+        """The decode tick of `smallthinker.chatmix-saturated` (48
+        slots, the published widths, two of its layers: one full, one
+        windowed) with both selectors answering as on a TPU: the three
+        products of each layer are the kernel, the pools and the expert
+        stacks are read where they lie, and no temporary is the size
+        of a stack (252 MB a matrix)."""
+        from hyperion_tpu.models import llama, smallthinker
+        from hyperion_tpu.models.llama import init_paged_cache
+        from hyperion_tpu.ops import moe
+        from hyperion_tpu.serve import engine as E
+
+        paged, grouped = llama.select_paged_attn_impl, \
+            moe.select_grouped_impl
+        for mod in (llama, smallthinker):
+            monkeypatch.setattr(
+                mod, "select_paged_attn_impl",
+                lambda window, rep, backend: paged(window, rep, "tpu"))
+        monkeypatch.setattr(
+            moe, "select_grouped_impl",
+            lambda rows, groups, k, n, backend, itemsize=2: grouped(
+                rows, groups, k, n, "tpu", itemsize))
+        slots, L, bs = 48, 1024, 16
+        mb = L // bs
+        # the vocabulary cut to 8192: the head is not this test's
+        cfg = smallthinker.SmallthinkerConfig(
+            sliding_window_layout=(0, 1), rope_layout=(0, 1), max_len=L,
+            vocab_size=8192)
+        model = smallthinker.Smallthinker(cfg)
+
+        def on_chip(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        params = on_chip(jax.eval_shape(
+            lambda: model.init_params(jax.random.key(0))))
+        stack = 64 * 2560 * 768 * 2
+        assert params["layer_0"]["moe"]["experts_gate"].dtype == BF16
+        cache = on_chip(jax.eval_shape(lambda: init_paged_cache(
+            cfg, {"full": slots * mb + 1, "window": slots * mb + 1}, bs)))
+        st = on_chip(jax.eval_shape(lambda: {
+            "lengths": jnp.zeros((slots,), jnp.int32),
+            "active": jnp.zeros((slots,), bool),
+            "last_token": jnp.zeros((slots,), jnp.int32),
+            "generated": jnp.zeros((slots,), jnp.int32),
+            "budget": jnp.ones((slots,), jnp.int32),
+            "temperature": jnp.zeros((slots,), jnp.float32),
+            "top_k": jnp.zeros((slots,), jnp.int32),
+            "top_p": jnp.ones((slots,), jnp.float32),
+            "keys": jax.random.split(jax.random.key(0), slots)}))
+        table = jax.ShapeDtypeStruct((slots, mb), jnp.int32,
+                                     sharding=one_chip)
+        compiled = jax.jit(
+            E._tick_impl, static_argnums=(0, 1, 2), donate_argnums=(4, 5),
+        ).lower(model, None, 0, {"params": params}, cache, st,
+                {"full": table, "window": table},
+                jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip),
+                ).compile()
+        text = compiled.as_text()
+        # a layer: the read kernel and three grouped products
+        assert text.count("tpu_custom_call") == 2 * 4
+        assert "ragged-dot" not in text
+        ma = compiled.memory_analysis()
+        # 8 MB here; a copied stack would be 252
+        assert ma.temp_size_in_bytes < stack // 8
 
 
 class TestFlashAttentionCompiles:
