@@ -29,7 +29,10 @@ module is the one way to time it:
     (`kv_blocks_written` whole blocks, `kv_rows_written` positions
     row by row), and which form of the grouped products an expert
     model's steps sent their rows through (`expert_rows_kernel`,
-    `expert_rows_ragged`).
+    `expert_rows_ragged`). The profiler's own flight account
+    (FLIGHT_COUNTERS: how much of each step a program was out, what
+    second fetches and the collector cost it) is in every record's `c`
+    and, summed over the window, under `inflight`.
   * `FlightRecorder` — the post-mortem half. The tick ring's tail plus
     recent notable events spill periodically (and on SIGTERM / fatal
     exception) to `flight.json` next to the heartbeat, atomically, so
@@ -46,9 +49,12 @@ contract as the null tracer/heartbeat.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import threading
 import time
+import weakref
 from collections import deque
 from pathlib import Path
 
@@ -59,12 +65,31 @@ FLIGHT_NAME = "flight.json"
 # every recorded tick carries a subset of these keys, seconds each.
 # "other" is derived at snapshot time (total minus named segments) so
 # unattributed host time is visible instead of silently vanishing.
-SEGMENTS = ("queue_pop", "admit", "chunk", "draft", "bt_upload",
-            "device", "accept", "journal", "sink", "slo")
+SEGMENTS = ("queue_pop", "admit", "chunk", "ensure", "count", "draft",
+            "bt_upload", "device", "accept", "journal", "sink", "slo")
 # A key `parent/child` is a CHILD: a stretch inside the segment `parent`
 # (`device/dispatch`, `admit/fetch`), recorded beside it and left out of
-# every sum over segments — its seconds are already in its parent's.
+# every sum over segments — its seconds are already in its parent's. A
+# child has children the same way (`admit/blocks/lookup`,
+# `device/fetch/tokens`): any key with a separator is out of every sum.
 CHILD_SEP = "/"
+# Two leaf names mean something to the profiler itself: a child named
+# `dispatch` hands a program to the device and the child named `fetch`
+# that follows waits for its results, one child of its own per array in
+# the order it waits. The first of those holds the program's run time;
+# every later one waits for a program that has already ended.
+DISPATCH, FETCH = "dispatch", "fetch"
+_ARRAY = "array"        # the role of a `x/fetch/<array>` child (`_role`)
+# the span of a collection that ran inside a step (`generation=n`)
+GC_KEY = "gc"
+# What the profiler itself puts into every record's `c`, whole
+# microseconds: the step's wall; the part of it during which a program of
+# the step had been dispatched and its last array was not yet on the
+# host (`step_us - inflight_us`: the chip had nothing of ours queued);
+# the gross seconds of every fetch child after its program's first (a
+# lower bound on what a second round trip to the device costs); the
+# collector's seconds inside the step.
+FLIGHT_COUNTERS = ("step_us", "inflight_us", "fetch_after_ready_us", "gc_us")
 # the span of a whole step in a profiler trace; a segment's span is
 # `serve.step/<key>`
 STEP_SPAN = "serve.step"
@@ -85,32 +110,57 @@ WRITE_COUNTERS = ("kv_blocks_written", "kv_rows_written")
 EXPERT_ROW_COUNTERS = ("expert_rows_kernel", "expert_rows_ragged")
 
 
+def _role(key: str) -> str | None:
+    """What a key's last two names say of a child: `x/dispatch`,
+    `x/fetch`, or an array's wait `x/fetch/<array>`."""
+    parent, _, leaf = key.rpartition(CHILD_SEP)
+    if not parent:
+        return None
+    if leaf in (DISPATCH, FETCH):
+        return leaf
+    return _ARRAY if parent.endswith(CHILD_SEP + FETCH) else None
+
+
 class _Seg:
     """One timed stretch of a step, a context manager (`TickProfiler.seg`).
     After exit `gross` is its wall seconds and `s` those seconds net of
     the segments that ran inside it."""
 
     __slots__ = ("s", "gross", "_prof", "_key", "_span", "_t0", "_inner",
-                 "_record")
+                 "_record", "_role")
 
-    def __init__(self, prof, key, span, record):
+    def __init__(self, prof, key, span, record, role):
         self._prof, self._key, self._span = prof, key, span
         self._record = record
+        self._role = role if record else None
         self._inner = 0.0
         self.s = self.gross = 0.0
 
     def __enter__(self):
+        prof = self._prof
         if self._span is not None:
             self._span.__enter__()
         if self._record:
-            self._prof._open.append(self)
-        self._t0 = self._prof._clock()
+            prof._open.append(self)
+        self._t0 = prof._clock()
+        if self._role == DISPATCH and prof._flight_t0 is None:
+            prof._flight_t0 = self._t0
+        elif self._role == FETCH:
+            prof._arrays = 0
         return self
 
     def __exit__(self, *exc):
         prof = self._prof
-        self.gross = prof._clock() - self._t0
+        now = prof._clock()
+        self.gross = now - self._t0
         self.s = max(0.0, self.gross - self._inner)
+        if self._role == FETCH and prof._flight_t0 is not None:
+            prof._inflight_s += now - prof._flight_t0
+            prof._flight_t0 = None
+        elif self._role == _ARRAY:
+            if prof._arrays and prof._flight_t0 is not None:
+                prof._after_ready_s += self.gross
+            prof._arrays += 1
         if self._record:
             prof._open.pop()
             cur = prof._cur
@@ -145,6 +195,9 @@ class _Tick:
             self._span.__enter__()
         prof = self._prof
         prof._cur, prof._open = {}, []
+        prof._flight_t0, prof._arrays = None, 0
+        prof._inflight_s = prof._after_ready_s = prof._gc_s = 0.0
+        prof._thread = threading.get_ident()
         self._t0 = prof._clock()
         return self
 
@@ -152,10 +205,16 @@ class _Tick:
         prof = self._prof
         total = prof._clock() - self._t0
         cur, prof._cur, prof._open = prof._cur, None, []
-        if exc_type is None:
-            prof.record(self._tick, cur, total, self._counters)
+        # the span ends where `total_s` does: filing the record is not
+        # the step's
         if self._span is not None:
             self._span.__exit__(exc_type, *exc)
+        if exc_type is None:
+            prof.record(self._tick, cur, total, {
+                **(self._counters or {}),
+                **dict(zip(FLIGHT_COUNTERS, (round(1e6 * x) for x in (
+                    total, prof._inflight_s, prof._after_ready_s,
+                    prof._gc_s))))})
         return False
 
 
@@ -180,13 +239,21 @@ class TickProfiler:
         self._annotate = annotate
         self._cur: dict | None = None   # the open tick's seconds by key
         self._open: list[_Seg] = []     # its open segments, outermost first
+        # the open tick's flight account (FLIGHT_COUNTERS), in seconds
+        self._flight_t0: float | None = None    # a program is out since
+        self._arrays = 0            # arrays the open fetch has waited for
+        self._inflight_s = self._after_ready_s = self._gc_s = 0.0
+        self._thread: int | None = None         # the open tick's thread
+        # by key, the span's name and the key's role (`_role`)
+        self._known: dict[str, tuple[str, str | None]] = {}
         self.ticks_recorded = 0
 
     def tick(self, n: int) -> _Tick:
         """`with prof.tick(n) as tk:` around one step: the span
         `serve.step` with `tick=n`, and on exit the record of tick `n`:
         the step's wall as `total_s`, the segments timed inside, and
-        what `tk.count(...)` was given."""
+        what `tk.count(...)` was given beside the profiler's own
+        FLIGHT_COUNTERS."""
         ann = self._annotate
         return _Tick(self, n, ann(STEP_SPAN, tick=n) if ann else None)
 
@@ -196,13 +263,25 @@ class TickProfiler:
         as its arguments) open meanwhile. A segment that runs inside
         another is netted out of it (journal and sink writes inside
         `accept`, the table upload inside `device`); a child
-        (`device/fetch`) is not. Outside a step, or with `record`
+        (`device/fetch`, its own `device/fetch/tokens`) is not, and a
+        child named `dispatch` or `fetch` feeds the step's flight
+        account (FLIGHT_COUNTERS). Outside a step, or with `record`
         false (a caller that may be on another thread than the
         step's), it is a span and a stopwatch and touches no record."""
+        known = self._known.get(key)
+        if known is None:
+            known = self._known[key] = (f"{STEP_SPAN}/{key}", _role(key))
         ann = self._annotate
-        return _Seg(self, key,
-                    ann(f"{STEP_SPAN}/{key}", **args) if ann else None,
-                    record and self._cur is not None)
+        return _Seg(self, key, ann(known[0], **args) if ann else None,
+                    record and self._cur is not None, known[1])
+
+    def watch_collector(self) -> None:
+        """From now on a collection that runs inside a step, on the
+        step's thread, is the span `serve.step/gc` (`generation=n`) and
+        its seconds are the record's `gc_us`; the segment it interrupted
+        keeps them too. The hook in `gc.callbacks` holds this profiler
+        weakly and leaves the list when the profiler goes."""
+        _CollectorWatch(self)
 
     def record(self, tick: int, segments: dict, total_s: float,
                counters: dict | None = None) -> None:
@@ -282,6 +361,12 @@ class TickProfiler:
                                    + EXPERT_ROW_COUNTERS)}):
                 out["counters"][key] = sum(
                     r.get("c", {}).get(key, 0) for r in recs)
+        if recs:
+            # where the window's step time went as the chip sees it:
+            # microseconds of steps, of those with a program out, of
+            # second fetches, of collections
+            out["inflight"] = {k: sum(r.get("c", {}).get(k, 0) for r in recs)
+                               for k in FLIGHT_COUNTERS}
         # an expert model's decode ticks (serve/engine.py
         # `_expert_counters`): picks that landed on the experts held
         # here and held experts touched, a tick (both summed over the
@@ -319,6 +404,49 @@ class TickProfiler:
                 "restricted_rows": ([min(restricted), max(restricted)]
                                     if restricted else None)}
         return out
+
+
+class _CollectorWatch:
+    """One entry of `gc.callbacks` (`TickProfiler.watch_collector`): two
+    clock reads a collection, and no work when none runs. Safe wherever
+    a collection starts: it reads the profiler's open step and adds to
+    one float of it, and touches no segment."""
+
+    __slots__ = ("_prof", "_t0", "_span", "_callbacks")
+
+    def __init__(self, prof: TickProfiler):
+        # the callback of the reference runs when the profiler is freed,
+        # never while the interpreter walks `gc.callbacks`
+        self._prof = weakref.ref(prof, self._gone)
+        self._t0 = self._span = None
+        # the list itself: at interpreter exit the module `gc` is gone
+        self._callbacks = gc.callbacks
+        self._callbacks.append(self)
+
+    def _gone(self, _ref) -> None:
+        if self in self._callbacks:
+            self._callbacks.remove(self)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        prof = self._prof()
+        if prof is None:
+            return
+        if phase == "start":
+            if prof._cur is None or prof._thread != threading.get_ident():
+                return
+            if prof._annotate is not None:
+                self._span = prof._annotate(
+                    f"{STEP_SPAN}/{GC_KEY}", generation=info["generation"])
+                self._span.__enter__()
+            self._t0 = prof._clock()
+            return
+        # each on its own: an interrupt may have cut `start` short
+        if self._t0 is not None:
+            prof._gc_s += prof._clock() - self._t0
+            self._t0 = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
 
 class FlightRecorder:

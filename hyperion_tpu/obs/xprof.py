@@ -51,10 +51,18 @@ import statistics
 import struct
 from pathlib import Path
 
+from hyperion_tpu.obs.tickprof import DISPATCH, FETCH
+
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 UNSCOPED = "unscoped"
 NO_SPAN = "(no span)"
 LONGEST_GAPS = 20
+# the spans with the most idle seconds that get the runtime's own events
+# listed under them, and how many events each
+MEANWHILE_SPANS = MEANWHILE = 5
+# A span that hands a program to the device ends in DISPATCH, the span
+# that waits for its results in FETCH (`serve.step/device/dispatch`,
+# `train.dispatch`): the names `obs/tickprof.py` gives those children.
 # the program's own host spans start with one of these: the engine's
 # step segments and the trainer's loop. Everything else on a host line
 # is the runtime's (`PjitFunction(..)`, `PjRtCpuExecutable::Execute`).
@@ -424,23 +432,98 @@ class _Span:
         return out
 
 
-def _program_spans(profile) -> list[list[_Span]]:
-    """The program's spans, one list per host thread that opened any,
-    each sorted by start (a span's children start no earlier and end no
-    later)."""
-    threads = []
+def _host_lines(profile):
+    """((plane name, line's place, line name), line) of every host
+    thread's line: two threads may share a name."""
     for plane in profile.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            # `_`-led stats are the profiler's own bookkeeping
-            spans = [_Span(e.name, *_seconds(e),
-                           {k: v for k, v in e.stats
-                            if not k.startswith("_")})
-                     for e in line.events if e.name.startswith(SPAN_PREFIXES)]
-            if spans:
-                threads.append(sorted(spans, key=lambda s: (s.start, -s.end)))
+        if plane.name.startswith("/host:"):
+            for at, line in enumerate(plane.lines):
+                yield (plane.name, at, line.name), line
+
+
+def _program_spans(profile) -> dict[tuple, list[_Span]]:
+    """The program's spans by the host line that holds them, each list
+    sorted by start (a span's children start no earlier and end no
+    later)."""
+    threads = {}
+    for key, line in _host_lines(profile):
+        # `_`-led stats are the profiler's own bookkeeping
+        spans = [_Span(e.name, *_seconds(e),
+                       {k: v for k, v in e.stats if not k.startswith("_")})
+                 for e in line.events if e.name.startswith(SPAN_PREFIXES)]
+        if spans:
+            threads[key] = sorted(spans, key=lambda s: (s.start, -s.end))
     return threads
+
+
+def _runtime_events(profile, but: tuple | None) -> list[tuple]:
+    """(start, end, name, line name) of what the runtime's own threads
+    recorded: every host line but the loop's (`but`), the program's
+    spans on them left out. Sorted by start."""
+    return sorted(
+        (*_seconds(e), e.name, key[2])
+        for key, line in _host_lines(profile) if key != but
+        for e in line.events if not e.name.startswith(SPAN_PREFIXES))
+
+
+def _overlaps(intervals, events) -> dict[tuple, float]:
+    """{(event name, line name): seconds} of `events`
+    (`_runtime_events`) inside the sorted, disjoint `intervals`."""
+    starts = [a for a, _ in intervals]
+    ends = [b for _, b in intervals]
+    held: dict[tuple, float] = {}
+    for s, e, name, line in events:
+        k = bisect.bisect_right(ends, s)
+        over = 0.0
+        while k < len(starts) and starts[k] < e:
+            over += min(e, ends[k]) - max(s, starts[k])
+            k += 1
+        if over > 0:
+            held[(name, line)] = held.get((name, line), 0.0) + over
+    return held
+
+
+def _program_lag(spans: list[_Span], devices: list[dict]) -> list[dict]:
+    """How long a program waited to start and how long its results
+    waited to be read, by (program, dispatch span): for each `*dispatch`
+    span of the loop's thread and the `*fetch` span that follows it, the
+    longest run of a compiled program inside the pair (an upload's
+    one-operation `convert_element_type` may run there too). Launch lag
+    is the run's start minus the dispatch span's start; fetch lag the
+    fetch span's end minus the run's end."""
+    fetches: dict[str, list[_Span]] = {}
+    for sp in spans:
+        if sp.name.endswith(FETCH):
+            fetches.setdefault(sp.name, []).append(sp)
+    starts_of = {name: [sp.start for sp in sps]
+                 for name, sps in fetches.items()}
+    by_device = [(d["runs"], [r[1] for r in d["runs"]]) for d in devices]
+    lags: dict[tuple, list[tuple[float, float]]] = {}
+    for sp in spans:
+        if not sp.name.endswith(DISPATCH):
+            continue
+        partner = sp.name[:-len(DISPATCH)] + FETCH
+        k = bisect.bisect_left(starts_of.get(partner, []), sp.end)
+        if k >= len(fetches.get(partner, [])):
+            continue
+        fetch = fetches[partner][k]
+        for runs, run_starts in by_device:
+            inside = [r for r in runs[bisect.bisect_left(
+                run_starts, sp.start):bisect.bisect_right(
+                run_starts, fetch.end)] if r[2] <= fetch.end]
+            if inside:
+                prog, start, end = max(inside, key=lambda r: r[2] - r[1])
+                lags.setdefault((prog, sp.name), []).append(
+                    (start - sp.start, fetch.end - end))
+    n = len(devices)
+    return sorted((
+        {"program": prog, "dispatch": name, "n": len(v),
+         "launch_median_s": statistics.median(a for a, _ in v),
+         "launch_total_s": sum(a for a, _ in v) / n,
+         "fetch_median_s": statistics.median(b for _, b in v),
+         "fetch_total_s": sum(b for _, b in v) / n}
+        for (prog, name), v in lags.items()),
+        key=lambda r: -(r["launch_total_s"] + r["fetch_total_s"]))
 
 
 def _innermost(spans: list[_Span]) -> list[tuple[float, float, _Span]]:
@@ -521,8 +604,11 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
         raise ValueError("no operation ran on a device in this trace")
 
     threads = _program_spans(profile)
-    spans = [sp for th in threads for sp in th]
-    pieces = _innermost(max(threads, key=_loop_seconds, default=[]))
+    spans = [sp for th in threads.values() for sp in th]
+    # the thread that runs the loop
+    loop = max(threads, key=lambda k: _loop_seconds(threads[k]),
+               default=None)
+    pieces = _innermost(threads.get(loop, []))
     if window is None:
         window = (min(o.start for d in devices for o in d["ops"]),
                   max(o.end for d in devices for o in d["ops"]))
@@ -536,6 +622,8 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
                  "none": 0.0}
     left: dict[tuple, list] = {}
     idle_by: dict[str, float] = {}
+    # by span, each device's idle intervals under it
+    idle_at: dict[str, list[list]] = {}
     gaps: list[dict] = []
     per_device = []
     modules: dict[str, list[float]] = {}
@@ -612,7 +700,7 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
         edges = [lo] + [x for se in merged for x in se] + [hi]
         dev_gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2])
                     if b > a]
-        _attribute(dev_gaps, pieces, idle_by, gaps, dev, 1 / n)
+        _attribute(dev_gaps, pieces, idle_by, idle_at, gaps, dev, 1 / n)
 
     total = sum(r["s"] for r in rows.values()) or 1e-12
     device_rows = []
@@ -638,6 +726,21 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
         if sp.end > lo and sp.start < hi:
             host.setdefault(sp.name, []).append(sp.end - sp.start)
     gaps.sort(key=lambda g: -g["s"])
+    idle_rows = [
+        {"span": k, "s": v, "share": v / idle if idle > 0 else 0.0}
+        for k, v in sorted(idle_by.items(), key=lambda kv: -kv[1])]
+    # what the runtime's own threads were doing in the gaps under the
+    # spans that hold most of the idle time
+    runtime = _runtime_events(profile, loop) if idle_rows else []
+    for row in idle_rows[:MEANWHILE_SPANS]:
+        held: dict[tuple, float] = {}
+        for intervals in idle_at.get(row["span"], []):
+            for key, sec in _overlaps(intervals, runtime).items():
+                held[key] = held.get(key, 0.0) + sec / n
+        row["meanwhile"] = [
+            {"event": name, "thread": line, "s": sec}
+            for (name, line), sec in sorted(
+                held.items(), key=lambda kv: -kv[1])[:MEANWHILE]]
     return {
         "trace": str(path), "devices": n,
         "window_s": hi - lo, "busy_s": busy, "idle_s": idle,
@@ -655,9 +758,9 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
              "s": sec, "n": cnt}
             for (prog, _), (sec, cnt, name) in sorted(
                 left.items(), key=lambda kv: -kv[1][0])[:LONGEST_GAPS]],
-        "idle_by_span": [
-            {"span": k, "s": v, "share": v / idle if idle > 0 else 0.0}
-            for k, v in sorted(idle_by.items(), key=lambda kv: -kv[1])],
+        "idle_by_span": idle_rows,
+        # launch and fetch lag of each compiled program the loop sent
+        "program_lag": _program_lag(threads.get(loop, []), devices),
         "longest_gaps": gaps[:LONGEST_GAPS],
         "host_spans": sorted((
             {"span": k, "n": len(v), "total_s": sum(v),
@@ -670,10 +773,14 @@ def summarize(trace, peaks: dict | None = None, *, window=None,
     }
 
 
-def _attribute(dev_gaps, pieces, idle_by, gaps, dev, weight) -> None:
+def _attribute(dev_gaps, pieces, idle_by, idle_at, gaps, dev,
+               weight) -> None:
     """Each idle gap's seconds go to the innermost spans it overlaps
-    (both lists sorted and disjoint); the gap itself is listed under the
-    span that holds most of it, with the programs around it."""
+    (both lists sorted and disjoint), and the overlap itself to this
+    device's list under the span's name in `idle_at`; the gap itself is
+    listed under the span that holds most of it, with the programs
+    around it."""
+    mine: dict[str, list] = {}
     by_end = sorted(dev["ops"], key=lambda o: o.end)
     by_start = sorted(dev["ops"], key=lambda o: o.start)
     ends = [o.end for o in by_end]
@@ -689,6 +796,7 @@ def _attribute(dev_gaps, pieces, idle_by, gaps, dev, weight) -> None:
             over = min(b, e) - max(a, s)
             if over > 0:
                 idle_by[sp.name] = idle_by.get(sp.name, 0.0) + over * weight
+                mine.setdefault(sp.name, []).append((max(a, s), min(b, e)))
                 left -= over
                 if over > best_s:
                     best, best_s = sp, over
@@ -704,6 +812,8 @@ def _attribute(dev_gaps, pieces, idle_by, gaps, dev, weight) -> None:
             "args": best.all_args() if best and best_s >= left else {},
             "before": by_end[k].module if k >= 0 else None,
             "after": by_start[m].module if m < len(starts) else None})
+    for name, intervals in mine.items():
+        idle_at.setdefault(name, []).append(intervals)
 
 
 def to_markdown(summary: dict, rows: int = 40) -> str:
@@ -747,6 +857,25 @@ def to_markdown(summary: dict, rows: int = 40) -> str:
             "| span | s | % of idle |", "|---|---|---|"]
     out += [f"| {r['span']} | {r['s']:.4f} | {100 * r['share']:.1f} |"
             for r in s["idle_by_span"][:rows]]
+    for r in s["idle_by_span"]:
+        if r.get("meanwhile"):
+            out += ["", f"the runtime's threads in the gaps under "
+                        f"{r['span']} ({r['s']:.4f} s):", "",
+                    "| event | thread | s |", "|---|---|---|"]
+            out += [f"| {m['event'][:80]} | {m['thread']} | {m['s']:.4f} |"
+                    for m in r["meanwhile"]]
+    if s["program_lag"]:
+        out += ["", "launch lag (a program's start after its dispatch "
+                    "span's) and fetch lag (its fetch span's end after "
+                    "the program's):", "",
+                "| program | dispatch span | n | launch median ms | "
+                "launch total s | fetch median ms | fetch total s |",
+                "|---|---|---|---|---|---|---|"]
+        out += [f"| {r['program']} | {r['dispatch']} | {r['n']} | "
+                f"{1e3 * r['launch_median_s']:.3f} | "
+                f"{r['launch_total_s']:.4f} | "
+                f"{1e3 * r['fetch_median_s']:.3f} | "
+                f"{r['fetch_total_s']:.4f} |" for r in s["program_lag"]]
     out += ["", "longest gaps:", "",
             "| ms | span | args | program before | program after |",
             "|---|---|---|---|---|"]

@@ -671,8 +671,7 @@ class Engine:
             # publish occupancy from tick zero: `obs top` renders a
             # null gauge as tier-DISABLED, and an enabled-but-cold
             # tier must read 0.00/0M instead
-            self.metrics.observe_host_cache(
-                self.host.occupancy_mb, len(self.host))
+            self.metrics.observe_host_cache(self.host.occupancy_mb)
         self._bt = self._bts[first_kind]
         self._bt_dev = None   # device mirror of (_bts, live); None = stale
         self._pending_reserve: dict[str, dict[str, int]] = {}
@@ -697,6 +696,8 @@ class Engine:
         # every segment of a step is timed through this profiler and is
         # a span on the device profiler's clock meanwhile
         self.tickprof = TickProfiler(clock=_CLOCK, annotate=annotate)
+        # a collection inside a step: the span `serve.step/gc`, `gc_us`
+        self.tickprof.watch_collector()
         self.flight = (FlightRecorder(flight_path, run=self.tracer.run)
                        if flight_path else null_flight_recorder())
         self._prefill_tokens = 0  # padded tokens prefilled this step
@@ -907,8 +908,14 @@ class Engine:
             self._cache, self._state, first, finished = self._prefill_jit(
                 self.model, self.cfg.eos_id,
                 self.variables, self._cache, self._state, *args)
+        # an array a wait, in the order the host waits: the first holds
+        # the program's run, the second a program that has ended
         with prof.seg(f"{seg}/fetch", **at):
-            return int(first), bool(finished)
+            with prof.seg(f"{seg}/fetch/tokens"):
+                first = int(first)
+            with prof.seg(f"{seg}/fetch/finished"):
+                finished = bool(finished)
+            return first, finished
 
     def _live_mask(self) -> np.ndarray:
         """Slots the decode tick may advance: occupied AND not mid-
@@ -1020,8 +1027,14 @@ class Engine:
             for x in counted.values():
                 # rides the tokens' fetch: on the host by the time they are
                 x.copy_to_host_async()
-            toks, fins = np.asarray(toks), np.asarray(fins)
-            self._tick_counted = {k: int(v) for k, v in counted.items()}
+            with prof.seg("device/fetch/tokens"):
+                toks = np.asarray(toks)
+            with prof.seg("device/fetch/finished"):
+                fins = np.asarray(fins)
+            if counted:
+                with prof.seg("device/fetch/counters"):
+                    self._tick_counted = {
+                        k: int(v) for k, v in counted.items()}
             return toks, fins
 
     def _collect_drafts(self) -> np.ndarray:
@@ -1046,8 +1059,12 @@ class Engine:
                 self.variables, self._cache, self._state, *tables,
                 jnp.asarray(drafts))
         with prof.seg("device/fetch"):
-            return (np.asarray(out), np.asarray(cnt), np.asarray(acc),
-                    np.asarray(fins))
+            fetched = []
+            for name, x in (("tokens", out), ("counts", cnt),
+                            ("accepted", acc), ("finished", fins)):
+                with prof.seg(f"device/fetch/{name}"):
+                    fetched.append(np.asarray(x))
+            return tuple(fetched)
 
     # --------------------------------------------------- block plumbing
 
@@ -1196,8 +1213,7 @@ class Engine:
         payload = payload.reshape(-1, *payload.shape[2:])
         if self.host.put(chain_tokens, payload):
             self.metrics.on_host_spill(payload.nbytes)
-            self.metrics.observe_host_cache(
-                self.host.occupancy_mb, len(self.host))
+            self.metrics.observe_host_cache(self.host.occupancy_mb)
 
     def _restore_blocks(self, blocks: list[int],
                         payloads: list[np.ndarray]) -> int:
@@ -1239,7 +1255,9 @@ class Engine:
         probe, pins, allocation, copy-on-write fork and restore. Returns
         (prompt, budget, start, seqs), `start` the prefix hit in tokens
         and `seqs` the slot's chain of each layer kind, or None when
-        allocation lost a race."""
+        allocation lost a race. Each stage that runs is a child of the
+        caller's `admit/blocks`."""
+        prof = self.tickprof
         prompt, budget = self._effective(req)
         P = int(prompt.shape[0])
         bs = self.cfg.block_size
@@ -1249,42 +1267,48 @@ class Engine:
         host_payloads: list[np.ndarray] = []
         device_start = 0
         if self.prefix is not None:
-            m = self.prefix.lookup(prompt, P - 1)
-            shared, start, cow_src = m.blocks, m.tokens, m.cow_src
-            device_start = start
-            if self.host is not None:
-                # device-miss -> host-hit fall-through: probe the host
-                # tier for full-block chain links beyond the device
-                # match. A host extension only wins when it covers MORE
-                # than the device walk (its mid-block COW extension
-                # included) — then the restore supersedes the COW copy.
-                base = len(shared) * bs
-                host_payloads = self.host.match(prompt, base, P - 1)
-                if host_payloads \
-                        and base + len(host_payloads) * bs > start:
-                    start = base + len(host_payloads) * bs
-                    cow_src = None
-                else:
-                    host_payloads = []
-        need_now = blocks_for(P, bs) - len(shared)
-        # pin the matched chain (and the COW source) BEFORE allocating:
-        # allocation may evict radix holds, and a trie-only block we
-        # just matched is exactly what LRU eviction would pick off
-        pin = shared + ([cow_src] if cow_src is not None else [])
-        self.mgr.incref(pin)
-        fresh = self._alloc(need_now) if need_now else []
-        # a windowed kind starts with the blocks of the first piece the
-        # prefill writes; `_slide_windows` moves the chain on from there
-        piece = blocks_for(min(P, self.cfg.prefill_chunk or P), bs)
-        windows = {k: self._mgrs[k].alloc(piece)
-                   for k, w in self._kinds.items() if w} \
-            if fresh is not None else {}
-        if fresh is None or None in windows.values():
-            self.mgr.decref(pin + (fresh or []))
-            for k, got in windows.items():
-                self._mgrs[k].decref(got or [])
-            self._release_pending(req.id)
-            return None
+            with prof.seg("admit/blocks/lookup"):
+                m = self.prefix.lookup(prompt, P - 1)
+                shared, start, cow_src = m.blocks, m.tokens, m.cow_src
+                device_start = start
+                if self.host is not None:
+                    # device-miss -> host-hit fall-through: probe the
+                    # host tier for full-block chain links beyond the
+                    # device match. A host extension only wins when it
+                    # covers MORE than the device walk (its mid-block COW
+                    # extension included) — then the restore supersedes
+                    # the COW copy.
+                    base = len(shared) * bs
+                    host_payloads = self.host.match(prompt, base, P - 1)
+                    if host_payloads \
+                            and base + len(host_payloads) * bs > start:
+                        start = base + len(host_payloads) * bs
+                        cow_src = None
+                    else:
+                        host_payloads = []
+        # radix eviction, where the pool is short, is inside `_alloc`
+        with prof.seg("admit/blocks/alloc_evict"):
+            need_now = blocks_for(P, bs) - len(shared)
+            # pin the matched chain (and the COW source) BEFORE
+            # allocating: allocation may evict radix holds, and a
+            # trie-only block we just matched is exactly what LRU
+            # eviction would pick off
+            pin = shared + ([cow_src] if cow_src is not None else [])
+            self.mgr.incref(pin)
+            fresh = self._alloc(need_now) if need_now else []
+            # a windowed kind starts with the blocks of the first piece
+            # the prefill writes; `_slide_windows` moves the chain on
+            # from there
+            piece = blocks_for(min(P, self.cfg.prefill_chunk or P), bs)
+            windows = {k: self._mgrs[k].alloc(piece)
+                       for k, w in self._kinds.items() if w} \
+                if fresh is not None else {}
+            if fresh is None or None in windows.values():
+                self.mgr.decref(pin + (fresh or []))
+                for k, got in windows.items():
+                    self._mgrs[k].decref(got or [])
+                self._release_pending(req.id)
+                return None
         # Re-derive the growth reservation instead of netting the
         # gate's estimate against need_now: an earlier admission this
         # round may have evicted blocks the gate counted as shared, and
@@ -1292,31 +1316,33 @@ class Engine:
         # not depend on sharing at all, so computing it directly keeps
         # the reserve-mode "exhaustion impossible" ledger exact even
         # when the gate's sharing estimate went stale.
-        self._release_pending(req.id)
-        growth = 0
-        span = blocks_for(P + budget, bs)
-        if self.cfg.admission == "reserve":
-            growth = span - blocks_for(P, bs)
-            self.mgr.reserve(growth)
-        seq = SeqAlloc(
-            blocks=shared + fresh, n_shared=len(shared),
-            reserved=growth, order=next(self._order),
-        )
-        seqs = {self._first_kind: seq}
-        for k, got in windows.items():
-            ahead = min(self._hold[k], span) - piece \
-                if self.cfg.admission == "reserve" else 0
-            self._mgrs[k].reserve(ahead)
-            seqs[k] = SeqAlloc(blocks=got, reserved=ahead, limit=span)
+        with prof.seg("admit/blocks/reserve"):
+            self._release_pending(req.id)
+            growth = 0
+            span = blocks_for(P + budget, bs)
+            if self.cfg.admission == "reserve":
+                growth = span - blocks_for(P, bs)
+                self.mgr.reserve(growth)
+            seq = SeqAlloc(
+                blocks=shared + fresh, n_shared=len(shared),
+                reserved=growth, order=next(self._order),
+            )
+            seqs = {self._first_kind: seq}
+            for k, got in windows.items():
+                ahead = min(self._hold[k], span) - piece \
+                    if self.cfg.admission == "reserve" else 0
+                self._mgrs[k].reserve(ahead)
+                seqs[k] = SeqAlloc(blocks=got, reserved=ahead, limit=span)
         if cow_src is not None:
             # mid-block divergence: duplicate the agreeing block so our
             # writes (suffix prefill + decode) never touch the shared
             # original — the copy-on-write half of the design
-            self._cache = self._copy_jit(
-                self._cache, self._in_every_segment([cow_src]),
-                self._in_every_segment([fresh[0]]))
-            self.mgr.decref([cow_src])  # the pin; the copy is ours now
-            self.metrics.on_cow()
+            with prof.seg("admit/blocks/cow"):
+                self._cache = self._copy_jit(
+                    self._cache, self._in_every_segment([cow_src]),
+                    self._in_every_segment([fresh[0]]))
+                self.mgr.decref([cow_src])  # the pin; the copy is ours now
+                self.metrics.on_cow()
         if host_payloads:
             # promote the matched chain out of the host tier: the first
             # len(host_payloads) fresh blocks are exactly the logical
@@ -1325,26 +1351,27 @@ class Engine:
             # post-prefill `prefix.insert` re-registers the whole chain
             # (restored blocks included) in the radix, so the prefix is
             # device-cached again for the next sharer.
-            moved = self._restore_blocks(
-                fresh[:len(host_payloads)], host_payloads)
-            host_tokens = len(host_payloads) * bs
-            self.metrics.on_host_restore(len(host_payloads), moved)
-            self.metrics.observe_host_cache(
-                self.host.occupancy_mb, len(self.host))
-            self.tracer.event(
-                "host_restore", request=req.id, tick=self._tick_no,
-                blocks=len(host_payloads), tokens=host_tokens,
-                bytes=moved, **_tr(req))
+            with prof.seg("admit/blocks/restore"):
+                moved = self._restore_blocks(
+                    fresh[:len(host_payloads)], host_payloads)
+                host_tokens = len(host_payloads) * bs
+                self.metrics.on_host_restore(len(host_payloads), moved)
+                self.metrics.observe_host_cache(self.host.occupancy_mb)
+                self.tracer.event(
+                    "host_restore", request=req.id, tick=self._tick_no,
+                    blocks=len(host_payloads), tokens=host_tokens,
+                    bytes=moved, **_tr(req))
         if self.prefix is not None:
-            self.metrics.on_prefix_lookup(P, start)
-            # tier attribution: under a host hit the device's share is
-            # the full-block walk (the superseded COW extension never
-            # ran), so device + host sum to exactly `start`
-            self.metrics.on_tier_lookup(
-                device_tokens=len(shared) * bs if host_payloads
-                else device_start,
-                host_tokens=len(host_payloads) * bs)
-            self._hot_roots.note(prefix_root_digest(prompt))
+            with prof.seg("admit/blocks/account"):
+                self.metrics.on_prefix_lookup(P, start)
+                # tier attribution: under a host hit the device's share
+                # is the full-block walk (the superseded COW extension
+                # never ran), so device + host sum to exactly `start`
+                self.metrics.on_tier_lookup(
+                    device_tokens=len(shared) * bs if host_payloads
+                    else device_start,
+                    host_tokens=len(host_payloads) * bs)
+                self._hot_roots.note(prefix_root_digest(prompt))
         return prompt, budget, start, seqs
 
     def _admit(self, req: Request, slot: int) -> TokenEvent | None:
@@ -2098,44 +2125,47 @@ class Engine:
         happen, so the segments around them come out net of them."""
         # the record and the span carry the number the step STARTED under,
         # the one its `serve_tick` JSONL span and its events carry
-        with self.tickprof.tick(self._tick_no) as tk:
+        prof = self.tickprof
+        with prof.tick(self._tick_no) as tk:
             emissions = self._step()
-            tk.count(
-                # positions whose keys and values the live slots hold in
-                # the pool: host bookkeeping, no device read
-                kv_tokens=sum(q.n_filled for q in self._seqs
-                              if q is not None),
-                # and those among them a windowed layer kind still holds
-                # (its leading blocks went back to the pool): per kind
-                **{f"kv_tokens_{k}": sum(
-                    q.n_filled - self._allocs[k][s].first
-                    * self.cfg.block_size
-                    for s, q in enumerate(self._seqs) if q is not None)
-                   for k, w in self._kinds.items() if w},
-                # an expert model's picks on this step's decode tick
-                **self._tick_counted,
-                # a looped model's decode tick: the steps it ran a row
-                # and the cache layers it wrote and read (steps x layers)
-                **self._loop_counted,
-                prefill_tokens=self._prefill_tokens,
-                # what the tick's rows asked of `sample_token_slots`,
-                # from the requests' own parameters: 0 and 0 = the tick
-                # ran the argmax alone, any restricted row = it sorted
-                # the vocabulary for every row
-                sampling_rows=self._sampling_rows,
-                restricted_rows=self._restricted_rows,
-                # how much of each kind's table the tick's read touched:
-                # the blocks the kernel's loops visit a layer (0 = the
-                # tick gathered), of the entries a gather copies a layer
-                **self._walk_counted,
-                # how the step's prefills, its chunk and its tick put
-                # keys and values into the pools, a layer of each kind:
-                # whole blocks, and positions row by row
-                **self._write_counted,
-                # an expert model: the (token, pick) rows the step's
-                # tick, chunk and prefills sent through each form of
-                # the grouped products, over the expert layers
-                **self._expert_rows)
+            # `count`: what the record's counters cost the step
+            with prof.seg("count"):
+                tk.count(
+                    # positions whose keys and values the live slots hold in
+                    # the pool: host bookkeeping, no device read
+                    kv_tokens=sum(q.n_filled for q in self._seqs
+                                  if q is not None),
+                    # and those among them a windowed layer kind still holds
+                    # (its leading blocks went back to the pool): per kind
+                    **{f"kv_tokens_{k}": sum(
+                        q.n_filled - self._allocs[k][s].first
+                        * self.cfg.block_size
+                        for s, q in enumerate(self._seqs) if q is not None)
+                       for k, w in self._kinds.items() if w},
+                    # an expert model's picks on this step's decode tick
+                    **self._tick_counted,
+                    # a looped model's decode tick: the steps it ran a row
+                    # and the cache layers it wrote and read (steps x layers)
+                    **self._loop_counted,
+                    prefill_tokens=self._prefill_tokens,
+                    # what the tick's rows asked of `sample_token_slots`,
+                    # from the requests' own parameters: 0 and 0 = the tick
+                    # ran the argmax alone, any restricted row = it sorted
+                    # the vocabulary for every row
+                    sampling_rows=self._sampling_rows,
+                    restricted_rows=self._restricted_rows,
+                    # how much of each kind's table the tick's read touched:
+                    # the blocks the kernel's loops visit a layer (0 = the
+                    # tick gathered), of the entries a gather copies a layer
+                    **self._walk_counted,
+                    # how the step's prefills, its chunk and its tick put
+                    # keys and values into the pools, a layer of each kind:
+                    # whole blocks, and positions row by row
+                    **self._write_counted,
+                    # an expert model: the (token, pick) rows the step's
+                    # tick, chunk and prefills sent through each form of
+                    # the grouped products, over the expert layers
+                    **self._expert_rows)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -2296,41 +2326,44 @@ class Engine:
                     self._on_finished(ev.request)
 
         if self.n_active:
-            self._ensure_blocks()
+            with prof.seg("ensure"):
+                self._ensure_blocks()
         n_live = self.n_active - len(self._chunking)
         if n_live > 0:
             if self.chaos is not None:
                 self.chaos.on_tick(self._tick_no)
             spec = self._spec
             cnts = accs = None
-            for s, req in enumerate(self._slots):
-                if req is not None and req.temperature > 0 \
-                        and s not in self._chunking:
-                    self._sampling_rows += 1
-                    self._restricted_rows += \
-                        req.top_k > 0 or req.top_p < 1.0
-            self._walk_counted = self._count_walk()
-            self._loop_counted = self._loop_per_tick
-            # the tick writes each live slot's window row by row
-            for _, positions in self._write_names.values():
-                self._write_counted[positions] += n_live * self._tick_width
-            # the tick runs every slot's row through the experts, live
-            # or masked out
-            self._count_experts(self.cfg.slots * self._tick_width)
+            # what the tick record's counters cost the step: `count`
+            with prof.seg("count"):
+                for s, req in enumerate(self._slots):
+                    if req is not None and req.temperature > 0 \
+                            and s not in self._chunking:
+                        self._sampling_rows += 1
+                        self._restricted_rows += \
+                            req.top_k > 0 or req.top_p < 1.0
+                self._walk_counted = self._count_walk()
+                self._loop_counted = self._loop_per_tick
+                # the tick writes each live slot's window row by row
+                for _, positions in self._write_names.values():
+                    self._write_counted[positions] += \
+                        n_live * self._tick_width
+                # the tick runs every slot's row through the experts,
+                # live or masked out
+                self._count_experts(self.cfg.slots * self._tick_width)
             with prof.seg("draft"):
                 drafts = self._collect_drafts() if spec else None
             # the device call's wall splits into the host->device table
             # upload (`bt_upload`, when the table went stale) and
             # `device`: its children dispatch and fetch
             with self.tracer.span("serve_tick", step=self._tick_no) as sp:
-                with prof.seg("device") as dev:
+                with prof.seg("device"):
                     if spec:
                         toks, cnts, accs, fins = \
                             self._spec_tick_device(drafts)
                     else:
                         toks, fins = self._tick_device()
                 sp.set(active=self.n_active)
-            dur = dev.gross
             emitted = 0
             slot_ticks = 0
             # accept host path: token routing + gap netting, net of the
@@ -2385,42 +2418,46 @@ class Engine:
                     if fin_slot:
                         self._on_finished(req)
                         self._free_slot(s)
-            self.metrics.on_tick(dur, emitted, slot_ticks)
-            self._tick_no += 1
-            if self.cfg.snapshot_every \
-                    and self._tick_no % self.cfg.snapshot_every == 0:
-                rss = hb_host_rss_mb()
-                if rss is not None:
-                    # a gauge SERIES across snapshots — doctor reads the
-                    # trend for its host-leak warning
-                    self.metrics.reg.gauge("host_rss_mb").set(rss)
-                self.tracer.snapshot(self.metrics.reg, step=self._tick_no,
-                                     tickprof=self.tickprof.snapshot())
+                # the tick's own account closes inside its segment
+                self.metrics.on_tick(emitted, slot_ticks)
+                self._tick_no += 1
+                if self.cfg.snapshot_every \
+                        and self._tick_no % self.cfg.snapshot_every == 0:
+                    with prof.seg("accept/snapshot"):
+                        rss = hb_host_rss_mb()
+                        if rss is not None:
+                            # a gauge SERIES across snapshots — doctor
+                            # reads the trend for its host-leak warning
+                            self.metrics.reg.gauge("host_rss_mb").set(rss)
+                        self.tracer.snapshot(
+                            self.metrics.reg, step=self._tick_no,
+                            tickprof=self.tickprof.snapshot())
 
-        # compile ledger: 4 host-int reads per step. Any growth after
-        # warmup is a broken invariant — count it, name the executable,
-        # and leave churn context (what shape work just ran) for doctor
-        growth = self.ledger.check(self.compile_stats())
-        if growth:
-            self.metrics.on_recompile(
-                sum(g["after"] - g["before"] for g in growth))
-            for g in growth:
-                ctx = dict(tick=self._tick_no, active=self.n_active,
-                           queue=len(self.queue),
-                           last_prefill_bucket=self._last_prefill_bucket)
-                self.tracer.event("recompile_after_warmup",
-                                  executable=g["executable"],
-                                  before=g["before"], after=g["after"],
-                                  **ctx)
-                self.flight.note("recompile_after_warmup",
-                                 executable=g["executable"], **ctx)
-
+        # the step's watch duties: the compile ledger, the gauges, the
+        # SLO engine, the heartbeat
         with prof.seg("slo"):
+            # compile ledger: 4 host-int reads per step. Any growth after
+            # warmup is a broken invariant — count it, name the
+            # executable, and leave churn context (what shape work just
+            # ran) for doctor
+            growth = self.ledger.check(self.compile_stats())
+            if growth:
+                self.metrics.on_recompile(
+                    sum(g["after"] - g["before"] for g in growth))
+                for g in growth:
+                    ctx = dict(tick=self._tick_no, active=self.n_active,
+                               queue=len(self.queue),
+                               last_prefill_bucket=self._last_prefill_bucket)
+                    self.tracer.event("recompile_after_warmup",
+                                      executable=g["executable"],
+                                      before=g["before"], after=g["after"],
+                                      **ctx)
+                    self.flight.note("recompile_after_warmup",
+                                     executable=g["executable"], **ctx)
             self.metrics.observe_state(
                 len(self.queue), self.n_active, self.cfg.slots)
             self.metrics.observe_cache(
-                self.mgr.in_use, self.mgr.num_free, self.n_active,
-                self._block_bytes)
+                self.mgr.in_use, self.n_active, self._block_bytes)
             self._slo_tick()
             roots = self._hot_roots.top()
             self.hb.beat(step=self._tick_no, phase="serve",

@@ -201,11 +201,10 @@ class ServeMetrics:
             self.reg.gauge("serve_restore_bytes_per_s").set(
                 self._restore_bytes / elapsed)
 
-    def observe_host_cache(self, occupancy_mb: float, chains: int) -> None:
+    def observe_host_cache(self, occupancy_mb: float) -> None:
         """Host-tier occupancy after a spill or restore — the memory
         ledger's host-side sibling of blocks_in_use."""
         self.reg.gauge("serve_host_cache_mb").set(occupancy_mb)
-        self.reg.gauge("serve_host_cache_chains").set(chains)
 
     def on_preempt(self) -> None:
         self.reg.counter("serve_preempted").inc()
@@ -254,15 +253,14 @@ class ServeMetrics:
     def on_evict(self, n: int) -> None:
         self.reg.counter("serve_blocks_evicted").inc(n)
 
-    def observe_cache(self, blocks_in_use: int, blocks_free: int,
-                      active_reqs: int, block_bytes: int) -> None:
+    def observe_cache(self, blocks_in_use: int, active_reqs: int,
+                      block_bytes: int) -> None:
         """Cache-pressure gauges, refreshed every step. blocks_in_use
         near capacity with preemptions counting up = `--num-blocks`
         undersized; hbm_per_req_mb is the honest per-request memory
         cost AFTER sharing — the number the slab design could never
         report below slots x max_len."""
         self.reg.gauge("serve_blocks_in_use").set(blocks_in_use)
-        self.reg.gauge("serve_blocks_free").set(blocks_free)
         if active_reqs:
             self.reg.gauge("serve_hbm_per_req_mb").set(
                 blocks_in_use * block_bytes / active_reqs / 2**20)
@@ -278,10 +276,9 @@ class ServeMetrics:
             self._tokens += n
             self.reg.counter("tokens").inc(n)
 
-    def on_tick(self, dur_s: float, tokens_emitted: int,
+    def on_tick(self, tokens_emitted: int,
                 slot_ticks: int | None = None) -> None:
         self.reg.counter("serve_ticks").inc()
-        self.reg.histogram("serve_tick_ms").observe(dur_s * 1e3)
         self.count_tokens(tokens_emitted)
         # effective tokens per SLOT-tick (one live slot in one tick):
         # decode emissions over slot-ticks, prefill firsts excluded.
@@ -316,7 +313,6 @@ class ServeMetrics:
         out; occupancy low with rejections = prompt lengths exceed the
         cache, not capacity."""
         self.reg.gauge("queue_depth").set(queue_depth)
-        self.reg.gauge("slots_active").set(slots_active)
         self.reg.gauge("slot_occupancy").set(
             slots_active / n_slots if n_slots else 0.0)
         elapsed = self._clock() - self._t0

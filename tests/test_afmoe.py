@@ -364,6 +364,52 @@ def test_a_held_shares_steps_count_every_pick_row_by_form(tiny):
             c["prefill_tokens"] + 3 * ("device" in r["s"]))
 
 
+def test_a_chunked_prompts_fetches_and_the_flight_account(tiny):
+    """The by-kind model with chunks: a piece that is not the last waits
+    once (`chunk/fetch`, no array to name), the last piece's fetch and
+    the tick's name their arrays, the tick's device counters among
+    them; `ensure` and `count` are segments; the four flight counters
+    are in every record, in order, and a step's `inflight_us` holds its
+    chunk's and its tick's dispatch-to-fetch stretches."""
+    cfg, model, params = tiny
+    eng = Engine(model, {"params": params}, EngineConfig(
+        slots=3, max_len=64, block_size=4, prefill_chunk=8,
+        prefix_cache=False))
+    eng.warmup([8])
+    rng = np.random.default_rng(2)
+    serve(eng, [Request(prompt_ids=rng.integers(1, cfg.vocab_size, n)
+                        .astype(np.int32), max_new_tokens=g, id=f"r{i}")
+                for i, (n, g) in enumerate([(5, 6), (29, 4)])])
+    recs = eng.tickprof.tail(256)
+    keys = {k for r in recs for k in r["s"]}
+    assert {"chunk/upload", "chunk/dispatch", "chunk/fetch",
+            "chunk/fetch/tokens", "chunk/fetch/finished",
+            "admit/fetch/tokens", "admit/fetch/finished",
+            "device/fetch/tokens", "device/fetch/finished",
+            "device/fetch/counters", "ensure", "count"} <= keys
+    # a windowed model has no prefix cache: no lookup, no account
+    assert {k for k in keys if k.startswith("admit/blocks/")} == {
+        "admit/blocks/alloc_evict", "admit/blocks/reserve"}
+    middle = [r for r in recs if "chunk/fetch" in r["s"]
+              and "chunk/fetch/tokens" not in r["s"]]
+    assert len(middle) == 3                 # 29 = 8 + 8 + 8 + the last 5
+    for r in recs:
+        c, s = r["c"], r["s"]
+        assert c["step_us"] == round(r["total_s"] * 1e6)
+        assert 0 <= c["fetch_after_ready_us"] <= c["inflight_us"] \
+            <= c["step_us"]
+        flights = sum(s.get(f"{p}/dispatch", 0) + s.get(f"{p}/fetch", 0)
+                      for p in ("admit", "chunk", "device"))
+        assert c["inflight_us"] >= round(1e6 * flights) - 4
+        # the stretches between a dispatch and its fetch are a few
+        # microseconds of Python (and a collection, should one fall there)
+        assert c["inflight_us"] <= 1e6 * flights + 2000 + c["gc_us"]
+    snap = eng.tickprof.snapshot(window_s=3600)
+    assert snap["inflight"]["step_us"] == sum(
+        r["c"]["step_us"] for r in recs)
+    assert "ensure" in snap["segments"] and "count" in snap["segments"]
+
+
 @contextlib.contextmanager
 def as_on_a_tpu():
     """The one selector, answering as it does in a process whose backend

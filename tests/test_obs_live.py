@@ -702,7 +702,10 @@ class TestTickSegments:
         # filed under the number the step was opened with, its span's
         assert rec["tick"] == 4 and rec["total_s"] == pytest.approx(0.013)
         assert rec["s"] == {"queue_pop": 0.001, "device": 0.01}
-        assert rec["c"] == {"kv_tokens": 120, "prefill_tokens": 64}
+        # what the step counted, and the profiler's own four beside it
+        assert rec["c"] == {"kv_tokens": 120, "prefill_tokens": 64,
+                            "step_us": 13000, "inflight_us": 0,
+                            "fetch_after_ready_us": 0, "gc_us": 0}
         snap = tp.snapshot(now=clk.wall())
         assert snap["segments"]["other"]["s"] == pytest.approx(0.003)
         # the level as of the newest step, the flow summed over the window
@@ -977,6 +980,242 @@ class TestTickSegments:
         out = subprocess.run([sys.executable, "-c", code], check=True,
                              capture_output=True, text=True, timeout=60)
         assert out.stdout.strip() == "False"
+
+
+class TestFlightAccount:
+    """What the profiler itself counts into every record's `c`
+    (`FLIGHT_COUNTERS`): the step's wall, the part of it with a program
+    out, the fetches of a program that had ended, the collector."""
+
+    def _prof(self, **kw):
+        from hyperion_tpu.obs.tickprof import TickProfiler
+
+        clk = VirtualClock()
+        return TickProfiler(wall=clk.wall, clock=clk, **kw), clk
+
+    @staticmethod
+    def _watch(tp):
+        import gc
+
+        from hyperion_tpu.obs import tickprof
+
+        return [cb for cb in gc.callbacks
+                if isinstance(cb, tickprof._CollectorWatch)
+                and cb._prof() is tp]
+
+    def _a_tick(self, tp, clk):
+        with tp.seg("device"):
+            with tp.seg("device/dispatch"):
+                clk.advance(0.001)
+            clk.advance(0.0005)             # between the two: in flight
+            with tp.seg("device/fetch"):
+                clk.advance(0.0002)         # before the first wait
+                with tp.seg("device/fetch/tokens"):
+                    clk.advance(0.008)      # the program's own run
+                with tp.seg("device/fetch/finished"):
+                    clk.advance(0.0027)     # a second round trip
+                with tp.seg("device/fetch/counters"):
+                    clk.advance(0.0001)
+
+    def test_a_child_of_a_child_is_left_out_of_every_sum(self):
+        tp, clk = self._prof()
+        with tp.tick(0):
+            with tp.seg("admit"):
+                with tp.seg("admit/blocks"):
+                    with tp.seg("admit/blocks/lookup"):
+                        clk.advance(0.004)
+                    with tp.seg("admit/blocks/alloc_evict"):
+                        clk.advance(0.005)
+                    clk.advance(0.001)
+                clk.advance(0.002)
+            self._a_tick(tp, clk)
+        s = tp.tail(1)[0]["s"]
+        assert s["admit"] == pytest.approx(0.012)
+        assert s["admit/blocks"] == pytest.approx(0.010)
+        assert s["admit/blocks/lookup"] == pytest.approx(0.004)
+        assert s["device"] == pytest.approx(0.0125)
+        assert s["device/fetch"] == pytest.approx(0.011)
+        assert s["device/fetch/finished"] == pytest.approx(0.0027)
+        snap = tp.snapshot(now=clk.wall())
+        # the two segments are the whole step: no child was added again
+        assert snap["total_s"] == pytest.approx(0.0245)
+        assert snap["segments"]["admit"]["s"] \
+            + snap["segments"]["device"]["s"] == pytest.approx(0.0245)
+        assert not any("/" in k for k in snap["segments"])
+        assert snap["children"]["admit/blocks/alloc_evict"] == \
+            pytest.approx(0.005)
+        assert snap["children"]["device/fetch/tokens"] == \
+            pytest.approx(0.008)
+
+    def test_dispatch_to_fetch_is_in_flight_and_later_arrays_are_lag(self):
+        tp, clk = self._prof()
+        with tp.tick(0) as tk:
+            with tp.seg("admit"):
+                with tp.seg("admit/blocks"):
+                    clk.advance(0.010)      # nothing of ours on the chip
+                with tp.seg("admit/dispatch", bucket=64):
+                    clk.advance(0.001)
+                with tp.seg("admit/fetch", bucket=64):
+                    with tp.seg("admit/fetch/tokens"):
+                        clk.advance(0.030)
+                    with tp.seg("admit/fetch/finished"):
+                        clk.advance(0.002)
+            clk.advance(0.0003)
+            self._a_tick(tp, clk)
+            tk.count(kv_tokens=7)
+        rec = tp.tail(1)[0]
+        c = rec["c"]
+        assert c["kv_tokens"] == 7
+        assert c["step_us"] == round(rec["total_s"] * 1e6) == 55800
+        # admit: 1 + 30 + 2 ms; the tick: 1 + 0.5 + 0.2 + 8 + 2.7 + 0.1
+        assert c["inflight_us"] == 33000 + 12500
+        # `finished` of the prefill; `finished` and `counters` of the tick
+        assert c["fetch_after_ready_us"] == 2000 + 2700 + 100
+        assert c["gc_us"] == 0
+        assert 0 <= c["fetch_after_ready_us"] <= c["inflight_us"] \
+            <= c["step_us"]
+        # no segment's seconds moved for it
+        assert rec["s"]["admit"] == pytest.approx(0.043)
+        assert rec["s"]["device"] == pytest.approx(0.0125)
+
+    def test_a_step_that_ran_no_tick_still_carries_the_four(self):
+        from hyperion_tpu.obs.tickprof import FLIGHT_COUNTERS
+
+        tp, clk = self._prof()
+        with tp.tick(3):
+            with tp.seg("queue_pop"):
+                clk.advance(0.0004)
+        with tp.tick(4):
+            # a fetch no dispatch went before, outside a step's account
+            with tp.seg("device/fetch"):
+                with tp.seg("device/fetch/tokens"):
+                    clk.advance(0.001)
+                with tp.seg("device/fetch/finished"):
+                    clk.advance(0.001)
+        first, second = tp.tail(2)
+        assert first["c"] == {"step_us": 400, "inflight_us": 0,
+                              "fetch_after_ready_us": 0, "gc_us": 0}
+        assert tuple(first["c"]) == FLIGHT_COUNTERS
+        assert second["c"] == {"step_us": 2000, "inflight_us": 0,
+                               "fetch_after_ready_us": 0, "gc_us": 0}
+        snap = tp.snapshot(now=clk.wall())
+        assert snap["inflight"] == {"step_us": 2400, "inflight_us": 0,
+                                    "fetch_after_ready_us": 0, "gc_us": 0}
+
+    def test_the_windows_sums_ride_the_snapshot_under_one_key(self):
+        tp, clk = self._prof()
+        for n in range(3):
+            with tp.tick(n):
+                self._a_tick(tp, clk)
+                clk.advance(0.0005)
+        snap = tp.snapshot(now=clk.wall())
+        assert snap["inflight"] == {
+            "step_us": 3 * 13000, "inflight_us": 3 * 12500,
+            "fetch_after_ready_us": 3 * 2800, "gc_us": 0}
+        # a flow of its own key: the counters' keys are what they were
+        assert set(snap["counters"]) == {"kv_tokens", "prefill_tokens"}
+        assert "inflight" not in self._prof()[0].snapshot()
+
+    @pytest.mark.parametrize("args, want", [
+        # the share of the steps' wall with nothing of the engine's out
+        ({"counter": "inflight_us", "over": "step_us", "scale": 100,
+          "complement": True}, 100 * (1 - 12500 / 13000)),
+        # the share spent fetching from a program that had ended
+        ({"counter": "fetch_after_ready_us", "over": "step_us",
+          "scale": 100}, 100 * 2800 / 13000),
+        # the collector's milliseconds a second
+        ({"counter": "gc_us", "over": "step_us", "scale": 1000}, 0.0),
+    ])
+    def test_the_benchmarks_counter_ratio_divides_the_records_sums(
+            self, args, want):
+        """No cell lists such a metric yet (PERF.md section 7): the
+        accepted reader needs a data file alone to read one."""
+        from benchmarks.readers import counter_ratio
+
+        tp, clk = self._prof()
+        for n in range(3):
+            with tp.tick(n):
+                self._a_tick(tp, clk)
+                clk.advance(0.0005)
+        counted = [{"kv_tokens": 7, **r["c"]} for r in tp.tail(3)]
+        assert counter_ratio.read({"counted": counted}, **args) == \
+            pytest.approx(want)
+        # the parent's records lack the four: nothing to read
+        assert counter_ratio.read(
+            {"counted": [{"kv_tokens": 7}]}, **args) is None
+
+    def test_a_collection_is_a_span_and_changes_no_segments_seconds(self):
+        spans = _FakeSpans()
+        tp, clk = self._prof(annotate=spans)
+        tp.watch_collector()
+        (watch,) = self._watch(tp)
+        watch("start", {"generation": 2})       # outside any step: nothing
+        clk.advance(0.5)
+        watch("stop", {"generation": 2, "collected": 0})
+        with tp.tick(9):
+            with tp.seg("accept"):
+                clk.advance(0.001)
+                watch("start", {"generation": 1})
+                clk.advance(0.040)
+                watch("stop", {"generation": 1, "collected": 5})
+                clk.advance(0.001)
+        rec = tp.tail(1)[0]
+        assert rec["s"] == {"accept": 0.042}    # nothing netted out
+        assert rec["total_s"] == pytest.approx(0.042)
+        assert rec["c"]["gc_us"] == 40000 and rec["c"]["step_us"] == 42000
+        assert spans.log == [
+            ("open", "serve.step", {"tick": 9}),
+            ("open", "serve.step/accept", {}),
+            ("open", "serve.step/gc", {"generation": 1}),
+            ("close", "serve.step/gc"),
+            ("close", "serve.step/accept"),
+            ("close", "serve.step"),
+        ]
+
+    def test_another_threads_collection_is_not_the_steps(self):
+        import threading
+
+        tp, clk = self._prof()
+        tp.watch_collector()
+        (watch,) = self._watch(tp)
+
+        def collect():
+            watch("start", {"generation": 0})
+            clk.advance(0.010)
+            watch("stop", {"generation": 0})
+
+        with tp.tick(0):
+            th = threading.Thread(target=collect)
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+        assert tp.tail(1)[0]["c"]["gc_us"] == 0
+
+    def test_a_real_collection_inside_a_step_and_none_after_the_profiler(
+            self):
+        import gc
+
+        from hyperion_tpu.obs.tickprof import TickProfiler
+
+        tp, other = TickProfiler(), TickProfiler()
+        tp.watch_collector()
+        other.watch_collector()
+        with tp.tick(0):
+            with tp.seg("accept"):
+                gc.collect()
+        with other.tick(0):
+            pass
+        assert tp.tail(1)[0]["c"]["gc_us"] > 0
+        assert tp.tail(1)[0]["s"]["accept"] * 1e6 \
+            >= tp.tail(1)[0]["c"]["gc_us"] - 1
+        # the other profiler's hook saw the same collection and no step
+        assert other.tail(1)[0]["c"]["gc_us"] == 0
+        (watch,) = self._watch(tp)
+        assert len(self._watch(other)) == 1
+        del tp
+        gc.collect()
+        assert watch not in gc.callbacks and watch._prof() is None
+        assert len(self._watch(other)) == 1
 
 
 class TestFlightRecorder:

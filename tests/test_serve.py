@@ -13,6 +13,7 @@ The two acceptance anchors from the issue live here in tier-1:
 
 from __future__ import annotations
 
+import gc
 import io
 import json
 import threading
@@ -2692,6 +2693,182 @@ class TestIntrospection:
             for _, _, st in spans[f"serve.step/admit/{name}"]:
                 assert st["bucket"] in (8, 16) and st["start"] == 0
 
+    def test_admit_blocks_children_sum_to_it_stage_by_stage(
+            self, llama, request):
+        """TestTieredKV's drill (seed a shared prefix, evict it to the
+        host tier under pressure, hit it again) runs every stage of
+        `_claim_blocks`: each that ran is a child of `admit/blocks`,
+        one that did not opened nothing, and together they hold its
+        seconds to within a tenth."""
+        eng = _engine(llama, block_size=8, num_blocks=8,
+                      admission="optimistic", queue_capacity=16,
+                      host_cache_mb=8)
+        eng.warmup()
+        rng = np.random.default_rng(83)
+        shared = rng.integers(1, 250, 16).astype(np.int32)
+        # a collection between two children (0.1 s in a process that
+        # holds JAX) would be `admit/blocks`' own: none while timing
+        gc.disable()
+        request.addfinalizer(gc.enable)
+
+        def tail(n):
+            return np.concatenate(
+                [shared, rng.integers(1, 250, n).astype(np.int32)])
+
+        def run(*reqs):
+            before = eng.tickprof.ticks_recorded
+            for r in reqs:
+                ok, reason = eng.submit(r)
+                assert ok, reason
+                eng.step()
+            _drain(eng)
+            recs = eng.tickprof.tail(eng.tickprof.ticks_recorded - before)
+            stages: dict[str, float] = {}
+            for r in recs:
+                for k, v in r["s"].items():
+                    if k.startswith("admit/blocks"):
+                        stages[k] = stages.get(k, 0.0) + v
+            return stages
+
+        p0 = tail(3)
+        first = run(Request(prompt_ids=p0, max_new_tokens=4, id="s0"))
+        # nothing to copy and nothing to restore yet: no such child
+        assert set(first) == {
+            "admit/blocks", "admit/blocks/lookup",
+            "admit/blocks/alloc_evict", "admit/blocks/reserve",
+            "admit/blocks/account"}
+        # a second prompt that leaves the first inside its second block
+        forked = run(Request(prompt_ids=np.concatenate(
+            [p0[:12], rng.integers(1, 250, 6).astype(np.int32)]),
+            max_new_tokens=4, id="s1"))
+        run(*[Request(prompt_ids=rng.integers(1, 250, 6),
+                      max_new_tokens=12, id=f"gr{i}") for i in range(3)])
+        assert len(eng.host) >= 2           # the chain went to the host
+        again = run(Request(prompt_ids=tail(4), max_new_tokens=4, id="s2"))
+        assert eng.metrics.summary()["host_restored_blocks"] >= 2
+        assert "admit/blocks/restore" in again
+        assert "admit/blocks/restore" not in forked
+        seen = set(first) | set(forked) | set(again)
+        assert seen == {
+            "admit/blocks", "admit/blocks/lookup",
+            "admit/blocks/alloc_evict", "admit/blocks/reserve",
+            "admit/blocks/cow", "admit/blocks/restore",
+            "admit/blocks/account"}
+        # the two admissions that copied and restored, together
+        whole = forked.pop("admit/blocks") + again.pop("admit/blocks")
+        kids = sum(forked.values()) + sum(again.values())
+        assert 0.9 * whole <= kids <= whole + 1e-5, (whole, forked, again)
+
+    def test_every_fetch_names_its_arrays_and_the_flight_account_holds(
+            self, llama):
+        """`device/fetch` and `admit/fetch` hold one child an array in
+        the order the host waits; the four flight counters are in every
+        record, ordered as they must be, and the window's sums ride the
+        exposition."""
+        eng = _engine(llama)
+        eng.warmup([8, 16])
+        for i, p in enumerate(_prompts([5, 11, 7], seed=31)):
+            eng.submit(Request(prompt_ids=p, max_new_tokens=4, id=f"f{i}"))
+        _drain(eng)
+        eng.step()                  # idle: a step that ran no tick
+        recs = eng.tickprof.tail(32)
+        assert "device" not in recs[-1]["s"]
+        first = recs[0]["s"]
+        for key in ("admit/fetch/tokens", "admit/fetch/finished",
+                    "device/fetch/tokens", "device/fetch/finished",
+                    "ensure", "count"):
+            assert first.get(key, 0) > 0, (key, first)
+        # a dense model's tick counts nothing on the device
+        assert "device/fetch/counters" not in first
+        for rec in recs:
+            c, s = rec["c"], rec["s"]
+            assert c["step_us"] == round(rec["total_s"] * 1e6)
+            assert 0 <= c["fetch_after_ready_us"] <= c["inflight_us"] \
+                <= c["step_us"]
+            assert c["gc_us"] >= 0
+            flights = sum(s.get(f"{p}/dispatch", 0) + s.get(f"{p}/fetch", 0)
+                          for p in ("admit", "chunk", "device"))
+            # dispatch start to fetch end, the stretch between the two
+            # included
+            assert c["inflight_us"] >= round(1e6 * flights) - 3
+            later = sum(v for k, v in s.items()
+                        if k.endswith("/fetch/finished"))
+            assert c["fetch_after_ready_us"] == pytest.approx(
+                1e6 * later, abs=2 + 1e-2 * 1e6 * later)
+        assert recs[-1]["c"]["inflight_us"] == 0
+        sums = eng.exposition()["tickprof"]["inflight"]
+        assert sums["step_us"] == sum(r["c"]["step_us"] for r in recs)
+        assert 0 < sums["inflight_us"] < sums["step_us"]
+
+    def test_a_collection_inside_a_step_is_counted_once_and_balanced(
+            self, llama):
+        """A forced `gc.collect()` inside a step leaves `gc_us` and one
+        balanced `serve.step/gc` span in that engine's step, nothing in
+        a second engine's; the segment it interrupted keeps the seconds;
+        a dead engine's hook leaves `gc.callbacks`."""
+        import weakref
+
+        from hyperion_tpu.obs import tickprof
+
+        def hooks():
+            return [cb for cb in gc.callbacks
+                    if isinstance(cb, tickprof._CollectorWatch)]
+
+        model, variables = llama
+        cfg = EngineConfig(slots=3, max_len=48, eos_id=None)
+        eng = Engine(model, variables, cfg,
+                     on_event=lambda ev: gc.collect())
+        other = Engine(model, variables, cfg)
+        assert sum(cb._prof() in (eng.tickprof, other.tickprof)
+                   for cb in hooks()) == 2
+        log = []
+
+        class _Span:
+            def __init__(self, name, args):
+                self.name, self.args = name, args
+
+            def __enter__(self):
+                log.append(("open", self.name, self.args))
+
+            def __exit__(self, *exc):
+                log.append(("close", self.name))
+
+        eng.tickprof._annotate = lambda name, **args: _Span(name, args)
+        eng.warmup([8])
+        eng.submit(Request(prompt_ids=_prompts([5], seed=33)[0],
+                           max_new_tokens=3, id="gc0"))
+        _drain(eng)
+        recs = eng.tickprof.tail(8)
+        assert recs and all(r["c"]["gc_us"] > 0 for r in recs
+                            if "device" in r["s"])
+        for r in recs:
+            # the collections ran inside `admit` and `accept` (the sink
+            # of an emitted token): nothing was netted out of them
+            # (a young collection of the interpreter's own may fall
+            # anywhere in the step: a few hundred microseconds)
+            held = r["s"].get("admit", 0) + r["s"].get("accept", 0)
+            assert 0.9 * r["c"]["gc_us"] <= 1e6 * held
+            assert r["c"]["gc_us"] <= r["c"]["step_us"]
+        opened = [e for e in log if e[1] == "serve.step/gc"]
+        assert opened and len(opened) % 2 == 0
+        for i, e in enumerate(log):
+            if e[:2] == ("open", "serve.step/gc"):
+                assert e[2]["generation"] in (0, 1, 2)
+                assert log[i + 1] == ("close", "serve.step/gc")
+        assert ("open", "serve.step/gc", {"generation": 2}) in log
+        # the other engine's hook saw every collection and no step of its
+        assert other.tickprof._gc_s == 0.0
+        assert other.tickprof.ticks_recorded == 0
+        gone = weakref.ref(eng.tickprof)
+        del eng, recs, log
+        gc.collect()
+        assert gone() is None
+        assert all(cb._prof() is not None for cb in hooks())
+        assert sum(cb._prof() is other.tickprof for cb in hooks()) == 1
+        del other
+        gc.collect()
+        assert all(cb._prof() is not None for cb in hooks())
+
     def test_tick_record_keeps_its_keys_and_gains_children(self, llama):
         """The record's contract: every segment key it had, seconds
         each; children beside them, never more than their parent and
@@ -2714,9 +2891,10 @@ class TestIntrospection:
             top = {k: v for k, v in s.items() if "/" not in k}
             assert set(top) <= set(SEGMENTS)
             assert sum(top.values()) <= rec["total_s"] + 1e-5
-            for parent in ("admit", "device", "chunk"):
+            # at every depth: `admit`'s children, `admit/blocks`' own
+            for parent in {k.rpartition("/")[0] for k in s if "/" in k}:
                 kids = sum(v for k, v in s.items()
-                           if k.startswith(parent + "/"))
+                           if k.rpartition("/")[0] == parent)
                 assert kids <= s.get(parent, 0.0) + 1e-5, (parent, s)
         snap = eng.tickprof.snapshot()
         assert not any("/" in k for k in snap["segments"])
@@ -2732,7 +2910,11 @@ class TestIntrospection:
         assert set(eng._flight_payload()["ticks"][-1]["c"]) == {
             "kv_tokens", "prefill_tokens", "sampling_rows",
             "restricted_rows", "kv_blocks_walked", "kv_table_entries",
-            "kv_blocks_written", "kv_rows_written"}
+            "kv_blocks_written", "kv_rows_written",
+            # the profiler's own four, in every record
+            "step_us", "inflight_us", "fetch_after_ready_us", "gc_us"}
+        assert set(eng.exposition()["tickprof"]["inflight"]) == {
+            "step_us", "inflight_us", "fetch_after_ready_us", "gc_us"}
 
     def test_tick_counters_follow_the_slots(self, llama):
         """`kv_tokens` is host bookkeeping of what the live slots hold in

@@ -502,6 +502,106 @@ class TestSyntheticPlanes:
             ("jit__tick_impl", "%copy.6", 1),
             ("jit__tick_impl", "%fusion.7", 1)]
 
+    def test_launch_and_fetch_lag_and_the_runtime_under_the_fetch(
+            self, tmp_path):
+        """Two steps, microseconds. Step 0: dispatch [100,150), fetch
+        [150,900) with `tokens` [160,700) and `finished` [700,890); the
+        tick program runs [200,500). Step 1 (from 1000): dispatch
+        [1100,1150), fetch [1150,1800), `tokens` [1150,1600), `finished`
+        [1600,1800); the program runs [1160,1500). An upload's one-
+        microsecond program at 120 is not the pair's. A runtime thread's
+        `TransferFromDevice` [600,880) lies under step 0's fetch; the
+        loop line's own `PjitFunction` names nothing."""
+        names = ["serve.step", "serve.step/device",
+                 "serve.step/device/dispatch", "serve.step/device/fetch",
+                 "serve.step/device/fetch/tokens",
+                 "serve.step/device/fetch/finished",
+                 "PjitFunction(_tick_impl)", "TransferFromDevice",
+                 "ThreadPool::Wait"]
+        mid = {n: i + 1 for i, n in enumerate(names)}
+
+        def ev(name, start_us, end_us):
+            return (f"events {{ metadata_id: {mid[name]} offset_ps: "
+                    f"{start_us}000000 duration_ps: "
+                    f"{end_us - start_us}000000 }}\n")
+
+        loop = "".join(ev(n, a + at, b + at) for at, rows in (
+            (0, [("serve.step", 0, 1000), ("serve.step/device", 100, 900),
+                 ("serve.step/device/dispatch", 100, 150),
+                 ("PjitFunction(_tick_impl)", 100, 150),
+                 ("serve.step/device/fetch", 150, 900),
+                 ("serve.step/device/fetch/tokens", 160, 700),
+                 ("serve.step/device/fetch/finished", 700, 890)]),
+            (1000, [("serve.step", 0, 1000), ("serve.step/device", 100, 800),
+                    ("serve.step/device/dispatch", 100, 150),
+                    ("serve.step/device/fetch", 150, 800),
+                    ("serve.step/device/fetch/tokens", 150, 600),
+                    ("serve.step/device/fetch/finished", 600, 800)]))
+            for n, a, b in rows)
+        host = (
+            'planes { name: "/host:CPU"\n'
+            f'  lines {{ name: "python" timestamp_ns: 0\n{loop} }}\n'
+            '  lines { name: "tpu-runtime/7" timestamp_ns: 0\n'
+            f'{ev("TransferFromDevice", 600, 880)}'
+            f'{ev("ThreadPool::Wait", 0, 2000)} }}\n'
+            + "".join(f'  event_metadata {{ key: {i} value {{ id: {i} '
+                      f'name: "{n}" }} }}\n' for n, i in mid.items())
+            + '}\n')
+        dev = (
+            'planes { name: "/device:TPU:0"\n'
+            '  lines { name: "XLA Modules" timestamp_ns: 0\n'
+            '    events { metadata_id: 101 offset_ps: 120000000 '
+            'duration_ps: 1000000 }\n'
+            '    events { metadata_id: 100 offset_ps: 200000000 '
+            'duration_ps: 300000000 }\n'
+            '    events { metadata_id: 100 offset_ps: 1160000000 '
+            'duration_ps: 340000000 } }\n'
+            '  lines { name: "XLA Ops" timestamp_ns: 0\n'
+            f'{_op(1, 200, 300)}{_op(1, 1160, 340)} }}\n'
+            f'{_meta(1, "fusion.1", "jit(_tick_impl)/Llama/lm_head/dot")}'
+            '  event_metadata { key: 100 value { id: 100 name: '
+            '"jit__tick_impl(7)" } }\n'
+            '  event_metadata { key: 101 value { id: 101 name: '
+            f'"jit_convert_element_type(3)" }} }}\n{STAT_NAMES} }}\n')
+        s = xprof.summarize(_write(tmp_path, dev + host),
+                            window=(0.0, 2000e-6))
+        (lag,) = s["program_lag"]
+        assert (lag["program"], lag["dispatch"], lag["n"]) == (
+            "jit__tick_impl", "serve.step/device/dispatch", 2)
+        # the run's start after the dispatch span's: 100 and 60 us
+        assert lag["launch_median_s"] == pytest.approx(80e-6)
+        assert lag["launch_total_s"] == pytest.approx(160e-6)
+        # the fetch span's end after the run's: 400 and 300 us
+        assert lag["fetch_median_s"] == pytest.approx(350e-6)
+        assert lag["fetch_total_s"] == pytest.approx(700e-6)
+        by = {r["span"]: r for r in s["idle_by_span"]}
+        # idle under each array's wait: before the program and after it
+        assert by["serve.step/device/fetch/tokens"]["s"] == pytest.approx(
+            (40 + 200 + 10 + 100) * 1e-6)
+        assert by["serve.step/device/fetch/finished"]["s"] == pytest.approx(
+            (190 + 200) * 1e-6)
+        assert sum(r["s"] for r in s["idle_by_span"]) == pytest.approx(
+            s["idle_s"])
+        under = {m["event"]: m for m in
+                 by["serve.step/device/fetch/finished"]["meanwhile"]}
+        # the runtime's transfer [600,880) under `finished` [700,890)
+        assert under["TransferFromDevice"]["s"] == pytest.approx(180e-6)
+        assert under["TransferFromDevice"]["thread"] == "tpu-runtime/7"
+        assert under["ThreadPool::Wait"]["s"] == pytest.approx(390e-6)
+        tokens = {m["event"]: m["s"] for m in
+                  by["serve.step/device/fetch/tokens"]["meanwhile"]}
+        assert tokens["TransferFromDevice"] == pytest.approx(100e-6)
+        # the loop's own line is not another thread, and a span is no
+        # runtime event
+        assert not any(m["event"].startswith(("PjitFunction", "serve."))
+                       for r in s["idle_by_span"]
+                       for m in r.get("meanwhile", []))
+        # only the spans with the most idle seconds carry the list
+        assert sum("meanwhile" in r for r in s["idle_by_span"]) == min(
+            xprof.MEANWHILE_SPANS, len(s["idle_by_span"]))
+        text = xprof.to_markdown(s)
+        assert "launch median ms" in text and "TransferFromDevice" in text
+
     def test_a_trace_with_no_device_work_is_refused(self, tmp_path):
         with pytest.raises(ValueError, match="no operation"):
             xprof.summarize(_write(tmp_path, HOST))
