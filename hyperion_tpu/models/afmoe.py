@@ -37,7 +37,8 @@ cache=, cache_index=, block_tables=)` with `cache` from
 size). A call reads its kind's pool as `llama.select_paged_attn_impl`
 says for its shape and the backend: the decode tick on a TPU in place,
 through the paged-attention kernel with the kind's table and window;
-chunks, prefills and every other backend through the gather. The
+chunks and prefills there through the gather and the tiled kernel over
+the view; every other backend through the gather. The
 expert layers' grouped products take the form
 `ops.moe.select_grouped_impl` names for the call's rows, the held
 experts and their matrices' bytes (the grouped-matmul kernel or
@@ -210,8 +211,9 @@ class AfmoeAttention(nn.Module):
             table = block_tables[name]
             ck, cv = paged_kv_write(cache, k, v, table, base)
             # the kind's pool through the kind's table: in place for a
-            # few-row window on a TPU (the decode tick), gathered for
-            # a chunk, a prefill and every other backend
+            # few-row window on a TPU (the decode tick), gathered and
+            # tiled for a chunk and a prefill there, gathered for the
+            # narrowest buckets and on every other backend
             impl = select_paged_attn_impl(
                 T, H // Hkv, jax.default_backend())
             a = paged_read(impl, q, ck, cv, table, base, window)

@@ -59,7 +59,10 @@ class LlamaConfig:
     # ops.pallas.paged_attention, which walks each row's live blocks
     # in-kernel and reads the pools in place; "auto" resolves per call
     # from its static shape and the backend (`select_paged_attn_impl`:
-    # the kernel for a few-token window on a TPU, the gather otherwise).
+    # on a TPU that kernel for a few-token window and, for a
+    # prompt-length one, "tiled": the gather, then a tiled online
+    # softmax over the view, `ops.pallas.window_attention`; the gather
+    # otherwise).
     # Identical masking contract; pinned-tolerance numerics (online
     # softmax, see the kernel docstring). Ignored outside the paged
     # (block_tables) path.
@@ -99,8 +102,8 @@ class LlamaConfig:
         return jnp.dtype(self.dtype)
 
     def paged_attn_for(self, window: int) -> str:
-        """The paged read, "pallas" or "gather", of a call whose rows
-        each carry `window` query positions: `paged_attn_impl`, with
+        """The paged read ("pallas", "tiled" or "gather") of a call
+        whose rows each carry `window` query positions: `paged_attn_impl`, with
         "auto" resolved from that width and the process's backend. The
         attention module asks per call; the engine asks for its tick."""
         if self.paged_attn_impl != "auto":
@@ -271,21 +274,41 @@ def _grouped_cache_attention(q, ck, cv, mask, rep):
 # [48, 1] at rep 4 (4 rows) and a verify window [48, 4] (16 rows), both
 # several times faster than the gather. Above it lie prompt-length
 # windows ([1, 8] at rep 4 is 32 rows): one slot's chain, gathered once
-# for many queries, and a window the kernel's VMEM plan has to cut into
-# head groups; not measured, so they stay on the gather.
+# for many queries.
 PAGED_KERNEL_MAX_ROWS = 16
+# The narrowest prompt window, in the same rows, that "auto" sends to
+# the tiled kernel over the gathered chain (`paged_tiled_read`). From
+# the cells' shapes on a v5e (PERF.md section 6, PR 37): the float32
+# scores the gather path writes to HBM are what a wide window costs
+# (Mistral's 512 bucket, 2048 rows: 0.17 ms a layer against the
+# gather's 0.71; its 2048 bucket 0.53 against 2.34; a 512-position
+# chunk of Trinity's full layer 0.28-1.14 against 5.2), and under 1024
+# rows (Mistral's buckets up to 128, every bucket of Ouro's at one
+# query head a KV head and a view of 768 keys) the two read within
+# 0.02 ms of each other. Between the two (the 256 buckets: 0.14 against
+# 0.19 at Mistral's) the kernel is a little ahead, but every program
+# that holds it lowers its Mosaic module at every start of the server
+# (0.3 s of warm-up each on the chip's host), and those buckets are a
+# prompt's short last piece: they keep the gather's one-shot softmax.
+PAGED_TILED_MIN_ROWS = 2048
 
 
 def select_paged_attn_impl(window: int, rep: int, backend: str) -> str:
-    """Resolve `paged_attn_impl="auto"` to "pallas" or "gather" for one
-    call, from its static shape (`window` query positions a row, `rep`
-    query heads a KV head) and the backend. Resolved at trace time, as
+    """Resolve `paged_attn_impl="auto"` to "pallas", "tiled" or
+    "gather" for one call, from its static shape (`window` query
+    positions a row, `rep` query heads a KV head) and the backend: the
+    decode tick and the verify window read the pools in place, a
+    prompt-length window gathers its chain and runs the tiled kernel
+    over it, and what lies between gathers and takes the one-shot
+    softmax. Resolved at trace time, as
     `ops.attention.select_attention_impl` is: jit sees one branch. Off
-    a TPU the kernel would run through the interpreter, which is an
+    a TPU the kernels would run through the interpreter, which is an
     oracle and not a read path."""
     if backend != "tpu":
         return "gather"
-    return "pallas" if window * rep <= PAGED_KERNEL_MAX_ROWS else "gather"
+    if window * rep <= PAGED_KERNEL_MAX_ROWS:
+        return "pallas"
+    return "tiled" if window * rep >= PAGED_TILED_MIN_ROWS else "gather"
 
 
 def _chain_view(pool, block_tables):
@@ -420,6 +443,14 @@ def paged_kv_write(cache, k, v, block_tables, base, shift=None):
             by_block, _kv_write_blocks, _kv_write_rows, *args)
 
 
+def _table_slices(block_tables, fb, nb: int):
+    """Columns `fb[b]..fb[b]+nb-1` of each row's table: the blocks a
+    windowed layer's read takes of a chain."""
+    return jax.vmap(
+        lambda row, f: jax.lax.dynamic_slice_in_dim(row, f, nb)
+    )(block_tables, fb)
+
+
 def paged_gather_read(q, ck, cv, block_tables, base, first=None,
                       window: int = 0):
     """The gather read path: each row's chain gathered out of the pools
@@ -454,9 +485,7 @@ def paged_gather_read(q, ck, cv, block_tables, base, first=None,
             mask = (kv_pos <= q_pos) & (kv_pos > q_pos - window)
     with jax.named_scope("kv_read"):
         if first is not None:
-            block_tables = jax.vmap(
-                lambda row, f: jax.lax.dynamic_slice_in_dim(row, f, nb)
-            )(block_tables, fb)
+            block_tables = _table_slices(block_tables, fb, nb)
         # the gather and the layout copy; the cast to float32
         # that completes the read is scoped where it happens
         kview = _chain_view(ck, block_tables)
@@ -464,16 +493,59 @@ def paged_gather_read(q, ck, cv, block_tables, base, first=None,
     return _grouped_cache_attention(q, kview, vview, mask, rep)
 
 
+def paged_tiled_read(q, ck, cv, block_tables, base, first=None,
+                     window: int = 0):
+    """The tiled read path of a prompt-length window: the chain is
+    gathered as on the gather path (the same blocks of the same table,
+    `paged_gather_read`'s), left in whole blocks `[B, nb, Hkv, bs, D]`
+    in the cache's dtype, and the masked product over it is
+    `ops.pallas.window_attention`: an online softmax a tile at a time,
+    no score array in HBM, no key tile read that no query of the window
+    sees. The view is padded to whole key tiles with copies of the
+    table's last entry, which the kernel masks by position."""
+    from hyperion_tpu.ops.pallas.window_attention import (
+        view_tile,
+        window_attention,
+    )
+
+    B, T, H, D = q.shape
+    Hkv, bs = ck.shape[1], ck.shape[2]
+    MB = block_tables.shape[1]
+    nb = MB if first is None else min(
+        MB, window_view_blocks(window, T, bs))
+    per_tile = view_tile(T, H // Hkv, D, bs, nb, q.dtype, ck.dtype) // bs
+    pad = -nb % per_tile
+    with jax.named_scope("kv_read"):
+        if first is None:
+            fb = jnp.zeros_like(base)
+        else:
+            fb = jnp.clip(first // bs, 0, MB - nb)              # [B]
+            block_tables = _table_slices(block_tables, fb, nb)
+        if pad:
+            block_tables = jnp.pad(
+                block_tables, ((0, 0), (0, pad)), mode="edge")
+        kview, vview = ck[block_tables], cv[block_tables]
+    with jax.named_scope("attention"):
+        return window_attention(
+            q, kview, vview, base, fb * bs, window=window,
+            keys=nb * bs if pad else 0)
+
+
 def paged_read(impl: str, q, ck, cv, block_tables, base, window: int = 0,
                shift=None):
-    """A paged layer's read by the path `impl` names ("pallas" or
-    "gather": `select_paged_attn_impl`'s answer for the call, or a
+    """A paged layer's read by the path `impl` names ("pallas", "tiled"
+    or "gather": `select_paged_attn_impl`'s answer for the call, or a
     config's explicit value): q [B, T, H, D] at positions
     `base[b]..base[b]+T-1` against the pools through one layer kind's
     table, a full layer's (`window` 0) or a windowed one's (a query at
-    p sees keys `p - window < j <= p`). `shift` (`segment_shift`) reads
-    one segment of pools that hold several: both paths are handed the
-    shifted table and stay as they are (a null entry reads the
+    p sees keys `p - window < j <= p`). "pallas" walks the pools in
+    place (the decode tick, a verify window); "tiled" gathers the chain
+    and runs the tiled online softmax over it (`paged_tiled_read`: a
+    prompt-length window on a TPU); "gather" gathers it and takes one
+    softmax over the whole view (`paged_gather_read`: the oracle, every
+    CPU run, the narrow prompt windows). `shift` (`segment_shift`)
+    reads one segment of pools that hold several: every path is handed
+    the shifted table and stays as it is (a null entry reads the
     segment's null block, masked by position as block 0 is)."""
     if shift is not None:
         block_tables = block_tables + shift
@@ -487,12 +559,13 @@ def paged_read(impl: str, q, ck, cv, block_tables, base, window: int = 0,
         with jax.named_scope("kv_read"):
             return paged_attention(q, ck, cv, block_tables, base,
                                    window=window)
-    if impl != "gather":
+    if impl not in ("gather", "tiled"):
         raise ValueError(
-            f"unknown paged read {impl!r} (want 'gather' or 'pallas'; "
-            "a config's 'auto' resolves to one of them)")
+            f"unknown paged read {impl!r} (want 'gather', 'tiled' or "
+            "'pallas'; a config's 'auto' resolves to one of them)")
     first = jnp.maximum(base - window + 1, 0) if window else None
-    return paged_gather_read(q, ck, cv, block_tables, base, first, window)
+    read = paged_tiled_read if impl == "tiled" else paged_gather_read
+    return read(q, ck, cv, block_tables, base, first, window)
 
 
 class LlamaAttention(nn.Module):
@@ -523,7 +596,9 @@ class LlamaAttention(nn.Module):
         walk each row's live blocks in-kernel against the pools in
         place (`"pallas"`, ops.pallas.paged_attention: no contiguous
         copy); `"auto"`, the default, chooses per call from the window's
-        width and the backend (`select_paged_attn_impl`).
+        width and the backend (`select_paged_attn_impl`), and on a TPU
+        sends a prompt-length window to a third form: the gather, then
+        a tiled online softmax over the view (`paged_tiled_read`).
         Out-of-range or unmapped positions
         route to physical block 0 (the serve engine's null block), so
         bucket padding can never corrupt a neighbour's blocks.

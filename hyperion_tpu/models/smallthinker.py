@@ -37,7 +37,8 @@ cache=, cache_index=, block_tables=)` with `cache` from
 `llama.init_paged_cache(cfg, {kind: blocks}, bs)` and `block_tables`
 `{kind: [B, MB]}` (`cfg.layer_kinds`). A call reads its kind's pool as
 `llama.select_paged_attn_impl` says for its shape and the backend (7
-query heads a KV head: the decode tick on a TPU takes the kernel),
+query heads a KV head: the decode tick on a TPU takes the kernel, a
+chunk and a prefill there the gather and the tiled kernel over it),
 and multiplies its sorted rows with the experts' matrices as
 `ops.moe.select_grouped_impl` says for its shape and the backend (the
 grouped-matmul kernel for the tick's few rows an expert on a TPU,
@@ -197,8 +198,9 @@ class SmallthinkerAttention(nn.Module):
             table = block_tables[name]
             ck, cv = paged_kv_write(cache, k, v, table, base)
             # the kind's pool through the kind's table: in place for a
-            # few-row window on a TPU (the decode tick), gathered for
-            # a chunk, a prefill and every other backend
+            # few-row window on a TPU (the decode tick), gathered and
+            # tiled for a chunk and a prefill there, gathered for the
+            # narrowest buckets and on every other backend
             impl = select_paged_attn_impl(
                 T, H // Hkv, jax.default_backend())
             a = paged_read(impl, q, ck, cv, table, base, window)

@@ -47,6 +47,7 @@ from hyperion_tpu.obs.registry import percentile
 from hyperion_tpu.obs.tickprof import (
     EXPERT_ROW_COUNTERS,
     FLIGHT_NAME,
+    PROMPT_READ_COUNTERS,
     WALK_COUNTERS,
     WRITE_COUNTERS,
     flight_final_tick,
@@ -1253,6 +1254,12 @@ def render_markdown(d: dict) -> str:
             counted += (f"; experts: {_fmt(by_kernel)} row(s) through the "
                         f"grouped kernel, {_fmt(by_ragged)} through "
                         f"ragged_dot")
+        # which read the steps' prompt windows took, in positions
+        tiled, gathered = (c.get(k) or 0 for k in PROMPT_READ_COUNTERS)
+        if tiled or gathered:
+            counted += (f"; prompt windows: {_fmt(tiled)} position(s) "
+                        f"through the tiled kernel, {_fmt(gathered)} "
+                        f"through the gather's softmax")
         # an expert model: what its ticks sent to the experts held here
         ex = tp.get("experts") or {}
         if ex:

@@ -29,7 +29,9 @@ module is the one way to time it:
     (`kv_blocks_written` whole blocks, `kv_rows_written` positions
     row by row), and which form of the grouped products an expert
     model's steps sent their rows through (`expert_rows_kernel`,
-    `expert_rows_ragged`). The profiler's own flight account
+    `expert_rows_ragged`), and which read the steps' prompt windows
+    took (`prompt_positions_tiled`, `prompt_positions_gather`). The
+    profiler's own flight account
     (FLIGHT_COUNTERS: how much of each step a program was out, what
     second fetches and the collector cost it) is in every record's `c`
     and, summed over the window, under `inflight`.
@@ -108,6 +110,11 @@ WRITE_COUNTERS = ("kv_blocks_written", "kv_rows_written")
 # kernel and through `ragged_dot`, summed over the expert layers, as
 # `ops.moe.select_grouped_impl` chose for each call's shape
 EXPERT_ROW_COUNTERS = ("expert_rows_kernel", "expert_rows_ragged")
+# a step's prefills and chunk (`Engine._count_prompt`): the positions of
+# their windows, bucket padding included, that read the gathered chain
+# through the tiled kernel and through the gather's one-shot softmax, as
+# `models.llama.select_paged_attn_impl` chose for each window's width
+PROMPT_READ_COUNTERS = ("prompt_positions_tiled", "prompt_positions_gather")
 
 
 def _role(key: str) -> str | None:
@@ -354,11 +361,13 @@ class TickProfiler:
             # gathered a layer, the blocks the paged-attention kernel
             # walked instead (0: the ticks gathered), by layer kind
             # and how the window's steps wrote it: by block, by row;
-            # an expert model: its steps' rows by the products' form
+            # an expert model: its steps' rows by the products' form;
+            # the prompt windows' positions by the read they took
             for key in sorted({k for r in recs for k in r.get("c", {})
                                if k.startswith(
                                    WALK_COUNTERS + WRITE_COUNTERS
-                                   + EXPERT_ROW_COUNTERS)}):
+                                   + EXPERT_ROW_COUNTERS
+                                   + PROMPT_READ_COUNTERS)}):
                 out["counters"][key] = sum(
                     r.get("c", {}).get(key, 0) for r in recs)
         if recs:

@@ -39,8 +39,12 @@ The walk:
     accumulator per head across groups.
   * `_plan` chooses `Hg` and `G` from the call's static shape under a
     VMEM budget: prompt-length windows get fewer heads a step and
-    smaller groups; they compile and are right, but the model sends
-    them down the gather path (`select_paged_attn_impl`).
+    smaller groups; they compile and are right, but the model does not
+    send them here (`select_paged_attn_impl`): a prompt window gathers
+    its slot's chain once and reads it through the tiled kernel of
+    `window_attention.py`, or, where it is narrow, through the
+    gather's one-shot softmax (measured against this walk at the
+    cells' shapes: PERF.md section 6, PR 37).
 
 Masking contract, identical to the gather path: kv position `p`
 attends iff `p <= base[b] + row // rep` (per-row causal frontier over

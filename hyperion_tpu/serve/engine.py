@@ -101,6 +101,7 @@ from hyperion_tpu.obs.heartbeat import host_rss_mb as hb_host_rss_mb
 from hyperion_tpu.obs.ledger import CompileLedger
 from hyperion_tpu.obs.tickprof import (
     EXPERT_ROW_COUNTERS,
+    PROMPT_READ_COUNTERS,
     WALK_COUNTERS,
     WRITE_COUNTERS,
     FlightRecorder,
@@ -571,10 +572,12 @@ class Engine:
         # model without an option of its own asks the selector, as its
         # attention does per call.
         self._tick_width = cfg.spec_k + 1 if self._spec else 1
-        self._tick_read = mcfg.paged_attn_for(self._tick_width) \
-            if hasattr(mcfg, "paged_attn_for") else select_paged_attn_impl(
-                self._tick_width, mcfg.n_heads // mcfg.n_kv_heads,
+        self._read_for = mcfg.paged_attn_for \
+            if hasattr(mcfg, "paged_attn_for") else lambda width: \
+            select_paged_attn_impl(
+                width, mcfg.n_heads // mcfg.n_kv_heads,
                 jax.default_backend())
+        self._tick_read = self._read_for(self._tick_width)
         num_blocks = cfg.num_blocks or cfg.slots * self._mb + 1
         if num_blocks < self._mb + 1:
             raise ValueError(
@@ -731,6 +734,10 @@ class Engine:
         self._no_expert_rows = {} if self._expert_step is None \
             else dict.fromkeys(EXPERT_ROW_COUNTERS, 0)
         self._expert_rows = dict(self._no_expert_rows)
+        # the positions of this step's prefills and chunk, bucket
+        # padding and all, by the read their windows took
+        # (`_count_prompt`)
+        self._prompt_positions = dict.fromkeys(PROMPT_READ_COUNTERS, 0)
         # what this step's decode tick counted on the device (an expert
         # model's picks: `_expert_counters`), fetched with its tokens
         self._tick_counted: dict[str, int] = {}
@@ -892,6 +899,7 @@ class Engine:
         self._count_write(
             {k: t[slot] for k, t in self._bts.items()}, start, Pb)
         self._count_experts(Pb)
+        self._count_prompt(Pb)
         prof, at = self.tickprof, {"bucket": Pb, "start": start}
         with prof.seg(f"{seg}/upload", **at):
             buf = np.full((1, Pb), self.cfg.pad_id, np.int32)
@@ -970,6 +978,19 @@ class Engine:
             rows, e["groups"], e["k"], e["n"], jax.default_backend(),
             e["itemsize"])
         self._expert_rows[f"expert_rows_{form}"] += e["layers"] * rows
+
+    def _count_prompt(self, positions: int) -> None:
+        """Add a prefill's or a chunk's window of `positions` to the
+        step's `prompt_positions_tiled` or `prompt_positions_gather`,
+        by the read the model's selector names for a window that wide
+        (`llama.select_paged_attn_impl`, or the model's explicit
+        choice): the question the program asked at trace time. A window
+        read in place ("pallas": a bucket no wider than a verify
+        window, or a model told to read every window so) adds to
+        neither. Host arithmetic."""
+        name = f"prompt_positions_{self._read_for(positions)}"
+        if name in self._prompt_positions:
+            self._prompt_positions[name] += positions
 
     def _count_walk(self) -> dict[str, int]:
         """The tick record's walk counters for the tick about to be
@@ -1494,6 +1515,7 @@ class Engine:
             self._prefill_tokens += C
             self._count_write(ck["rows"], pos, C)
             self._count_experts(C)
+            self._count_prompt(C)
             t0 = _CLOCK()
             with prof.seg("chunk/upload", **at):
                 args = (jnp.asarray(np.asarray(prompt[pos:pos + C],
@@ -2165,7 +2187,11 @@ class Engine:
                     # an expert model: the (token, pick) rows the step's
                     # tick, chunk and prefills sent through each form of
                     # the grouped products, over the expert layers
-                    **self._expert_rows)
+                    **self._expert_rows,
+                    # the positions of the step's prefills and chunk by
+                    # the read their windows took: the tiled kernel over
+                    # the gathered chain, or the gather's one softmax
+                    **self._prompt_positions)
         if self.flight.due(self._tick_no):
             self.flight.spill("periodic", self._flight_payload(),
                               tick=self._tick_no)
@@ -2180,6 +2206,7 @@ class Engine:
         self._walk_counted = self._no_walk
         self._write_counted = dict(self._no_write)
         self._expert_rows = dict(self._no_expert_rows)
+        self._prompt_positions = dict.fromkeys(PROMPT_READ_COUNTERS, 0)
         self._tick_counted = {}
         self._loop_counted = {}
 

@@ -516,6 +516,78 @@ def test_the_tick_reads_both_pools_in_place_and_serves_the_same_tokens(tiny):
     assert snap["kv_table_entries_window"] == 9 * len(checked)
 
 
+@pytest.mark.parametrize("where", ["off_a_tpu", "as_on_a_tpu"])
+def test_the_step_record_counts_the_prompt_positions_by_read(tiny, where):
+    """`prompt_positions_tiled` + `prompt_positions_gather` of a step
+    are the positions of its prefills and its chunk, bucket padding and
+    all (`prefill_tokens`), each window under the read
+    `select_paged_attn_impl` names for its width: the question the
+    model asked at trace time. Off a TPU every window gathers. With the
+    selector answering as on a TPU, and the threshold brought down to
+    this engine's chunk of 16 (32 rows), the chunks and the 16-position buckets
+    read through the tiled kernel (the interpreter here), windowed
+    layers and released blocks and all, the 8-position buckets through
+    the gather, and the greedy streams are the gather's."""
+    from hyperion_tpu.models import llama
+    from hyperion_tpu.obs.tickprof import PROMPT_READ_COUNTERS
+
+    cfg, model, params = tiny
+    ecfg = EngineConfig(slots=3, max_len=64, block_size=4, prefill_chunk=16,
+                        prefix_cache=False)
+
+    def requests():
+        rng = np.random.default_rng(11)
+        return [Request(prompt_ids=rng.integers(1, cfg.vocab_size, n)
+                        .astype(np.int32), max_new_tokens=g, id=f"r{i}")
+                for i, (n, g) in enumerate([(5, 4), (37, 6), (28, 3)])]
+
+    def served(eng, reqs, backend):
+        """Serve, and hold the counters to the windows the engine
+        dispatched and to the selector's answer for each."""
+        windows, count = [], eng._count_prompt
+        eng._count_prompt = lambda n: (windows.append(n), count(n))[1]
+        serve(eng, reqs)
+        recs = eng.tickprof.tail(256)
+        assert any("chunk" in r["s"] for r in recs)
+        total = eng.exposition()["tickprof"]["counters"]
+        assert sum(windows) == total["prefill_tokens"]
+        for read in ("tiled", "gather"):
+            name = f"prompt_positions_{read}"
+            assert total[name] == sum(r["c"][name] for r in recs) == sum(
+                n for n in windows
+                if llama.select_paged_attn_impl(n, 2, backend) == read)
+        assert all(sum(r["c"][k] for k in PROMPT_READ_COUNTERS)
+                   <= r["c"]["prefill_tokens"] for r in recs)
+        return total
+
+    plain = requests()
+    total = served(Engine(model, {"params": params}, ecfg), plain, "cpu")
+    # 5 -> 8; 37 -> 16 + 16 + 8; 28 -> 16 + 16: all that was prefilled
+    assert total["prompt_positions_gather"] == 8 + 40 + 32 \
+        == total["prefill_tokens"]
+    assert total["prompt_positions_tiled"] == 0
+    if where == "off_a_tpu":
+        return
+    with as_on_a_tpu(), pytest.MonkeyPatch.context() as mp:
+        mp.setattr(llama, "PAGED_TILED_MIN_ROWS", 32)
+        # an identity of its own: its traces hold another read
+        twin = Afmoe(dataclasses.replace(cfg, max_len=88))
+        eng = Engine(twin, {"params": params}, ecfg)
+        # two query heads a KV head: a bucket of 8 is 16 rows, which
+        # the selector reads in place like a verify window, and the
+        # counters of the two gathered reads leave out
+        assert [llama.select_paged_attn_impl(t, 2, "tpu")
+                for t in (1, 8, 16)] == ["pallas", "pallas", "tiled"]
+        tiled = requests()
+        total = served(eng, tiled, "tpu")
+    assert total["prompt_positions_tiled"] == 32 + 32
+    assert total["prompt_positions_gather"] == 0
+    assert total["prefill_tokens"] == 64 + 8 + 8
+    for a, b in zip(plain, tiled):
+        assert a.status == b.status == "done"
+        assert a.tokens == b.tokens, a.id
+
+
 def test_off_a_tpu_the_tick_gathers_and_says_so(tiny):
     cfg, model, params = tiny
     eng = Engine(model, {"params": params}, EngineConfig(

@@ -902,3 +902,54 @@ class TestExpertRowsByForm:
             assert sentence in row
         else:
             assert "grouped kernel" not in row
+
+
+class TestPromptPositionsByRead:
+    """Every tick record carries `prompt_positions_tiled` /
+    `prompt_positions_gather` (serve/engine.py `_count_prompt`): flows
+    the snapshot sums over the window's steps, and a sentence of
+    doctor's `host tick profile` row where a window was prefilled."""
+
+    @pytest.mark.parametrize("steps, summed, sentence", [
+        # a 2048-position bucket and a 512-position chunk through the
+        # tiled kernel, the 32-position suffix of a prefix hit gathered
+        ([{"prompt_positions_tiled": 2048, "prompt_positions_gather": 0},
+          {"prompt_positions_tiled": 512, "prompt_positions_gather": 32}],
+         {"prompt_positions_tiled": 2560, "prompt_positions_gather": 32},
+         "prompt windows: 2560 position(s) through the tiled kernel, "
+         "32 through the gather's softmax"),
+        # off a TPU: every window gathers
+        ([{"prompt_positions_tiled": 0, "prompt_positions_gather": 24}] * 2,
+         {"prompt_positions_tiled": 0, "prompt_positions_gather": 48},
+         "prompt windows: 0 position(s) through the tiled kernel, "
+         "48 through the gather's softmax"),
+        # steps that prefilled nothing, or a process that predates the
+        # counters: nothing is said
+        ([{"prompt_positions_tiled": 0, "prompt_positions_gather": 0}],
+         {"prompt_positions_tiled": 0, "prompt_positions_gather": 0}, None),
+        ([{}], {}, None),
+    ], ids=["wide_tiled_narrow_gathered", "all_gathered", "ticks_only",
+            "no_counters"])
+    def test_rolled_up_and_said_in_words(self, tmp_path, steps, summed,
+                                         sentence):
+        from hyperion_tpu.obs.tickprof import TickProfiler
+
+        clk = VirtualClock()
+        tp = TickProfiler(wall=clk)
+        for n, c in enumerate(steps):
+            tp.record(n, {"device": 0.020}, 0.021,
+                      {"kv_tokens": 100, "prefill_tokens": 0, **c})
+            clk.advance(0.021)
+        snap = tp.snapshot(now=clk.t)
+        assert {k: v for k, v in snap["counters"].items()
+                if k.startswith("prompt_positions")} == summed
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        if sentence:
+            assert sentence in row
+        else:
+            assert "tiled kernel" not in row

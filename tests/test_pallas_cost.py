@@ -23,6 +23,7 @@ from hyperion_tpu.ops.pallas import (
     fused_norm,
     grouped_matmul,
     paged_attention,
+    window_attention,
 )
 from hyperion_tpu.ops.pallas.backend import LANES, SUBLANES, cost
 
@@ -204,6 +205,33 @@ def test_windowed_paged_attention_counts_the_window_not_the_table(
         2 * chain * Dh * 2
         + _nbytes(*[((Bs, Hkv, rows_p, Dh), BF16)] * 2,
                   ((Bs, MB), jnp.int32), ((Bs,), jnp.int32)))
+
+
+@pytest.mark.parametrize("window, keys_a_query", [
+    (0, 512 - 128 // 2),   # under the diagonal of a window at the view's end
+    (192, 192),            # a windowed layer: its window
+    (4096, 512),           # a window wider than the view: the view
+])
+def test_window_attention_counts_the_mask_not_the_square(
+        window, keys_a_query):
+    B, T, Hq, Hkv, Dh, bs, NBv = 2, 128, 8, 2, 128, 16, 32   # 512 keys
+    q = jnp.zeros((B, T, Hq, Dh), BF16)
+    view = jnp.zeros((B, NBv, Hkv, bs, Dh), BF16)
+    zero = jnp.zeros((B,), jnp.int32)
+    (est,) = _estimates(
+        lambda q, k, v: window_attention.window_attention(
+            q, k, v, zero, zero, window=window), q, view, view)
+    # the static worst case of the mask: what a call multiplies follows
+    # `base`, which no estimate can see (here 0: a quarter of it)
+    pairs = B * Hq * T * keys_a_query
+    assert est.flops == 4 * pairs * Dh
+    assert est.transcendentals == pairs
+    # the regrouped query in, the output out, the view's keys and
+    # values once each, the two scalar rows: a tile read again for the
+    # next query tile is the tiling's, not the algorithm's
+    assert est.bytes_accessed == _nbytes(
+        *[((B, Hkv, T * Hq // Hkv, Dh), BF16)] * 2,
+        *[(view.shape, BF16)] * 2, *[((B,), jnp.int32)] * 2)
 
 
 @pytest.mark.parametrize("M, G, K, N, tiling", [

@@ -747,6 +747,292 @@ class TestPagedAttention:
         step(ids, jnp.asarray([9, 3], jnp.int32), bt)     # [S, 1]
 
 
+WINDOW_HEADS = {"8x4": (8, 4), "8x6": (8, 6), "4x7": (4, 7), "16x1": (16, 1)}
+# the first position of a 40-position window: a prompt's start, a
+# chunk's multiple of the block, a prefix hit that ends inside a block
+WINDOW_BASES = {"start": 0, "on_a_block": 96, "mid_block": 150}
+
+
+class TestWindowAttention:
+    """The tiled prompt-window kernel (ops/pallas/window_attention)
+    through `paged_read("tiled")` against `paged_read("gather")`, the
+    oracle: the same blocks of the same table, the same mask, an online
+    softmax over key tiles in place of one softmax over the view.
+    Float32 within the tick kernel's 2e-5; tiles of 16 positions and 128
+    keys, so every case crosses query tiles, key tiles, the diagonal and
+    (windowed) the window's far edge, and skips tiles at both ends."""
+
+    T, bs, D, MB, WINDOW = 40, 4, 16, 112, 160   # a view of 448 keys
+
+    @pytest.fixture(autouse=True)
+    def small_tiles(self, monkeypatch):
+        import hyperion_tpu.ops.pallas.window_attention as wa
+
+        monkeypatch.setattr(wa, "_plan", lambda *a: (16, 128))
+
+    def _geometry(self, Hkv, rep, base, window, *, B=1, T=None, seed=0,
+                  dtype=jnp.float32, segments=1):
+        """A live slot as the engine hands it over: its chain mapped
+        from the block of the first position the window's first query
+        sees (0 in a full layer) to the frontier `base + T`, null
+        entries before (blocks let go) and beyond; a garbage null block
+        of each segment."""
+        T = T or self.T
+        bs, D, MB = self.bs, self.D, self.MB
+        NB = B * MB + 1
+        ks = jax.random.split(jax.random.key(seed), 3)
+        q = jax.random.normal(ks[0], (B, T, Hkv * rep, D), dtype)
+        kp = jax.random.normal(ks[1], (segments * NB, Hkv, bs, D), dtype)
+        vp = jax.random.normal(ks[2], (segments * NB, Hkv, bs, D), dtype)
+        rng = np.random.default_rng(seed)
+        bt = np.zeros((B, MB), np.int32)
+        order = rng.permutation(np.arange(1, NB))
+        for b in range(B):
+            lo = max(base - window + 1, 0) // bs if window else 0
+            hi = min(MB, -(-(base + T) // bs))
+            bt[b, lo:hi] = order[b * MB + lo:b * MB + hi]
+        return (q, kp, vp, jnp.asarray(bt),
+                jnp.full((B,), base, jnp.int32))
+
+    def _both(self, q, kp, vp, bt, base, window, shift=None):
+        from hyperion_tpu.models.llama import paged_read
+
+        return (paged_read("tiled", q, kp, vp, bt, base, window, shift),
+                paged_read("gather", q, kp, vp, bt, base, window, shift))
+
+    @pytest.mark.parametrize("base", sorted(WINDOW_BASES))
+    @pytest.mark.parametrize("kind", ["full", "windowed"])
+    @pytest.mark.parametrize("heads", sorted(WINDOW_HEADS))
+    def test_matches_the_gather(self, heads, kind, base):
+        Hkv, rep = WINDOW_HEADS[heads]
+        window = self.WINDOW if kind == "windowed" else 0
+        geo = self._geometry(Hkv, rep, WINDOW_BASES[base], window,
+                             seed=len(heads + kind + base))
+        out, ref = self._both(*geo, window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("kind", ["full", "windowed"])
+    @pytest.mark.parametrize("heads", ["16x1", "8x4"])
+    @pytest.mark.parametrize("segment", [0, 2])
+    def test_through_a_shifted_table(self, segment, heads, kind):
+        """A looped model's prompt window (models/ouro.py): pools of
+        three segments behind one table, read through the table shifted
+        by `segment * NB`, null entries and the view's padding too;
+        against the gather through the same shift and against the
+        segment cut out of the pools."""
+        from hyperion_tpu.models.llama import segment_shift
+
+        Hkv, rep = WINDOW_HEADS[heads]
+        window = self.WINDOW if kind == "windowed" else 0
+        q, kp, vp, bt, base = self._geometry(
+            Hkv, rep, 150, window, seed=11, segments=3)
+        shift = segment_shift(kp, jnp.int32(segment), 3)
+        out, ref = self._both(q, kp, vp, bt, base, window, shift)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        NB = kp.shape[0] // 3
+        cut = slice(segment * NB, (segment + 1) * NB)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(self._both(q, kp[cut], vp[cut], bt, base,
+                                  window)[1]),
+            atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("kind", ["full", "windowed"])
+    def test_several_rows_each_at_its_own_base(self, kind):
+        from hyperion_tpu.models.llama import paged_read
+
+        window = self.WINDOW if kind == "windowed" else 0
+        q, kp, vp, bt, _ = self._geometry(2, 3, 0, 0, B=3, seed=5)
+        # every chain mapped whole: each row reads from its own depth
+        bt = jnp.asarray(np.random.default_rng(5).permutation(
+            np.arange(1, 3 * self.MB + 1)).reshape(3, self.MB)
+            .astype(np.int32))
+        base = jnp.asarray([0, 203, 408], jnp.int32)
+        out = paged_read("tiled", q, kp, vp, bt, base, window)
+        ref = paged_read("gather", q, kp, vp, bt, base, window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("case", [
+        "padding_past_the_frontier", "blocks_let_go", "inactive_lane"])
+    def test_garbage_gets_weight_exactly_zero(self, case):
+        """What the table does not cover reads the null block, and what
+        the null block holds is garbage by contract: a prompt of 21
+        positions in its bucket of 40 (the bucket's tail is written to
+        block 0 and lies past every real query), the blocks a windowed
+        layer has let go before the window, a lane with an all-null
+        table and its last occupant's length. Huge values there change
+        no real row by a bit, and every row stays finite."""
+        from hyperion_tpu.models.llama import paged_read
+
+        window = self.WINDOW if case == "blocks_let_go" else 0
+        base, real = 203, 21 if case == "padding_past_the_frontier" \
+            else self.T
+        q, kp, vp, bt, b = self._geometry(2, 4, base, window, T=real,
+                                          seed=9)
+        q = jnp.concatenate(
+            [q, jnp.ones((1, self.T - real, *q.shape[2:]))], axis=1)
+        if case == "inactive_lane":
+            bt = jnp.zeros_like(bt)
+        outs = []
+        for null in (0.0, 3e4):
+            poisoned = [p.at[0].set(null) for p in (kp, vp)]
+            outs.append(np.asarray(
+                paged_read("tiled", q, *poisoned, bt, b, window)))
+            assert np.isfinite(outs[-1]).all()
+        if case != "inactive_lane":
+            np.testing.assert_array_equal(
+                outs[0][:, :real], outs[1][:, :real])
+            ref = paged_read("gather", q, kp, vp, bt, b, window)
+            np.testing.assert_allclose(
+                outs[1][:, :real], np.asarray(ref)[:, :real],
+                atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("kind", ["full", "windowed"])
+    def test_bf16_sits_as_near_a_float32_reference_as_the_gather(
+            self, kind):
+        """bf16 query and cache: the operands go to both products as
+        they are, so the kernel and the gather (float32 products on the
+        CPU) part by bf16 rounding of the weights; each is held to the
+        float32 reference of the same values, as the tick's kernel is."""
+        window = self.WINDOW if kind == "windowed" else 0
+        geo = self._geometry(8, 4, 150, window, seed=3,
+                             dtype=jnp.bfloat16)
+        out, ref = self._both(*geo, window)
+        assert out.dtype == jnp.bfloat16
+        exact = self._both(
+            *(a.astype(jnp.float32) for a in geo[:3]), *geo[3:], window)[1]
+        err = lambda a: float(jnp.sqrt(jnp.mean(  # noqa: E731
+            (a.astype(jnp.float32) - exact) ** 2)))
+        scale = float(jnp.sqrt(jnp.mean(exact ** 2)))
+        assert err(ref) < 0.01 * scale
+        assert err(out) < 0.01 * scale
+
+    def test_only_the_tiles_a_window_sees_are_copied(self):
+        """The key axis' block index, as the pipeline sees it over a
+        query tile's sweep: clamped into `_tile_span`, so a step outside
+        the span names the block the step before it held and starts no
+        copy. A 40-position prompt at 150 in a view of 448 keys: tile
+        1 only for a full layer's first query tile; a windowed layer
+        (160) starts where its first query's window does."""
+        import hyperion_tpu.ops.pallas.window_attention as wa
+
+        def sweep(base, view0, qi, window):
+            lo, hi = wa._tile_span(jnp.int32(base), jnp.int32(view0), qi,
+                                   tq=16, tk=128, nk=4, window=window)
+            return [int(jnp.clip(ki, lo, hi)) for ki in range(4)]
+
+        assert sweep(150, 0, 0, 0) == [0, 1, 1, 1]
+        assert sweep(0, 0, 0, 0) == [0, 0, 0, 0]
+        assert sweep(300, 0, 2, 0) == [0, 1, 2, 2]
+        assert sweep(300, 0, 0, 160) == [1, 1, 2, 2]
+        # a view that starts at the window's block: position 140
+        assert sweep(300, 140, 0, 160) == [0, 1, 1, 1]
+        # bucket padding beyond the view's end stays inside it
+        assert sweep(440, 0, 2, 0) == [0, 1, 2, 3]
+
+    @pytest.mark.parametrize("tiles", [(16, 128), (8, 256), (64, 512)])
+    def test_tiles_given_by_the_caller(self, tiles):
+        """The kernel itself over a view the caller gathered, with the
+        tiles handed in (`tile_q`, `tile_k`: what a probe sweeps): the
+        same answer at any tiling, a window that is no multiple of the
+        query tile included."""
+        from hyperion_tpu.models.llama import paged_read
+        from hyperion_tpu.ops.pallas.window_attention import (
+            window_attention,
+        )
+
+        q, kp, vp, bt, base = self._geometry(2, 4, 150, 0, seed=4)
+        bt = jnp.pad(bt, ((0, 0), (0, 16)), mode="edge")   # 512 keys
+        out = window_attention(
+            q, kp[bt], vp[bt], base, jnp.zeros_like(base),
+            keys=self.MB * self.bs, tile_q=tiles[0], tile_k=tiles[1])
+        ref = paged_read("gather", q, kp, vp, bt[:, :self.MB], base)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_refuses_a_view_that_is_not_whole_tiles(self):
+        from hyperion_tpu.ops.pallas.window_attention import (
+            window_attention,
+        )
+
+        q = jnp.zeros((1, 16, 4, 16))
+        kv = jnp.zeros((1, 40, 2, 4, 16))      # 160 keys, tiles of 128
+        zero = jnp.zeros((1,), jnp.int32)
+        with pytest.raises(ValueError, match="whole tiles"):
+            window_attention(q, kv, kv, zero, zero)
+
+    def test_plan_follows_the_shape_and_the_budget(self, monkeypatch):
+        import hyperion_tpu.ops.pallas.window_attention as wa
+
+        monkeypatch.undo()
+        for T, rep, blocks in [(2048, 4, 128), (512, 6, 289),
+                               (512, 6, 768), (512, 7, 289),
+                               (512, 1, 48), (64, 4, 128), (8, 4, 128)]:
+            tq, tk = wa._plan(T, rep, 128, 16, blocks, jnp.bfloat16,
+                              jnp.bfloat16)
+            assert tq & (tq - 1) == 0 and 16 <= tq <= max(16, T)
+            assert tk % 128 == 0 and tk % 16 == 0
+            assert tk <= -(-blocks * 16 // 128) * 128
+            assert wa.plan_vmem_bytes(tq, tk, rep, 128, 2, 2) \
+                <= wa._VMEM_BUDGET < wa._VMEM_LIMIT
+        # float32 operands at wide heads: the step is cut, not refused
+        tq, tk = wa._plan(4096, 8, 256, 16, 1024, jnp.float32, jnp.float32)
+        assert wa.plan_vmem_bytes(tq, tk, 8, 256, 4, 4) <= wa._VMEM_BUDGET
+
+    def test_model_level_matches_gather(self):
+        """Llama tiny (GQA rep 2) with every paged read through the
+        tiled kernel against the gather, caches threaded forward per
+        impl: a prefill of a bucket longer than its prompt, a chunk at
+        a mid-block base, a verify window and a decode row. Logits and
+        caches within the pinned float32 bound at every step."""
+        import dataclasses
+
+        from hyperion_tpu.models.llama import (
+            Llama, init_paged_cache, llama_tiny_config)
+
+        cfg = llama_tiny_config(n_kv_heads=2, max_len=48)
+        bs, B = 4, 2
+        MB = cfg.max_len // bs
+        models = {"gather": Llama(cfg), "tiled": Llama(
+            dataclasses.replace(cfg, paged_attn_impl="tiled"))}
+        params = models["gather"].init(
+            jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+        caches = {n: init_paged_cache(cfg, B * MB + 1, bs) for n in models}
+        rng = np.random.default_rng(0)
+        bt = jnp.asarray(rng.permutation(np.arange(1, B * MB + 1))
+                         .reshape(B, MB).astype(np.int32))
+
+        def step(ids, index, tables, real=None):
+            outs = {}
+            for name, model in models.items():
+                outs[name], caches[name] = model.apply(
+                    {"params": params}, ids, cache=caches[name],
+                    cache_index=index, block_tables=tables)
+            np.testing.assert_allclose(
+                np.asarray(outs["tiled"])[:, :real],
+                np.asarray(outs["gather"])[:, :real],
+                atol=2e-5, rtol=2e-5)
+            for lg, lt in zip(caches["gather"], caches["tiled"]):
+                for kv in ("k", "v"):
+                    # block 0 holds what the bucket's padding wrote
+                    np.testing.assert_allclose(
+                        np.asarray(lg[kv])[1:], np.asarray(lt[kv])[1:],
+                        atol=2e-5, rtol=2e-5)
+
+        def ids(*shape):
+            return jnp.asarray(rng.integers(0, cfg.vocab_size, shape),
+                               jnp.int32)
+
+        # a prompt of 11 in a bucket of 16: its table covers 3 blocks
+        step(ids(1, 16), 0, bt[:1].at[:, 3:].set(0), real=11)
+        step(ids(1, 16), 11, bt[:1])                     # a chunk at 11
+        step(ids(B, 3), jnp.asarray([27, 0], jnp.int32), bt)   # [S, k+1]
+        step(ids(B, 1), jnp.asarray([30, 3], jnp.int32), bt)   # [S, 1]
+
+
 class TestGroupedMatmul:
     """`ops/pallas/grouped_matmul.py` through the interpreter against
     `lax.ragged_dot`, on the rows that belong to a group: what lies

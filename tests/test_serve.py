@@ -253,27 +253,49 @@ class TestPagedAttnPallas:
         assert led["kv_gather_bytes_per_tick"] == \
             2 * eng._mb * eng._block_bytes > 0
 
-    @pytest.mark.parametrize("window, rep, backend, want", [
-        (1, 4, "tpu", "pallas"),       # the decode tick [S, 1]
-        (4, 4, "tpu", "pallas"),       # a verify window [S, k+1]
-        (5, 1, "tpu", "pallas"),       # the same without GQA
-        (8, 4, "tpu", "gather"),       # the smallest prefill bucket
-        (512, 4, "tpu", "gather"),     # a chunk [1, 512]
-        (2048, 4, "tpu", "gather"),    # the largest prefill bucket
-        (1, 4, "cpu", "gather"),       # the interpreter is no read path
-        (4, 4, "cpu", "gather"),
-        (2048, 4, "cpu", "gather"),
-    ])
-    def test_auto_selects_from_shape_and_backend(
-            self, window, rep, backend, want):
+    # every window the benchmark's serving cells dispatch, by the query
+    # heads a KV head of the cell's model: the tick, Mistral's verify
+    # window, each prefill bucket (a final piece's too) and the chunk
+    CELL_WINDOWS = {
+        "mistral7b": (4, {
+            1: "pallas", 4: "pallas", 8: "gather", 16: "gather",
+            32: "gather", 64: "gather", 128: "gather", 256: "gather",
+            512: "tiled", 1024: "tiled", 2048: "tiled"}),
+        "trinity": (6, {
+            1: "pallas", 8: "gather", 16: "gather", 32: "gather",
+            64: "gather", 128: "gather", 256: "gather", 512: "tiled"}),
+        "smallthinker": (7, {
+            1: "pallas", 8: "gather", 16: "gather", 32: "gather",
+            64: "gather", 128: "gather", 256: "gather", 512: "tiled"}),
+        # one query row a KV head: the two narrowest buckets are no
+        # wider than a verify window and read in place, as they did;
+        # no bucket reaches the tiled kernel's rows
+        "ouro": (1, {
+            1: "pallas", 8: "pallas", 16: "pallas", 32: "gather",
+            64: "gather", 128: "gather", 256: "gather", 512: "gather"}),
+    }
+
+    @pytest.mark.parametrize("backend", ["tpu", "cpu"])
+    @pytest.mark.parametrize("cell, window", [
+        (cell, w) for cell, (_, by_window) in CELL_WINDOWS.items()
+        for w in by_window])
+    def test_auto_selects_from_shape_and_backend(self, cell, window,
+                                                 backend):
         from hyperion_tpu.models.llama import (
             PAGED_KERNEL_MAX_ROWS,
+            PAGED_TILED_MIN_ROWS,
             select_paged_attn_impl,
         )
 
+        rep, by_window = self.CELL_WINDOWS[cell]
+        # the interpreter is no read path: off a TPU everything gathers
+        want = by_window[window] if backend == "tpu" else "gather"
         assert select_paged_attn_impl(window, rep, backend) == want
+        on_tpu = select_paged_attn_impl(window, rep, "tpu")
         assert (window * rep <= PAGED_KERNEL_MAX_ROWS) == \
-            (select_paged_attn_impl(window, rep, "tpu") == "pallas")
+            (on_tpu == "pallas")
+        assert (window * rep >= PAGED_TILED_MIN_ROWS) == \
+            (on_tpu == "tiled")
 
     def test_auto_lowers_the_gather_programs_on_cpu(self, llama):
         """Off a TPU `auto` is the gather: the tick and the prefill the
@@ -2906,11 +2928,13 @@ class TestIntrospection:
         # the exposition and the flight record carry both
         assert set(eng.exposition()["tickprof"]["counters"]) == {
             "kv_tokens", "prefill_tokens", "kv_blocks_walked",
-            "kv_table_entries", "kv_blocks_written", "kv_rows_written"}
+            "kv_table_entries", "kv_blocks_written", "kv_rows_written",
+            "prompt_positions_tiled", "prompt_positions_gather"}
         assert set(eng._flight_payload()["ticks"][-1]["c"]) == {
             "kv_tokens", "prefill_tokens", "sampling_rows",
             "restricted_rows", "kv_blocks_walked", "kv_table_entries",
             "kv_blocks_written", "kv_rows_written",
+            "prompt_positions_tiled", "prompt_positions_gather",
             # the profiler's own four, in every record
             "step_us", "inflight_us", "fetch_after_ready_us", "gc_us"}
         assert set(eng.exposition()["tickprof"]["inflight"]) == {

@@ -24,6 +24,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -32,6 +33,7 @@ import hyperion_tpu.ops.pallas.fused_ce as ce_mod
 import hyperion_tpu.ops.pallas.fused_norm as norm_mod
 import hyperion_tpu.ops.pallas.grouped_matmul as grouped_mod
 import hyperion_tpu.ops.pallas.paged_attention as paged_mod
+import hyperion_tpu.ops.pallas.window_attention as window_mod
 
 # the package re-exports the flash_attention function under the module's
 # own name, so `import ... as` would bind the function
@@ -70,9 +72,10 @@ def one_chip(topo):
 @pytest.fixture
 def mosaic(monkeypatch):
     """The session's backend is the CPU, where `_interpret()` picks the
-    interpreter; steer the five kernels to the compiled path here, in
+    interpreter; steer the six kernels to the compiled path here, in
     the test, rather than through an option of the program."""
-    for mod in (flash_mod, ce_mod, norm_mod, paged_mod, grouped_mod):
+    for mod in (flash_mod, ce_mod, norm_mod, paged_mod, grouped_mod,
+                window_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -173,6 +176,58 @@ class TestPagedAttentionCompiles:
             S((S_, MB), jnp.int32), S((S_,), jnp.int32),
         )
         assert "gather" not in text
+
+
+# The prompt windows of the benchmark's cells, one layer's read: query
+# positions, KV heads, query heads a KV head, table columns, window.
+# Head size 128 and 16-position blocks throughout.
+WINDOW_CELLS = {
+    "mistral_bucket_2048": (2048, 8, 4, 128, 0),
+    "trinity_chunk_windowed": (512, 8, 6, 768, 4096),
+    "trinity_chunk_full": (512, 8, 6, 768, 0),
+    "smallthinker_chunk_windowed": (512, 4, 7, 512, 4096),
+    "smallthinker_chunk_full": (512, 4, 7, 512, 0),
+}
+
+
+class TestWindowAttentionCompiles:
+    @pytest.mark.parametrize("cell", sorted(WINDOW_CELLS))
+    def test_benchmark_cell_geometry(self, mosaic, one_chip, cell):
+        """A prompt window's tiled read (`paged_read("tiled")`: the
+        gather of the chain in whole blocks, then the kernel over it)
+        at each cell's widths: the plan's step fits the VMEM budget it
+        states, the kernel is there, the view goes from the gather to
+        the kernel as it lies, and no float32 array wider than the
+        query itself, let alone `[rows, view]` of scores, is left in
+        the program."""
+        import re
+
+        from hyperion_tpu.models import llama
+
+        T, Hkv, rep, MB, window = WINDOW_CELLS[cell]
+        D, bs, NB = 128, 16, 6145
+        nb = min(MB, paged_mod.window_view_blocks(window, T, bs)) \
+            if window else MB
+        assert nb == (289 if window else MB)
+        tq, tk = window_mod._plan(T, rep, D, bs, nb, BF16, BF16)
+        assert (tq, tk) == (256, 1024)
+        assert window_mod.plan_vmem_bytes(tq, tk, rep, D, 2, 2) \
+            <= window_mod._VMEM_BUDGET < window_mod._VMEM_LIMIT
+        pool = S((NB, Hkv, bs, D), BF16)
+        text = _compile(
+            lambda q, ck, cv, bt, base: llama.paged_read(
+                "tiled", q, ck, cv, bt, base, window),
+            one_chip,
+            S((1, T, Hkv * rep, D), BF16), pool, pool,
+            S((1, MB), jnp.int32), S((1,), jnp.int32),
+        )
+        # the view in whole key tiles, in the cache's dtype
+        blocks = -(-nb * bs // tk) * tk // bs
+        assert f"bf16[1,{blocks},{Hkv},{bs},{D}]" in text
+        f32 = [int(np.prod([int(d) for d in dims.split(",")]))
+               for dims in re.findall(r"f32\[([0-9,]+)\]", text)]
+        # the query scaled in float32 inside its fusion, nothing wider
+        assert max(f32, default=0) <= T * Hkv * rep * D < T * Hkv * rep * tk
 
 
 class TestOuroProgramsStayInPlace:
