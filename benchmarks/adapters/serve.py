@@ -158,6 +158,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         "measured": measured, "peak_bytes": peak_bytes, "extra": {"kv": kv},
         "ctx": {"cell": cell, "series": series, "requests": reqs,
                 "trace": data["trace"],
+                # the engine's tick records' counters, a decode tick of
+                # the window each
+                "counted": [k["c"] for k in in_win],
                 # what a traced decode tick had to read, on average
                 "tick_bytes": float(np.mean([costs.tick_bytes(
                     m, k["kv_tokens"]) for k in traced])) if traced else None},
@@ -223,7 +226,7 @@ def _drive(engine, source, seconds, cell, trace, trace_dir,
             prof = engine.tickprof.tail(1)[0]
             (traced_ticks if tracing else ticks).append({
                 "t": end, "total_s": prof["total_s"], "s": prof["s"],
-                "active": engine.n_active,
+                "c": prof["c"], "active": engine.n_active,
                 "kv_tokens": sum(
                     len(r["req"].prompt_ids) + len(r["req"].tokens)
                     for r in live.values() if r["req"].tokens)})

@@ -102,24 +102,6 @@ def afmoe_weights(model, seed: int):
         decoder_weights(model, seed))
 
 
-class _Counting:
-    """The engine as `_drive` sees it, keeping each step's tick record:
-    `_drive` copies the segments only, the readers here need the
-    counters (`c`) too."""
-
-    def __init__(self, engine):
-        self._engine = engine
-        self.records: list[tuple[float, dict]] = []
-
-    def __getattr__(self, name):
-        return getattr(self._engine, name)
-
-    def step(self):
-        out = self._engine.step()
-        self.records.append((CLOCK(), self._engine.tickprof.tail(1)[0]))
-        return out
-
-
 def run(cell: dict, seed: int, seconds: float, trace: bool,
         trace_dir: str, t_start: float, say) -> dict:
     import jax
@@ -160,15 +142,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     # worst case holds, slots x (window + a chunk + two blocks)
     window_bound = memory["kv_by_kind"]["window"]["pool_bytes"] \
         // kv_token_bytes["window"] - engine.cfg.block_size
-    counting = _Counting(engine)
     source = spec.plugin("traffic", cell["generator"]).Source(
         cell["traffic"], seed, m["vocab_size"])
-    data = _drive(counting, source, seconds, cell, trace, trace_dir,
+    data = _drive(engine, source, seconds, cell, trace, trace_dir,
                   token_times, lost)
     w0, w1 = data["window"]
     no_compile = engine.compile_stats() == compiled
     peak_bytes = _peak_bytes()
-    del engine, counting._engine    # the pools go; the reference needs the room
+    del engine          # the pools go; the reference needs the room
     gc.collect()
 
     reqs = data["requests"]
@@ -189,16 +170,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
                 + c["kv_tokens_window"] * kv_token_bytes["window"])
 
     # the engine's own tick records: the window's, then the traced ones
-    in_window = [r["c"] for t, r in counting.records if w0 <= t <= w1]
-    counted = [{**r["c"], "expert_layers": sizes["expert_layers"],
+    in_win = [k for k in window if "device" in k["s"]]
+    in_window = [k["c"] for k in window]
+    counted = [{**k["c"], "expert_layers": sizes["expert_layers"],
                 "experts_held_all_layers":
                     sizes["expert_layers"] * sizes["experts_held"]}
-               for t, r in counting.records
-               if w0 <= t <= w1 and "device" in r["s"]]
-    traced = [r["c"] for t, r in counting.records
-              if t > w1 and "device" in r["s"]
-              and "experts_touched" in r["c"]]
-    in_win = [k for k in window if "device" in k["s"]]
+               for k in in_win]
+    traced = [k["c"] for k in data["traced_ticks"]
+              if "device" in k["s"] and "experts_touched" in k["c"]]
     series = {
         "gap_s": gaps,
         # of the requests whose first token fell in the window: one sent

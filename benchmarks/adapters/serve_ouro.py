@@ -45,7 +45,7 @@ import numpy as np
 
 from benchmarks import costs_ouro, spec, stats
 from benchmarks.adapters.serve import CLOCK, _drive, _ms, _peak_bytes
-from benchmarks.adapters.serve_afmoe import _Counting, checked_requests
+from benchmarks.adapters.serve_afmoe import checked_requests
 
 # How far below its row's best reference logit a served token lies, in
 # standard deviations of the checked rows (`z`): cell 4's quantity and
@@ -193,17 +193,16 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     sizes = costs_ouro.ouro_sizes(m)
     kv_token_bytes = sizes["kv_bytes_per_token"]
     pool_bytes = memory["kv_pool_bytes"]
-    counting = _Counting(engine)
     source = AfterRamp(spec.plugin("traffic", cell["generator"]).Source(
         cell["traffic"], seed, m["vocab_size"]), cell["traffic"]["strata"])
-    data = _drive(counting, source, seconds, cell, trace, trace_dir,
+    data = _drive(engine, source, seconds, cell, trace, trace_dir,
                   token_times, lost)
     w0, w1 = data["window"]
     no_compile = engine.compile_stats() == compiled
     bucket = {r["id"]: engine.bucket(len(r["prompt"]))
               for r in data["requests"]}
     peak_bytes = _peak_bytes()
-    del engine, counting._engine    # the pools go; the reference needs the room
+    del engine          # the pools go; the reference needs the room
     gc.collect()
 
     reqs = data["requests"]
@@ -220,25 +219,22 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     done_in_window = sum(w0 <= r["times"][-1] <= w1 for r in done)
 
     # the engine's own tick records: the window's, then the traced ones
-    records = [r for t, r in counting.records if w0 <= t <= w1]
-    ticked = [r for r in records if "device" in r["s"]]
-    traced = [r["c"] for t, r in counting.records
-              if t > w1 and "device" in r["s"]]
-    in_win = [k for k in window if "device" in k["s"]]
+    ticked = [k for k in window if "device" in k["s"]]
+    traced = [k["c"] for k in data["traced_ticks"] if "device" in k["s"]]
     series = {
         "gap_s": gaps,
         # of the requests whose first token fell in the window
         "prefill_s": [r["prefill_s"] for r in reqs
                       if r["first_token"] is not None
                       and w0 <= r["first_token"] <= w1],
-        "tick_device_s": [k["s"]["device"] for k in in_win],
+        "tick_device_s": [k["s"]["device"] for k in ticked],
         "tick_host_s": [k["total_s"] - k["s"]["device"]
                         - k["s"].get("admit", 0.0) - k["s"].get("chunk", 0.0)
-                        for k in in_win],
+                        for k in ticked],
         "occupancy": [k["active"] / slots for k in window],
         # bytes the running requests hold over the bytes of the pool
         "kv_fill": [r["c"]["kv_tokens"] * kv_token_bytes / pool_bytes
-                    for r in records],
+                    for r in window],
         # a tick's device segment over the cache layers it wrote and
         # read (the record's own counter: loop steps x layers); empty
         # where the program counts none
@@ -246,7 +242,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
             r["s"]["device"] / r["c"]["layer_passes"] for r in ticked
             if r["c"].get("layer_passes")],
     }
-    held = [r["c"]["kv_tokens"] * kv_token_bytes for r in records] or [0.0]
+    held = [r["c"]["kv_tokens"] * kv_token_bytes for r in window] or [0.0]
     kv = {"pool_bytes": pool_bytes,
           "live_bytes_mean": float(np.mean(held)),
           "live_bytes_peak": float(max(held))}

@@ -12,8 +12,7 @@ outside, so this file brings:
     as they stand (the model has no leaf that is neither a matrix nor a
     norm scale);
   * `run`, `serve_afmoe.py`'s with this model, `costs_smallthinker`
-    and the check below (`_Counting` and `checked_requests` are
-    imported from it);
+    and the check below (`checked_requests` is imported from it);
   * `correct`: cell 3's rule (nothing compiled in the window, nothing
     lost, every finished request whole, the windowed pool under its
     bound, and the served tokens of the `shortest` finished requests
@@ -35,7 +34,7 @@ import numpy as np
 
 from benchmarks import costs_smallthinker, spec, stats
 from benchmarks.adapters.serve import CLOCK, _drive, _ms, _peak_bytes
-from benchmarks.adapters.serve_afmoe import _Counting, checked_requests
+from benchmarks.adapters.serve_afmoe import checked_requests
 
 # How far below its row's best reference logit a served token lies, in
 # standard deviations of the checked rows (`z`): cell 3's quantity, and
@@ -164,15 +163,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     # worst case holds, slots x (window + a chunk + two blocks)
     window_bound = memory["kv_by_kind"]["window"]["pool_bytes"] \
         // kv_token_bytes["window"] - engine.cfg.block_size
-    counting = _Counting(engine)
     source = spec.plugin("traffic", cell["generator"]).Source(
         cell["traffic"], seed, m["vocab_size"])
-    data = _drive(counting, source, seconds, cell, trace, trace_dir,
+    data = _drive(engine, source, seconds, cell, trace, trace_dir,
                   token_times, lost)
     w0, w1 = data["window"]
     no_compile = engine.compile_stats() == compiled
     peak_bytes = _peak_bytes()
-    del engine, counting._engine    # the pools go; the reference needs the room
+    del engine          # the pools go; the reference needs the room
     gc.collect()
 
     reqs = data["requests"]
@@ -193,16 +191,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
                 + c["kv_tokens_window"] * kv_token_bytes["window"])
 
     # the engine's own tick records: the window's, then the traced ones
-    in_window = [r["c"] for t, r in counting.records if w0 <= t <= w1]
-    counted = [{**r["c"], "expert_layers": sizes["expert_layers"],
+    in_win = [k for k in window if "device" in k["s"]]
+    in_window = [k["c"] for k in window]
+    counted = [{**k["c"], "expert_layers": sizes["expert_layers"],
                 "experts_held_all_layers":
                     sizes["expert_layers"] * sizes["experts"]}
-               for t, r in counting.records
-               if w0 <= t <= w1 and "device" in r["s"]]
-    traced = [r["c"] for t, r in counting.records
-              if t > w1 and "device" in r["s"]
-              and "experts_touched" in r["c"]]
-    in_win = [k for k in window if "device" in k["s"]]
+               for k in in_win]
+    traced = [k["c"] for k in data["traced_ticks"]
+              if "device" in k["s"] and "experts_touched" in k["c"]]
     series = {
         "gap_s": gaps,
         # of the requests whose first token fell in the window (a request

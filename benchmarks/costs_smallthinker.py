@@ -1,10 +1,11 @@
 """What a decode tick of the served SmallThinker configuration has to
 read, from shapes alone: the numerator of `tick_hbm_roofline_pct` in
 the cell that runs `models/smallthinker.py`. The routed products are
-XLA's own grouped matrix product (`ragged_dot`), no Pallas kernel of
-this model's: the tick's share of the HBM roofline is the bound, and no
-kernel has a row of its own here. Kept with the benchmark so that a PR
-that changes the program cannot change it."""
+the grouped-matmul Pallas kernel on a TPU at this cell's shapes and
+XLA's `ragged_dot` elsewhere: either reads what is counted here, each
+touched expert's matrices once. The tick's share of the HBM roofline is
+the bound, and no kernel has a row of its own here. Kept with the
+benchmark so that a PR that changes the program cannot change it."""
 
 from __future__ import annotations
 

@@ -21,6 +21,13 @@ def benchmark(root: Path = ROOT) -> dict:
     return _load(root / "BENCHMARK.json")
 
 
+def reported_in(bench: dict, kind: str, name: str) -> list[dict]:
+    """The entries of `bench[kind]` (`end_to_end` or `per_layer`) that
+    cell `name` reports: those that list it, and those that list no
+    cell."""
+    return [m for m in bench[kind] if name in m.get("workloads", [name])]
+
+
 def cell(name: str, root: Path = ROOT) -> dict:
     """One entry of `workloads`, joined with its own file
     (`workloads/<name>.json`), its configuration's file and the metrics
@@ -31,18 +38,14 @@ def cell(name: str, root: Path = ROOT) -> dict:
         raise SystemExit(f"BENCHMARK.json has no workload {name!r}")
     cfg = next(c for c in bench["configs"] if c["name"] == entry["config"])
     here = root / "benchmarks"
-
-    def mine(metric: dict) -> bool:
-        return name in metric.get("workloads", [name])
-
     return {
         **entry,
         **_load(here / "workloads" / f"{name}.json"),
         "model": _load(root / cfg["file"]),
-        "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
+        "end_to_end": reported_in(bench, "end_to_end", name),
         "per_layer": [
             {**m, **_load(here / "metrics" / f"{m['name']}.json")}
-            for m in bench["per_layer"] if mine(m)],
+            for m in reported_in(bench, "per_layer", name)],
     }
 
 

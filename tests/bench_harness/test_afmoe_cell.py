@@ -41,7 +41,25 @@ def test_costs_from_shapes():
         + 1000 * 4096 + 600 * 16384
 
 
+def check_benchmark(bench: dict) -> None:
+    """What this cell holds of BENCHMARK.json, each entry found by its
+    name: a later PR appends configurations, cells and metrics."""
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["config"] == "trinity-large-l5-ep8" and entry["chips"] == 1
+    config = next(c for c in bench["configs"]
+                  if c["name"] == "trinity-large-l5-ep8")
+    assert config["file"] == "benchmarks/configs/trinity-large-l5-ep8.json"
+    assert {x["name"] for x in spec.reported_in(bench, "per_layer", CELL)} \
+        >= {"expert_picks_per_tick", "experts_touched_pct",
+            "kv_window_released_pct", "tick_hbm_roofline_pct",
+            "prefill_p50_ms", "gap_p95_ms", "device_unfed_pct",
+            "fetch_after_ready_pct", "gc_pause_ms_per_s"}
+    assert {x["name"] for x in spec.reported_in(bench, "end_to_end", CELL)} \
+        == {"out_tok_per_s", "gap_p99_ms", "setup_s"}
+
+
 def test_the_cell_as_issue_26_gives_it():
+    check_benchmark(spec.benchmark())
     cell = spec.cell(CELL)
     m = cell["model"]
     assert (m["hidden_size"], m["head_dim"], m["moe_intermediate_size"],
@@ -64,12 +82,6 @@ def test_the_cell_as_issue_26_gives_it():
     cfg = serve_afmoe.model_config(m)
     assert cfg.layer_kinds == (("window", 4096),) * 4 + (("full", 0),)
     assert cfg.n_dense_layers == 1 and cfg.experts_held == (0, 32)
-    assert {x["name"] for x in cell["per_layer"]} >= {
-        "expert_picks_per_tick", "experts_touched_pct",
-        "kv_window_released_pct", "tick_hbm_roofline_pct",
-        "prefill_p50_ms", "gap_p95_ms"}
-    assert {x["name"] for x in cell["end_to_end"]} == {
-        "out_tok_per_s", "gap_p99_ms", "setup_s"}
 
 
 TICKS = [

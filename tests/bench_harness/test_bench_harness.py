@@ -90,6 +90,27 @@ def test_every_cell_rehearses_and_prints_the_contract_line(cache, name):
     assert proc.stdout.startswith("REHEARSAL")
 
 
+# the shares of a step's wall the tick records' flight counters give
+FLIGHT = ("device_unfed_pct", "fetch_after_ready_pct", "gc_pause_ms_per_s")
+
+
+@pytest.mark.parametrize("name", sorted({
+    w for m in spec.benchmark()["per_layer"] if m["name"] in FLIGHT
+    for w in m["workloads"]}))
+def test_every_serving_cells_traced_rehearsal_reads_the_flight_counters(
+        cache, name):
+    """The engine's tick records reach the readers in every cell that
+    lists the three metrics, whichever adapter drives it."""
+    out = last_line(rehearse(cache, name, 1))
+    assert out["correct"] is True and out["failed"] == 0
+    cell = spec.cell(name)
+    assert set(out["metrics"]) <= {m["name"] for m in cell["per_layer"]}
+    for metric in FLIGHT:
+        value = out["metrics"][metric]["value"]
+        assert math.isfinite(value) and value >= 0, (metric, value)
+    assert out["metrics"]["device_unfed_pct"]["value"] <= 100
+
+
 def test_serve_rehearsal_prints_the_knee_on_an_earlier_line(serve_run):
     infos = [json.loads(x)["info"] for x in serve_run.stdout.splitlines()
              if x.startswith('{"info"')]
@@ -189,13 +210,13 @@ def test_new_cell_generator_metric_and_reader_are_found_as_new_files(
 # ---------------------------------------------------------------- data files
 
 
-def test_every_name_in_benchmark_json_has_its_files():
-    b = spec.benchmark()
+def every_name_has_its_files(root: Path) -> None:
+    b = spec.benchmark(root)
     e2e = {m["name"]: m for m in b["end_to_end"]}
     cells = {w["name"] for w in b["workloads"]}
     for w in b["workloads"]:
-        cell = spec.cell(w["name"])
-        assert (ROOT / "benchmarks" / "adapters"
+        cell = spec.cell(w["name"], root=root)
+        assert (root / "benchmarks" / "adapters"
                 / f"{cell['adapter']}.py").exists()
         assert {m["name"] for m in cell["end_to_end"]} > {"setup_s"}
         assert cell["per_layer"], w["name"]
@@ -204,9 +225,69 @@ def test_every_name_in_benchmark_json_has_its_files():
             # the metric it should move is reported in this cell
             assert w["name"] in e2e[m["moves"]].get("workloads", cells)
     for c in b["configs"]:
-        held = json.loads((ROOT / c["file"]).read_text())
+        held = json.loads((root / c["file"]).read_text())
         assert held["source"] == c["source"]
         assert set(c["reduced"]) == set(held.get("published", {}))
+
+
+def test_every_name_in_benchmark_json_has_its_files():
+    every_name_has_its_files(ROOT)
+
+
+def test_an_appended_configuration_cell_and_metric_leave_every_check_whole(
+        tmp_path):
+    """What a later PR that brings a model does to BENCHMARK.json: one
+    configuration, one cell and one per-layer metric of that cell
+    appended, the cell's name appended to the end-to-end metrics it
+    reports, no entry moved or edited. Every cell test's checks of
+    BENCHMARK.json hold on the result, and every cell there reads as it
+    did."""
+    import test_afmoe_cell
+    import test_ouro_cell
+    import test_smallthinker_cell
+
+    shutil.copytree(ROOT / "benchmarks", tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    b = spec.benchmark()
+    base = next(c for c in b["configs"]
+                if c["name"] == spec.cell(SERVE)["config"])
+    here = tmp_path / "benchmarks"
+    shutil.copy(ROOT / base["file"], here / "configs" / "appended.json")
+    b["configs"].append({**base, "name": "appended",
+                         "file": "benchmarks/configs/appended.json"})
+    b["workloads"].append({"name": "appended.chat", "config": "appended",
+                           "traffic": "chat", "chips": 1, "why": "test"})
+    shutil.copy(here / "workloads" / f"{SERVE}.json",
+                here / "workloads" / "appended.chat.json")
+    for m in b["end_to_end"]:
+        if SERVE in m.get("workloads", []):
+            m["workloads"].append("appended.chat")
+    b["per_layer"].append({
+        "name": "appended_ratio", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "serve engine host loop",
+        "moves": "out_tok_per_s", "workloads": ["appended.chat"]})
+    (here / "metrics" / "appended_ratio.json").write_text(json.dumps(
+        {"reader": "counter_ratio",
+         "args": {"counter": "gc_us", "over": "step_us", "scale": 100}}))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+
+    for module in (test_afmoe_cell, test_ouro_cell, test_smallthinker_cell):
+        module.check_benchmark(spec.benchmark(tmp_path))
+    every_name_has_its_files(tmp_path)
+
+    def as_reported(cell):
+        # an end-to-end entry's list of cells has grown by the new one
+        return {**cell, "end_to_end": [
+            {k: v for k, v in m.items() if k != "workloads"}
+            for m in cell["end_to_end"]]}
+
+    for w in spec.benchmark()["workloads"]:
+        assert as_reported(spec.cell(w["name"], root=tmp_path)) == \
+            as_reported(spec.cell(w["name"]))
+    added = spec.cell("appended.chat", root=tmp_path)
+    assert added["model"] == spec.cell(SERVE)["model"]
+    # the existing metrics list their cells: the new one has its own
+    assert [m["name"] for m in added["per_layer"]] == ["appended_ratio"]
 
 
 def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error():

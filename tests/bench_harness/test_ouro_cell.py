@@ -56,6 +56,29 @@ def test_costs_from_shapes():
         == 3 * s["step_bytes"] + 2900 * 3 * 48 * 8192
 
 
+def check_benchmark(bench: dict) -> None:
+    """What this cell holds of BENCHMARK.json, each entry found by its
+    name: a later PR appends configurations, cells and metrics."""
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["config"] == "ouro-2.6b" and entry["chips"] == 1
+    config = next(c for c in bench["configs"] if c["name"] == "ouro-2.6b")
+    assert config["file"] == "benchmarks/configs/ouro-2.6b.json"
+    assert config["reduced"] == ["max_position_embeddings"]
+    assert {x["name"] for x in spec.reported_in(bench, "per_layer", CELL)} \
+        == {"tick_host_ms", "batch_occupancy_pct", "kv_fill_pct",
+            "decode_tick_ms", "prefill_p50_ms", "gap_p95_ms", "gap_p50_ms",
+            "tick_hbm_roofline_pct", "device_idle_pct.serve",
+            "tick_ms_per_layer_pass", "device_unfed_pct",
+            "fetch_after_ready_pct", "gc_pause_ms_per_s"}
+    assert {x["name"] for x in spec.reported_in(bench, "end_to_end", CELL)} \
+        == {"out_tok_per_s", "gap_p99_ms", "setup_s"}
+    assert next(x for x in bench["per_layer"]
+                if x["name"] == "tick_ms_per_layer_pass") == {
+        "name": "tick_ms_per_layer_pass", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "serve model step",
+        "moves": "out_tok_per_s", "workloads": [CELL]}
+
+
 def test_the_configuration_is_the_catalogs_row():
     m = spec.cell(CELL)["model"]
     if CATALOG.exists():
@@ -66,7 +89,6 @@ def test_the_configuration_is_the_catalogs_row():
         assert changed == {"max_position_embeddings"}
     entry = next(c for c in spec.benchmark()["configs"]
                  if c["name"] == "ouro-2.6b")
-    assert entry["reduced"] == ["max_position_embeddings"]
     assert entry["source"] == m["source"]
     assert (m["hidden_size"], m["num_attention_heads"],
             m["num_key_value_heads"], m["head_dim"], m["intermediate_size"],
@@ -82,9 +104,7 @@ def test_the_configuration_is_the_catalogs_row():
 
 
 def test_the_cell_as_issue_34_gives_it():
-    bench = spec.benchmark()
-    assert next(w for w in bench["workloads"]
-                if w["name"] == CELL)["chips"] == 1
+    check_benchmark(spec.benchmark())
     cell = spec.cell(CELL)
     assert cell["generator"] == "closed_loop"
     assert cell["adapter"] == "serve_ouro"
@@ -107,21 +127,6 @@ def test_the_cell_as_issue_34_gives_it():
     # blocks x 64 KiB stays under 2 GiB
     assert (cfg.pool_layers, cfg.cache_segments) == (16, 64)
     assert 64 * 385 * 65536 < 2 ** 31
-    assert {x["name"] for x in cell["per_layer"]} == {
-        "tick_host_ms", "batch_occupancy_pct", "kv_fill_pct",
-        "decode_tick_ms", "prefill_p50_ms", "gap_p95_ms", "gap_p50_ms",
-        "tick_hbm_roofline_pct", "device_idle_pct.serve",
-        "tick_ms_per_layer_pass"}
-    assert {x["name"] for x in cell["end_to_end"]} == {
-        "out_tok_per_s", "gap_p99_ms", "setup_s"}
-    new = next(x for x in bench["per_layer"]
-               if x["name"] == "tick_ms_per_layer_pass")
-    assert new == {
-        "name": "tick_ms_per_layer_pass", "unit": "ms", "better": "lower",
-        "source": "program_span", "layer": "serve model step",
-        "moves": "out_tok_per_s", "workloads": [CELL]}
-    assert bench["per_layer"][-1] == new and bench["workloads"][-1][
-        "name"] == CELL and bench["configs"][-1]["name"] == "ouro-2.6b"
 
 
 def test_the_traffic_is_what_the_issue_says_of_it():

@@ -53,14 +53,30 @@ def test_costs_from_shapes():
         + 1000 * 4096 + 600 * 12288
 
 
+def check_benchmark(bench: dict) -> None:
+    """What this cell holds of BENCHMARK.json, each entry found by its
+    name: a later PR appends configurations, cells and metrics."""
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["config"] == "smallthinker-21b-l8" and entry["chips"] == 1
+    config = next(c for c in bench["configs"]
+                  if c["name"] == "smallthinker-21b-l8")
+    assert config["file"] == "benchmarks/configs/smallthinker-21b-l8.json"
+    assert config["reduced"] == ["num_hidden_layers",
+                                 "max_position_embeddings"]
+    assert {x["name"] for x in spec.reported_in(bench, "per_layer", CELL)} \
+        == {"tick_host_ms", "batch_occupancy_pct", "kv_fill_pct",
+            "decode_tick_ms", "prefill_p50_ms", "gap_p95_ms", "gap_p50_ms",
+            "tick_hbm_roofline_pct", "device_idle_pct.serve",
+            "expert_picks_per_tick", "experts_touched_pct",
+            "kv_window_released_pct", "expert_tokens_per_touched",
+            "device_unfed_pct", "fetch_after_ready_pct",
+            "gc_pause_ms_per_s"}
+    assert {x["name"] for x in spec.reported_in(bench, "end_to_end", CELL)} \
+        == {"out_tok_per_s", "gap_p99_ms", "setup_s"}
+
+
 def test_the_cell_as_issue_31_gives_it():
-    bench = spec.benchmark()
-    entry = next(c for c in bench["configs"]
-                 if c["name"] == "smallthinker-21b-l8")
-    assert entry["reduced"] == ["num_hidden_layers",
-                                "max_position_embeddings"]
-    assert next(w for w in bench["workloads"]
-                if w["name"] == CELL)["chips"] == 1
+    check_benchmark(spec.benchmark())
     cell = spec.cell(CELL)
     m = cell["model"]
     assert (m["hidden_size"], m["num_attention_heads"],
@@ -98,14 +114,6 @@ def test_the_cell_as_issue_31_gives_it():
     assert cfg.rope_layout == (0, 1, 1, 1) * 2
     assert (cfg.n_experts, cfg.top_k) == (64, 6)
     assert (cfg.n_heads, cfg.n_kv_heads, cfg.max_len) == (28, 4, 8192)
-    assert {x["name"] for x in cell["per_layer"]} == {
-        "tick_host_ms", "batch_occupancy_pct", "kv_fill_pct",
-        "decode_tick_ms", "prefill_p50_ms", "gap_p95_ms", "gap_p50_ms",
-        "tick_hbm_roofline_pct", "device_idle_pct.serve",
-        "expert_picks_per_tick", "experts_touched_pct",
-        "kv_window_released_pct", "expert_tokens_per_touched"}
-    assert {x["name"] for x in cell["end_to_end"]} == {
-        "out_tok_per_s", "gap_p99_ms", "setup_s"}
 
 
 def test_the_traffic_is_what_the_issue_says_of_it():
