@@ -749,7 +749,7 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None,
 
 
 def init_paged_cache(cfg, num_blocks, block_size: int,
-                     dtype=None) -> list[dict]:
+                     dtype=None, slots: int = 0) -> list[dict]:
     """Per-layer pooled KV cache for block-table decoding: physical
     block 0 is the null block (serve/blocks.py routes masked writes
     there), blocks 1..num_blocks-1 are allocatable. Logical positions
@@ -767,11 +767,18 @@ def init_paged_cache(cfg, num_blocks, block_size: int,
     are `[cfg.cache_segments * n, ...]` (`cache_steps x pool_layers`
     segments): each segment (blocks `s * n ..`, the first of them the
     segment's null block) holds one layer's keys and values of one
-    step, all behind the one table (`segment_shift`)."""
+    step, all behind the one table (`segment_shift`).
+
+    A layer of the `recurrent` kind keeps no blocks: one row a slot
+    (`slots` of them) of each array the config declares by name in
+    `cfg.recurrent_state` (shape, dtype)."""
     dtype = dtype or cfg.compute_dtype
     segments = getattr(cfg, "cache_segments", 1)
 
     def pool(kind):
+        if kind == "recurrent":
+            return {name: jnp.zeros((slots, *shape), dt)
+                    for name, (shape, dt) in cfg.recurrent_state.items()}
         n = num_blocks[kind] if isinstance(num_blocks, dict) else num_blocks
         shape = (segments * n, cfg.n_kv_heads, block_size, cfg.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -788,13 +795,24 @@ def paged_cache_block_bytes(cfg, block_size: int, dtype=None,
     `kind`, across the layers of that kind: a block of that kind's
     pool. A block id names `block_size` positions in every one of the
     model's `cache_steps` segments, so it costs that many times a
-    layer's bytes."""
+    layer's bytes. A `recurrent` layer holds no block
+    (`recurrent_state_bytes`)."""
     dtype = jnp.dtype(dtype or cfg.compute_dtype)
-    n_layers = cfg.n_layers if kind is None else sum(
-        k == kind for k, _ in cfg.layer_kinds)
+    n_layers = cfg.n_layers - sum(
+        k == "recurrent" for k, _ in cfg.layer_kinds) if kind is None \
+        else sum(k == kind for k, _ in cfg.layer_kinds)
     n_layers *= getattr(cfg, "cache_steps", 1)
     return (2 * n_layers * block_size * cfg.n_kv_heads
             * cfg.head_dim * dtype.itemsize)
+
+
+def recurrent_state_bytes(cfg) -> int:
+    """HBM bytes one slot's state rows cost across the `recurrent`
+    layers (`cfg.recurrent_state`); 0 for a model without them."""
+    per_layer = sum(
+        int(np.prod(shape)) * jnp.dtype(dt).itemsize
+        for shape, dt in getattr(cfg, "recurrent_state", {}).values())
+    return per_layer * sum(k == "recurrent" for k, _ in cfg.layer_kinds)
 
 
 class Llama(nn.Module):

@@ -27,7 +27,11 @@ once, at warmup, forever:
     manager and one block table per kind. A `full` layer keeps every
     position of a request; a `window` layer keeps the positions its
     queries can still see, its leading blocks going back to the free
-    list as the request moves on (`_slide_windows`).
+    list as the request moves on (`_slide_windows`). A `recurrent`
+    layer keeps no blocks: one state row a slot of the shapes the model
+    declares (`cfg.recurrent_state`), beside the pools in the cache the
+    programs donate, advanced only where a window's positions are real
+    (SERVING.md "The recurrent layer kind").
   * Every per-request quantity the tick needs — cache depth, eos
     latch, remaining budget, temperature/top_k/top_p, PRNG key — is a
     `[S]` device array threaded through the jitted call, so slot
@@ -149,11 +153,15 @@ def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
     # null block and emit pad.
     act = st["active"] & live
     # `tick_stats`: what a model sows for the tick record (an expert
-    # model its rows' picks, models/afmoe.py); nothing for most models
+    # model its rows' picks, models/afmoe.py; a model with recurrent
+    # layers the rows whose state the tick advanced); nothing for most
     (logits, cache), sown = model.apply(
         variables, st["last_token"][:, None],
         cache=cache, cache_index=st["lengths"], block_tables=bt,
         mutable=["tick_stats"],
+        # a recurrent layer advances the state of the rows the tick
+        # advances, and no other
+        **_state_len(model, act.astype(jnp.int32)),
     )
     with jax.named_scope("sampling"):
         keys = jax.vmap(jax.random.fold_in)(st["keys"], st["lengths"])
@@ -176,8 +184,36 @@ def _tick_impl(model, eos_id, pad_id, variables, cache, st, bt, live):
             "lengths": lengths,
             "active": act & ~finished,
         }
-        counted = _expert_counters(sown, act)
+        counted = {**_expert_counters(sown, act), **_state_counters(sown)}
     return cache, st, nxt, finished, counted
+
+
+def _holds_state(model) -> bool:
+    """Whether the model has layers of the `recurrent` kind: a state row
+    a slot (`cfg.recurrent_state`) where other layers hold blocks."""
+    return any(kind == "recurrent" for kind, _ in model.cfg.layer_kinds)
+
+
+def _state_len(model, lens) -> dict:
+    """What a call tells a model with recurrent layers besides the
+    cache: `state_len` [B], how many leading positions of each window
+    row advance its state. Nothing for every other model: its programs
+    are what they were."""
+    return {"state_len": lens} if _holds_state(model) else {}
+
+
+def _sown(sown, name: str) -> list:
+    """The leaves a model sowed under `name`, in the tree's order."""
+    return [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+        sown)[0] if any(getattr(k, "key", None) == name for k in path)]
+
+
+def _state_counters(sown) -> dict:
+    """The tick record's `recurrent_rows` from what the recurrent layers
+    sowed (`state_rows`: rows whose state the tick advanced, a layer),
+    summed over the layers. `{}` for a model that sows none."""
+    rows = _sown(sown, "state_rows")
+    return {"recurrent_rows": sum(rows)} if rows else {}
 
 
 def _expert_counters(sown, act) -> dict:
@@ -187,7 +223,7 @@ def _expert_counters(sown, act) -> dict:
     held experts, held experts with at least one token (both summed
     over layers), and the busiest expert's tokens. `{}` for a model
     that sows nothing: its program is what it was."""
-    loads = jax.tree.leaves(sown)
+    loads = _sown(sown, "expert_load")
     if not loads:
         return {}
     per_expert = jnp.sum(
@@ -285,6 +321,9 @@ def _prefill_impl(model, eos_id, variables, cache, st, prompt, bt_row,
     logits, cache = model.apply(
         variables, prompt, cache=cache, cache_index=start,
         block_tables=jax.tree.map(lambda row: row[None], bt_row),
+        # a recurrent layer stops at the prompt's true length: padding
+        # would enter its state
+        **_state_len(model, true_len[None]),
     )
     with jax.named_scope("sampling"):
         last = jax.lax.dynamic_slice_in_dim(
@@ -337,9 +376,10 @@ def _copy_impl(cache, src, dst):
     # onward. src/dst are [C] vectors so one executable serves every
     # fork.
     with jax.named_scope("kv_copy"):
+        # a recurrent layer's state rows are no blocks
         return [
             {kv: layer[kv].at[dst].set(layer[kv][src])
-             for kv in ("k", "v")}
+             for kv in ("k", "v")} if "k" in layer else layer
             for layer in cache
         ]
 
@@ -484,6 +524,7 @@ class Engine:
         from hyperion_tpu.models.llama import (
             init_paged_cache,
             paged_cache_block_bytes,
+            recurrent_state_bytes,
             select_paged_attn_impl,
         )
         from hyperion_tpu.ops.attention import window_view_blocks
@@ -535,7 +576,32 @@ class Engine:
         # (`full` where the model has one): the chain that carries a
         # slot's order and write frontier
         self._kinds: dict[str, int] = dict(sorted(
-            mcfg.layer_kinds, key=lambda kw: kw[0] != "full"))
+            ((k, w) for k, w in mcfg.layer_kinds if k != "recurrent"),
+            key=lambda kw: kw[0] != "full"))
+        # a `recurrent` layer keeps no blocks: one state row a slot, of
+        # the shapes the model declares (`cfg.recurrent_state`), beside
+        # the pools in the cache the programs donate and return
+        self._state_layers = sum(
+            k == "recurrent" for k, _ in mcfg.layer_kinds)
+        if self._state_layers:
+            # no block holds a recurrent state, so nothing that counts on
+            # finding a prefix's blocks again can restore one: refuse
+            # loudly, nothing silently off
+            for on, what, why in (
+                (cfg.prefix_cache, "the radix prefix cache (prefix_cache)",
+                 "a prefix hit would skip positions whose state no block "
+                 "holds"),
+                (cfg.host_cache_mb > 0, "the host spill tier "
+                 "(host_cache_mb)", "it restores prefix chains, and needs "
+                 "the prefix cache"),
+                (self._spec, "speculative decoding (spec_k, draft)",
+                 "a rejected draft would already have advanced the state"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{type(model).__name__} has {self._state_layers} "
+                        f"recurrent layers: the engine cannot combine them "
+                        f"with {what} yet, because {why}. Turn it off.")
         windowed = {k: w for k, w in self._kinds.items() if w}
         self._windowed = bool(windowed)
         if windowed:
@@ -684,8 +750,11 @@ class Engine:
             k: paged_cache_block_bytes(mcfg, bs, kind=k)
             for k in self._kinds}
         self._block_bytes = self._kind_block_bytes[first_kind]
+        # bytes of one slot's state rows over the recurrent layers
+        self._slot_state_bytes = recurrent_state_bytes(mcfg)
         self._cache = init_paged_cache(
-            mcfg, pool_blocks if windowed else num_blocks, bs)
+            mcfg, pool_blocks if windowed else num_blocks, bs,
+            slots=cfg.slots)
         self._state = self._init_state()
         self._tick_no = 0
         # cumulative transport-sink seconds (all requests); per-request
@@ -857,8 +926,9 @@ class Engine:
                 self._cache = self._chunk_jit(
                     self.model, self.variables, self._cache,
                     jnp.full((1, C), self.cfg.pad_id, jnp.int32),
-                    {k: jnp.zeros((self._mb,), jnp.int32)
-                     for k in self._kinds}, jnp.int32(0))
+                    self._rows_on_device(0, rows={
+                        k: np.zeros((self._mb,), np.int32)
+                        for k in self._kinds}), jnp.int32(0))
                 compile_s["chunk"] = round(time.perf_counter() - t0, 4)
             zero = self._in_every_segment([0])
             t0 = time.perf_counter()
@@ -1019,11 +1089,16 @@ class Engine:
                         rows: dict | None = None) -> dict:
         """The block tables by kind as the jits take them: every slot's
         (the tick), one slot's rows (its prefill), or rows held aside
-        (a chunking slot's)."""
+        (a chunking slot's, `slot` naming its state row)."""
         if rows is None:
             rows = self._bts if slot is None else \
                 {k: t[slot] for k, t in self._bts.items()}
-        return {k: jnp.asarray(t) for k, t in rows.items()}
+        out = {k: jnp.asarray(t) for k, t in rows.items()}
+        if self._state_layers and slot is not None:
+            # a recurrent layer's "row" is the slot's state row; the tick
+            # reads every slot's in order and is handed none
+            out["recurrent"] = jnp.asarray(slot, jnp.int32)
+        return out
 
     def _tables_on_device(self) -> tuple:
         if self._bt_dev is None:
@@ -1520,7 +1595,7 @@ class Engine:
             with prof.seg("chunk/upload", **at):
                 args = (jnp.asarray(np.asarray(prompt[pos:pos + C],
                                                np.int32)[None, :]),
-                        self._rows_on_device(rows=ck["rows"]),
+                        self._rows_on_device(slot, rows=ck["rows"]),
                         jnp.int32(pos))
             with prof.seg("chunk/dispatch", **at):
                 self._cache = self._chunk_jit(
@@ -2044,7 +2119,8 @@ class Engine:
 
     def memory_ledger(self) -> dict:
         """Live memory accounting from known shapes — param bytes, the
-        KV pool's full and in-use footprint, host RSS. Pure host
+        KV pool's full and in-use footprint by block, a recurrent
+        model's state rows by slot, host RSS. Pure host
         arithmetic (`.nbytes` is metadata, `_block_bytes` a cached
         int), so any thread may ask."""
         # by layer kind: a kind's block costs its own layers' bytes
@@ -2059,8 +2135,16 @@ class Engine:
         # in place, so the copy is zero.
         gather = 0 if self._tick_read == "pallas" else int(self.cfg.slots * sum(
             self._view[k] * bb for k, bb in self._kind_block_bytes.items()))
+        # a model with recurrent layers: its state rows, by slot (what
+        # every slot holds, and what the occupied slots hold)
+        state = {} if not self._state_layers else {"state": {
+            "slot_bytes": self._slot_state_bytes,
+            "pool_bytes": self.cfg.slots * self._slot_state_bytes,
+            "in_use_bytes": self._slot_state_bytes * sum(
+                r is not None for r in self._slots)}}
         return {
             "param_bytes": self._param_bytes,
+            **state,
             "kv_pool_bytes": sum(v["pool_bytes"] for v in by_kind.values()),
             "blocks_in_use_bytes": sum(
                 v["in_use_bytes"] for v in by_kind.values()),
