@@ -34,7 +34,9 @@ the decays apply as differences of cumulative log-decays, every factor
 at most 1, so nothing overflows in float32 however strong the decay;
 the state passes from chunk to chunk in float32. A one-position window
 (the decode tick) runs the STEP form (`delta_rule_step`): read S, decay
-it, update it, read the output off it.
+it, update it, read the output off it. On a TPU the tick's step is the
+kernel `ops/pallas/state_update.py` over the rows it advances alone,
+in place (`select_state_update_impl`).
 
 **Serving.** The call surface is the engine's: `apply(variables, ids,
 cache=, cache_index=, block_tables=, state_len=)`. A `full` layer's
@@ -145,6 +147,15 @@ class SolarOpen2Config:
         return {"S": ((H, K, K), "float32"),
                 "conv": ((self.conv_size - 1, 3 * H * K), self.dtype)}
 
+    def tick_state_update(self) -> str:
+        """The form ("kernel" or "dense") of the decode tick's state
+        update, resolved from the tick's shape and the process's
+        backend: what each KDA layer asks at trace time, and the engine
+        asks for the tick record's `state_rows_visited`."""
+        K = self.kda_head_dim
+        return select_state_update_impl(1, True, K, K,
+                                        jax.default_backend())
+
     @property
     def expert_step(self) -> dict:
         """The static shape of an expert layer's `grouped_experts` call
@@ -215,6 +226,30 @@ def positions(q, k, base):
 
 
 # ------------------------------------------------------------ the recurrence
+
+# The state-update kernel's block is `[heads, K, V]` float32: K on the
+# sublanes and V on the lanes, both in whole lane tiles
+STATE_KERNEL_WIDTH_GRAIN = 128
+
+
+def select_state_update_impl(window: int, slot_rows: bool, k: int, v: int,
+                             backend: str) -> str:
+    """"kernel" or "dense" for one call's state update, from its static
+    shape (`window` positions a row; `slot_rows`: the window's rows are
+    the cache's state rows in order, a cache and no `rows`; keys of `k`
+    and values of `v` channels) and the backend. Resolved at trace
+    time, as `ops.moe.select_grouped_impl` is: jit sees one branch. The
+    kernel (`ops/pallas/state_update.py`) takes the decode tick's form
+    on a TPU at widths in whole lane tiles: it updates the rows the
+    tick advances in place and never touches the others. Everything
+    else keeps `delta_rule_step` and its select, or the chunked scan:
+    off a TPU the kernel would run through the interpreter, which is
+    an oracle and not a path."""
+    if backend != "tpu" or window != 1 or not slot_rows:
+        return "dense"
+    whole = k % STATE_KERNEL_WIDTH_GRAIN == 0 \
+        and v % STATE_KERNEL_WIDTH_GRAIN == 0
+    return "kernel" if whole else "dense"
 
 
 def delta_rule_step(q, k, v, g, beta, S):
@@ -315,6 +350,9 @@ class KimiDeltaAttention(nn.Module):
         # the state's reads and writes are the scan's (a prompt's window)
         # or the step's (the tick)
         state_scope = "step" if T == 1 else "scan"
+        impl = select_state_update_impl(
+            T, cache is not None and rows is None, K, K,
+            jax.default_backend())
         with jax.named_scope(state_scope):
             if cache is None:
                 S0 = jnp.zeros((B, H, K, K), jnp.float32)
@@ -362,16 +400,31 @@ class KimiDeltaAttention(nn.Module):
             beta = jnp.where(valid[..., None], beta, 0.0)
         new_cache = None
         with jax.named_scope(state_scope):
-            if T == 1:
+            if impl == "kernel":
+                # the rows the tick advances, each read and written once
+                # in place; the others' state is never touched, and
+                # their output holds no number: 0 is selected there
+                from hyperion_tpu.ops.pallas.state_update import (
+                    delta_rule_step_rows,
+                )
+
+                adv = valid.any(axis=1)
+                o, S1 = delta_rule_step_rows(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S_in,
+                    adv)
+                o = jnp.where(adv[:, None, None], o, 0.0)[:, None]
+            elif T == 1:
                 o, S1 = delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                         beta[:, 0], S_in)
                 o = o[:, None]
             else:
                 o, S1 = chunked_delta_rule(q, k, v, g, beta, S_in)
             if cache is not None:
-                # a row that advanced nothing keeps its state bit for bit
-                adv = valid.any(axis=1)
-                S1 = jnp.where(adv[:, None, None, None], S1, S0)
+                if impl == "dense":
+                    # a row that advanced nothing keeps its state bit
+                    # for bit
+                    adv = valid.any(axis=1)
+                    S1 = jnp.where(adv[:, None, None, None], S1, S0)
                 tail = jnp.where(adv[:, None, None],
                                  tail.astype(tail0.dtype), tail0)
                 if rows is None:

@@ -48,6 +48,7 @@ from hyperion_tpu.obs.tickprof import (
     EXPERT_ROW_COUNTERS,
     FLIGHT_NAME,
     PROMPT_READ_COUNTERS,
+    STATE_ROW_COUNTERS,
     WALK_COUNTERS,
     WRITE_COUNTERS,
     flight_final_tick,
@@ -1260,6 +1261,13 @@ def render_markdown(d: dict) -> str:
             counted += (f"; prompt windows: {_fmt(tiled)} position(s) "
                         f"through the tiled kernel, {_fmt(gathered)} "
                         f"through the gather's softmax")
+        # a model with recurrent layers: the state rows its ticks read
+        # and wrote, of those the state holds, and so the form
+        visited, held = (c.get(k) for k in STATE_ROW_COUNTERS)
+        if held:
+            form = "kernel" if visited < held else "every row"
+            counted += (f"; state update: {_fmt(visited)} of {_fmt(held)} "
+                        f"rows visited ({form})")
         # an expert model: what its ticks sent to the experts held here
         ex = tp.get("experts") or {}
         if ex:

@@ -30,7 +30,9 @@ module is the one way to time it:
     row by row), and which form of the grouped products an expert
     model's steps sent their rows through (`expert_rows_kernel`,
     `expert_rows_ragged`), and which read the steps' prompt windows
-    took (`prompt_positions_tiled`, `prompt_positions_gather`). The
+    took (`prompt_positions_tiled`, `prompt_positions_gather`), and
+    the state rows a recurrent model's ticks updated, of those it holds
+    (`state_rows_visited`, `state_rows_held`). The
     profiler's own flight account
     (FLIGHT_COUNTERS: how much of each step a program was out, what
     second fetches and the collector cost it) is in every record's `c`
@@ -115,6 +117,11 @@ EXPERT_ROW_COUNTERS = ("expert_rows_kernel", "expert_rows_ragged")
 # through the tiled kernel and through the gather's one-shot softmax, as
 # `models.llama.select_paged_attn_impl` chose for each window's width
 PROMPT_READ_COUNTERS = ("prompt_positions_tiled", "prompt_positions_gather")
+# a model with recurrent layers (`Engine._count_state`): the state rows
+# (rows x recurrent layers) a step's decode tick read and wrote, as
+# `models.solar_open2.select_state_update_impl` chose its form, and
+# those the state holds (slots x recurrent layers)
+STATE_ROW_COUNTERS = ("state_rows_visited", "state_rows_held")
 
 
 def _role(key: str) -> str | None:
@@ -362,12 +369,14 @@ class TickProfiler:
             # walked instead (0: the ticks gathered), by layer kind
             # and how the window's steps wrote it: by block, by row;
             # an expert model: its steps' rows by the products' form;
-            # the prompt windows' positions by the read they took
+            # the prompt windows' positions by the read they took;
+            # a recurrent model: the state rows its ticks visited
             for key in sorted({k for r in recs for k in r.get("c", {})
                                if k.startswith(
                                    WALK_COUNTERS + WRITE_COUNTERS
                                    + EXPERT_ROW_COUNTERS
-                                   + PROMPT_READ_COUNTERS)}):
+                                   + PROMPT_READ_COUNTERS
+                                   + STATE_ROW_COUNTERS)}):
                 out["counters"][key] = sum(
                     r.get("c", {}).get(key, 0) for r in recs)
         if recs:

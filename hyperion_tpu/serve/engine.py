@@ -803,6 +803,14 @@ class Engine:
         self._no_expert_rows = {} if self._expert_step is None \
             else dict.fromkeys(EXPERT_ROW_COUNTERS, 0)
         self._expert_rows = dict(self._no_expert_rows)
+        # a model with recurrent layers: the form its decode tick's
+        # state update takes ("kernel": the advanced rows alone;
+        # "dense": every slot's), as the layers ask it at trace time,
+        # and the state rows it holds, slots x recurrent layers
+        # (`_count_state`)
+        self._state_form = mcfg.tick_state_update() \
+            if self._state_layers else None
+        self._state_rows_held = cfg.slots * self._state_layers
         # the positions of this step's prefills and chunk, bucket
         # padding and all, by the read their windows took
         # (`_count_prompt`)
@@ -1048,6 +1056,21 @@ class Engine:
             rows, e["groups"], e["k"], e["n"], jax.default_backend(),
             e["itemsize"])
         self._expert_rows[f"expert_rows_{form}"] += e["layers"] * rows
+
+    def _count_state(self) -> dict[str, int]:
+        """The tick record's `state_rows_visited` and `state_rows_held`:
+        the state rows (rows x recurrent layers) the step's decode tick
+        read and wrote, and those the state holds. The kernel visits
+        the rows the tick advanced (`recurrent_rows`, fetched with its
+        tokens), the dense form every slot's. Host arithmetic; nothing
+        for a model without recurrent layers or a step without a tick."""
+        advanced = self._tick_counted.get("recurrent_rows")
+        if advanced is None:
+            return {}
+        held = self._state_rows_held
+        return {"state_rows_visited":
+                advanced if self._state_form == "kernel" else held,
+                "state_rows_held": held}
 
     def _count_prompt(self, positions: int) -> None:
         """Add a prefill's or a chunk's window of `positions` to the
@@ -2250,6 +2273,9 @@ class Engine:
                        for k, w in self._kinds.items() if w},
                     # an expert model's picks on this step's decode tick
                     **self._tick_counted,
+                    # a model with recurrent layers: the state rows its
+                    # decode tick read and wrote, of those it holds
+                    **self._count_state(),
                     # a looped model's decode tick: the steps it ran a row
                     # and the cache layers it wrote and read (steps x layers)
                     **self._loop_counted,

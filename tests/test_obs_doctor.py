@@ -904,6 +904,50 @@ class TestExpertRowsByForm:
             assert "grouped kernel" not in row
 
 
+class TestStateRowsVisited:
+    """A recurrent model's tick records carry `state_rows_visited` /
+    `state_rows_held` (serve/engine.py `_count_state`): flows the
+    snapshot sums over the window's steps, and a sentence of doctor's
+    `host tick profile` row that names the update's form."""
+
+    @pytest.mark.parametrize("steps, summed, sentence", [
+        # 128 slots x 3 recurrent layers; the kernel visits the rows the
+        # ticks advanced, 54 and 54 live rows a layer
+        ([{"state_rows_visited": 162, "state_rows_held": 384}] * 2,
+         {"state_rows_visited": 324, "state_rows_held": 768},
+         "state update: 324 of 768 rows visited (kernel)"),
+        # the dense form (off a TPU) reads and writes every slot's row
+        ([{"state_rows_visited": 9, "state_rows_held": 9}] * 3,
+         {"state_rows_visited": 27, "state_rows_held": 27},
+         "state update: 27 of 27 rows visited (every row)"),
+        # a model without recurrent layers: nothing is said
+        ([{}], {}, None),
+    ], ids=["kernel", "every_row", "no_recurrent_layers"])
+    def test_rolled_up_and_said_in_words(self, tmp_path, steps, summed,
+                                         sentence):
+        from hyperion_tpu.obs.tickprof import TickProfiler
+
+        clk = VirtualClock()
+        tp = TickProfiler(wall=clk)
+        for n, c in enumerate(steps):
+            tp.record(n, {"device": 0.020}, 0.021,
+                      {"kv_tokens": 100, "prefill_tokens": 0, **c})
+            clk.advance(0.021)
+        snap = tp.snapshot(now=clk.t)
+        assert {k: v for k, v in snap["counters"].items()
+                if k.startswith("state_rows")} == summed
+        (tmp_path / "telemetry.jsonl").write_text(json.dumps(
+            {"kind": "snapshot", "run": "r", "t": 1.0, "metrics": {},
+             "tickprof": snap}) + "\n")
+        row = next(ln for ln in doctor.render_markdown(
+                       doctor.diagnose(tmp_path)).splitlines()
+                   if ln.startswith("| host tick profile"))
+        if sentence:
+            assert sentence in row
+        else:
+            assert "state update" not in row
+
+
 class TestPromptPositionsByRead:
     """Every tick record carries `prompt_positions_tiled` /
     `prompt_positions_gather` (serve/engine.py `_count_prompt`): flows

@@ -23,6 +23,7 @@ from hyperion_tpu.ops.pallas import (
     fused_norm,
     grouped_matmul,
     paged_attention,
+    state_update,
     window_attention,
 )
 from hyperion_tpu.ops.pallas.backend import LANES, SUBLANES, cost
@@ -260,3 +261,30 @@ def test_grouped_matmul_counts_rows_once_and_every_matrix_once(
         ((Mp, K), BF16), ((G, K, N), BF16), ((Mp, N), BF16),
         ((G + 1,), jnp.int32), *[((visits,), jnp.int32)] * 2,
         ((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("B, H, K, V", [
+    (8, 4, 128, 128),
+    (8, 64, 128, 128),
+    (3, 2, 8, 16),
+])
+def test_state_update_counts_every_row_once(B, H, K, V):
+    q = jnp.zeros((B, H, K), F32)
+    v = jnp.zeros((B, H, V), F32)
+    beta = jnp.zeros((B, H), F32)
+    S = jnp.zeros((B, H, K, V), F32)
+    (est,) = _estimates(
+        lambda q, v, beta, S: state_update.delta_rule_step_rows(
+            q, q, v, q, beta, S, jnp.ones((B,), bool)), q, v, beta, S)
+    # per element of S: the decay, a multiply-add for each of the two
+    # sums and one for the update; per (row, head): `q.k` (K
+    # multiply-adds), `u` (V subtractions and products) and `o` (V
+    # products and sums); one exponential a key channel
+    assert est.flops == B * H * (7 * K * V + 2 * K + 4 * V)
+    assert est.transcendentals == B * H * K
+    # the static worst case, every row advanced: the visit list and its
+    # count, q, k, v, g, beta once, S read once and written once, o
+    assert est.bytes_accessed == _nbytes(
+        ((B,), jnp.int32), ((1,), jnp.int32), *[((B, H, K), F32)] * 3,
+        ((B, H, V), F32), ((B, H, 1), F32), *[((B, H, K, V), F32)] * 2,
+        ((B, H, V), F32))

@@ -8,6 +8,7 @@ shape, so they share its compiled programs."""
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 
 import jax
@@ -17,6 +18,7 @@ import pytest
 
 from benchmarks.reference import afmoe as afmoe_reference
 from benchmarks.reference import solar_open2 as reference
+from hyperion_tpu.models import solar_open2
 from hyperion_tpu.models.afmoe import AfmoeMoE
 from hyperion_tpu.models.llama import (
     init_paged_cache,
@@ -460,3 +462,202 @@ def test_scopes_of_the_programs_name_the_recurrent_parts(tiny, engine):
             k: np.zeros((eng._mb,), np.int32) for k in eng._kinds}),
         jnp.int32(0)).compile().as_text())
     assert any("layer_*/recurrent/scan" in s for s in chunk)
+
+
+# ------------------------------------------------ the tick's state kernel
+
+
+@contextlib.contextmanager
+def state_kernel_forced():
+    """`select_state_update_impl` answering as on a TPU at widths in
+    whole lane tiles: the tick's form takes the kernel (through the
+    interpreter here), every other form keeps its own. No option of the
+    model or the engine is involved: there is none."""
+    select = solar_open2.select_state_update_impl
+
+    def forced(window, slot_rows, k, v, backend):
+        return select(window, slot_rows, 128, 128, "tpu")
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(solar_open2, "select_state_update_impl", forced)
+        yield
+
+
+def twin_of(cfg, name):
+    """The model under another identity: the engine's jits are shared
+    process-wide and keyed by the model, so the twin holds traces of
+    its own."""
+    return type(f"SolarOpen2_{name}", (SolarOpen2,), {})(cfg)
+
+
+def test_the_selector_keeps_every_other_form_dense():
+    """Only the tick's form (one position, the cache's rows in order) on
+    a TPU at widths in whole lane tiles takes the kernel."""
+    select = solar_open2.select_state_update_impl
+    assert select(1, True, 128, 128, "tpu") == "kernel"
+    for window, slot_rows, k, v, backend in [
+            (512, False, 128, 128, "tpu"),  # a chunk, a prefill bucket
+            (64, False, 128, 128, "tpu"),
+            (1, False, 128, 128, "tpu"),    # a one-token prefill (`rows`)
+            (8, False, 128, 128, "tpu"),    # the cache-less forward
+            (1, True, 8, 8, "tpu"),         # the tiny configuration
+            (1, True, 128, 64, "tpu"),
+            (1, True, 128, 128, "cpu")]:    # the CPU backend
+        assert select(window, slot_rows, k, v, backend) == "dense"
+
+
+def test_each_form_asks_the_selector_with_its_own_shape(tiny):
+    """What the KDA layer asks the selector, by form: only the tick's
+    call says its rows are the cache's own."""
+    cfg, model, params = tiny
+    asked = []
+    select = solar_open2.select_state_update_impl
+
+    def spy(window, slot_rows, k, v, backend):
+        asked.append((window, slot_rows, k, v, backend))
+        return select(window, slot_rows, k, v, backend)
+
+    cache = init_paged_cache(cfg, 3 * 16 + 1, 4, slots=3)
+    table = jnp.zeros((1, 16), jnp.int32)
+    forms = {
+        "forward": lambda: model.apply({"params": params},
+                                       jnp.ones((1, 8), jnp.int32)),
+        "prefill": lambda: model.apply(
+            {"params": params}, jnp.ones((1, 8), jnp.int32), cache=cache,
+            cache_index=jnp.int32(0), block_tables={
+                FULL: table, RECURRENT: jnp.asarray([1])},
+            state_len=jnp.asarray([5])),
+        "one_token_prefill": lambda: model.apply(
+            {"params": params}, jnp.ones((1, 1), jnp.int32), cache=cache,
+            cache_index=jnp.int32(0), block_tables={
+                FULL: table, RECURRENT: jnp.asarray([1])},
+            state_len=jnp.asarray([1])),
+        "tick": lambda: model.apply(
+            {"params": params}, jnp.ones((3, 1), jnp.int32), cache=cache,
+            cache_index=jnp.zeros((3,), jnp.int32),
+            block_tables={FULL: jnp.zeros((3, 16), jnp.int32)},
+            state_len=jnp.asarray([1, 0, 1])),
+    }
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(solar_open2, "select_state_update_impl", spy)
+        for name, fn in forms.items():
+            asked.clear()
+            jax.eval_shape(fn)
+            # once a KDA layer, each with the same question
+            assert len(asked) == 3 and len(set(asked)) == 1, name
+            window, slot_rows, k, v, backend = asked[0]
+            assert (k, v, backend) == (8, 8, "cpu")
+            assert slot_rows == (name == "tick"), name
+            assert select(window, slot_rows, 128, 128, "tpu") == (
+                "kernel" if name == "tick" else "dense"), name
+
+
+def test_the_ticks_kernel_gives_the_dense_forms_logits(tiny):
+    """The tick's form through the kernel against the dense form, on
+    one cache with a state in every row: the logits of the rows that
+    advance agree to 1e-5; the row that does not (a lane mid-chunk)
+    keeps its S and its conv tail bit for bit on both paths, and the
+    advanced rows' state agrees."""
+    cfg, model, params = tiny
+    MB = 16
+    cache = init_paged_cache(cfg, 3 * MB + 1, 4, slots=3)
+    keys = iter(jax.random.split(jax.random.key(8), 8))
+    for layer in cache[1:]:
+        for name in ("S", "conv"):
+            layer[name] = 0.5 * jax.random.normal(
+                next(keys), layer[name].shape, layer[name].dtype)
+    tables = jnp.asarray(np.arange(1, 3 * MB + 1).reshape(3, MB), jnp.int32)
+    args = (jnp.asarray([[5], [7], [9]], jnp.int32),)
+    kw = dict(cache_index=jnp.asarray([6, 3, 11], jnp.int32),
+              block_tables={FULL: tables}, state_len=jnp.asarray([1, 0, 1]))
+
+    def tick():
+        fn = jax.jit(lambda v, ids, c: model.apply(v, ids, cache=c, **kw))
+        return fn({"params": params}, *args, cache), jax.make_jaxpr(fn)(
+            {"params": params}, *args, cache)
+
+    (dense, dense_cache), plain = tick()
+    with state_kernel_forced():
+        (got, got_cache), kernel = tick()
+    assert "pallas_call" in str(kernel) and "pallas_call" not in str(plain)
+    scale = max(1.0, float(dense.std()))
+    for row in (0, 2):
+        assert float(jnp.abs(got[row] - dense[row]).max()) < 1e-5 * scale
+    for before, a, b in zip(cache[1:], dense_cache[1:], got_cache[1:]):
+        for name in ("S", "conv"):
+            np.testing.assert_array_equal(np.asarray(b[name][1]),
+                                          np.asarray(before[name][1]))
+            np.testing.assert_array_equal(np.asarray(a[name][1]),
+                                          np.asarray(before[name][1]))
+        for name in ("S", "conv"):
+            np.testing.assert_allclose(np.asarray(b[name][::2]),
+                                       np.asarray(a[name][::2]), atol=1e-6)
+
+
+def chunking(eng, cfg):
+    """Drive `eng` until one slot decodes while a long prompt prefills
+    in chunks; the chunking slot."""
+    short, long = requests(cfg, [(4, 30), (40, 3)], seed=4)
+    eng.submit(short)
+    drain(eng, until=lambda: len(short.tokens) >= 2)
+    eng.submit(long)
+    drain(eng, until=lambda: bool(eng._chunking))
+    (slot,) = eng._chunking
+    return slot
+
+
+def states(eng, slot):
+    return [{k: np.asarray(layer[k][slot]) for k in ("S", "conv")}
+            for layer in eng._cache[1:]]
+
+
+@pytest.mark.parametrize("fault", [None, "ticks_advance_a_chunking_lane"])
+def test_on_the_kernel_a_chunking_lane_keeps_its_state(tiny, fault):
+    """The engine's tick with the state update on the kernel: a slot
+    that prefills in chunks leaves the tick as it entered it, bit for
+    bit, and the record counts the rows the kernel visited (the live
+    one, three layers) of the nine the state holds. With the check's
+    fault planted (the tick advances every lane) the kernel visits the
+    chunking lane too and its state moves: the fault is one of the path
+    that runs."""
+    from bench_harness import solar_open2_faults as faults
+
+    cfg, _, params = tiny
+    planted = faults.system(fault) if fault else contextlib.nullcontext()
+    with state_kernel_forced(), planted:
+        eng = Engine(twin_of(cfg, f"kernel_{fault}"), {"params": params},
+                     EngineConfig(**ENGINE))
+        assert eng._state_form == "kernel"
+        slot = chunking(eng, cfg)
+        before = states(eng, slot)
+        eng._tick_device()
+        after = states(eng, slot)
+        if fault is None:
+            for was, now in zip(before, after):
+                for k in ("S", "conv"):
+                    np.testing.assert_array_equal(now[k], was[k])
+            assert eng._count_state() == {"state_rows_visited": 3,
+                                          "state_rows_held": 9}
+        else:
+            assert any(not np.array_equal(now["S"], was["S"])
+                       for was, now in zip(before, after))
+            assert eng._count_state() == {"state_rows_visited": 9,
+                                          "state_rows_held": 9}
+
+
+def test_the_tick_record_counts_the_state_rows_visited(tiny, engine):
+    """Every decode tick's record says how many state rows (rows x
+    recurrent layers) its update read and wrote: off a TPU the dense
+    form, every slot's rows; a step without a tick says nothing."""
+    cfg, _, _ = tiny
+    eng = engine
+    assert eng._state_form == "dense"
+    for r in requests(cfg, [(5, 4), (30, 3)], seed=6):
+        eng.submit(r)
+    drain(eng)
+    recs = eng.tickprof.tail(256)
+    ticks = [r["c"] for r in recs if "recurrent_rows" in r["c"]]
+    assert ticks and all(c["state_rows_visited"] == 9
+                         and c["state_rows_held"] == 9 for c in ticks)
+    assert all("state_rows_visited" not in r["c"] for r in recs
+               if "recurrent_rows" not in r["c"])

@@ -33,6 +33,7 @@ import hyperion_tpu.ops.pallas.fused_ce as ce_mod
 import hyperion_tpu.ops.pallas.fused_norm as norm_mod
 import hyperion_tpu.ops.pallas.grouped_matmul as grouped_mod
 import hyperion_tpu.ops.pallas.paged_attention as paged_mod
+import hyperion_tpu.ops.pallas.state_update as state_mod
 import hyperion_tpu.ops.pallas.window_attention as window_mod
 
 # the package re-exports the flash_attention function under the module's
@@ -72,10 +73,10 @@ def one_chip(topo):
 @pytest.fixture
 def mosaic(monkeypatch):
     """The session's backend is the CPU, where `_interpret()` picks the
-    interpreter; steer the six kernels to the compiled path here, in
+    interpreter; steer the seven kernels to the compiled path here, in
     the test, rather than through an option of the program."""
     for mod in (flash_mod, ce_mod, norm_mod, paged_mod, grouped_mod,
-                window_mod):
+                window_mod, state_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -410,6 +411,38 @@ class TestGroupedMatmulCompiles:
         ma = compiled.memory_analysis()
         # 8 MB here; a copied stack would be 252
         assert ma.temp_size_in_bytes < stack // 8
+
+
+class TestStateUpdateCompiles:
+    """The state-update kernel at the decode tick's shape of
+    `solar2.chat-saturated`: 128 slots' rows of 64 heads of [128, 128]
+    float32, S aliased through a donated argument, the visits counted
+    at run time."""
+
+    def test_the_selector_sends_the_ticks_shape(self):
+        from hyperion_tpu.models.solar_open2 import (
+            select_state_update_impl,
+        )
+
+        assert select_state_update_impl(1, True, 128, 128, "tpu") == "kernel"
+
+    def test_the_cells_tick_shape_in_place(self, mosaic, one_chip):
+        B, H, K = 128, 64, 128
+        A = jnp.float32
+        args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+                for shape, dt in (((B, H, K), A), ((B, H, K), A),
+                                  ((B, H, K), A), ((B, H, K), A),
+                                  ((B, H), A), ((B, H, K, K), A),
+                                  ((B,), jnp.bool_))]
+        compiled = jax.jit(state_mod.delta_rule_step_rows,
+                           donate_argnums=(5,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert 'cost_estimate":{"flops":"' in text
+        ma = compiled.memory_analysis()
+        # S (537 MB) goes out where it came in: no copy of it
+        assert ma.alias_size_in_bytes == B * H * K * K * 4
+        assert ma.temp_size_in_bytes < 1 << 20
 
 
 class TestFlashAttentionCompiles:
